@@ -377,8 +377,8 @@ def main(argv: Optional[list] = None) -> int:
 
         t0 = _time.time()
         # own session + group kill on timeout: the provisioning
-        # command's typical job is an XLA compile, a known wedge shape
-        # on relay-backed fleets — a hung grandchild must die with it
+        # command's typical job is an XLA compile that may spawn
+        # helpers — a hung grandchild must die with it
         proc = subprocess.Popen(
             ["/bin/sh", "-c", args.provision_cmd],
             start_new_session=True,

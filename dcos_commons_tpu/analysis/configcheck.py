@@ -105,7 +105,7 @@ _ENV_RECEIVERS = frozenset({"environ", "env", "_env", "task_env"})
 # sandbox contract plus ambient toolchain switches the deploy wrapper
 # exports (developer-guide §3)
 _AMBIENT_VARS = frozenset({
-    "SANDBOX", "REPO_ROOT", "JAX_PLATFORMS", "XLA_FLAGS",
+    "SANDBOX", "JAX_PLATFORMS", "XLA_FLAGS", "LIBTPU_INIT_ARGS",
     "PATH", "HOME", "PYTHONPATH",
 })
 # inline `VAR=value` assignments at the front of a task cmd

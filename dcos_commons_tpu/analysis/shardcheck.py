@@ -258,9 +258,12 @@ def _abstract_params(config):
         sharding_rules,
     )
 
-    shapes = jax.eval_shape(
-        functools.partial(init_params, config), jax.random.key(0)
-    )
+    # the key is made UNDER the eval_shape trace: a concrete
+    # jax.random.key(0) argument is a device array, i.e. a backend
+    # init, and this runs inside the scheduler process (PUT
+    # /v1/multi admission) — which must never own the chip its
+    # agent-launched workers need
+    shapes = jax.eval_shape(lambda: init_params(config, jax.random.key(0)))
     return shapes, sharding_rules(config)
 
 
@@ -382,9 +385,8 @@ def _mnist_profile(env, tpu, pod, task) -> Workload:
     from dcos_commons_tpu.parallel.mesh import MeshSpec
 
     config = MlpConfig()
-    shapes = jax.eval_shape(
-        functools.partial(mlp_init, config), jax.random.key(0)
-    )
+    # key made under the trace: see _abstract_params
+    shapes = jax.eval_shape(lambda: mlp_init(config, jax.random.key(0)))
     leaves = _walk_shapes(shapes, {}, "params")
     leaves += [
         AbstractLeaf(l.path.replace("params/", f"opt/{m}/", 1), l.shape,

@@ -18,6 +18,7 @@ from dcos_commons_tpu.models.transformer import (
     init_params,
     loss_fn,
     make_train_step,
+    train_state_shardings,
     forward,
     pipeline_forward,
     pipeline_loss_fn,
@@ -62,6 +63,7 @@ __all__ = [
     "sample_token",
     "loss_fn",
     "make_train_step",
+    "train_state_shardings",
     "mlp_forward",
     "mlp_init",
     "mlp_train_step",

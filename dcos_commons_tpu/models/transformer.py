@@ -18,6 +18,7 @@ Design choices (not a port of anything):
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Optional
@@ -54,9 +55,9 @@ class TransformerConfig:
     remat: bool = True
     # remat granularity: "full" recomputes the whole layer in backward;
     # "save-attn" additionally SAVES each layer's attention output
-    # (b*s*d bf16 per layer) so the flash kernel never re-runs.
-    # Measured on v5e the extra residual traffic made "save-attn"
-    # slightly SLOWER (0.486 vs 0.525 MFU), so "full" is the default.
+    # (b*s*d bf16 per layer) so the flash kernel never re-runs, at the
+    # price of extra residual traffic.  "full" is the default; which
+    # wins is not measured on the current toolchain (ROADMAP D4).
     remat_policy: str = "full"
     # mixed remat: the last k layers store activations instead of
     # recomputing (see _layer_scan) — each costs ~2.2 GB HBM at the
@@ -564,6 +565,52 @@ def pipeline_param_specs(params_or_shapes) -> Dict[str, Any]:
     return walk(params_or_shapes)
 
 
+def train_state_shardings(config: TransformerConfig, optimizer, mesh: Mesh):
+    """(params, optimizer-state) NamedSharding trees — the layout the
+    mesh train step pins with in/out_shardings.  A caller that
+    ``jax.device_put``s its fresh state onto them BEFORE the first
+    step gives every step the same input types; left default-placed,
+    step 0 runs on one type and step 1 on step 0's mesh-sharded
+    outputs, and the same step is traced and compiled twice."""
+    from dcos_commons_tpu.parallel.mesh import replicated as rep
+
+    params_shapes = jax.eval_shape(
+        lambda: init_params(config, jax.random.key(0))
+    )
+    p_shard = param_shardings(config, mesh, params_shapes)
+    opt_shapes = jax.eval_shape(optimizer.init, params_shapes)
+    replicated = NamedSharding(mesh, rep())
+
+    # optimizer state shardings: any leaf shaped like a param (whose
+    # path ends with that param's path) inherits the param's sharding;
+    # everything else (adam counts, scalars) is replicated
+    def path_key(path):
+        return tuple(
+            str(getattr(k, "key", getattr(k, "idx", "?"))) for k in path
+        )
+
+    flat_params = {
+        path_key(path): leaf.shape
+        for path, leaf in jax.tree_util.tree_flatten_with_path(params_shapes)[0]
+    }
+    flat_pshard = {
+        path_key(path): leaf
+        for path, leaf in jax.tree_util.tree_flatten_with_path(p_shard)[0]
+    }
+
+    def opt_leaf_sharding(path, leaf):
+        for ppath, pshape in flat_params.items():
+            if leaf.shape == pshape and path[-len(ppath):] == ppath:
+                return flat_pshard[ppath]
+        return replicated
+
+    opt_shard = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: opt_leaf_sharding(path_key(path), leaf),
+        opt_shapes,
+    )
+    return p_shard, opt_shard
+
+
 def make_train_step(
     config: TransformerConfig,
     optimizer,
@@ -593,6 +640,12 @@ def make_train_step(
     factor.
     """
     grad_accum = max(1, int(grad_accum))
+    kernel_mesh = (
+        functools.partial(
+            jax.sharding.use_abstract_mesh, mesh.abstract_mesh
+        )
+        if mesh is not None else contextlib.nullcontext
+    )
 
     def grads_of(params, tokens, targets):
         return jax.value_and_grad(
@@ -627,10 +680,14 @@ def make_train_step(
         return loss_sum / grad_accum, grads
 
     def step(params, opt_state, tokens, targets):
-        if grad_accum == 1:
-            loss, grads = grads_of(params, tokens, targets)
-        else:
-            loss, grads = accumulate(params, tokens, targets)
+        # the kernels find the mesh as the ambient abstract mesh while
+        # this body is traced and run per shard (parallel/mesh.py
+        # per_shard); without it XLA replicates them on every chip
+        with kernel_mesh():
+            if grad_accum == 1:
+                loss, grads = grads_of(params, tokens, targets)
+            else:
+                loss, grads = accumulate(params, tokens, targets)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = jax.tree.map(
             lambda p, u: (p + u.astype(p.dtype)), params, updates
@@ -642,41 +699,9 @@ def make_train_step(
 
     from dcos_commons_tpu.parallel.mesh import batch_spec, replicated as rep
 
-    params_shapes = jax.eval_shape(
-        functools.partial(init_params, config), jax.random.key(0)
-    )
-    p_shard = param_shardings(config, mesh, params_shapes)
-    opt_shapes = jax.eval_shape(optimizer.init, params_shapes)
+    p_shard, opt_shard = train_state_shardings(config, optimizer, mesh)
     batch_sharding = NamedSharding(mesh, batch_spec())
     replicated = NamedSharding(mesh, rep())
-
-    # optimizer state shardings: any leaf shaped like a param (whose
-    # path ends with that param's path) inherits the param's sharding;
-    # everything else (adam counts, scalars) is replicated
-    def path_key(path):
-        return tuple(
-            str(getattr(k, "key", getattr(k, "idx", "?"))) for k in path
-        )
-
-    flat_params = {
-        path_key(path): leaf.shape
-        for path, leaf in jax.tree_util.tree_flatten_with_path(params_shapes)[0]
-    }
-    flat_pshard = {
-        path_key(path): leaf
-        for path, leaf in jax.tree_util.tree_flatten_with_path(p_shard)[0]
-    }
-
-    def opt_leaf_sharding(path, leaf):
-        for ppath, pshape in flat_params.items():
-            if leaf.shape == pshape and path[-len(ppath):] == ppath:
-                return flat_pshard[ppath]
-        return replicated
-
-    opt_shard = jax.tree_util.tree_map_with_path(
-        lambda path, leaf: opt_leaf_sharding(path_key(path), leaf),
-        opt_shapes,
-    )
     return jax.jit(
         step,
         in_shardings=(p_shard, opt_shard, batch_sharding, batch_sharding),

@@ -42,9 +42,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
 
     # MXU rate note: operands stay in the INPUT dtype (bf16) with f32
     # accumulation — casting q/k/v to f32 before the dots would run the
-    # systolic array at the f32 rate, HALF the bf16 rate (measured 31
-    # vs 60+ TF/s fwd on v5e at these shapes).  The scale is applied to
-    # the f32 scores, not the bf16 operands, so no precision is lost.
+    # systolic array at the f32 rate, half the bf16 rate.  The scale is
+    # applied to the f32 scores, not the bf16 operands, so no precision
+    # is lost.
     q = q_ref[:]
     m = jnp.full((block_q, 1), _NEG, jnp.float32)
     l = jnp.zeros((block_q, 1), jnp.float32)
@@ -289,6 +289,7 @@ def _pallas_attention(q, k, v, causal, block_q, block_k, interpret,
         ],
         out_specs=out_specs,
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qr, kr, vr)
     out = result[0].reshape(batch, heads, seq_q, head_dim)
     if save_residuals:
@@ -334,6 +335,7 @@ def _pallas_attention_bwd(q, k, v, o, lse, do, causal, block_q, block_k,
                   lane_spec],
         out_specs=row_spec,
         interpret=interpret,
+        name="flash_attention_dq",
     )(qr, kr, vr, dor, lse, di)
 
     kcol_spec = pl.BlockSpec((None, block_k, head_dim), lambda b, j: (b, j, 0))
@@ -348,6 +350,7 @@ def _pallas_attention_bwd(q, k, v, o, lse, do, causal, block_q, block_k,
                   full_lanes, full_lanes],
         out_specs=[kcol_spec, kcol_spec],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(kr, vr, qr, dor, lse, di)
 
     shape = (batch, heads, seq_q, head_dim)
@@ -420,10 +423,32 @@ def flash_attention(
 ) -> jax.Array:
     """[batch, heads, seq, head_dim] attention, differentiable.
 
-    Dispatch: Pallas kernels on TPU (or when forced / interpreted for
-    tests); jnp reference otherwise.  Falls back when shapes do not
-    tile (ragged seq), keeping the call always-correct.
+    Dispatch (a shape rule, see :func:`_dispatch_pallas`): the Pallas
+    kernels on TPU (or when forced / interpreted for tests) when both
+    sequence lengths tile by the block sizes; the jnp reference
+    otherwise.  Which branch a compiled program took is visible in its
+    text: the kernels are ``tpu_custom_call``s named
+    ``flash_attention_{fwd,dq,dkv}`` (ops/introspect.py).
+
+    Under a mesh (``parallel.mesh.per_shard``) the kernels run on each
+    device's [batch, heads] shard: a ``pallas_call`` has no GSPMD
+    partitioning rule, and left to itself XLA all-gathers q/k/v and
+    runs the full global batch on every chip.
     """
-    return _make_attention(causal, block_q, block_k, force_pallas, interpret)(
-        q, k, v
+    from jax.sharding import PartitionSpec as P
+
+    from dcos_commons_tpu.parallel.mesh import (
+        BATCH_AXES,
+        ambient_axes,
+        per_shard,
     )
+
+    attn = _make_attention(causal, block_q, block_k, force_pallas, interpret)
+    if _dispatch_pallas(q, k, block_q, block_k, force_pallas, interpret):
+        spec = P(
+            ambient_axes(q.shape[0], BATCH_AXES),
+            ambient_axes(q.shape[1], ("tp",)),
+            None, None,
+        )
+        attn = per_shard(attn, in_specs=(spec, spec, spec), out_specs=spec)
+    return attn(q, k, v)

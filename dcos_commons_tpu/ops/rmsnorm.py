@@ -36,6 +36,7 @@ def _pallas_rms_norm(x, w, eps, block_rows, interpret):
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         interpret=interpret,
+        name="rms_norm_fwd",
     )(x, w)
 
 
@@ -81,8 +82,30 @@ def rms_norm(
     force_pallas: bool = False,
     interpret: bool = False,
 ) -> jax.Array:
-    """RMSNorm over the last axis; any leading shape. Differentiable."""
-    shape = x.shape
-    flat = x.reshape(-1, shape[-1])
-    out = _make_rms_norm(eps, block_rows, force_pallas, interpret)(flat, w)
-    return out.reshape(shape)
+    """RMSNorm over the last axis; any leading shape. Differentiable.
+
+    Dispatch (a shape rule): the Pallas kernel on TPU (or when forced /
+    interpreted) when the row count tiles by ``block_rows``, the jnp
+    reference otherwise; the kernel is the ``tpu_custom_call`` named
+    ``rms_norm_fwd``.  Under a mesh the leading (batch) axis stays
+    sharded and each device norms its own rows (see
+    ``ops.attention.flash_attention`` for why)."""
+    from jax.sharding import PartitionSpec as P
+
+    from dcos_commons_tpu.parallel.mesh import (
+        BATCH_AXES,
+        ambient_axes,
+        per_shard,
+    )
+
+    norm = _make_rms_norm(eps, block_rows, force_pallas, interpret)
+
+    def rows(x, w):
+        return norm(x.reshape(-1, x.shape[-1]), w).reshape(x.shape)
+
+    if x.ndim > 1:
+        spec = P(
+            ambient_axes(x.shape[0], BATCH_AXES), *[None] * (x.ndim - 1)
+        )
+        rows = per_shard(rows, in_specs=(spec, P()), out_specs=spec)
+    return rows(x, w)

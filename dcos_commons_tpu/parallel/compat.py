@@ -1,57 +1,23 @@
-"""JAX version compatibility for the parallelism layer.
+"""The three jax names the parallelism layer needs, in one place.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` into the
-top-level ``jax`` namespace (jax 0.6+), and its replication-checker
-kwarg was renamed ``check_rep`` -> ``check_vma`` in the same era.
-Everything in this repo (and its tests) imports it from here so one
-shim tracks both moves: prefer the top-level export, fall back to the
-experimental path on the older jax the container ships, translating
-``check_vma`` to the old spelling.
+The repo runs on one installation (jax 0.9): ``shard_map`` is the
+top-level export with the ``check_vma`` spelling, ``axis_size`` is a
+``lax`` primitive helper, and marking a value device-varying for
+shard_map's replication checker is ``lax.pcast(..., to="varying")``.
+Everything in this repo (and its tests) imports them from here.
 """
 
 from __future__ import annotations
 
-import functools
+from jax import lax
+from jax import shard_map  # noqa: F401 — re-exported
+from jax.lax import axis_size  # noqa: F401 — re-exported
 
-try:  # jax >= 0.6: top-level export, check_vma spelling
-    from jax import shard_map
-except ImportError:  # older jax: experimental home, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    @functools.wraps(_shard_map)
-    def shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
-
-try:  # jax >= 0.5: static mesh-axis size as a lax primitive helper
-    from jax.lax import axis_size
-except ImportError:
-    def axis_size(axis_name):
-        """Static size of a bound mesh axis (``lax.axis_size``
-        backport).  On old jax ``jax.core.axis_frame`` returns the
-        size directly (an int); newer intermediates return a frame
-        object carrying ``.size``."""
-        import jax.core as core
-
-        frame = core.axis_frame(axis_name)
-        return getattr(frame, "size", frame)
 
 def pvary(x, axis_names):
     """Mark ``x`` device-varying over ``axis_names`` for shard_map's
-    replication (vma) checker.  The primitive has gone through three
-    spellings — ``lax.pcast(..., to="varying")``, ``lax.pvary`` — and
-    does not exist at all on old jax, where no vma checker runs and
-    identity is correct."""
-    from jax import lax
-
-    pcast = getattr(lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, tuple(axis_names), to="varying")
-    fn = getattr(lax, "pvary", None)
-    if fn is not None:
-        return fn(x, tuple(axis_names))
-    return x
+    replication (vma) checker."""
+    return lax.pcast(x, tuple(axis_names), to="varying")
 
 
 __all__ = ["axis_size", "pvary", "shard_map"]

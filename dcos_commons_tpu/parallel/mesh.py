@@ -197,3 +197,45 @@ def batch_spec() -> PartitionSpec:
 
 def replicated() -> PartitionSpec:
     return PartitionSpec()
+
+
+# -- kernels under a mesh ---------------------------------------------
+#
+# A pallas_call is an opaque custom call to GSPMD: it has no
+# partitioning rule, so inside a sharded jit XLA all-gathers its
+# operands and every chip runs the kernel over the GLOBAL array.  The
+# ops therefore run their kernels through shard_map on each device's
+# shard.  The mesh is the AMBIENT abstract mesh (jax.sharding.
+# use_abstract_mesh / jax.set_mesh): make_train_step enters it while
+# its step is traced, so the model code between the step and the
+# kernels needs no mesh argument.
+
+
+def ambient_axes(size: int, axes: Sequence[str]) -> Optional[Tuple[str, ...]]:
+    """The ``axes`` of the ambient mesh a dimension of ``size`` shards
+    over: those bound with more than one device, if their product
+    divides ``size``.  None (= unsharded, the PartitionSpec spelling)
+    with no ambient mesh, inside an enclosing shard_map (its manual
+    axes are already per-shard), or when the dimension does not split
+    evenly — the kernel then sees that dimension whole."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.manual_axes:
+        return None
+    bound = tuple(a for a in axes if mesh.shape.get(a, 1) > 1)
+    parts = 1
+    for a in bound:
+        parts *= mesh.shape[a]
+    return bound if bound and size % parts == 0 else None
+
+
+def per_shard(fn, in_specs, out_specs):
+    """``fn`` run on each device's shard under the ambient mesh, or
+    ``fn`` itself when no spec shards anything."""
+    from dcos_commons_tpu.parallel.compat import shard_map
+
+    specs = list(in_specs) + [out_specs]
+    if not any(axis is not None for spec in specs for axis in spec):
+        return fn
+    return shard_map(
+        fn, in_specs=tuple(in_specs), out_specs=out_specs, check_vma=False
+    )

@@ -10,17 +10,21 @@ that could hide its reduce-scatters behind the backward pass instead
 serializes them at the end (the megatron/alpa overlap discipline,
 lost by default).
 
-:func:`enable_collective_overlap` prepends the known-good flag set to
-``XLA_FLAGS`` — BEFORE jax initializes its backend, which is why the
-worker calls it first thing in ``main()``.  Rules of engagement:
+:func:`enable_collective_overlap` prepends the flag set to
+``LIBTPU_INIT_ARGS`` — BEFORE jax initializes its backend, which is
+why the worker calls it first thing in ``main()``.  Rules of
+engagement:
 
-* TPU-only: the flags are libtpu vocabulary; an XLA:CPU build treats
-  unknown flags as fatal, so nothing is touched unless the
-  scheduler's env contract says this is a TPU task
-  (``TPU_GENERATION``) and ``JAX_PLATFORMS`` is not forcing cpu;
-* the operator wins: a flag already spelled in ``XLA_FLAGS`` (either
-  polarity) is never overridden — ours are PREPENDED and XLA lets the
-  later spelling win;
+* the flags are libtpu vocabulary and travel in libtpu's own variable:
+  jaxlib parses ``XLA_FLAGS`` itself and aborts the process on a name
+  it does not know (every ``xla_tpu_*`` flag), so ``XLA_FLAGS`` is
+  never touched;
+* TPU-only: nothing is added unless the scheduler's env contract says
+  this is a TPU task (``TPU_GENERATION``) and ``JAX_PLATFORMS`` is not
+  forcing cpu;
+* the operator wins: a flag already spelled in ``LIBTPU_INIT_ARGS``
+  (either polarity) is never overridden — ours are PREPENDED and the
+  later spelling wins;
 * ``TRAIN_XLA_OVERLAP=0`` opts the whole set out (the same escape
   hatch family as ``TRAIN_INFLIGHT_STEPS=0``).
 """
@@ -29,6 +33,8 @@ from __future__ import annotations
 
 import os
 from typing import List, MutableMapping, Optional
+
+LIBTPU_ENV = "LIBTPU_INIT_ARGS"
 
 # the latency-hiding scheduler set: fuse collectives with async
 # start/done pairs and let the scheduler float compute between them
@@ -45,7 +51,7 @@ OVERLAP_FLAGS = (
 def enable_collective_overlap(
     env: Optional[MutableMapping[str, str]] = None,
 ) -> List[str]:
-    """Prepend the overlap flag set to ``env['XLA_FLAGS']``.
+    """Prepend the overlap flag set to ``env['LIBTPU_INIT_ARGS']``.
 
     Returns the flags actually added (empty when opted out, not a TPU
     task, or every flag was already spelled by the operator).  Pass a
@@ -59,7 +65,7 @@ def enable_collective_overlap(
         return []
     if "cpu" in env.get("JAX_PLATFORMS", "").lower():
         return []
-    current = env.get("XLA_FLAGS", "")
+    current = env.get(LIBTPU_ENV, "")
     # token-wise name match: a substring test would let the operator's
     # --..._fusion_fuse_all_gather spelling silently suppress the
     # shorter --..._fusion flag they never set
@@ -71,7 +77,7 @@ def enable_collective_overlap(
         if flag.split("=", 1)[0] not in current_names
     ]
     if added:
-        env["XLA_FLAGS"] = " ".join(
+        env[LIBTPU_ENV] = " ".join(
             added + ([current] if current else [])
         )
     return added

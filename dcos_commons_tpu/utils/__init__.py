@@ -10,6 +10,7 @@ from dcos_commons_tpu.utils.checkpoint import (
     save_checkpoint,
 )
 from dcos_commons_tpu.utils.compile_cache import enable_compilation_cache
+from dcos_commons_tpu.utils.devices import claim_devices
 from dcos_commons_tpu.utils.microbatch import (
     MicroBatcher,
     WorkItem,
@@ -22,6 +23,7 @@ __all__ = [
     "MicroBatcher",
     "StaleWriterError",
     "WorkItem",
+    "claim_devices",
     "claim_incarnation",
     "enable_compilation_cache",
     "pack_mixed_rows",

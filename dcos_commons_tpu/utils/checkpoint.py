@@ -302,7 +302,12 @@ class AsyncCheckpointer:
     :func:`save_checkpoint` (write + fsync + rename + fenced prune)
     off the hot path.  The queue is BOUNDED: saving faster than the
     disk drains backpressures ``save()`` instead of hoarding
-    device-memory snapshots.
+    snapshots.  ONE snapshot at a time is device-resident: it is a
+    full copy of params + optimizer state in HBM, and at chip-filling
+    sizes a second one does not fit (the 872M flagship on a 16 GB v5e
+    died RESOURCE_EXHAUSTED at its second save).  The writer moves a
+    snapshot to host memory FIRST and frees the device copy before
+    the slow npz write; ``save()`` waits for that, not for the disk.
 
     Fencing: the writer claims an incarnation up front (or is handed
     one).  The first save that hits a newer incarnation's frontier
@@ -340,6 +345,8 @@ class AsyncCheckpointer:
         self._fence_lock = threading.Lock()
         self.fenced = False
         self._queue: "queue.Queue" = queue.Queue(maxsize=max(1, max_pending))
+        # held from the device copy until the writer has it on host
+        self._device_slot = threading.Semaphore(1)
         self._thread = threading.Thread(
             target=self._drain, name="async-ckpt", daemon=True
         )
@@ -381,19 +388,34 @@ class AsyncCheckpointer:
                 return
         elif self.fenced:
             return
-        snapshot = _snapshot_tree(tree)
         if multi_host:
-            snapshot = jax.tree.map(_host_array, snapshot)
-        self._queue.put((step, snapshot))
+            # on host before this returns: never a queued device copy
+            snapshot = jax.tree.map(_host_array, _snapshot_tree(tree))
+        else:
+            self._device_slot.acquire()
+            try:
+                snapshot = _snapshot_tree(tree)
+            except BaseException:
+                self._device_slot.release()
+                raise
+        self._queue.put((step, snapshot, not multi_host))
 
     def _drain(self) -> None:
+        import jax
+
         while True:
             item = self._queue.get()
             try:
                 if item is None:
                     return
-                step, snapshot = item
+                step, snapshot, on_device = item
+                del item
                 try:
+                    if on_device:
+                        try:
+                            snapshot = jax.tree.map(_host_array, snapshot)
+                        finally:
+                            self._device_slot.release()
                     self.saved.append(save_checkpoint(
                         self.directory, step, snapshot, keep=self.keep,
                         incarnation=self.incarnation,

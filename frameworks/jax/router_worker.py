@@ -22,7 +22,11 @@ deploy onto a CPU-only node in front of the TPU serve fleet.
 import os
 import sys
 
-sys.path.insert(0, os.environ.get("REPO_ROOT", "/root/repo"))
+# the package is found from this file (frameworks/jax/ sits two levels
+# under the checkout): tasks run with their sandbox as cwd
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)
+))))
 
 from dcos_commons_tpu.router.frontdoor import (  # noqa: E402
     RouterServer,

@@ -54,7 +54,11 @@ import sys
 import numpy as np
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
-sys.path.insert(0, os.environ.get("REPO_ROOT", "/root/repo"))
+# the package is found from this file (frameworks/jax/ sits two levels
+# under the checkout): tasks run with their sandbox as cwd
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)
+))))
 
 from dcos_commons_tpu.serve import (  # noqa: E402
     SERVESTATS_NAME,
@@ -191,9 +195,6 @@ def main() -> int:
     contract = initialize_from_env()
 
     import jax
-
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     from jax.experimental import multihost_utils
     from jax.sharding import NamedSharding
@@ -205,10 +206,14 @@ def main() -> int:
     from dcos_commons_tpu.parallel.mesh import MeshSpec, make_mesh
     from dcos_commons_tpu.serve.pool import PagedPoolModel, PoolModel
     from dcos_commons_tpu.utils import (
+        claim_devices,
         enable_compilation_cache,
         restore_checkpoint,
     )
 
+    # a tpu: pod that fell back to the CPU stops here
+    devices = claim_devices()
+    print(f"devices: {json.dumps(devices)}", flush=True)
     enable_compilation_cache()
     rank = contract["worker_id"]
     # a RELAUNCH reuses the sandbox: a stale ready file from the
@@ -219,9 +224,7 @@ def main() -> int:
         pass
     config = config_from_env(
         os.environ,
-        dtype=jnp.bfloat16 if os.environ.get(
-            "JAX_PLATFORMS"
-        ) != "cpu" else jnp.float32,
+        dtype=jnp.bfloat16 if devices["platform"] == "tpu" else jnp.float32,
         remat=False,
     )
     max_len = int(os.environ.get("MAX_LEN", "256"))

@@ -39,10 +39,15 @@ import json
 import math
 import os
 import sys
+import time
 
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
-sys.path.insert(0, os.environ.get("REPO_ROOT", "/root/repo"))
+# the package is found from this file (frameworks/jax/ sits two levels
+# under the checkout): tasks run with their sandbox as cwd
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)
+))))
 
 from dcos_commons_tpu.serve import (  # noqa: E402
     SERVESTATS_NAME,
@@ -74,23 +79,24 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-
     from dcos_commons_tpu.metrics.registry import Metrics
     from dcos_commons_tpu.models import config_from_env, init_params
     from dcos_commons_tpu.serve.pool import PagedPoolModel, PoolModel
     from dcos_commons_tpu.utils import (
+        claim_devices,
         enable_compilation_cache,
         restore_checkpoint,
     )
 
+    # what this server runs on, before anything is built on it: a tpu:
+    # pod that fell back to the CPU stops here instead of serving
+    devices = claim_devices()
+    print(f"devices: {json.dumps(devices)}", flush=True)
     enable_compilation_cache()
     config = config_from_env(
         os.environ,
-        dtype=jnp.bfloat16 if os.environ.get(
-            "JAX_PLATFORMS"
-        ) != "cpu" else jnp.float32,
+        # the dtype follows the platform JAX actually gave us
+        dtype=jnp.bfloat16 if devices["platform"] == "tpu" else jnp.float32,
         remat=False,
     )
     max_len = int(os.environ.get("MAX_LEN", "256"))
@@ -383,6 +389,7 @@ def main() -> int:
             extra_stats={"http_port": bound_port},
         )
     engine.register_metrics(metrics)
+    warm_t0 = time.monotonic()
     if paged is not None:
         pool.warm()
         shape = (
@@ -393,6 +400,20 @@ def main() -> int:
     else:
         pool.warm(prompt_len)
         shape = f"slot pool: {slots} slots x {max_len}"
+    # /stats and the sandbox snapshot state what answers the requests
+    # and what warming it cost (XLA compile, or persistent-cache read)
+    engine.annotate_stats(
+        platform=devices["platform"],
+        device_kind=devices["device_kind"],
+        device_count=devices["device_count"],
+        model={
+            "vocab": config.vocab, "d_model": config.d_model,
+            "n_layers": config.n_layers, "n_heads": config.n_heads,
+            "n_kv_heads": config.n_kv_heads, "d_ff": config.d_ff,
+            "dtype": jnp.dtype(config.dtype).name,
+        },
+        warm_s=round(time.monotonic() - warm_t0, 2),
+    )
     with open("ready", "w") as f:
         f.write("warm\n")
     print(

@@ -7,22 +7,27 @@ host mesh, and trains the flagship transformer with orbax-style
 checkpointing so PERMANENT gang recovery resumes from the last step.
 """
 
+import json
 import os
 import sys
 import time
 
-sys.path.insert(0, os.environ.get("REPO_ROOT", "/root/repo"))
+# the package is found from this file (frameworks/jax/ sits two levels
+# under the checkout): tasks run with their sandbox as cwd
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)
+))))
 
 
 def main() -> int:
     from dcos_commons_tpu.parallel.distributed import initialize_from_env
     from dcos_commons_tpu.parallel.overlap import enable_collective_overlap
 
-    # XLA's latency-hiding scheduler flags must land in XLA_FLAGS
-    # before the first jax backend init: without them several libtpu
-    # builds serialize the grad reduce-scatters the microbatched step
-    # was restructured to overlap (TPU-only; TRAIN_XLA_OVERLAP=0
-    # opts out)
+    # libtpu's latency-hiding scheduler flags must land in
+    # LIBTPU_INIT_ARGS before the first jax backend init: without them
+    # several libtpu builds serialize the grad reduce-scatters the
+    # microbatched step was restructured to overlap (TPU-only;
+    # TRAIN_XLA_OVERLAP=0 opts out)
     enable_collective_overlap()
     contract = initialize_from_env()
     if contract["num_slices"] > 1:
@@ -37,9 +42,6 @@ def main() -> int:
         )
 
     import jax
-
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import optax
 
@@ -47,11 +49,14 @@ def main() -> int:
         config_from_env,
         init_params,
         make_train_step,
+        train_state_shardings,
     )
+    from dcos_commons_tpu.ops.introspect import mosaic_calls
     from dcos_commons_tpu.parallel.mesh import mesh_from_env
     from dcos_commons_tpu.trace.steplog import InflightWindow, StepLog
     from dcos_commons_tpu.utils import (
         AsyncCheckpointer,
+        claim_devices,
         claim_incarnation,
         enable_compilation_cache,
         restore_checkpoint,
@@ -59,9 +64,13 @@ def main() -> int:
         synthetic_tokens,
     )
 
+    # what this worker runs on, before anything is built on it: a tpu:
+    # pod that fell back to the CPU stops here
+    devices = claim_devices()
+    print(f"devices: {json.dumps(devices)}", flush=True)
     # a recovered/replaced gang worker re-jits the identical train
     # step; the persistent cache turns that into a disk read
-    enable_compilation_cache()
+    cache_dir = enable_compilation_cache()
 
     steps = int(os.environ.get("TRAIN_STEPS", "100"))
     ckpt_dir = os.environ.get("CHECKPOINT_DIR", "checkpoints")
@@ -143,6 +152,14 @@ def main() -> int:
                     "sandboxes or restore a shared CHECKPOINT_DIR"
                 )
             start = int(starts[0])
+        # lay the state out as the step pins it BEFORE the first call:
+        # default-placed, step 0 would run on one input type and step
+        # 1 on step 0's mesh-sharded outputs — two traces, two XLA
+        # compiles of the same step on every cold start
+        params, opt_state = jax.device_put(
+            (params, opt_state),
+            train_state_shardings(config, optimizer, mesh),
+        )
         # the step-time fast path (ISSUE 7): donated buffers (the
         # params/opt-state update happens in place instead of paying a
         # full HBM copy per step), optional microbatched gradient
@@ -262,6 +279,40 @@ def main() -> int:
                         flush=True,
                     )
 
+        # build the step BEFORE the loop and say what was built: the
+        # Mosaic kernels in it with the operand shapes ONE device runs
+        # them over (ops/introspect.py — a step that dispatched to the
+        # jnp reference, or runs a kernel over the global batch, shows
+        # here and nowhere else), and what building cost — an XLA
+        # compile, or a read of the persistent cache — apart from the
+        # first step's own time.  The loop's first call then finds the
+        # executable in that cache.
+        batch_aval = jax.ShapeDtypeStruct((batch, config.max_seq), jnp.int32)
+        build_t0 = time.time()
+        lowered = step_fn.lower(params, opt_state, batch_aval, batch_aval)
+        kernels = mosaic_calls(lowered.as_text())
+        compile_t0 = time.time()
+        compiled = lowered.compile()
+        built = time.time()
+        print("train step: " + json.dumps({
+            "mesh": {a: n for a, n in mesh.shape.items() if n > 1},
+            "batch": batch,
+            "seq": config.max_seq,
+            "vocab": config.vocab,
+            "d_model": config.d_model,
+            "n_layers": config.n_layers,
+            "n_heads": config.n_heads,
+            "n_kv_heads": config.n_kv_heads,
+            "d_ff": config.d_ff,
+            "dtype": jnp.dtype(config.dtype).name,
+            "lower_s": round(compile_t0 - build_t0, 2),
+            "compile_s": round(built - compile_t0, 2),
+            "compile_cache": cache_dir,
+            "mosaic_calls": kernels,
+            "all_gathers": (compiled.as_text() or "").count(" all-gather("),
+        }), flush=True)
+        del lowered, compiled
+
         t0 = time.time()
         for i in range(start, steps):
             step_t0 = time.time()
@@ -297,18 +348,27 @@ def main() -> int:
                 i, loss, step_t0, blocked_s=blocked_s,
                 tokens=tokens.shape[0] * tokens.shape[1],
                 worker=contract["worker_id"],
+                platform=devices["platform"],
             ))
         note_drained(window.drain())
-        if checkpointer is not None:
-            ckpt_errors = checkpointer.close()
-            if ckpt_errors:
-                print(
-                    f"checkpoint writer errors: {ckpt_errors[:3]}",
-                    file=sys.stderr, flush=True,
-                )
+        # every local device should be holding its share of the state
+        # (the CPU backend reports no memory stats)
+        print("device memory: " + json.dumps([
+            {"id": d.id,
+             "bytes_in_use": (d.memory_stats() or {}).get("bytes_in_use")}
+            for d in jax.local_devices()
+        ]), flush=True)
+        ckpt_errors = checkpointer.close() if checkpointer is not None else []
         steplog.close()
         if batches is not None:
             batches.close()
+        if ckpt_errors:
+            # a run whose checkpoints did not land is not a finished
+            # run: recovery would resume from an older step than the
+            # log claims
+            raise RuntimeError(
+                f"checkpoint writer failed: {ckpt_errors[:3]}"
+            )
         dt = time.time() - t0
         tps = batch * config.max_seq * (steps - start) / max(dt, 1e-9)
         print(

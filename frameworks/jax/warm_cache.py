@@ -1,11 +1,11 @@
 """Provision-time compile-cache seeding (VERDICT r3 #8).
 
 Run ONCE when a host is provisioned (agent `--provision-cmd`, or by
-hand) with the fleet's shared `JAX_COMPILATION_CACHE_DIR`: it
-compiles the framework's standard programs at their deployed shapes
-into the persistent cache, so the FIRST deploy on a fresh host pays
-disk-cache-hit time instead of a full XLA compile — cold deploy ~=
-warm deploy.  Programs are compiled with `jax.jit(...).lower().
+hand): it compiles the framework's standard programs at their deployed
+shapes into the persistent cache the tasks will read — the directory
+`JAX_COMPILATION_CACHE_DIR` names, else the checkout's fixed default
+(utils/compile_cache.py) — so the FIRST deploy on a fresh host pays
+disk-cache-hit time instead of a full XLA compile.  Programs are compiled with `jax.jit(...).lower().
 compile()` (no data, no training) and selected by WARM_TARGETS
 (comma list; default: mnist).
 
@@ -18,7 +18,11 @@ import os
 import sys
 import time
 
-sys.path.insert(0, os.environ.get("REPO_ROOT", "/root/repo"))
+# the package is found from this file (frameworks/jax/ sits two levels
+# under the checkout): tasks run with their sandbox as cwd
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)
+))))
 
 
 def warm_mnist() -> None:
@@ -39,18 +43,19 @@ def warm_mnist() -> None:
 
 
 def main() -> int:
-    import jax
+    from dcos_commons_tpu.utils import (
+        claim_devices,
+        enable_compilation_cache,
+    )
 
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
-    from dcos_commons_tpu.utils import enable_compilation_cache
-
-    if not enable_compilation_cache():
-        print(
-            "warm_cache: no JAX_COMPILATION_CACHE_DIR set — nothing "
-            "to seed", file=sys.stderr,
-        )
-        return 1
+    # the cache key covers the device kind: seeding from a CPU fallback
+    # would write entries no TPU task ever reads
+    devices = claim_devices()
+    cache_dir = enable_compilation_cache()
+    print(
+        f"warm_cache: seeding {cache_dir} on {devices['platform']} "
+        f"({devices['device_kind']})", flush=True,
+    )
     targets = os.environ.get("WARM_TARGETS", "mnist").split(",")
     for target in targets:
         target = target.strip()

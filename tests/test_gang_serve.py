@@ -38,7 +38,7 @@ pods:
       worker:
         goal: RUNNING
         cmd: >-
-          JAX_PLATFORMS=cpu REPO_ROOT={{REPO_ROOT}}
+          JAX_PLATFORMS=cpu
           CHECKPOINT_DIR={{CKPT_DIR}} DATA_DIR={{DATA_DIR}}
           VOCAB=128 D_MODEL=64 N_LAYERS=2 SEQ_LEN=64 TRAIN_STEPS=4000
           python {{REPO_ROOT}}/frameworks/jax/train_worker.py

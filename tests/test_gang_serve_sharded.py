@@ -85,7 +85,6 @@ def test_sharded_serving_gang_failover_token_identical(tmp_path, quant):
             "PERMANENT_FAILURE_TIMEOUT_S": "1",
             "JAX_FRAMEWORK_DIR": os.path.join(REPO, "frameworks", "jax"),
             "TASKCFG_ALL_JAX_PLATFORMS": "cpu",
-            "TASKCFG_ALL_REPO_ROOT": REPO,
             # tiny flagship: 2-process Gloo mesh compiles in seconds
             "VOCAB": "64",
             "D_MODEL": "32",

@@ -299,6 +299,44 @@ def test_async_checkpointer_snapshot_isolated_from_donation(tmp_path):
             np.testing.assert_array_equal(want, np.asarray(have))
 
 
+def test_async_checkpointer_holds_one_device_snapshot(tmp_path, monkeypatch):
+    """A snapshot is a full copy of the train state in HBM; at
+    chip-filling sizes two do not fit (found on a v5e: the 872M
+    flagship died RESOURCE_EXHAUSTED at its second save).  However
+    fast save() is called and however slow the disk, at most one
+    snapshot is device-resident: the writer moves it to host before
+    the write, and the next save() waits for THAT."""
+    from dcos_commons_tpu.utils import checkpoint as ckpt
+
+    lock = threading.Lock()
+    resident = {"now": 0, "max": 0}
+
+    def snapshot_tree(tree):
+        with lock:
+            resident["now"] += 1
+            resident["max"] = max(resident["max"], resident["now"])
+        return tree
+
+    def host_array(leaf):
+        with lock:
+            resident["now"] -= 1
+        return leaf
+
+    def slow_disk(directory, step, tree, **kwargs):
+        time.sleep(0.1)
+        return f"step_{step}"
+
+    monkeypatch.setattr(ckpt, "_snapshot_tree", snapshot_tree)
+    monkeypatch.setattr(ckpt, "_host_array", host_array)
+    monkeypatch.setattr(ckpt, "save_checkpoint", slow_disk)
+    checkpointer = ckpt.AsyncCheckpointer(str(tmp_path), incarnation=1)
+    for step in range(5):
+        checkpointer.save(step, {"w": np.ones(4)})  # one leaf per tree
+    assert checkpointer.close() == []
+    assert checkpointer.saved == [f"step_{i}" for i in range(5)]
+    assert resident == {"now": 0, "max": 1}
+
+
 def test_zombie_writer_cannot_destroy_newer_frontier(tmp_path):
     """The ADVICE round-5 regression: recovery relaunches a trainer
     (new incarnation) while the superseded one still has a save in
@@ -399,9 +437,11 @@ def test_restore_prefers_newest_incarnation_at_same_step(tmp_path):
 
 
 def test_collective_overlap_flags_tpu_only_and_operator_wins():
-    """The latency-hiding flag set lands only for TPU tasks, never
-    clobbers an operator's explicit spelling, and honors the
-    TRAIN_XLA_OVERLAP opt-out."""
+    """The latency-hiding flag set lands only for TPU tasks, in
+    libtpu's own LIBTPU_INIT_ARGS (jaxlib aborts on an xla_tpu_* name
+    in XLA_FLAGS, which is therefore never touched), never clobbers an
+    operator's explicit spelling, and honors the TRAIN_XLA_OVERLAP
+    opt-out."""
     from dcos_commons_tpu.parallel.overlap import (
         OVERLAP_FLAGS,
         enable_collective_overlap,
@@ -410,37 +450,41 @@ def test_collective_overlap_flags_tpu_only_and_operator_wins():
     # not a TPU task: untouched
     env = {"JAX_PLATFORMS": "cpu", "TPU_GENERATION": "v5e"}
     assert enable_collective_overlap(env) == []
-    assert "XLA_FLAGS" not in env
+    assert "LIBTPU_INIT_ARGS" not in env and "XLA_FLAGS" not in env
     env = {}
     assert enable_collective_overlap(env) == []
 
-    # TPU task: the full set lands, idempotently
-    env = {"TPU_GENERATION": "v5e"}
+    # TPU task: the full set lands, idempotently, and XLA_FLAGS keeps
+    # exactly what the operator put there
+    mine = "--xla_force_host_platform_device_count=8"
+    env = {"TPU_GENERATION": "v5e", "XLA_FLAGS": mine}
     assert enable_collective_overlap(env) == list(OVERLAP_FLAGS)
     assert enable_collective_overlap(env) == []
     for flag in OVERLAP_FLAGS:
-        assert flag in env["XLA_FLAGS"]
+        assert flag in env["LIBTPU_INIT_ARGS"]
+    assert env["XLA_FLAGS"] == mine
 
     # the operator's polarity survives (their spelling stays, ours is
     # not added for that flag)
     theirs = "--xla_tpu_enable_async_collective_fusion=false"
-    env = {"TPU_GENERATION": "v5e", "XLA_FLAGS": theirs}
+    env = {"TPU_GENERATION": "v5e", "LIBTPU_INIT_ARGS": theirs}
     added = enable_collective_overlap(env)
     assert OVERLAP_FLAGS[0] not in added
-    assert env["XLA_FLAGS"].count(
+    assert env["LIBTPU_INIT_ARGS"].count(
         "--xla_tpu_enable_async_collective_fusion="
     ) >= 1
-    assert theirs in env["XLA_FLAGS"]
+    assert theirs in env["LIBTPU_INIT_ARGS"]
+    assert "XLA_FLAGS" not in env
 
     # name matching is token-wise: spelling only the LONGER
     # fuse_all_gather flag must not suppress the shorter fusion flag
     # (review r7: substring containment did exactly that)
     sub = "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=false"
-    env = {"TPU_GENERATION": "v5e", "XLA_FLAGS": sub}
+    env = {"TPU_GENERATION": "v5e", "LIBTPU_INIT_ARGS": sub}
     added = enable_collective_overlap(env)
     assert OVERLAP_FLAGS[0] in added
     assert OVERLAP_FLAGS[1] not in added
-    assert sub in env["XLA_FLAGS"]
+    assert sub in env["LIBTPU_INIT_ARGS"]
 
     # the opt-out knob
     env = {"TPU_GENERATION": "v5e", "TRAIN_XLA_OVERLAP": "0"}
@@ -453,7 +497,6 @@ def test_collective_overlap_flags_tpu_only_and_operator_wins():
 def _run_worker(sandbox, env_overrides):
     env = {
         **os.environ,
-        "REPO_ROOT": REPO,
         "JAX_PLATFORMS": "cpu",
         "SANDBOX": sandbox,
         "CHECKPOINT_DIR": os.path.join(sandbox, "ckpt"),

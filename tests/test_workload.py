@@ -211,6 +211,52 @@ def test_transformer_sharded_train_step():
                                    atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize(
+    "spec", [MeshSpec(dp=4, tp=2), MeshSpec(dp=8), MeshSpec(dp=2, fsdp=2, tp=2)]
+)
+def test_sharded_train_step_runs_kernels_per_shard(spec, monkeypatch):
+    """Under a mesh the Pallas kernels (interpreted here) run through
+    shard_map on each device's batch/head shard; the step must still
+    equal the single-device step — including the norm weight's
+    gradient, which shard_map has to sum over the batch shards."""
+    from dcos_commons_tpu.models import transformer as tmod
+
+    monkeypatch.setattr(
+        tmod, "flash_attention",
+        functools.partial(flash_attention, interpret=True),
+    )
+    monkeypatch.setattr(
+        tmod, "rms_norm",
+        functools.partial(rms_norm, interpret=True, block_rows=64),
+    )
+    config = TransformerConfig(
+        vocab=64, d_model=64, n_layers=2, n_heads=2, n_kv_heads=2,
+        d_ff=128, max_seq=128, dtype=jnp.float32,
+    )
+    params = init_params(config, jax.random.key(0))
+    optimizer = optax.sgd(0.1)
+    tokens, targets = synthetic_tokens(jax.random.key(1), 8, 128, 64)
+
+    def one_step(mesh):
+        step = make_train_step(config, optimizer, mesh=mesh, donate=False)
+        state = optimizer.init(params)
+        if mesh is None:
+            return step(params, state, tokens, targets)
+        with mesh:
+            lowered = step.lower(params, state, tokens, targets)
+            # the kernels sit in shard_map bodies, not in the GSPMD part
+            assert "sdy.manual_computation" in lowered.as_text()
+            return step(params, state, tokens, targets)
+
+    want_params, _, want_loss = one_step(None)
+    got_params, _, got_loss = one_step(make_mesh(spec))
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-5)
+    for got, want in zip(
+        jax.tree.leaves(got_params), jax.tree.leaves(want_params)
+    ):
+        np.testing.assert_allclose(got, want, atol=1e-5)
+
+
 def test_transformer_ring_attention_end_to_end():
     """sp=4: forward with ring attention == unsharded forward."""
     mesh = make_mesh(MeshSpec(sp=4, tp=2))
