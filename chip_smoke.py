@@ -20,8 +20,13 @@ the next one starts:
   train    frameworks/jax/svc.yml: deploy plan COMPLETE, a few steps
            whose loss is finite and falls, one steplog record per
            step, a checkpoint, and the Mosaic kernels present in the
-           worker's own train step at per-device shapes.
-  serve    frameworks/jax/svc_serve.yml: readiness gates on warm, the
+           worker's own train step at per-device shapes.  Full width,
+           ONE layer: the trainer's checkpoint (params + adam moments)
+           goes to the host's disk, 0.75 GiB a save at this depth and
+           4.9 GiB at the flagship's twelve, and a chip host may cap
+           the size of a file.
+  serve    frameworks/jax/svc_serve.yml (full width and depth: it
+           writes nothing but logs): readiness gates on warm, the
            address comes from /v1/endpoints/http, POST /generate
            answers single, batched and concurrent mixed-length
            requests with the requested token counts, greedy replies
@@ -46,6 +51,7 @@ import glob
 import json
 import math
 import os
+import resource
 import shutil
 import signal
 import subprocess
@@ -57,11 +63,16 @@ import urllib.request
 HERE = os.path.dirname(os.path.abspath(__file__))
 JAX_DIR = os.path.join(HERE, "frameworks", "jax")
 
-# the dense flagship (bench.py flagship_config): full width AND depth
+# the dense flagship (bench.py flagship_config)
 FLAGSHIP = {
     "VOCAB": "32768", "D_MODEL": "2048", "N_LAYERS": "12",
     "N_HEADS": "16", "N_KV_HEADS": "16", "D_FF": "8192",
 }
+# ... cut to one layer where it trains: every save puts params + both
+# adam moments (6 bytes a parameter) on the host's disk, and the first
+# chip host this ran on outside the builder's tool refused the
+# twelve-layer file with EFBIG
+TRAIN_N_LAYERS = "1"
 TOY = {
     "VOCAB": "64", "D_MODEL": "32", "N_LAYERS": "2",
     "N_HEADS": "2", "N_KV_HEADS": "2", "D_FF": "64",
@@ -223,6 +234,20 @@ def kernel_leg(tiny: bool, chips: int) -> int:
 
 
 # -- the parent: stdlib only -------------------------------------------
+
+
+def host_limits(workdir: str) -> str:
+    """What the host lets this run write: the legs keep their sandboxes
+    (logs, the trainer's checkpoint) under ``workdir`` and the compile
+    cache in the checkout."""
+    soft, _hard = resource.getrlimit(resource.RLIMIT_FSIZE)
+    return json.dumps({
+        "file_size_limit_bytes":
+            None if soft == resource.RLIM_INFINITY else soft,
+        "free_bytes": {
+            path: shutil.disk_usage(path).free for path in (workdir, HERE)
+        },
+    })
 
 
 def http_json(url: str, payload=None, timeout: float = 30.0):
@@ -436,13 +461,15 @@ def run_kernel_leg(tiny: bool, chips: int, child_env: dict) -> dict:
 def run_train_leg(tiny: bool, chips: int, workdir: str,
                   child_env: dict) -> dict:
     say("== train (frameworks/jax/svc.yml)")
-    model = dict(TOY if tiny else FLAGSHIP)
+    model = dict(TOY) if tiny else dict(FLAGSHIP, N_LAYERS=TRAIN_N_LAYERS)
     model["SEQ_LEN"] = "128" if tiny else "2048"
     env = {
         "TRAINER_COUNT": "1",
         "TPU_CHIPS_PER_HOST": str(chips),
         "TPU_TOPOLOGY": {1: "1x1", 4: "2x2"}[chips],
         "TRAIN_STEPS": str(TRAIN_STEPS),
+        # the step-1 save goes once the last step's has landed
+        "CHECKPOINT_KEEP": "1",
         # the worker lingers after training (goal RUNNING); bounded so
         # a leaked one cannot hold the chip for long
         "TASKCFG_ALL_KEEPALIVE_S": "120",
@@ -563,7 +590,10 @@ def run_train_leg(tiny: bool, chips: int, workdir: str,
             },
             "all_gathers": step.get("all_gathers"),
             "device_bytes_in_use": [m.get("bytes_in_use") for m in memory],
-            "checkpoints": sorted(os.path.basename(c) for c in checkpoints),
+            "checkpoints": {
+                os.path.basename(c): os.path.getsize(c)
+                for c in sorted(checkpoints)
+            },
         }
         say("  TRAIN " + json.dumps(report))
     finally:
@@ -722,6 +752,7 @@ def main(argv=None) -> int:
             f"--xla_force_host_platform_device_count={args.chips}"
         )
     workdir = tempfile.mkdtemp(prefix="chip-smoke-")
+    say("HOST " + host_limits(workdir))
     t0 = time.monotonic()
     try:
         device = run_kernel_leg(args.tiny_cpu, args.chips, child_env)
@@ -729,6 +760,7 @@ def main(argv=None) -> int:
         run_serve_leg(args.tiny_cpu, args.chips, workdir, child_env)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr, flush=True)
+        print(f"chip_smoke: host {host_limits(workdir)}", file=sys.stderr)
         if os.listdir(workdir):
             print(f"chip_smoke: logs kept in {workdir}", file=sys.stderr)
         else:

@@ -6,7 +6,10 @@ PERMANENT gang recovery = re-place the sub-slice, restore the latest
 step here, resume.
 
 Leaves that numpy cannot round-trip (bfloat16 and friends) are stored
-as float32 with the original dtype recorded; global jax.Arrays that
+as their raw bits (an unsigned integer of the same width) with the
+original dtype recorded, so the file costs what the state costs — a
+bf16 flagship's params + adam moments are 4.9 GiB, not the 9.8 GiB a
+widening to float32 wrote; global jax.Arrays that
 span non-addressable devices (multi-host pjit) are gathered to the
 host first.  The step stamp is "next step to run", so resume never
 double-applies an update.
@@ -158,9 +161,9 @@ def save_checkpoint(
         arr = _host_array(leaf)
         if arr.dtype.kind not in "fiub":
             # numpy's npz cannot round-trip extension dtypes (ml_dtypes
-            # bfloat16 reads back as void): widen to f32 and remember
+            # bfloat16 reads back as void): store the bits and remember
             dtypes[str(i)] = arr.dtype.name
-            arr = arr.astype(np.float32)
+            arr = arr.view(f"u{arr.dtype.itemsize}")
         arrays[f"leaf_{i}"] = arr
 
     if getattr(jax, "process_index", lambda: 0)() != 0:
@@ -187,10 +190,21 @@ def save_checkpoint(
         "dtypes": dtypes, "step": step,
         "incarnation": incarnation or 0,
     }).encode()
-    with open(tmp, "wb") as f:
-        np.savez(f, __meta__=np.frombuffer(meta, dtype=np.uint8), **arrays)
-        f.flush()
-        os.fsync(f.fileno())
+    try:
+        with open(tmp, "wb") as f:
+            np.savez(
+                f, __meta__=np.frombuffer(meta, dtype=np.uint8), **arrays
+            )
+            f.flush()
+            os.fsync(f.fileno())
+    except BaseException:
+        # a write the disk refused (full, or a file-size limit) must
+        # not go on holding the space the next save needs
+        try:
+            os.remove(tmp)
+        except OSError:
+            pass
+        raise
     os.replace(tmp, path)
     if keep > 0:
         # prune by the LISTED names (not reconstructed ones): a
@@ -256,10 +270,19 @@ def restore_checkpoint(
             f"no checkpoint for step {step} in {directory}"
         )
     data = np.load(os.path.join(directory, names[-1]))
+    stored_as_bits = {}
+    if "__meta__" in data.files:
+        stored_as_bits = json.loads(bytes(data["__meta__"])).get("dtypes", {})
     leaves, treedef = jax.tree.flatten(like)
     restored = []
     for i, leaf in enumerate(leaves):
         arr = data[f"leaf_{i}"]
+        if str(i) in stored_as_bits and arr.dtype.kind == "u":
+            # the bits of an extension dtype (older files hold such
+            # leaves widened to float32: the cast below covers those)
+            import ml_dtypes
+
+            arr = arr.view(getattr(ml_dtypes, stored_as_bits[str(i)]))
         if hasattr(leaf, "dtype"):
             restored.append(jnp.asarray(arr).astype(leaf.dtype))
         else:

@@ -7,6 +7,7 @@ the driver's dryrun exercises.
 """
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -578,3 +579,31 @@ def test_checkpoint_bf16_roundtrip(tmp_path):
         np.asarray(restored["w"].astype(jnp.float32)),
         np.full((4, 4), 1.5, np.float32),
     )
+    # stored as the 16 bits it is, not widened: the file costs what
+    # the state costs (the widened flagship checkpoint was refused by
+    # a chip host's file-size limit)
+    (name,) = [n for n in os.listdir(tmp_path) if n.endswith(".npz")]
+    stored = np.load(tmp_path / name)
+    assert {stored[k].dtype for k in stored.files if k != "__meta__"} == {
+        np.dtype("uint16"), np.dtype("int32"),
+    }
+    # a float32-widened leaf (what older files hold) still restores
+    widened = {k: stored[k] for k in stored.files}
+    for key in [k for k in widened if widened[k].dtype == np.uint16]:
+        widened[key] = np.full((4, 4), 1.5, np.float32)
+    np.savez(tmp_path / "step_0000000004.npz", **widened)
+    restored, step = restore_checkpoint(str(tmp_path), like)
+    assert step == 4 and restored["w"].dtype == jnp.bfloat16
+    assert float(restored["w"][0, 0]) == 1.5
+
+
+def test_checkpoint_refused_write_leaves_no_tmp(tmp_path, monkeypatch):
+    """A write the disk refuses raises and leaves nothing behind: a
+    stranded multi-GB .tmp would take the space the next save needs."""
+    def refuse(*_args, **_kwargs):
+        raise OSError(27, "File too large")
+
+    monkeypatch.setattr(np, "savez", refuse)
+    with pytest.raises(OSError):
+        save_checkpoint(str(tmp_path), 1, {"w": np.ones(4)})
+    assert os.listdir(tmp_path) == []
