@@ -1,0 +1,480 @@
+#!/usr/bin/env python3
+"""One run of one benchmark cell.
+
+    python3 perfbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+Deploys ``frameworks/jax/svc_serve.yml`` through the real scheduler,
+its agent and the serve worker at the cell's sizes, waits for the
+deploy plan, drives ``/generate`` with the cell's traffic from this
+process (which never touches JAX: the worker owns the chip), tears the
+service down, checks a sample of the served tokens against the
+benchmark's own reference in a child process, and prints one JSON line.
+
+The cell, its configuration, its traffic mix and its metrics are data:
+``BENCHMARK.json`` names them and the files under this directory hold
+them (see ``harness/manifest.py``).
+"""
+
+import time
+
+PROCESS_START = time.monotonic()
+PROCESS_START_WALL = time.time()
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import subprocess  # noqa: E402
+import sys  # noqa: E402
+import threading  # noqa: E402
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+CHECKOUT = os.path.dirname(HERE)
+sys.path.insert(0, CHECKOUT)
+
+from perfbench.harness import manifest as manifests  # noqa: E402
+from perfbench.harness.deploy import (  # noqa: E402
+    DeployFailure,
+    Deployment,
+    http_json,
+)
+from perfbench.harness.loadgen import LoadRun  # noqa: E402
+from perfbench.harness.traffic import schedule  # noqa: E402
+
+TASK = "server-0-api"
+PROGRAM_FILES = (
+    os.path.join("dcos_commons_tpu", "__main__.py"),
+    os.path.join("frameworks", "jax", "svc_serve.yml"),
+    os.path.join("frameworks", "jax", "serve_worker.py"),
+)
+
+
+class RunFailure(Exception):
+    pass
+
+
+def say(message: str) -> None:
+    print(message, flush=True)
+
+
+def sizing_env(model: dict, mix: dict) -> dict:
+    """The env that SIZES the deployment; every other knob of the
+    program stays at the repo's default."""
+    if model["head_dim"] * model["num_attention_heads"] != model["hidden_size"]:
+        raise RunFailure(
+            "the program derives head_dim as hidden_size / heads; "
+            "this configuration states another"
+        )
+    templated = {
+        "VOCAB": model["vocab_size"],
+        "D_MODEL": model["hidden_size"],
+        "N_LAYERS": model["num_hidden_layers"],
+    }
+    routed = {
+        "N_HEADS": model["num_attention_heads"],
+        "N_KV_HEADS": model["num_key_value_heads"],
+        "D_FF": model["intermediate_size"],
+        "N_EXPERTS": model.get("num_local_experts", 0),
+    }
+    env = {k: str(v) for k, v in templated.items()}
+    env.update({k: str(v) for k, v in mix["sizing_env"].items()})
+    env.update({f"TASKCFG_ALL_{k}": str(v) for k, v in routed.items()})
+    return env
+
+
+def deployment_env(bench, cell: dict, model: dict, mix: dict, seed: int,
+                   worker_dir: str) -> dict:
+    """What the scheduler is started with: the sizes, the benchmark's
+    worker entry in place of the program's, and what that entry needs
+    to build the seed's weights."""
+    env = sizing_env(model, mix)
+    env["JAX_FRAMEWORK_DIR"] = os.path.abspath(worker_dir)
+    env["TASKCFG_ALL_PERFBENCH_CONFIG_FILE"] = bench.config_path(
+        cell["config"]
+    )
+    env["TASKCFG_ALL_PERFBENCH_SEED"] = str(seed)
+    return env
+
+
+class StatsPoller:
+    """The worker's ``/stats`` once a second, stamped on this clock."""
+
+    def __init__(self, address: str):
+        self.address = address
+        self.samples = []
+        self._stop = threading.Event()
+        self._thread = threading.Thread(target=self._loop, daemon=True)
+
+    def _loop(self) -> None:
+        while not self._stop.is_set():
+            try:
+                _code, stats = http_json(
+                    f"http://{self.address}/stats", timeout=5
+                )
+                stats["_t"] = time.monotonic()
+                self.samples.append(stats)
+            except (OSError, ValueError):
+                pass
+            self._stop.wait(1.0)
+
+    def start(self) -> None:
+        self._thread.start()
+
+    def stop(self) -> None:
+        self._stop.set()
+        self._thread.join(timeout=10)
+
+
+def control(deployment: Deployment, path: str = "", payload=None):
+    with open(os.path.join(
+        deployment.sandbox(TASK), "perfbench_control.json"
+    )) as f:
+        port = json.load(f)["port"]
+    return http_json(f"http://127.0.0.1:{port}{path}", payload, timeout=120)[1]
+
+
+def check_device(device: dict, chips: int, peaks: dict) -> None:
+    """The measurement path runs on the accelerator the cell asks for,
+    of a kind whose peaks the benchmark knows, or not at all."""
+    if device["platform"] != "tpu":
+        raise RunFailure(f"no TPU: the worker runs on {device}")
+    if device["count"] < chips:
+        raise RunFailure(
+            f"the cell asks for {chips} chip(s), JAX sees {device['count']}"
+        )
+    if device["kind"] not in peaks:
+        raise RunFailure(
+            f"device kind {device['kind']!r} is not in the benchmark's "
+            "table of peaks"
+        )
+
+
+def check_answers(judged, vocab: int):
+    """Every judged request: answered 200 with exactly the tokens asked
+    for, each inside the vocabulary.  Returns (failed, reasons)."""
+    failed, reasons = 0, []
+    for o in judged:
+        why = ""
+        if o.status != 200:
+            why = f"status {o.status} {o.error}"
+        elif len(o.tokens) != o.request.max_new_tokens:
+            why = (f"{len(o.tokens)} tokens for "
+                   f"{o.request.max_new_tokens} asked")
+        elif not all(0 <= t < vocab for t in o.tokens):
+            why = "token outside the vocabulary"
+        if why:
+            failed += 1
+            if len(reasons) < 5:
+                reasons.append(f"request {o.request.index}: {why}")
+    return failed, reasons
+
+
+def run_children(argv, env, timeout_s):
+    """A child of this run (the reference check, the trace reduction):
+    its output goes through, its last line is its JSON result."""
+    proc = subprocess.run(
+        argv, env=env, cwd=CHECKOUT, stdout=subprocess.PIPE,
+        stderr=subprocess.PIPE, text=True, timeout=timeout_s,
+    )
+    lines = proc.stdout.splitlines()
+    for line in lines[:-1]:
+        say("  " + line)
+    if proc.returncode != 0 or not lines:
+        raise RunFailure(
+            f"{os.path.basename(argv[2])} exited {proc.returncode}:\n"
+            + "\n".join(lines[-5:]) + "\n" + proc.stderr[-3000:]
+        )
+    return json.loads(lines[-1])
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--workload", required=True)
+    parser.add_argument("--seed", type=int, required=True)
+    parser.add_argument("--seconds", type=float, required=True)
+    parser.add_argument("--trace", type=int, choices=(0, 1), required=True)
+    # what follows is for tests and for the builder's own chip runs;
+    # the driver passes none of it
+    parser.add_argument("--root", default=CHECKOUT, help=argparse.SUPPRESS)
+    parser.add_argument("--rehearse-cpu", action="store_true",
+                        help=argparse.SUPPRESS)
+    parser.add_argument("--worker-dir", default=os.path.join(HERE, "worker"),
+                        help=argparse.SUPPRESS)
+    parser.add_argument("--program-env", action="append", default=[],
+                        help=argparse.SUPPRESS)
+    parser.add_argument("--keep", help=argparse.SUPPRESS)
+    args = parser.parse_args(argv)
+    try:
+        return run(args)
+    except (RunFailure, DeployFailure) as e:
+        print(f"perfbench: FAILED: {e}", file=sys.stderr, flush=True)
+        return 1
+
+
+def run(args) -> int:
+    missing = [
+        p for p in PROGRAM_FILES
+        if not os.path.exists(os.path.join(CHECKOUT, p))
+    ]
+    if missing:
+        print(
+            "perfbench: FAILED: not inside a tpu-service-sdk checkout "
+            f"(missing {', '.join(missing)})", file=sys.stderr,
+        )
+        return 2
+    bench = manifests.Manifest(args.root)
+    cell = bench.cell(args.workload)
+    model = bench.config(cell["config"])
+    mix = bench.traffic(cell["traffic"])
+    params = bench.cell_params(cell["name"])
+    peaks = bench.peaks()
+    trace = bool(args.trace)
+    rehearse = args.rehearse_cpu
+
+    child_env = dict(os.environ)
+    child_env.pop("BENCH_RUN", None)
+    if rehearse:
+        say("CPU REHEARSAL: platform cpu, no device metric is written")
+        child_env["JAX_PLATFORMS"] = "cpu"
+        child_env.pop("XLA_FLAGS", None)
+    elif "tpu" not in child_env.get("JAX_PLATFORMS", "tpu").lower():
+        raise RunFailure(
+            f"no TPU: JAX_PLATFORMS={child_env['JAX_PLATFORMS']!r} keeps "
+            "JAX off the accelerator"
+        )
+
+    requests = schedule(
+        mix, params, model["vocab_size"], args.seconds, args.seed
+    )
+    env = deployment_env(bench, cell, model, mix, args.seed, args.worker_dir)
+    for item in args.program_env:
+        key, _, value = item.partition("=")
+        env[f"TASKCFG_ALL_{key}"] = value
+        say(f"program env (not the cell's): {key}={value}")
+
+    # the run's files: at a fixed place beside BENCHMARK.json (inside
+    # the checkout, unless a test brought a root of its own), emptied
+    workdir = os.path.join(bench.root, ".perfbench_run")
+    shutil.rmtree(workdir, ignore_errors=True)
+    deployment = Deployment(
+        CHECKOUT, os.path.join(CHECKOUT, "frameworks", "jax", "svc_serve.yml"),
+        workdir, cell["chips"], env, child_env,
+    )
+    poller = load = None
+    left, stuck = [], 0
+    try:
+        deployment.wait_listening()
+        listening_s = time.monotonic() - PROCESS_START
+        deploy_plan_s = deployment.wait_deploy_complete(TASK, 1100)
+        _code, endpoint = http_json(f"{deployment.url}/v1/endpoints/http")
+        address = endpoint["address"][0]
+        _code, stats = http_json(f"http://{address}/stats")
+        device = {
+            "platform": stats["platform"], "kind": stats["device_kind"],
+            "count": stats["device_count"],
+        }
+        say("device " + json.dumps(device))
+        if not rehearse:
+            check_device(device, cell["chips"], peaks)
+        for key, want in sizing_env(model, mix).items():
+            field = key.replace("TASKCFG_ALL_", "").lower()
+            if field in stats["model"] and str(stats["model"][field]) != want:
+                raise RunFailure(
+                    f"the worker built {stats['model']}, asked {key}={want}"
+                )
+        setup_s = time.monotonic() - PROCESS_START
+        say(f"setup_s {setup_s:.3f} (deploy plan COMPLETE after "
+            f"{deploy_plan_s:.3f}s, worker warm_s {stats.get('warm_s')})")
+
+        # ramp, window, drain
+        clients = int(mix["clients"])
+        start = time.monotonic() + mix["ramp_s"]
+        load = LoadRun(address, mix, requests, start, args.seconds, clients)
+        poller = StatsPoller(address)
+        say(f"ramp {mix['ramp_s']}s, window {args.seconds}s, "
+            f"{mix['loop']} loop, {params['rate_rps']} requests/s")
+        ramp_wall = time.time()
+        load.begin()
+        poller.start()
+        trace_dir = os.path.join(workdir, "trace")
+        trace_window = None
+        if trace:
+            begin = start + mix["trace_after_s"]
+            time.sleep(max(0.0, begin - time.monotonic()))
+            t0 = time.monotonic()
+            control(deployment, "/trace/start", {"dir": trace_dir})
+            time.sleep(mix["trace_s"])
+            control(deployment, "/trace/stop", {})
+            trace_window = (t0, time.monotonic())
+        judged = load.drain()
+        poller.stop()
+        info = control(deployment)
+        _code, final_stats = http_json(f"http://{address}/stats")
+    finally:
+        if poller is not None:
+            poller.stop()
+        left = deployment.stop()
+        if load is not None:
+            stuck = load.finish()
+    if left:
+        raise RunFailure(f"the service left processes alive: {left}")
+    if stuck:
+        raise RunFailure(f"{stuck} load threads did not end")
+
+    # where the set-up's time went, for PERF.md: nothing is judged on it
+    worker_at = info["started"] - PROCESS_START_WALL
+    say("set-up, seconds from this process's start: scheduler listening "
+        f"{listening_s:.2f}, worker process {worker_at:.2f}, " + ", ".join(
+            f"{name} {worker_at + t:.2f}"
+            for name, t in sorted(info["setup_times"].items(),
+                                  key=lambda kv: kv[1])
+        ) + f", ready {setup_s:.2f}")
+
+    window_compiles = [
+        e for e in info["compile_events"] if e["t"] >= ramp_wall
+    ]
+    say(f"compilations inside ramp and window: {len(window_compiles)}")
+    if window_compiles:
+        raise RunFailure(
+            f"{len(window_compiles)} compilation(s) inside the measured "
+            f"window: {window_compiles[:3]}"
+        )
+
+    failed, reasons = check_answers(judged, model["vocab_size"])
+    for reason in reasons:
+        say("failed: " + reason)
+    say(f"judged requests {len(judged)}, failed {failed}, "
+        f"all requests sent {len(load.outcomes)}")
+
+    place = {id(o): i for i, o in enumerate(load.outcomes)}
+    run_data = {
+        "cell": cell, "model": model, "mix": mix, "params": params,
+        "seconds": args.seconds,
+        "window": [load.start, load.end],
+        "outcomes": [
+            {
+                "phase": o.request.phase, "due": o.due, "sent": o.sent,
+                "done": o.done, "ok": o.status == 200,
+                "prompt_tokens": o.request.prompt_len,
+                "output_tokens": o.request.max_new_tokens,
+            } for o in load.outcomes
+        ],
+        "judged": [place[id(o)] for o in judged],
+        "stats_samples": poller.samples,
+        "final_stats": final_stats,
+        "setup_s": setup_s, "deploy_plan_s": deploy_plan_s,
+        "trace_window": trace_window,
+        "peaks": peaks.get(device["kind"]),
+        "device": device,
+    }
+
+    # the reference check, now that the chip is free
+    sample = pick_sample(load.outcomes, judged, load.start, load.end,
+                         mix, args.seed)
+    say(f"reference reads {len(sample)} requests: "
+        f"{sum(n == len(o.tokens) for o, n in sample)} whole, the others' "
+        f"first {mix['check_tokens']} tokens")
+    check_file = os.path.join(workdir, "check_in.json")
+    with open(check_file, "w") as f:
+        json.dump({
+            "config_file": bench.config_path(cell["config"]),
+            "seed": args.seed, "limits": params["correct_limits"],
+            "routing_margin": params.get("routing_margin", 0.0),
+            "wide_gap": params.get("wide_gap", 0.1),
+            "requests": [
+                {"prompt": o.request.tokens.tolist(), "served": o.tokens[:n]}
+                for o, n in sample
+            ],
+        }, f)
+    verdict = run_children(
+        [sys.executable, "-m", "perfbench.harness.check", check_file],
+        child_env, 900,
+    )
+    for name, (value, limit) in verdict["compared"].items():
+        say(f"correct: {name} {value:.6g} (limit {limit})")
+    correct = bool(verdict["correct"]) and failed == 0
+
+    device["memory_peak_bytes"] = info["memory_peak_bytes"]
+    result = {
+        "correct": correct, "attempted": len(judged), "failed": failed,
+        "metrics": {}, "device": device,
+    }
+    if trace:
+        cpu_env = dict(child_env, JAX_PLATFORMS="cpu")
+        reduced = run_children(
+            [sys.executable, "-m", "perfbench.harness.trace_reduce",
+             trace_dir, os.path.join(workdir, "trace.json")],
+            cpu_env, 600,
+        )
+        run_data["trace"] = reduced
+        if not rehearse:
+            device["busy_s"] = reduced["busy_s"]
+            device["window_s"] = reduced["window_s"]
+            result["breakdown"] = reduced["breakdown"]
+    else:
+        run_data["trace"] = None
+
+    kind = "per_layer" if trace else "end_to_end"
+    for metric in bench.metrics(kind, cell["name"]):
+        value = bench.reader(kind, metric["name"])(run_data)
+        if value is None:
+            continue
+        if rehearse and metric["source"] == "device_trace":
+            continue
+        result["metrics"][metric["name"]] = {
+            "value": value, "unit": metric["unit"],
+        }
+    if args.keep:
+        os.makedirs(args.keep, exist_ok=True)
+        with open(os.path.join(workdir, "run.json"), "w") as f:
+            json.dump(run_data, f)
+        for name in ("run.json", "trace.json", "check_in.json"):
+            path = os.path.join(workdir, name)
+            if os.path.exists(path):
+                shutil.copy(path, os.path.join(
+                    args.keep, f"{cell['name']}.{args.seed}.{name}"
+                ))
+    shutil.rmtree(workdir, ignore_errors=True)
+    say(json.dumps(result))
+    return 0
+
+
+def pick_sample(outcomes, judged, start: float, end: float, mix: dict,
+                seed: int):
+    """The answered requests the reference reads, as (outcome, served
+    tokens to read).  Whole: the longest of the window and a seeded
+    draw of ``check_draw`` more of it.  Over their first
+    ``check_tokens`` served tokens: every request that was in flight
+    at the instant of the window at which the most were.  Requests in
+    flight together hold different rows of the engine, so those cover
+    every row in use at that instant."""
+    import random
+
+    def answered(o):
+        return o.status == 200 and bool(o.tokens)
+
+    window = [o for o in judged if answered(o)]
+    if not window:
+        raise RunFailure("no request of the window was answered")
+    longest = max(window, key=lambda o: o.request.prompt_len + len(o.tokens))
+    rest = [o for o in window if o is not longest]
+    random.Random(seed).shuffle(rest)
+    whole = [longest] + rest[:int(mix["check_draw"])]
+
+    everything = [o for o in outcomes if answered(o)]
+
+    def in_flight(t):
+        return [o for o in everything if o.sent <= t < o.done]
+
+    # the number in flight rises only where a request is sent
+    instants = [o.sent for o in everything if start <= o.sent <= end]
+    together = max((in_flight(t) for t in instants), key=len, default=[])
+    head = int(mix["check_tokens"])
+    return [(o, len(o.tokens)) for o in whole] + [
+        (o, min(head, len(o.tokens))) for o in together
+        if not any(o is w for w in whole)
+    ]
+
+
+if __name__ == "__main__":
+    sys.exit(main())
