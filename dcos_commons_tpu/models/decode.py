@@ -22,6 +22,7 @@ drop-free server by construction.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -384,6 +385,18 @@ def decode_step(
     return logits, new_cache
 
 
+def _serve_ffn(config: TransformerConfig, layer, x: jax.Array) -> jax.Array:
+    """The FFN of a paged serving layer under its profile scope: the
+    dense block is ``mlp``; the mixture names its own two parts
+    (``moe_router`` / ``moe_experts``, models/moe.py)."""
+    scope = (
+        contextlib.nullcontext() if config.n_experts > 0
+        else jax.named_scope("mlp")
+    )
+    with scope:
+        return _ffn_block(config, layer, x, decode=True)[0]
+
+
 def paged_prefill_chunk(
     config: TransformerConfig,
     params: Params,
@@ -447,42 +460,53 @@ def paged_prefill_chunk(
         else:
             layer, ck, cv = inputs
             cks = cvs = None
-        normed = rms_norm(x, layer["attn_norm"])
-        q, k_new, v_new = _project_kv(config, layer, normed, positions)
-        if quantized:
-            kq, ks_new = _quantize_kv(k_new)
-            vq, vs_new = _quantize_kv(v_new)
-            ck = ck.at[phys, slot_off].set(kq[0])
-            cv = cv.at[phys, slot_off].set(vq[0])
-            cks = cks.at[phys, slot_off].set(ks_new[0])
-            cvs = cvs.at[phys, slot_off].set(vs_new[0])
-        else:
-            ck = ck.at[phys, slot_off].set(k_new[0])
-            cv = cv.at[phys, slot_off].set(v_new[0])
+        with jax.named_scope("attention"):
+            normed = rms_norm(x, layer["attn_norm"])
+            q, k_new, v_new = _project_kv(config, layer, normed, positions)
+        with jax.named_scope("kv_write"):
+            if quantized:
+                kq, ks_new = _quantize_kv(k_new)
+                vq, vs_new = _quantize_kv(v_new)
+                ck = ck.at[phys, slot_off].set(kq[0])
+                cv = cv.at[phys, slot_off].set(vq[0])
+                cks = cks.at[phys, slot_off].set(ks_new[0])
+                cvs = cvs.at[phys, slot_off].set(vs_new[0])
+            else:
+                ck = ck.at[phys, slot_off].set(k_new[0])
+                cv = cv.at[phys, slot_off].set(v_new[0])
         # gather the request's whole virtual sequence through the
         # table (scatter-then-gather: in-chunk keys ride the same
         # path as prior pages — one attention covers both)
-        k_all = ck[table].reshape(1, length, kv, hd)
-        v_all = cv[table].reshape(1, length, kv, hd)
-        qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(
-            1, c, kv, reps, hd
-        )
-        scores = jnp.einsum(
-            "bqkrd,blkd->bqkrl", qg, k_all.astype(jnp.float32)
-        )
-        if quantized:
-            ks_all = cks[table].reshape(1, length, kv)
-            vs_all = cvs[table].reshape(1, length, kv)
-            scores = scores * ks_all.transpose(0, 2, 1)[:, None, :, None, :]
-        scores = jnp.where(valid[None, :, None, None, :], scores, _NEG)
-        probs = jax.nn.softmax(scores, axis=-1)
-        if quantized:
-            probs = probs * vs_all.transpose(0, 2, 1)[:, None, :, None, :]
-        attn = jnp.einsum(
-            "bqkrl,blkd->bqkrd", probs, v_all.astype(jnp.float32)
-        ).astype(config.dtype)
-        x = x + attn.reshape(1, c, h * hd) @ dq(layer["wo"], x.dtype)
-        x, _moe_aux = _ffn_block(config, layer, x, decode=True)
+        with jax.named_scope("paged_gather"):
+            k_all = ck[table].reshape(1, length, kv, hd)
+            v_all = cv[table].reshape(1, length, kv, hd)
+            if quantized:
+                ks_all = cks[table].reshape(1, length, kv)
+                vs_all = cvs[table].reshape(1, length, kv)
+        with jax.named_scope("attention"):
+            qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(
+                1, c, kv, reps, hd
+            )
+            scores = jnp.einsum(
+                "bqkrd,blkd->bqkrl", qg, k_all.astype(jnp.float32)
+            )
+            if quantized:
+                scores = (
+                    scores * ks_all.transpose(0, 2, 1)[:, None, :, None, :]
+                )
+            scores = jnp.where(
+                valid[None, :, None, None, :], scores, _NEG
+            )
+            probs = jax.nn.softmax(scores, axis=-1)
+            if quantized:
+                probs = (
+                    probs * vs_all.transpose(0, 2, 1)[:, None, :, None, :]
+                )
+            attn = jnp.einsum(
+                "bqkrl,blkd->bqkrd", probs, v_all.astype(jnp.float32)
+            ).astype(config.dtype)
+            x = x + attn.reshape(1, c, h * hd) @ dq(layer["wo"], x.dtype)
+        x = _serve_ffn(config, layer, x)
         if quantized:
             return x, (ck, cv, cks, cvs)
         return x, (ck, cv)
@@ -499,14 +523,15 @@ def paged_prefill_chunk(
             layer_fn, x, (params["layers"], cache["k"], cache["v"])
         )
         new_cache = {"k": ck, "v": cv}
-    x = rms_norm(x, params["final_norm"])
-    x_last = lax.dynamic_index_in_dim(
-        x, true_len - 1, axis=1, keepdims=False
-    )
-    logits = jnp.einsum(
-        "bd,vd->bv", x_last.astype(jnp.float32),
-        params["embed"].astype(jnp.float32),
-    )
+    with jax.named_scope("logits"):
+        x = rms_norm(x, params["final_norm"])
+        x_last = lax.dynamic_index_in_dim(
+            x, true_len - 1, axis=1, keepdims=False
+        )
+        logits = jnp.einsum(
+            "bd,vd->bv", x_last.astype(jnp.float32),
+            params["embed"].astype(jnp.float32),
+        )
     return logits, new_cache
 
 
@@ -553,39 +578,44 @@ def paged_decode_step(
         else:
             layer, ck, cv = inputs
             cks = cvs = None
-        normed = rms_norm(x, layer["attn_norm"])
-        q, k_new, v_new = _project_kv(config, layer, normed, positions)
-        if quantized:
-            kq, ks_new = _quantize_kv(k_new)
-            vq, vs_new = _quantize_kv(v_new)
-            ck = ck.at[phys, slot_off].set(kq[:, 0])
-            cv = cv.at[phys, slot_off].set(vq[:, 0])
-            cks = cks.at[phys, slot_off].set(ks_new[:, 0])
-            cvs = cvs.at[phys, slot_off].set(vs_new[:, 0])
-        else:
-            ck = ck.at[phys, slot_off].set(k_new[:, 0])
-            cv = cv.at[phys, slot_off].set(v_new[:, 0])
-        k_all = ck[tables].reshape(b, length, kv, hd)
-        v_all = cv[tables].reshape(b, length, kv, hd)
-        qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(
-            b, kv, reps, hd
-        )
-        scores = jnp.einsum(
-            "bkrd,blkd->bkrl", qg, k_all.astype(jnp.float32)
-        )
-        if quantized:
-            ks_all = cks[tables].reshape(b, length, kv)
-            vs_all = cvs[tables].reshape(b, length, kv)
-            scores = scores * ks_all.transpose(0, 2, 1)[:, :, None, :]
-        scores = jnp.where(valid[:, :, None, :], scores, _NEG)
-        probs = jax.nn.softmax(scores, axis=-1)
-        if quantized:
-            probs = probs * vs_all.transpose(0, 2, 1)[:, :, None, :]
-        attn = jnp.einsum(
-            "bkrl,blkd->bkrd", probs, v_all.astype(jnp.float32)
-        ).astype(config.dtype)
-        x = x + attn.reshape(b, 1, h * hd) @ dq(layer["wo"], x.dtype)
-        x, _moe_aux = _ffn_block(config, layer, x, decode=True)
+        with jax.named_scope("attention"):
+            normed = rms_norm(x, layer["attn_norm"])
+            q, k_new, v_new = _project_kv(config, layer, normed, positions)
+        with jax.named_scope("kv_write"):
+            if quantized:
+                kq, ks_new = _quantize_kv(k_new)
+                vq, vs_new = _quantize_kv(v_new)
+                ck = ck.at[phys, slot_off].set(kq[:, 0])
+                cv = cv.at[phys, slot_off].set(vq[:, 0])
+                cks = cks.at[phys, slot_off].set(ks_new[:, 0])
+                cvs = cvs.at[phys, slot_off].set(vs_new[:, 0])
+            else:
+                ck = ck.at[phys, slot_off].set(k_new[:, 0])
+                cv = cv.at[phys, slot_off].set(v_new[:, 0])
+        with jax.named_scope("paged_gather"):
+            k_all = ck[tables].reshape(b, length, kv, hd)
+            v_all = cv[tables].reshape(b, length, kv, hd)
+            if quantized:
+                ks_all = cks[tables].reshape(b, length, kv)
+                vs_all = cvs[tables].reshape(b, length, kv)
+        with jax.named_scope("attention"):
+            qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(
+                b, kv, reps, hd
+            )
+            scores = jnp.einsum(
+                "bkrd,blkd->bkrl", qg, k_all.astype(jnp.float32)
+            )
+            if quantized:
+                scores = scores * ks_all.transpose(0, 2, 1)[:, :, None, :]
+            scores = jnp.where(valid[:, :, None, :], scores, _NEG)
+            probs = jax.nn.softmax(scores, axis=-1)
+            if quantized:
+                probs = probs * vs_all.transpose(0, 2, 1)[:, :, None, :]
+            attn = jnp.einsum(
+                "bkrl,blkd->bkrd", probs, v_all.astype(jnp.float32)
+            ).astype(config.dtype)
+            x = x + attn.reshape(b, 1, h * hd) @ dq(layer["wo"], x.dtype)
+        x = _serve_ffn(config, layer, x)
         if quantized:
             return x, (ck, cv, cks, cvs)
         return x, (ck, cv)
@@ -602,11 +632,12 @@ def paged_decode_step(
             layer_fn, x, (params["layers"], cache["k"], cache["v"])
         )
         new_cache = {"k": ck, "v": cv}
-    x = rms_norm(x, params["final_norm"])
-    logits = jnp.einsum(
-        "bd,vd->bv", x[:, 0].astype(jnp.float32),
-        params["embed"].astype(jnp.float32),
-    )
+    with jax.named_scope("logits"):
+        x = rms_norm(x, params["final_norm"])
+        logits = jnp.einsum(
+            "bd,vd->bv", x[:, 0].astype(jnp.float32),
+            params["embed"].astype(jnp.float32),
+        )
     return logits, new_cache
 
 

@@ -241,7 +241,10 @@ def moe_ffn(
     if impl == "sorted":
         return _moe_sorted(config, params, x, capacity, axis_name)
     if axis_name is None:
-        dispatch, combine, aux = _routing(config, params, x, capacity)
+        # the two scopes name the parts in a device profile (the
+        # serving programs' moe_router / moe_experts)
+        with jax.named_scope("moe_router"):
+            dispatch, combine, aux = _routing(config, params, x, capacity)
         # dispatch/combine matmuls run in the COMPUTE dtype: the
         # one-hot dispatch is exactly representable in bf16 and the
         # expert FFN consumes bf16 anyway.  Measured MFU-neutral on
@@ -249,13 +252,14 @@ def moe_ffn(
         # kept for dtype consistency with the expert FFN, NOT as a
         # perf lever (r5 sweep notes in bench.py bench_moe).
         dt = config.dtype
-        expert_in = jnp.einsum(
-            "tec,td->ecd", dispatch.astype(dt), x.astype(dt)
-        )
-        expert_out = _expert_ffn(config, params, expert_in)
-        y = jnp.einsum(
-            "tec,ecd->td", combine.astype(dt), expert_out.astype(dt)
-        )
+        with jax.named_scope("moe_experts"):
+            expert_in = jnp.einsum(
+                "tec,td->ecd", dispatch.astype(dt), x.astype(dt)
+            )
+            expert_out = _expert_ffn(config, params, expert_in)
+            y = jnp.einsum(
+                "tec,ecd->td", combine.astype(dt), expert_out.astype(dt)
+            )
         return y.astype(x.dtype), aux
 
     ep = axis_size(axis_name)

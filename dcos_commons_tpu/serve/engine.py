@@ -34,30 +34,91 @@ next tick, freeing its slot early), and an ``on_idle`` hook so an
 SPMD gang keeps meeting in collectives with no traffic.
 
 Serving load telemetry: ``stats()`` reports queue depth, active
-slots, KV occupancy, tokens/s and TTFT percentiles; ``
-register_metrics`` exports the gauges through a metrics registry
-(StatsD/Prometheus), and ``stats_path`` mirrors them to
-``servestats.json`` in the task sandbox, where the scheduler's
-``GET /v1/debug/serving`` collects them per pod — the load signal
-ROADMAP item 2 names for scale-out decisions.
+slots, KV occupancy, tokens/s and TTFT percentiles, and
+``stats_path`` mirrors them to ``servestats.json`` in the task
+sandbox, where the scheduler's ``GET /v1/debug/serving`` collects
+them per pod — the load signal ROADMAP item 2 names for scale-out
+decisions.
+
+The engine's own timeline (ISSUE 24) has one instrumentation point at
+each boundary of the loop and of a request's life, feeding three
+sinks.  ``_phase(name)`` wraps the parts of the loop thread's work
+that cost enough to ask about (``PHASES``): it adds the elapsed time
+to a cumulative per-phase counter and closes an ``engine.<phase>``
+host span through the injected ``annotate`` (the workers pass
+``jax.profiler.TraceAnnotation``; the engine stays jax-free).  The
+time between two phases goes to ``other``, so the counters partition
+the loop thread's life exactly.  They and the per-request sums (queue
+wait, prefill, decode) ride ``stats()`` under the one key ``loop``,
+all cumulative: a reader windows them by the difference of two
+samples.  With a ``trace.TraceRecorder`` of non-zero capacity the
+engine also records ``engine.queue`` / ``engine.prefill`` /
+``engine.decode`` under the caller's ``request`` span and one
+``engine.tick`` per tick.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
 import time
 from collections import deque
-from typing import Callable, List, Optional, Sequence
+from typing import Callable, ContextManager, List, Optional, Sequence
 
 import numpy as np
 
+from dcos_commons_tpu.trace import NULL_TRACER, Span, TraceRecorder
 from dcos_commons_tpu.utils.microbatch import QueueTimeoutError
 
 SERVESTATS_NAME = "servestats.json"
 _TTFT_WINDOW = 512      # TTFT samples kept for the percentile gauges
 _RATE_WINDOW_S = 10.0   # tokens/s sliding window
+
+# where the loop thread's life goes.  ``wait`` is the loop parked on
+# its cv (and the gang's idle tick); the two ``_call`` phases are the
+# injected device callables, whose time is the device's and the
+# blocking fetch's; ``decode_prep`` / ``decode_apply`` the host's work
+# on either side of the decode call, ``stats`` a ``servestats.json``
+# write.  ``other`` is every instant outside a ``with`` block:
+# admission, page IO, a chunk's padding and bookkeeping, the glue
+PHASES = (
+    "wait", "prefill_call", "decode_prep", "decode_call",
+    "decode_apply", "stats", "other",
+)
+_NO_SPAN = contextlib.nullcontext()
+
+
+class _Phase:
+    """``with engine._phase(name)``: one reusable object a phase (the
+    loop thread is the only user and a phase never nests).  Entry
+    charges ``other`` with the time since the previous phase ended,
+    exit charges the phase, so the counters leave no instant out."""
+
+    __slots__ = ("_engine", "_name", "_span_name", "_span")
+
+    def __init__(self, engine: "SlotEngine", name: str):
+        self._engine = engine
+        self._name = name
+        self._span_name = "engine." + name
+        self._span = _NO_SPAN
+
+    def __enter__(self) -> None:
+        engine = self._engine
+        now = time.monotonic()
+        engine._phase_s["other"] += now - engine._phase_t
+        engine._phase_t = now
+        self._span = engine._annotate(self._span_name)
+        self._span.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        self._span.__exit__(*exc)
+        engine = self._engine
+        now = time.monotonic()
+        engine._phase_s[self._name] += now - engine._phase_t
+        engine._phase_t = now
+        return False
 
 
 class _Group:
@@ -79,6 +140,8 @@ class _Row:
     __slots__ = (
         "tokens", "n", "temp", "eos", "seed", "out", "group",
         "arrival", "slot", "rid", "frozen",
+        "admit_t", "first_t", "first_tick", "chunks", "cached",
+        "trace_id", "parent_id",
     )
 
     def __init__(self, tokens, n, temp, eos, seed, group):
@@ -97,6 +160,17 @@ class _Row:
         # unfrozen, released to a peer, or activated after a splice
         self.rid = -1
         self.frozen = False
+        # the timeline: when the slot (and page budget) was granted
+        # and when the first token landed, on the arrival's clock; 0.0
+        # = not yet, and a spliced-in migrated row never gets an
+        # admit_t, which keeps it out of the per-request sums
+        self.admit_t = 0.0
+        self.first_t = 0.0
+        self.first_tick = 0    # the engine's decode calls at first_t
+        self.chunks = 0        # prefill calls this row took
+        self.cached = 0        # prompt pages the prefix cache served
+        self.trace_id = 0      # the request's trace (0 = recorder off)
+        self.parent_id = 0     # the caller's ``request`` span
 
 
 class SlotEngine:
@@ -115,10 +189,6 @@ class SlotEngine:
     """
 
     _row_cls = _Row
-    # gauges register_metrics exports (subclasses extend)
-    METRIC_KEYS = (
-        "queue_depth", "active_slots", "kv_occupancy", "tokens_per_s",
-    )
 
     def __init__(
         self,
@@ -134,6 +204,8 @@ class SlotEngine:
         stats_every_s: float = 1.0,
         log: Optional[Callable[[str], None]] = None,
         extra_stats: Optional[dict] = None,
+        annotate: Optional[Callable[[str], ContextManager]] = None,
+        tracer: TraceRecorder = NULL_TRACER,
     ):
         if slots < 1:
             raise ValueError(f"slot pool needs >= 1 slot, got {slots}")
@@ -148,6 +220,29 @@ class SlotEngine:
         self._stats_path = stats_path
         self._stats_every_s = stats_every_s
         self._log = log
+        # the timeline's sinks: ``annotate(name)`` opens a host span
+        # on the profiler's clock (jax.profiler.TraceAnnotation in the
+        # workers: a flag test outside a profiler session), ``tracer``
+        # is the span ring (capacity 0 = every call a no-op)
+        self._annotate = (
+            annotate if annotate is not None else (lambda name: _NO_SPAN)
+        )
+        self._tracer = tracer
+        # loop-thread only (stats() copies them under the cv)
+        self._phases = {
+            name: _Phase(self, name) for name in PHASES if name != "other"
+        }
+        self._phase_s = dict.fromkeys(PHASES, 0.0)
+        self._phase_t = time.monotonic()
+        self._decode_calls = 0
+        self._prefill_calls = 0
+        self._tick_rows = 0  # rows the tick under way decodes for
+        # per-request sums over rows that finished normally (cv)
+        self._queue_wait_s_sum = 0.0
+        self._prefill_s_sum = 0.0
+        self._decode_s_sum = 0.0
+        self._decode_tokens_sum = 0
+        self._requests_timed = 0
 
         self._cv = threading.Condition()
         self._queue: deque = deque()
@@ -196,12 +291,15 @@ class SlotEngine:
         max_new_tokens: int,
         temperature: float = 0.0,
         eos_id: Optional[int] = None,
+        trace_parent: Optional[Span] = None,
     ) -> List[List[int]]:
         """Queue ``rows`` (each its own slot, admitted independently
         as slots free up — a multi-row request may overlap several
         pool generations) and block until every row finished.  Raises
         ``QueueTimeoutError`` on saturation (handlers map it to 503),
-        ``ValueError`` on caller error (400)."""
+        ``ValueError`` on caller error (400).  ``trace_parent`` is the
+        caller's ``request`` span: the engine's spans of these rows
+        nest under it and share its trace id."""
         if not rows:
             raise ValueError("tokens must be non-empty")
         if max_new_tokens < 1:
@@ -232,6 +330,13 @@ class SlotEngine:
             for row in rows
         ]
         group.remaining = len(group.rows)
+        if self._tracer.enabled:
+            for r in group.rows:
+                if trace_parent is not None and trace_parent.trace_id:
+                    r.trace_id = trace_parent.trace_id
+                    r.parent_id = trace_parent.span_id
+                else:
+                    r.trace_id = self._tracer.new_trace_id()
         with self._cv:
             now = time.monotonic()
             if not self._has_work_locked():
@@ -353,6 +458,7 @@ class SlotEngine:
                 "tokens_out": self._tokens_out,
             }
             out["stats_age_s"] = round(stats_age, 4)
+            out["loop"] = self._loop_stats_locked()
             out.update(self._stats_extra_locked())
             out.update(self._extra_stats)
         if ttft:
@@ -376,16 +482,28 @@ class SlotEngine:
     def _stats_extra_locked(self) -> dict:
         return {}
 
-    def register_metrics(self, metrics, prefix: str = "serving") -> None:
-        """Export the load gauges through a metrics registry
-        (metrics/registry.py): queue depth, active slots, KV
-        occupancy, tokens/s — scraped as gauges / pushed via StatsD
-        (the paged engine adds page-budget and prefix-cache gauges)."""
-        for key in self.METRIC_KEYS:
-            metrics.gauge(
-                f"{prefix}.{key}",
-                lambda key=key: self.stats()[key],
-            )
+    def _loop_stats_locked(self) -> dict:
+        """The timeline's counters, all cumulative since the engine
+        started: a reader takes the difference of two samples.
+        ``phase_s`` partitions the loop thread's life (it lags by the
+        phase under way); the per-request sums cover rows that
+        finished normally, and ``queue_wait + prefill + decode`` of a
+        row is its retire time minus its arrival."""
+        return {
+            "decode_calls": self._decode_calls,
+            "prefill_calls": self._prefill_calls,
+            "phase_s": {
+                k: round(v, 6) for k, v in self._phase_s.items()
+            },
+            "queue_wait_s_sum": round(self._queue_wait_s_sum, 6),
+            "prefill_s_sum": round(self._prefill_s_sum, 6),
+            "decode_s_sum": round(self._decode_s_sum, 6),
+            "decode_tokens_sum": self._decode_tokens_sum,
+            "requests_timed": self._requests_timed,
+        }
+
+    def _phase(self, name: str) -> _Phase:
+        return self._phases[name]
 
     # -- the loop ----------------------------------------------------
 
@@ -394,6 +512,7 @@ class SlotEngine:
         # through the outer loop once per idle TICK, and the terminal
         # flush must happen once per idle PERIOD, not at 20 Hz forever
         flushed_idle = False
+        self._phase_t = time.monotonic()  # the thread's life starts
         while True:
             idle = False
             flush_now = False
@@ -412,10 +531,12 @@ class SlotEngine:
                         flush_now = True
                         break
                     if self._on_idle is None:
-                        self._cv.wait()
+                        with self._phase("wait"):
+                            self._cv.wait()
                         self._last_tick_mono = time.monotonic()
                     else:
-                        self._cv.wait(timeout=self._idle_every_s)
+                        with self._phase("wait"):
+                            self._cv.wait(timeout=self._idle_every_s)
                         self._last_tick_mono = time.monotonic()
                         if not self._has_work_locked():
                             break  # fire on_idle OUTSIDE the lock
@@ -429,10 +550,18 @@ class SlotEngine:
                 self._write_stats(force=True)
                 continue
             if idle:
-                self._safe_idle()
+                with self._phase("wait"):
+                    self._safe_idle()
                 continue
             try:
-                self._work_tick(admits)
+                self._tick_rows = 0
+                chunks_before = self._prefill_calls
+                with self._tracer.span("engine.tick", track="loop") as tick:
+                    self._work_tick(admits)
+                    tick.set_attr("rows", self._tick_rows)
+                    tick.set_attr(
+                        "chunks", self._prefill_calls - chunks_before
+                    )
                 self._write_stats()
             except Exception as e:  # noqa: BLE001 — fail FAST, not silent
                 # a bookkeeping bug (bad decode shape, broken stats
@@ -462,18 +591,32 @@ class SlotEngine:
             if row.group.abandoned:
                 continue
             row.slot = self._free.pop()
+            self._admitted_locked(row)
             admits.append(row)
         return admits
+
+    def _admitted_locked(self, row: _Row) -> None:
+        """The row holds its slot (and its page budget) from now on:
+        its queue wait is over."""
+        row.admit_t = time.monotonic()
+        self._tracer.interval(
+            "engine.queue", row.arrival, row.admit_t,
+            trace_id=row.trace_id, parent_id=row.parent_id, track="req",
+            rid=row.rid,
+        )
 
     def _admit_all(self, admits: List[_Row]) -> None:
         for i, row in enumerate(admits):
             padded = np.zeros((1, self._prompt_len), np.int32)
             padded[0, : len(row.tokens)] = row.tokens
             try:
-                first = int(self._prefill_fn(
-                    padded, slot=row.slot, true_len=len(row.tokens),
-                    temp=row.temp, seed=row.seed,
-                ))
+                with self._phase("prefill_call"):
+                    self._prefill_calls += 1
+                    row.chunks += 1
+                    first = int(self._prefill_fn(
+                        padded, slot=row.slot, true_len=len(row.tokens),
+                        temp=row.temp, seed=row.seed,
+                    ))
             except Exception as e:  # noqa: BLE001 — fan out, keep serving
                 with self._cv:
                     # the popped-but-not-installed rows (this one and
@@ -493,11 +636,24 @@ class SlotEngine:
             with self._cv:
                 self._apply_admit_locked(row, first, now)
 
-    def _apply_admit_locked(self, row: _Row, first: int, now: float):
+    def _first_token_locked(self, row: _Row, first: int, now: float):
+        """Admission and TTFT are counted where the first token
+        lands; the row's prefill is over."""
         self._admitted += 1
         self._ttft.append(now - row.arrival)
         row.out.append(first)
+        row.first_t = now
+        row.first_tick = self._decode_calls
         self._count_tokens_locked(1, now)
+        self._tracer.interval(
+            "engine.prefill", row.admit_t or row.arrival, now,
+            trace_id=row.trace_id, parent_id=row.parent_id, track="req",
+            rid=row.rid, prompt_tokens=len(row.tokens),
+            chunks=row.chunks, cached_pages=row.cached,
+        )
+
+    def _apply_admit_locked(self, row: _Row, first: int, now: float):
+        self._first_token_locked(row, first, now)
         if self._row_finished(row, first, int(len(row.tokens))):
             self._retire_locked(row)
             return
@@ -526,7 +682,7 @@ class SlotEngine:
         return ()
 
     def _decode_tick(self) -> None:
-        with self._cv:
+        with self._phase("decode_prep"), self._cv:
             extra = self._decode_prep_locked()
             active = self._active
             # who this tick actually computes for: a row installed
@@ -541,18 +697,21 @@ class SlotEngine:
                 for r in self._rows
             ]
         try:
-            nxt = np.asarray(self._decode_fn(
-                self._tok.copy(), self._pos.copy(),
-                self._temps.copy(), self._seeds.copy(),
-                *extra, active,
-            ))
+            with self._phase("decode_call"):
+                self._decode_calls += 1
+                self._tick_rows = active
+                nxt = np.asarray(self._decode_fn(
+                    self._tok.copy(), self._pos.copy(),
+                    self._temps.copy(), self._seeds.copy(),
+                    *extra, active,
+                ))
         except Exception as e:  # noqa: BLE001 — fan out, keep serving
             with self._cv:
                 self._fail_all_locked(e)
             return
         now = time.monotonic()
         merged = None
-        with self._cv:
+        with self._phase("decode_apply"), self._cv:
             self._apply_decode_locked(nxt, now, dispatched)
             if self._active >= 2 and not self._merge_logged:
                 self._merge_logged = True
@@ -603,7 +762,46 @@ class SlotEngine:
             or pos >= self._max_len  # slot cache exhausted
         )
 
+    def _end_of(self, row: _Row) -> str:
+        """Why a retiring row ended.  The first three are the normal
+        ends (``_row_finished``); a migrated row's group already
+        carries its redirect."""
+        if row.group.abandoned:
+            return "abandoned"
+        if row.group.error is not None:
+            return "migrated"
+        if row.eos is not None and row.out and row.out[-1] == row.eos:
+            return "eos"
+        if len(row.out) >= row.n:
+            return "max_tokens"
+        return "max_len"
+
+    def _close_timeline_locked(self, row: _Row) -> None:
+        """The retiring row's share of the per-request sums and its
+        ``engine.decode`` span.  Only a row that was admitted HERE,
+        got its first token and ended normally is summed: abandoned
+        and migrated rows (either direction) are not a whole request
+        of this engine.  A row retired before its first token has
+        nothing left to record."""
+        if not row.first_t:
+            return
+        now = time.monotonic()
+        end = self._end_of(row)
+        if row.admit_t and end not in ("abandoned", "migrated"):
+            self._queue_wait_s_sum += row.admit_t - row.arrival
+            self._prefill_s_sum += row.first_t - row.admit_t
+            self._decode_s_sum += now - row.first_t
+            self._decode_tokens_sum += len(row.out) - 1
+            self._requests_timed += 1
+        self._tracer.interval(
+            "engine.decode", row.first_t, now,
+            trace_id=row.trace_id, parent_id=row.parent_id, track="req",
+            rid=row.rid, tokens=len(row.out),
+            ticks=self._decode_calls - row.first_tick, end=end,
+        )
+
     def _retire_locked(self, row: _Row) -> None:
+        self._close_timeline_locked(row)
         slot = row.slot
         if self._rows[slot] is row:
             self._rows[slot] = None
@@ -668,11 +866,12 @@ class SlotEngine:
             return
         self._stats_written = now
         try:
-            tmp = self._stats_path + ".tmp"
-            # durcheck: dur-file-discipline=telemetry mirror: loss on power failure is acceptable, the rename alone keeps readers partial-free
-            with open(tmp, "w", encoding="utf-8") as f:
-                json.dump(self.stats(), f)
-            os.replace(tmp, self._stats_path)
+            with self._phase("stats"):
+                tmp = self._stats_path + ".tmp"
+                # durcheck: dur-file-discipline=telemetry mirror: loss on power failure is acceptable, the rename alone keeps readers partial-free
+                with open(tmp, "w", encoding="utf-8") as f:
+                    json.dump(self.stats(), f)
+                os.replace(tmp, self._stats_path)
         except OSError:
             pass  # sdklint: disable=swallowed-exception — telemetry must never take the server down
 
@@ -730,10 +929,6 @@ class PagedEngine(SlotEngine):
     """
 
     _row_cls = _PagedRow
-    METRIC_KEYS = SlotEngine.METRIC_KEYS + (
-        "kv_pages_free", "prefix_cache_hit_rate",
-        "prefill_chunk_backlog", "migrations_in", "migrations_out",
-    )
 
     def __init__(
         self,
@@ -819,8 +1014,10 @@ class PagedEngine(SlotEngine):
             for i, entry in enumerate(admission.matched):
                 row.table[i] = entry.page
             # prefill resumes past the cache-served pages
-            row.fill_pos = len(admission.matched) * self._page_tokens
-            row.registered_to = len(admission.matched)
+            row.cached = len(admission.matched)
+            row.fill_pos = row.cached * self._page_tokens
+            row.registered_to = row.cached
+            self._admitted_locked(row)
             admits.append(row)
         return admits
 
@@ -878,10 +1075,13 @@ class PagedEngine(SlotEngine):
                 table = row.table.copy()
             padded = np.zeros((1, self._chunk_tokens), np.int32)
             padded[0, :clen] = row.tokens[start:start + clen]
-            first = self._prefill_fn(
-                padded, slot=row.slot, table=table, start=start,
-                true_len=clen, temp=row.temp, seed=row.seed,
-            )
+            with self._phase("prefill_call"):
+                self._prefill_calls += 1
+                row.chunks += 1
+                first = self._prefill_fn(
+                    padded, slot=row.slot, table=table, start=start,
+                    true_len=clen, temp=row.temp, seed=row.seed,
+                )
             now = time.monotonic()
             handoff_row = None
             with self._cv:
@@ -897,10 +1097,7 @@ class PagedEngine(SlotEngine):
                         # is done.  Count admission/TTFT HERE (the
                         # destination replays neither), fence the row
                         # and ship it to a decode pod outside the cv
-                        self._admitted += 1
-                        self._ttft.append(now - row.arrival)
-                        row.out.append(int(first))
-                        self._count_tokens_locked(1, now)
+                        self._first_token_locked(row, int(first), now)
                         if self._row_finished(row, int(first), plen):
                             self._prefilling.remove(row)
                             self._retire_locked(row)
@@ -1205,6 +1402,8 @@ class PagedEngine(SlotEngine):
             group.remaining = 1
             row.rid = self._next_rid
             self._next_rid += 1
+            if self._tracer.enabled:
+                row.trace_id = self._tracer.new_trace_id()
             row.slot = self._free.pop()
             row.admission = admission
             row.table = np.zeros(self._pages_per_row, np.int32)
@@ -1257,6 +1456,11 @@ class PagedEngine(SlotEngine):
             row.frozen = False
             self._register_pages_locked(row)
             self._migrated_in += 1
+            if row.out:
+                # its decode HERE starts now (the span's start; with
+                # no admit_t it stays out of the per-request sums)
+                row.first_t = time.monotonic()
+                row.first_tick = self._decode_calls
             plen = len(row.tokens)
             if row.fill_pos < plen:
                 self._prefilling.append(row)  # resumes chunked prefill

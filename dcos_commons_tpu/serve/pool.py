@@ -23,6 +23,13 @@ token-identical to whole-batch ``generate`` on the same prompts
 (tests/test_continuous_batching.py holds the equivalence under
 arbitrary admission orders).
 
+Both entry points close a host span on the profiler's clock
+(``pool.prefill`` / ``pool.prefill_chunk`` / ``pool.decode``) with an
+inner ``.fetch`` around the one blocking ``device_get``: in a
+profile the part of a call before its fetch is the host dispatching
+the program, the fetch is the host waiting for the device.  Outside a
+profiler session a ``TraceAnnotation`` is a flag test.
+
 The gang driver reuses this class unchanged: ``put`` lifts host
 arrays to global (broadcast_one_to_all hands every rank identical
 numpy), ``constrain_out`` pins token outputs replicated so rank 0
@@ -68,6 +75,7 @@ class PoolModel:
         )
 
         self._jax = jax
+        self._span = jax.profiler.TraceAnnotation
         self._np = np
         self.config = config
         self.params = params
@@ -88,8 +96,11 @@ class PoolModel:
             logits, cache = prefill_into_slot(
                 config, params, cache, tokens, slot, true_len
             )
-            key = jax.random.fold_in(jax.random.key(seed), true_len - 1)
-            return con(sample_token(logits[0], temp, key)), cache
+            with jax.named_scope("sample"):
+                key = jax.random.fold_in(
+                    jax.random.key(seed), true_len - 1
+                )
+                return con(sample_token(logits[0], temp, key)), cache
 
         def _decode(params, cache, tok, pos, temps, seeds):
             logits, cache = decode_step(config, params, cache, tok, pos)
@@ -98,7 +109,8 @@ class PoolModel:
                 key = jax.random.fold_in(jax.random.key(seed), p)
                 return sample_token(lg, temp, key)
 
-            nxt = jax.vmap(pick_row)(logits, temps, seeds, pos)
+            with jax.named_scope("sample"):
+                nxt = jax.vmap(pick_row)(logits, temps, seeds, pos)
             return con(nxt), cache
 
         # donate the pool cache (argnums 1): decode streams it every
@@ -117,13 +129,15 @@ class PoolModel:
     ) -> int:
         """Admit one right-padded [1, prompt_len] prompt into pool row
         ``slot``; returns the first generated token."""
-        first, self.cache = self._prefill_c(
-            self.params, self.cache,
-            self._put(np.asarray(tokens, np.int32)),
-            np.int32(slot), np.int32(true_len),
-            np.float32(temp), np.int32(seed),
-        )
-        return int(self._jax.device_get(first))
+        with self._span("pool.prefill"):
+            first, self.cache = self._prefill_c(
+                self.params, self.cache,
+                self._put(np.asarray(tokens, np.int32)),
+                np.int32(slot), np.int32(true_len),
+                np.float32(temp), np.int32(seed),
+            )
+            with self._span("pool.prefill.fetch"):
+                return int(self._jax.device_get(first))
 
     def decode(
         self, tok: np.ndarray, pos: np.ndarray,
@@ -136,14 +150,16 @@ class PoolModel:
         driver stamps it into the broadcast head); the computation
         always covers every slot — static shapes.  ONE bulk device
         fetch — per-element reads are a transfer each."""
-        nxt, self.cache = self._decode_c(
-            self.params, self.cache,
-            self._put(np.asarray(tok, np.int32)),
-            self._put(np.asarray(pos, np.int32)),
-            self._put(np.asarray(temps, np.float32)),
-            self._put(np.asarray(seeds, np.int32)),
-        )
-        return np.asarray(self._jax.device_get(nxt))
+        with self._span("pool.decode"):
+            nxt, self.cache = self._decode_c(
+                self.params, self.cache,
+                self._put(np.asarray(tok, np.int32)),
+                self._put(np.asarray(pos, np.int32)),
+                self._put(np.asarray(temps, np.float32)),
+                self._put(np.asarray(seeds, np.int32)),
+            )
+            with self._span("pool.decode.fetch"):
+                return np.asarray(self._jax.device_get(nxt))
 
     def warm(self, prompt_len: int) -> None:
         """Compile + execute both entry points before readiness: the
@@ -212,6 +228,7 @@ class PagedPoolModel:
         from dcos_commons_tpu.serve.paging import pages_for
 
         self._jax = jax
+        self._span = jax.profiler.TraceAnnotation
         self.config = config
         self.params = params
         self.slots = slots
@@ -240,10 +257,11 @@ class PagedPoolModel:
             # the fold matches the slot pool's: the chunk's last real
             # position is start + true_len - 1 == prompt_len - 1 on
             # the final chunk — same key, same sampled token
-            key = jax.random.fold_in(
-                jax.random.key(seed), start + true_len - 1
-            )
-            return con(sample_token(logits[0], temp, key)), cache
+            with jax.named_scope("sample"):
+                key = jax.random.fold_in(
+                    jax.random.key(seed), start + true_len - 1
+                )
+                return con(sample_token(logits[0], temp, key)), cache
 
         def _decode(params, cache, tok, pos, temps, seeds, tables):
             logits, cache = paged_decode_step(
@@ -254,7 +272,8 @@ class PagedPoolModel:
                 key = jax.random.fold_in(jax.random.key(seed), p)
                 return sample_token(lg, temp, key)
 
-            nxt = jax.vmap(pick_row)(logits, temps, seeds, pos)
+            with jax.named_scope("sample"):
+                nxt = jax.vmap(pick_row)(logits, temps, seeds, pos)
             return con(nxt), cache
 
         donate = {}
@@ -275,14 +294,16 @@ class PagedPoolModel:
         row id — a protocol rider (the gang driver broadcasts it), the
         math needs only the table."""
         del slot
-        first, self.cache = self._prefill_c(
-            self.params, self.cache,
-            self._put(np.asarray(tokens, np.int32)),
-            self._put(np.asarray(table, np.int32)),
-            np.int32(start), np.int32(true_len),
-            np.float32(temp), np.int32(seed),
-        )
-        return int(self._jax.device_get(first))
+        with self._span("pool.prefill_chunk"):
+            first, self.cache = self._prefill_c(
+                self.params, self.cache,
+                self._put(np.asarray(tokens, np.int32)),
+                self._put(np.asarray(table, np.int32)),
+                np.int32(start), np.int32(true_len),
+                np.float32(temp), np.int32(seed),
+            )
+            with self._span("pool.prefill_chunk.fetch"):
+                return int(self._jax.device_get(first))
 
     def decode(
         self, tok: np.ndarray, pos: np.ndarray,
@@ -291,15 +312,17 @@ class PagedPoolModel:
     ) -> np.ndarray:
         """One decode step over the whole pool through per-row page
         tables; ONE bulk device fetch, same as the slot pool."""
-        nxt, self.cache = self._decode_c(
-            self.params, self.cache,
-            self._put(np.asarray(tok, np.int32)),
-            self._put(np.asarray(pos, np.int32)),
-            self._put(np.asarray(temps, np.float32)),
-            self._put(np.asarray(seeds, np.int32)),
-            self._put(np.asarray(tables, np.int32)),
-        )
-        return np.asarray(self._jax.device_get(nxt))
+        with self._span("pool.decode"):
+            nxt, self.cache = self._decode_c(
+                self.params, self.cache,
+                self._put(np.asarray(tok, np.int32)),
+                self._put(np.asarray(pos, np.int32)),
+                self._put(np.asarray(temps, np.float32)),
+                self._put(np.asarray(seeds, np.int32)),
+                self._put(np.asarray(tables, np.int32)),
+            )
+            with self._span("pool.decode.fetch"):
+                return np.asarray(self._jax.device_get(nxt))
 
     def export_page(self, page: int) -> dict:
         """Snapshot one physical page as host numpy, every cache key

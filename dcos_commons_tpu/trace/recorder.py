@@ -124,6 +124,31 @@ class TraceRecorder:
         span.end()
         return span
 
+    def interval(
+        self,
+        name: str,
+        start_s: float,
+        end_s: float,
+        parent: Optional[Span] = None,
+        trace_id: int = 0,
+        parent_id: int = 0,
+        track: str = "",
+        **attrs,
+    ) -> Span:
+        """A span whose two ``time.monotonic()`` stamps are already
+        known (a request's queue wait, read off the row when it is
+        admitted): recorded closed in one call, so it can never leak
+        however the owner of the interval ends."""
+        span = self.span(
+            name, parent=parent, trace_id=trace_id, parent_id=parent_id,
+            track=track, **attrs,
+        )
+        if span is not self._null:
+            span.start_s = start_s
+            span.end_s = end_s
+            self._record(span)
+        return span
+
     # -- the ring -----------------------------------------------------
 
     def _record(self, span: Span) -> None:

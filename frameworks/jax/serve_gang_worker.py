@@ -200,7 +200,6 @@ def main() -> int:
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from dcos_commons_tpu.metrics.registry import Metrics
     from dcos_commons_tpu.models import config_from_env, init_params
     from dcos_commons_tpu.models.transformer import param_shardings
     from dcos_commons_tpu.parallel.mesh import MeshSpec, make_mesh
@@ -474,7 +473,6 @@ def main() -> int:
         queue_timeout_s = float(
             os.environ.get("SERVE_QUEUE_TIMEOUT_S", "600")
         )
-        metrics = Metrics()
         stats_path = os.path.join(
             os.environ.get("SANDBOX", "."), SERVESTATS_NAME
         )
@@ -585,6 +583,7 @@ def main() -> int:
                 stats_path=stats_path,
                 log=lambda msg: print(msg, flush=True),
                 extra_stats={"http_port": bound_port},
+                annotate=jax.profiler.TraceAnnotation,
             )
         else:
             engine = SlotEngine(
@@ -594,8 +593,8 @@ def main() -> int:
                 stats_path=stats_path,
                 log=lambda msg: print(msg, flush=True),
                 extra_stats={"http_port": bound_port},
+                annotate=jax.profiler.TraceAnnotation,
             )
-        engine.register_metrics(metrics)
         with open("ready", "w") as f:
             f.write("warm\n")
         shape = (

@@ -31,15 +31,27 @@ pool (SERVE_SLOTS x MAX_LEN rows).  Mixed prompt lengths, requested
 lengths AND temperatures still share one pool dispatch, and greedy
 outputs are token-identical on both paths.  GET /stats exposes the
 serving gauges (queue depth, KV occupancy, kv_pages_free,
-prefix_cache_hit_rate, prefill_chunk_backlog, tokens/s); the same
-snapshot lands in the sandbox for the scheduler's /v1/debug/serving.
+prefix_cache_hit_rate, prefill_chunk_backlog, tokens/s) and the
+engine loop's cumulative counters under ``loop``; the same snapshot
+lands in the sandbox for the scheduler's /v1/debug/serving.
+
+The engine's timeline: GET /trace (text; ?fmt=chrome for Perfetto)
+renders the worker's span ring — one ``request`` span a POST with the
+engine's queue/prefill/decode spans under it, one ``engine.tick`` a
+tick — when SERVE_TRACE_CAPACITY > 0 (default 0: off).  POST /profile
+{"seconds": n <= 30} captures a jax.profiler trace of the live
+process into $SANDBOX/profile (the last capture only), with the
+engine's ``engine.*`` and the pool's ``pool.*`` host spans above the
+device's lines; 409 while another profiler session is open.
 """
 
 import json
 import math
 import os
+import shutil
 import sys
 import time
+import urllib.parse
 
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
@@ -63,6 +75,11 @@ from dcos_commons_tpu.serve.migration import (  # noqa: E402
     SessionSnapshot,
     drain_sessions,
 )
+from dcos_commons_tpu.trace import (  # noqa: E402
+    TraceRecorder,
+    chrome_json,
+    to_text,
+)
 from dcos_commons_tpu.utils.microbatch import (  # noqa: E402
     MicroBatcher,
     QueueTimeoutError,
@@ -74,12 +91,30 @@ from dcos_commons_tpu.utils.microbatch import (  # noqa: E402
 _MicroBatcher = MicroBatcher
 _WorkItem = WorkItem
 
+PROFILE_MAX_S = 30.0
+
+
+def trace_reply(tracer: TraceRecorder, query: str = ""):
+    """(body, content type) of GET /trace: the span ring as the text
+    timeline, or as Chrome trace events for ``fmt=chrome``; a recorder
+    of capacity 0 says so instead of rendering an empty timeline."""
+    if not tracer.enabled:
+        return (
+            b"# trace recorder off: set SERVE_TRACE_CAPACITY > 0 in "
+            b"this task's env\n", "text/plain",
+        )
+    if urllib.parse.parse_qs(query).get("fmt", [""])[0] == "chrome":
+        return (
+            chrome_json(tracer, service="serve").encode(),
+            "application/json",
+        )
+    return to_text(tracer, service="serve").encode(), "text/plain"
+
 
 def main() -> int:
     import jax
     import jax.numpy as jnp
 
-    from dcos_commons_tpu.metrics.registry import Metrics
     from dcos_commons_tpu.models import config_from_env, init_params
     from dcos_commons_tpu.serve.pool import PagedPoolModel, PoolModel
     from dcos_commons_tpu.utils import (
@@ -140,30 +175,37 @@ def main() -> int:
     prompt_len = max_len - new_tokens
     kv_dtype = os.environ.get("KV_DTYPE", "native")
     queue_timeout_s = float(os.environ.get("SERVE_QUEUE_TIMEOUT_S", "600"))
-    metrics = Metrics()
-    stats_path = os.path.join(
-        os.environ.get("SANDBOX", "."), SERVESTATS_NAME
-    )
+    sandbox = os.environ.get("SANDBOX", ".")
+    stats_path = os.path.join(sandbox, SERVESTATS_NAME)
     paged = paged_config_from_env(os.environ)
+    # the request/engine span ring, OFF unless this task's env asks
+    # for it: the hot loop then meets only no-op spans
+    tracer = TraceRecorder(
+        capacity=int(os.environ.get("SERVE_TRACE_CAPACITY") or 0),
+        service="serve",
+    )
 
     class Handler(BaseHTTPRequestHandler):
         def log_message(self, fmt, *args):
             pass
 
         def do_GET(self):
-            if self.path.split("?")[0] != "/stats":
+            url = urllib.parse.urlsplit(self.path)
+            if url.path == "/stats":
+                self.send_response(200)
+                self._finish(json.dumps(engine.stats()).encode())
+            elif url.path == "/trace":
+                self.send_response(200)
+                self._finish(*trace_reply(tracer, url.query))
+            else:
                 self.send_error(404)
-                return
-            payload = json.dumps(engine.stats()).encode()
-            self.send_response(200)
-            self.send_header("Content-Type", "application/json")
-            self.send_header("Content-Length", str(len(payload)))
-            self.end_headers()
-            self.wfile.write(payload)
 
         def do_POST(self):
             if self.path == "/migrate":
                 self._do_migrate()
+                return
+            if self.path == "/profile":
+                self._do_profile()
                 return
             if self.path != "/generate":
                 self.send_error(404)
@@ -222,9 +264,13 @@ def main() -> int:
                 clean_rows = [
                     [int(t) % config.vocab for t in row] for row in rows
                 ]
-                result = engine.submit(
-                    clean_rows, n, temperature=temp, eos_id=eos
-                )
+                with tracer.span(
+                    "request", track="req", rows=len(clean_rows)
+                ) as request:
+                    result = engine.submit(
+                        clean_rows, n, temperature=temp, eos_id=eos,
+                        trace_parent=request,
+                    )
                 payload = json.dumps({"tokens": result}).encode()
                 self.send_response(200)
             except SessionMigratedError as e:
@@ -248,11 +294,52 @@ def main() -> int:
                 self.send_response(400)
             self._finish(payload)
 
-        def _finish(self, payload: bytes) -> None:
-            self.send_header("Content-Type", "application/json")
+        def _finish(self, payload: bytes,
+                    content_type: str = "application/json") -> None:
+            self.send_header("Content-Type", content_type)
             self.send_header("Content-Length", str(len(payload)))
             self.end_headers()
             self.wfile.write(payload)
+
+        def _do_profile(self) -> None:
+            """The program's own profiler entry: hold a jax.profiler
+            session open for ``seconds`` over whatever the engine is
+            serving, and name the directory it wrote.  One session a
+            process: 409 while another (this verb's, or one a harness
+            opened in this process) is under way."""
+            length = int(self.headers.get("Content-Length", 0))
+            out_dir = os.path.join(sandbox, "profile")
+            try:
+                body = json.loads(self.rfile.read(length) or b"{}")
+                seconds = float(body.get("seconds", 5.0))
+                if not 0.0 < seconds <= PROFILE_MAX_S:
+                    raise ValueError(
+                        f"seconds must be in (0, {PROFILE_MAX_S:g}], "
+                        f"got {seconds}"
+                    )
+            except Exception as e:  # noqa: BLE001 — surface to client
+                self.send_response(400)
+                self._finish(json.dumps({"error": str(e)}).encode())
+                return
+            try:
+                jax.profiler.start_trace(out_dir)
+                try:
+                    # the session is this call's: drop the capture
+                    # before it (stop_trace writes a new timestamped
+                    # run, and nothing else prunes the sandbox)
+                    shutil.rmtree(out_dir, ignore_errors=True)
+                    time.sleep(seconds)
+                finally:
+                    jax.profiler.stop_trace()
+            except RuntimeError as e:
+                # jax's own one-session rule, on either edge
+                self.send_response(409)
+                self._finish(json.dumps({"error": str(e)}).encode())
+                return
+            self.send_response(200)
+            self._finish(json.dumps(
+                {"dir": os.path.abspath(out_dir), "seconds": seconds}
+            ).encode())
 
         def _do_migrate(self) -> None:
             """The DCN lane's HTTP leg: this pod as a migration
@@ -375,6 +462,7 @@ def main() -> int:
             write_page=pool.import_page, handoff=handoff,
             log=lambda msg: print(msg, flush=True),
             extra_stats={"http_port": bound_port},
+            annotate=jax.profiler.TraceAnnotation, tracer=tracer,
         )
     else:
         # KV_PAGE_TOKENS=0: the PR 6 slot pool, kept as the
@@ -387,8 +475,8 @@ def main() -> int:
             queue_timeout_s=queue_timeout_s, stats_path=stats_path,
             log=lambda msg: print(msg, flush=True),
             extra_stats={"http_port": bound_port},
+            annotate=jax.profiler.TraceAnnotation, tracer=tracer,
         )
-    engine.register_metrics(metrics)
     warm_t0 = time.monotonic()
     if paged is not None:
         pool.warm()

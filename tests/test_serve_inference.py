@@ -134,6 +134,58 @@ def test_inference_pod_serves_generate(tmp_path):
                 raise AssertionError(f"should have failed: {bad}")
             except urllib.error.HTTPError as e:
                 assert e.code == 400, bad
+        # the engine's own timeline (ISSUE 24): the loop's counters
+        # ride /stats; the span ring is off unless SERVE_TRACE_CAPACITY asks
+        def get(path):
+            with urllib.request.urlopen(
+                f"http://127.0.0.1:{port}{path}", timeout=30
+            ) as resp:
+                return resp.read().decode()
+
+        loop = json.loads(get("/stats"))["loop"]
+        assert loop["requests_timed"] == 3 and loop["decode_calls"] >= 7
+        assert loop["phase_s"]["decode_call"] > 0
+        assert "recorder off" in get("/trace")
+        assert "recorder off" in get("/trace?fmt=chrome")
+
+        # the program's own profiler entry: a capture of the live
+        # process lands in the sandbox; a second session is refused
+        # while the first is open, and so is a silly length
+        def profile(seconds):
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{port}/profile",
+                data=json.dumps({"seconds": seconds}).encode(),
+                method="POST",
+            )
+            try:
+                with urllib.request.urlopen(req, timeout=60) as resp:
+                    return resp.status, json.loads(resp.read())
+            except urllib.error.HTTPError as e:
+                return e.code, json.loads(e.read())
+
+        import glob
+        import threading
+
+        first = {}
+        opener = threading.Thread(
+            target=lambda: first.update(reply=profile(1.5))
+        )
+        opener.start()
+        time.sleep(0.5)
+        assert profile(0.2)[0] == 409
+        post({"tokens": [[1, 2, 3, 4]], "max_new_tokens": 8})
+        opener.join(timeout=60)
+        code, reply = first["reply"]
+        assert code == 200, reply
+        assert reply["dir"] == str(
+            tmp_path / "sbx" / "server-0-api" / "profile"
+        )
+        runs = os.path.join(reply["dir"], "plugins", "profile", "*")
+        assert glob.glob(os.path.join(runs, "*.xplane.pb"))
+        assert profile(31)[0] == 400
+        # the sandbox keeps the last capture only
+        assert profile(0.2)[0] == 200
+        assert len(glob.glob(runs)) == 1
         # VIP discovery lists the live backend
         from dcos_commons_tpu.http.api import SchedulerApi
 

@@ -219,6 +219,25 @@ def test_span_end_is_idempotent_and_drop_skips_recording():
     assert tracer.dropped == 0  # ...and don't count as ring overflow
 
 
+def test_interval_records_a_closed_span_with_the_stamps_given():
+    tracer = TraceRecorder(capacity=8)
+    with tracer.span("request", track="req") as parent:
+        done = tracer.interval(
+            "waited", 10.0, 12.5, parent=parent, rid=7,
+        )
+    assert done.end_s == 12.5 and done.duration_s == 2.5
+    assert (done.trace_id, done.parent_id, done.track) == (
+        parent.trace_id, parent.span_id, "req",
+    )
+    done.end()  # already closed: not recorded twice
+    assert [s.name for s in tracer.snapshot()] == ["waited", "request"]
+    assert "rid=7" in to_text(tracer)
+    # a disabled recorder mints nothing
+    off = TraceRecorder(capacity=0)
+    assert off.interval("waited", 1.0, 2.0, rid=1).attrs == {}
+    assert off.snapshot() == []
+
+
 def test_idle_cycles_do_not_flood_the_ring():
     _runner, world = deploy_gang()
     tracer = world.scheduler.tracer
