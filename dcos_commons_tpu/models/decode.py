@@ -397,6 +397,48 @@ def _serve_ffn(config: TransformerConfig, layer, x: jax.Array) -> jax.Array:
         return _ffn_block(config, layer, x, decode=True)[0]
 
 
+def _scan_layers_over_arena(layer_fn, x, layers, cache):
+    """Run ``layer_fn`` over the stacked ``layers`` with the arena as
+    the scan's CARRY: ``layer_fn((x, arena), (layer, base))`` gets the
+    arena's arrays with layers and pages merged into one leading axis
+    (``[n_layers * n_pages, page_tokens, ...]``, a reshape of the
+    stored layout that moves no byte) and ``base``, the layer's first
+    row of that axis, so layer ``l`` reaches its page ``p`` at row
+    ``base + p`` with ONE scatter into the whole buffer and ONE gather
+    out of it.  Returns (x, the cache in its stored layout).
+
+    The arena must never be a scanned array (an ``xs``/``ys`` of this
+    scan): XLA then slices a whole layer out of the stacked buffer,
+    updates the copy and writes it into a second stacked buffer, in
+    every layer — gigabytes of HBM traffic a call for a few KiB of new
+    keys and values.  As a carry the donated buffer is updated in
+    place (tests/test_paged_kv.py holds the jaxpr to it)."""
+    n_layers, n_pages = cache["k"].shape[:2]
+    arena = {
+        name: arr.reshape((-1,) + arr.shape[2:])
+        for name, arr in cache.items()
+    }
+    bases = jnp.arange(n_layers, dtype=jnp.int32) * n_pages
+    (x, arena), _ = lax.scan(layer_fn, (x, arena), (layers, bases))
+    return x, {
+        name: arr.reshape(cache[name].shape)
+        for name, arr in arena.items()
+    }
+
+
+def _kv_entries(
+    k_new: jax.Array, v_new: jax.Array, quantized: bool
+) -> Dict[str, jax.Array]:
+    """What one layer writes into the arena, keyed like the cache:
+    the new K/V rows, int8 with their per-vector scales when the arena
+    is quantized."""
+    if not quantized:
+        return {"k": k_new, "v": v_new}
+    kq, ks_new = _quantize_kv(k_new)
+    vq, vs_new = _quantize_kv(v_new)
+    return {"k": kq, "v": vq, "k_scale": ks_new, "v_scale": vs_new}
+
+
 def paged_prefill_chunk(
     config: TransformerConfig,
     params: Params,
@@ -454,35 +496,28 @@ def paged_prefill_chunk(
     )                                            # [c, L]
     x = params["embed"][tokens].astype(config.dtype)
 
-    def layer_fn(x, inputs):
-        if quantized:
-            layer, ck, cv, cks, cvs = inputs
-        else:
-            layer, ck, cv = inputs
-            cks = cvs = None
+    def layer_fn(carry, inputs):
+        x, arena = carry
+        layer, base = inputs           # base: the layer's first page
         with jax.named_scope("attention"):
             normed = rms_norm(x, layer["attn_norm"])
             q, k_new, v_new = _project_kv(config, layer, normed, positions)
         with jax.named_scope("kv_write"):
-            if quantized:
-                kq, ks_new = _quantize_kv(k_new)
-                vq, vs_new = _quantize_kv(v_new)
-                ck = ck.at[phys, slot_off].set(kq[0])
-                cv = cv.at[phys, slot_off].set(vq[0])
-                cks = cks.at[phys, slot_off].set(ks_new[0])
-                cvs = cvs.at[phys, slot_off].set(vs_new[0])
-            else:
-                ck = ck.at[phys, slot_off].set(k_new[0])
-                cv = cv.at[phys, slot_off].set(v_new[0])
+            new = _kv_entries(k_new[0], v_new[0], quantized)
+            arena = {
+                name: arr.at[base + phys, slot_off].set(new[name])
+                for name, arr in arena.items()
+            }
         # gather the request's whole virtual sequence through the
         # table (scatter-then-gather: in-chunk keys ride the same
         # path as prior pages — one attention covers both)
         with jax.named_scope("paged_gather"):
-            k_all = ck[table].reshape(1, length, kv, hd)
-            v_all = cv[table].reshape(1, length, kv, hd)
+            pages = base + table
+            k_all = arena["k"][pages].reshape(1, length, kv, hd)
+            v_all = arena["v"][pages].reshape(1, length, kv, hd)
             if quantized:
-                ks_all = cks[table].reshape(1, length, kv)
-                vs_all = cvs[table].reshape(1, length, kv)
+                ks_all = arena["k_scale"][pages].reshape(1, length, kv)
+                vs_all = arena["v_scale"][pages].reshape(1, length, kv)
         with jax.named_scope("attention"):
             qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(
                 1, c, kv, reps, hd
@@ -507,22 +542,11 @@ def paged_prefill_chunk(
             ).astype(config.dtype)
             x = x + attn.reshape(1, c, h * hd) @ dq(layer["wo"], x.dtype)
         x = _serve_ffn(config, layer, x)
-        if quantized:
-            return x, (ck, cv, cks, cvs)
-        return x, (ck, cv)
+        return (x, arena), None
 
-    if quantized:
-        x, (ck, cv, cks, cvs) = lax.scan(
-            layer_fn, x,
-            (params["layers"], cache["k"], cache["v"],
-             cache["k_scale"], cache["v_scale"]),
-        )
-        new_cache = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
-    else:
-        x, (ck, cv) = lax.scan(
-            layer_fn, x, (params["layers"], cache["k"], cache["v"])
-        )
-        new_cache = {"k": ck, "v": cv}
+    x, new_cache = _scan_layers_over_arena(
+        layer_fn, x, params["layers"], cache
+    )
     with jax.named_scope("logits"):
         x = rms_norm(x, params["final_norm"])
         x_last = lax.dynamic_index_in_dim(
@@ -572,32 +596,25 @@ def paged_decode_step(
     quantized = "k_scale" in cache
     reps = h // kv
 
-    def layer_fn(x, inputs):
-        if quantized:
-            layer, ck, cv, cks, cvs = inputs
-        else:
-            layer, ck, cv = inputs
-            cks = cvs = None
+    def layer_fn(carry, inputs):
+        x, arena = carry
+        layer, base = inputs           # base: the layer's first page
         with jax.named_scope("attention"):
             normed = rms_norm(x, layer["attn_norm"])
             q, k_new, v_new = _project_kv(config, layer, normed, positions)
         with jax.named_scope("kv_write"):
-            if quantized:
-                kq, ks_new = _quantize_kv(k_new)
-                vq, vs_new = _quantize_kv(v_new)
-                ck = ck.at[phys, slot_off].set(kq[:, 0])
-                cv = cv.at[phys, slot_off].set(vq[:, 0])
-                cks = cks.at[phys, slot_off].set(ks_new[:, 0])
-                cvs = cvs.at[phys, slot_off].set(vs_new[:, 0])
-            else:
-                ck = ck.at[phys, slot_off].set(k_new[:, 0])
-                cv = cv.at[phys, slot_off].set(v_new[:, 0])
+            new = _kv_entries(k_new[:, 0], v_new[:, 0], quantized)
+            arena = {
+                name: arr.at[base + phys, slot_off].set(new[name])
+                for name, arr in arena.items()
+            }
         with jax.named_scope("paged_gather"):
-            k_all = ck[tables].reshape(b, length, kv, hd)
-            v_all = cv[tables].reshape(b, length, kv, hd)
+            pages = base + tables
+            k_all = arena["k"][pages].reshape(b, length, kv, hd)
+            v_all = arena["v"][pages].reshape(b, length, kv, hd)
             if quantized:
-                ks_all = cks[tables].reshape(b, length, kv)
-                vs_all = cvs[tables].reshape(b, length, kv)
+                ks_all = arena["k_scale"][pages].reshape(b, length, kv)
+                vs_all = arena["v_scale"][pages].reshape(b, length, kv)
         with jax.named_scope("attention"):
             qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(
                 b, kv, reps, hd
@@ -616,22 +633,11 @@ def paged_decode_step(
             ).astype(config.dtype)
             x = x + attn.reshape(b, 1, h * hd) @ dq(layer["wo"], x.dtype)
         x = _serve_ffn(config, layer, x)
-        if quantized:
-            return x, (ck, cv, cks, cvs)
-        return x, (ck, cv)
+        return (x, arena), None
 
-    if quantized:
-        x, (ck, cv, cks, cvs) = lax.scan(
-            layer_fn, x,
-            (params["layers"], cache["k"], cache["v"],
-             cache["k_scale"], cache["v_scale"]),
-        )
-        new_cache = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
-    else:
-        x, (ck, cv) = lax.scan(
-            layer_fn, x, (params["layers"], cache["k"], cache["v"])
-        )
-        new_cache = {"k": ck, "v": cv}
+    x, new_cache = _scan_layers_over_arena(
+        layer_fn, x, params["layers"], cache
+    )
     with jax.named_scope("logits"):
         x = rms_norm(x, params["final_norm"])
         logits = jnp.einsum(

@@ -893,3 +893,264 @@ def test_paged_gang_sim_broadcast_protocol_equivalence(tiny):
         assert ticks["noop"] >= 1
     finally:
         engine.stop()
+
+
+# -- the arena stays in place through the layer scan -------------------
+#
+# The step functions' cost on the chip hangs on ONE structural fact:
+# the arena rides the layer scan as a CARRY, never as a scanned array
+# (models/decode.py `_scan_layers_over_arena`).  No chip is here to time it, so the
+# jaxpr is held to it, and the result is held bit-equal to the plain
+# per-layer spelling the functions had before.
+
+ARENA_CASES = [
+    (step, kv_dtype)
+    for step in ("prefill_chunk", "decode_step")
+    for kv_dtype in ("native", "int8")
+]
+A_LAYERS, A_PAGES, A_PTOK = 3, 11, 4
+
+
+@pytest.fixture(scope="module")
+def arena_model():
+    import jax
+    import jax.numpy as jnp
+
+    from dcos_commons_tpu.models import TransformerConfig, init_params
+
+    config = TransformerConfig(
+        vocab=64, d_model=32, n_layers=A_LAYERS, n_heads=8, n_kv_heads=4,
+        d_ff=96, max_seq=64, dtype=jnp.float32, remat=False,
+    )
+    return config, init_params(config, jax.random.key(3))
+
+
+def _arena_case(config, step, kv_dtype):
+    """(cache, args) for one call: an arena full of earlier keys and
+    values (so what a layer gathers matters), page tables with
+    unallocated entries, a chunk with two padding tokens, a pool with
+    one inactive row."""
+    import jax
+    import jax.numpy as jnp
+
+    from dcos_commons_tpu.models.decode import init_paged_kv_cache
+
+    zero = init_paged_kv_cache(config, A_PAGES, A_PTOK, kv_dtype)
+    keys = jax.random.split(jax.random.key(11), len(zero))
+    cache = {}
+    for key, (name, arr) in zip(keys, sorted(zero.items())):
+        if arr.dtype == jnp.int8:
+            cache[name] = jax.random.randint(
+                key, arr.shape, -127, 128, jnp.int32
+            ).astype(jnp.int8)
+        elif name.endswith("_scale"):
+            cache[name] = jax.random.uniform(
+                key, arr.shape, jnp.float32, 0.001, 0.02
+            )
+        else:
+            cache[name] = jax.random.normal(key, arr.shape, arr.dtype)
+    if step == "prefill_chunk":
+        # second chunk of a prompt: positions 4..7 are real (virtual
+        # page 1 -> physical 7), 8 and 9 are padding; virtual pages
+        # 2.. are unallocated
+        tokens = jnp.asarray([[5, 9, 2, 31, 0, 0]], jnp.int32)
+        table = jnp.asarray([3, 7, 0, 0, 0, 0], jnp.int32)
+        return cache, (tokens, table, jnp.int32(4), jnp.int32(4))
+    token = jnp.asarray([7, 12, 40, 3], jnp.int32)
+    pos = jnp.asarray([5, 9, 0, 2], jnp.int32)        # row 2 inactive
+    tables = jnp.asarray([
+        [2, 9, 0, 0, 0, 0],
+        [4, 1, 6, 0, 0, 0],
+        [0, 0, 0, 0, 0, 0],
+        [10, 0, 0, 0, 0, 0],
+    ], jnp.int32)
+    return cache, (token, pos, tables)
+
+
+def _arena_step(step):
+    from dcos_commons_tpu.models import decode
+
+    return {
+        "prefill_chunk": decode.paged_prefill_chunk,
+        "decode_step": decode.paged_decode_step,
+    }[step]
+
+
+def _scans_over_layers(jaxpr):
+    """Every scan of length A_LAYERS in a jaxpr, nested ones too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and eqn.params["length"] == A_LAYERS:
+            found.append(eqn)
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                found += _scans_over_layers(inner)
+    return found
+
+
+@pytest.mark.parametrize("step,kv_dtype", ARENA_CASES)
+def test_arena_is_a_carry_of_the_layer_scan_never_scanned(
+    arena_model, step, kv_dtype
+):
+    """No xs and no ys of the layer scan is the arena stacked over
+    layers; every arena array is in the carry, in and out.  An edit
+    that scans the arena again makes XLA slice a whole layer out and
+    restack the whole arena in every layer (PERF.md §6, PR 25)."""
+    import jax
+
+    config, params = arena_model
+    cache, args = _arena_case(config, step, kv_dtype)
+    fn = _arena_step(step)
+    jaxpr = jax.make_jaxpr(
+        lambda cache, *args: fn(config, params, cache, *args)
+    )(cache, *args).jaxpr
+    scans = _scans_over_layers(jaxpr)
+    assert len(scans) == 1, "one scan over the layers is expected"
+    eqn = scans[0]
+    n_fixed = eqn.params["num_consts"] + eqn.params["num_carry"]
+    carry_in = eqn.invars[eqn.params["num_consts"]:n_fixed]
+    carry_out = eqn.outvars[:eqn.params["num_carry"]]
+    scanned = eqn.invars[n_fixed:] + eqn.outvars[eqn.params["num_carry"]:]
+    per_layer = {tuple(arr.shape[1:]) for arr in cache.values()}
+    for var in scanned:
+        assert tuple(var.aval.shape[1:]) not in per_layer, (
+            f"the layer scan scans {var.aval.str_short()}: the arena "
+            "must ride it as a carry"
+        )
+    for name, arr in cache.items():
+        for side, carried in (("in", carry_in), ("out", carry_out)):
+            assert any(
+                v.aval.size == arr.size and v.aval.dtype == arr.dtype
+                for v in carried
+            ), f"cache[{name!r}] is not in the scan's carry ({side})"
+
+
+def _reference_step(config, params, cache, step, args):
+    """The plain spelling: a Python loop over layers that takes the
+    layer's arena out (``cache[k][l]``), scatters the new rows into
+    it, gathers the request's pages from it and restacks the arena at
+    the end.  One body serves both steps: a chunk is one row of C
+    queries, a decode step S rows of one query."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from dcos_commons_tpu.models import decode as D
+
+    h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    p_tok = cache["k"].shape[2]
+    quantized = "k_scale" in cache
+    if step == "prefill_chunk":
+        tokens, table, start, true_len = args
+        offs = jnp.arange(tokens.shape[1], dtype=jnp.int32)
+        positions = (start + offs)[None, :]              # [1, c]
+        tables = table[None, :]
+        live = (offs < true_len)[None, :]
+        x = params["embed"][tokens].astype(config.dtype)
+    else:
+        token, pos, tables = args
+        positions = pos[:, None]                         # [S, 1]
+        live = jnp.ones_like(positions, bool)
+        x = params["embed"][token][:, None, :].astype(config.dtype)
+    b, s = positions.shape
+    m = tables.shape[1]
+    length = m * p_tok
+    vpage = jnp.minimum(positions // p_tok, m - 1)
+    phys = jnp.where(
+        live, jnp.take_along_axis(tables, vpage, axis=1), 0
+    )                                                    # [b, s]
+    slot_off = positions % p_tok
+    valid = (
+        lax.broadcasted_iota(jnp.int32, (b, s, length), 2)
+        <= positions[:, :, None]
+    )
+    # the steps' own contractions, so that sums run in the same order:
+    # a chunk keeps its query axis, a decode step has none
+    every = slice(None)
+    if step == "prefill_chunk":
+        qdims, q_shape = "bqkr", (b, s, kv, h // kv, hd)
+        per_key = (every, None, every, None, every)
+        per_query = (every, every, None, None, every)
+    else:
+        qdims, q_shape = "bkr", (b, kv, h // kv, hd)
+        per_key = (every, every, None, every)
+        per_query = (every, 0, None, None, every)
+    stacked = {name: [] for name in cache}
+    for l in range(config.n_layers):
+        layer = jax.tree.map(lambda a: a[l], params["layers"])
+        arena = {name: arr[l] for name, arr in cache.items()}
+        normed = D.rms_norm(x, layer["attn_norm"])
+        q, k_new, v_new = D._project_kv(config, layer, normed, positions)
+        new = {"k": k_new, "v": v_new}
+        if quantized:
+            new["k"], new["k_scale"] = D._quantize_kv(k_new)
+            new["v"], new["v_scale"] = D._quantize_kv(v_new)
+        for name in arena:
+            arena[name] = arena[name].at[
+                phys.reshape(-1), slot_off.reshape(-1)
+            ].set(new[name].reshape((b * s,) + new[name].shape[2:]))
+            stacked[name].append(arena[name])
+        k_all = arena["k"][tables].reshape(b, length, kv, hd)
+        v_all = arena["v"][tables].reshape(b, length, kv, hd)
+        qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(q_shape)
+        scores = jnp.einsum(
+            f"{qdims}d,blkd->{qdims}l", qg, k_all.astype(jnp.float32)
+        )
+        if quantized:
+            ks_all = arena["k_scale"][tables].reshape(b, length, kv)
+            vs_all = arena["v_scale"][tables].reshape(b, length, kv)
+            scores = scores * ks_all.transpose(0, 2, 1)[per_key]
+        scores = jnp.where(valid[per_query], scores, D._NEG)
+        probs = jax.nn.softmax(scores, axis=-1)
+        if quantized:
+            probs = probs * vs_all.transpose(0, 2, 1)[per_key]
+        attn = jnp.einsum(
+            f"{qdims}l,blkd->{qdims}d", probs, v_all.astype(jnp.float32)
+        ).astype(config.dtype)
+        x = x + attn.reshape(b, s, h * hd) @ layer["wo"]
+        x = D._serve_ffn(config, layer, x)
+    x = D.rms_norm(x, params["final_norm"])
+    if step == "prefill_chunk":
+        x_last = lax.dynamic_index_in_dim(
+            x, true_len - 1, axis=1, keepdims=False
+        )
+    else:
+        x_last = x[:, 0]
+    logits = jnp.einsum(
+        "bd,vd->bv", x_last.astype(jnp.float32),
+        params["embed"].astype(jnp.float32),
+    )
+    return logits, {name: jnp.stack(rows) for name, rows in stacked.items()}
+
+
+@pytest.mark.parametrize("step,kv_dtype", ARENA_CASES)
+def test_arena_in_place_equals_per_layer_slice_and_restack(
+    arena_model, step, kv_dtype
+):
+    """Logits AND the whole returned cache — trash page included — are
+    bit-equal to the plain per-layer reference, through tables with
+    unallocated entries, a chunk with padding and an inactive row."""
+    import jax
+
+    config, params = arena_model
+    cache, args = _arena_case(config, step, kv_dtype)
+    fn = _arena_step(step)
+    logits, new_cache = jax.jit(
+        lambda cache, *args: fn(config, params, cache, *args)
+    )(cache, *args)
+    want_logits, want_cache = jax.jit(
+        lambda cache, *args: _reference_step(
+            config, params, cache, step, args
+        )
+    )(cache, *args)
+    assert set(new_cache) == set(cache)
+    for name, arr in cache.items():
+        got = np.asarray(new_cache[name])
+        assert got.shape == arr.shape and got.dtype == arr.dtype
+        np.testing.assert_array_equal(got, np.asarray(want_cache[name]))
+        # and the call did write: the cache is not what went in
+        assert not np.array_equal(got, np.asarray(arr))
+    np.testing.assert_array_equal(
+        np.asarray(logits), np.asarray(want_logits)
+    )
