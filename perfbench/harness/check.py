@@ -2,7 +2,8 @@
 runs once the service is down and the chip is free.
 
 For a sample of the window's answered requests it runs the plain
-reference once over prompt + served tokens and reads, at every served
+reference of the configuration's family (``families/<family>/
+reference.py``) once over prompt + served tokens and reads, at every served
 position, how far the served token's logit lies below the reference's
 best.  Greedy tokens only.  Numbers that may be compared, each with
 its own limit from the cell's file (a cell compares those it limits):
@@ -15,14 +16,16 @@ its own limit from the cell's file (a cell compares those it limits):
                     ``wide_gap``
     steady_max_gap, steady_mean_gap, steady_mismatch_share,
     steady_wide_gap_share
-                    the same over the positions at which, in every layer
-                    of a mixture, the reference's last chosen expert
-                    leads the first one left out by more than the
-                    cell's ``routing_margin``.  Elsewhere any rounding
-                    upstream changes the experts chosen and with them
-                    the logits wholesale, whatever the precision: those
-                    positions say nothing about precision and are held
-                    only to the looser limits over all positions.
+                    the same over the positions whose steadiness
+                    margin, as the family's reference reads it, is over
+                    the cell's ``routing_margin``: in a mixture, by how
+                    much the last chosen expert leads the first one left
+                    out, narrowest over the layers; infinite where
+                    nothing is routed.  Elsewhere any rounding upstream
+                    changes the experts chosen and with them the logits
+                    wholesale, whatever the precision: those positions
+                    say nothing about precision and are held only to the
+                    looser limits over all positions.
 
 Last line of stdout: {"correct": bool, "compared": {name: [value, limit]}}
 """
@@ -45,12 +48,10 @@ def bucket(n: int, floor: int) -> int:
     return size
 
 
-def served_logits(model, weights, prompt, served, lower=None):
-    """The reference's logits at every served position, teacher-forced
-    along prompt + served tokens."""
+def served_logits(reference, model, weights, prompt, served, lower=None):
+    """The logits of ``reference`` (a family's) at every served
+    position, teacher-forced along prompt + served tokens."""
     import numpy as np
-
-    from perfbench.harness import reference
 
     tokens = list(prompt) + list(served[:-1])
     padded = np.zeros(bucket(len(tokens), 256), np.int32)
@@ -75,14 +76,16 @@ def chosen_gaps(logits, chosen):
     return np.asarray(best - mine, np.float64)
 
 
-def compare(model, weights, requests, limits, routing_margin=0.0,
+def compare(reference, model, weights, requests, limits, routing_margin=0.0,
             wide_gap=0.1):
     """(correct, {name: [value, limit]}, positions read, steady ones)."""
     import numpy as np
 
     gaps, margins = [], []
     for r in requests:
-        logits, margin = served_logits(model, weights, r["prompt"], r["served"])
+        logits, margin = served_logits(
+            reference, model, weights, r["prompt"], r["served"]
+        )
         gaps.append(chosen_gaps(logits, r["served"]))
         margins.append(margin)
     gaps, margins = np.concatenate(gaps), np.concatenate(margins)
@@ -112,7 +115,8 @@ def judge(gaps, steady, limits, wide_gap=0.1):
 
 
 def load_job(path: str):
-    """(job, model, weights, platform) of one check's input file."""
+    """(job, model, family, weights, platform) of one check's input
+    file."""
     sys.path.insert(0, CHECKOUT)
     with open(path) as f:
         job = json.load(f)
@@ -127,21 +131,25 @@ def load_job(path: str):
     jax.config.update("jax_compilation_cache_dir", cache)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
+    from perfbench.harness.manifest import family_of
     from perfbench.harness.weights import make_weights
 
+    family = family_of(job["config_file"])
     platform = jax.devices()[0].platform
     # the dtype the program serves in on this platform
     dtype = jnp.bfloat16 if platform == "tpu" else jnp.float32
-    return job, model, make_weights(model, job["seed"], dtype), platform
+    weights = make_weights(family.weight_specs(model), job["seed"], dtype)
+    return job, model, family, weights, platform
 
 
 def main(argv) -> int:
-    job, model, weights, platform = load_job(argv[1])
+    job, model, family, weights, platform = load_job(argv[1])
     correct, compared, positions, steady = compare(
-        model, weights, job["requests"], job["limits"],
+        family.reference, model, weights, job["requests"], job["limits"],
         job.get("routing_margin", 0.0), job.get("wide_gap", 0.1),
     )
-    print(f"reference on {platform}: {len(job['requests'])} requests, "
+    print(f"reference of family {family.name} on {platform}: "
+          f"{len(job['requests'])} requests, "
           f"{positions} served positions read, {steady} of them steady",
           flush=True)
     print(json.dumps({"correct": correct, "compared": compared}))

@@ -5,8 +5,9 @@ its configuration (``model``), mix and parameters, every request's
 times (``outcomes``, with ``judged`` indexing the window's), the
 worker's ``/stats`` once a second (``stats_samples``, stamped ``_t``
 on the same clock as ``window``), ``final_stats``, ``setup_s``,
-``deploy_plan_s``, the device's ``peaks`` and, in a traced run, the
-reduced ``trace`` with the span it covered (``trace_window``).
+``deploy_plan_s``, the device's ``peaks``, the configuration's file
+(``config_file``, by which its family is found) and, in a traced run,
+the reduced ``trace`` with the span it covered (``trace_window``).
 """
 
 from __future__ import annotations
@@ -14,6 +15,7 @@ from __future__ import annotations
 from statistics import mean
 
 from perfbench.harness import metrics, roofline
+from perfbench.harness.manifest import family_of
 
 
 def judged(run: dict) -> list:
@@ -48,6 +50,12 @@ def program_ms(run: dict, program: str):
     trace = run.get("trace") or {}
     entry = trace.get("programs", {}).get(program)
     return entry["median_ms"] if entry else None
+
+
+def family_needs(run: dict):
+    """What a call of this run's configuration needs of the chip: the
+    ``needs`` of its family, ``decode_tick`` and ``prefill_chunk``."""
+    return family_of(run["config_file"]).needs
 
 
 def roofline_share(run: dict, needs: dict, program: str):

@@ -4,11 +4,17 @@ One jitted call builds the whole tree on the device, in the dtype it
 is served in, from ``--seed``.  The worker entry hands the tree to the
 program in place of its own ``init_params`` and the reference builds
 the same tree from the same call, so neither takes anything the other
-has made.  The tree's names and layouts are the program's checkpoint
-format (stacked layers, ``x @ W`` orientation); that format is the
-interface of the system under test, and the worker entry checks it
-against the shapes of the program's own ``init_params`` before it
-builds anything (``tree_differences``).
+has made.  WHICH tree is the configuration's family's to say
+(``families/<family>/weight_specs.py``): its names and layouts are the
+program's checkpoint format, the interface of the system under test,
+and the worker entry checks them against the shapes of the program's
+own ``init_params`` before it builds anything (``tree_differences``).
+
+A spec is ``(path, shape, kind, scale, dtype)``: ``path`` a tuple of
+names of any depth; ``kind`` "normal" (``scale`` x N(0, 1)) or
+"around_one" (1 + ``scale`` x N(0, 1)); ``dtype`` "served" (the dtype
+the program serves in) or "float32".  Leaf ``i`` of the list draws from
+``fold_in(key, i)``, so a family that appends a leaf moves no other.
 """
 
 from __future__ import annotations
@@ -20,38 +26,8 @@ def split_seed(seed: int):
     return seed & 0xFFFFFF, seed >> 24
 
 
-def weight_specs(model: dict):
-    """(path, shape, kind, scale) for every leaf; kind is "normal"
-    (N(0, scale^2), served dtype), "norm" (1 + 0.1 N(0,1), served
-    dtype) or "router" (N(0, scale^2), float32)."""
-    d, n = model["hidden_size"], model["num_hidden_layers"]
-    h, kv, hd = (model["num_attention_heads"],
-                 model["num_key_value_heads"], model["head_dim"])
-    f, v = model["intermediate_size"], model["vocab_size"]
-    e = model.get("num_local_experts", 0)
-    specs = [
-        (("embed",), (v, d), "normal", d ** -0.5),
-        (("layers", "attn_norm"), (n, d), "norm", 0.0),
-        (("layers", "wq"), (n, d, h * hd), "normal", d ** -0.5),
-        (("layers", "wk"), (n, d, kv * hd), "normal", d ** -0.5),
-        (("layers", "wv"), (n, d, kv * hd), "normal", d ** -0.5),
-        (("layers", "wo"), (n, h * hd, d), "normal", (h * hd) ** -0.5),
-        (("layers", "mlp_norm"), (n, d), "norm", 0.0),
-        (("final_norm",), (d,), "norm", 0.0),
-    ]
-    lead = (n, e) if e else (n,)
-    if e:
-        specs.append((("layers", "router"), (n, d, e), "router", d ** -0.5))
-    specs += [
-        (("layers", "w_gate"), lead + (d, f), "normal", d ** -0.5),
-        (("layers", "w_up"), lead + (d, f), "normal", d ** -0.5),
-        (("layers", "w_down"), lead + (f, d), "normal", f ** -0.5),
-    ]
-    return specs
-
-
-def tree_differences(model: dict, dtype, theirs) -> list:
-    """How the tree this file builds differs from ``theirs``, a tree of
+def tree_differences(specs: list, dtype, theirs) -> list:
+    """How the tree of ``specs`` differs from ``theirs``, a tree of
     anything with ``shape`` and ``dtype`` (the program's ``init_params``
     under ``jax.eval_shape``): one line a leaf, none when they agree."""
     import jax
@@ -59,9 +35,9 @@ def tree_differences(model: dict, dtype, theirs) -> list:
 
     mine = {
         "/".join(path): (tuple(shape), np.dtype(
-            np.float32 if kind == "router" else dtype
+            np.float32 if leaf_dtype == "float32" else dtype
         ))
-        for path, shape, kind, _scale in weight_specs(model)
+        for path, shape, _kind, _scale, leaf_dtype in specs
     }
     found = {
         "/".join(str(getattr(k, "key", k)) for k in path):
@@ -77,31 +53,33 @@ def tree_differences(model: dict, dtype, theirs) -> list:
     return out
 
 
-def make_weights(model: dict, seed: int, dtype):
+def make_weights(specs: list, seed: int, dtype):
     """The whole tree in one jitted call; ``seed`` is traced, so every
     seed runs the same compiled program."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    specs = weight_specs(model)
-
     def build(lo, hi):
         key = jax.random.fold_in(jax.random.fold_in(jax.random.key(0), lo), hi)
-        tree = {"layers": {}}
-        for index, (path, shape, kind, scale) in enumerate(specs):
+        tree = {}
+        for index, (path, shape, kind, scale, leaf_dtype) in enumerate(specs):
             noise = jax.random.normal(
                 jax.random.fold_in(key, index), shape, jnp.float32
             )
-            if kind == "norm":
-                leaf = (1.0 + 0.1 * noise).astype(dtype)
-            elif kind == "router":
+            if kind == "around_one":
+                leaf = 1.0 + scale * noise
+            elif kind == "normal":
                 leaf = noise * scale
             else:
-                leaf = (noise * scale).astype(dtype)
+                raise ValueError(f"leaf {'/'.join(path)}: kind {kind!r}")
+            if leaf_dtype == "served":
+                leaf = leaf.astype(dtype)
+            elif leaf_dtype != "float32":
+                raise ValueError(f"leaf {'/'.join(path)}: dtype {leaf_dtype!r}")
             node = tree
             for name in path[:-1]:
-                node = node[name]
+                node = node.setdefault(name, {})
             node[path[-1]] = leaf
         return tree
 

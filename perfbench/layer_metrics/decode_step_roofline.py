@@ -1,8 +1,7 @@
 """One decode tick's share of the roofline: what the tick needs for the
 rows and tokens live while the trace ran, over the chip's peaks, over
 the tick's device time."""
-from perfbench.harness import roofline
-from perfbench.harness.readers import roofline_share, stat_mean
+from perfbench.harness.readers import family_needs, roofline_share, stat_mean
 
 
 def read(run):
@@ -10,6 +9,5 @@ def read(run):
     tokens = stat_mean(run, "kv_live_tokens", traced_only=True)
     if not rows or tokens is None:
         return None
-    return roofline_share(
-        run, roofline.decode_tick(run["model"], rows, tokens), "jit__decode"
-    )
+    needs = family_needs(run).decode_tick(run["model"], rows, tokens)
+    return roofline_share(run, needs, "jit__decode")

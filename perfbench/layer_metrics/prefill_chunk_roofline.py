@@ -2,8 +2,7 @@
 through the window's mean prompt."""
 from statistics import mean
 
-from perfbench.harness import roofline
-from perfbench.harness.readers import judged, roofline_share
+from perfbench.harness.readers import family_needs, judged, roofline_share
 
 
 def read(run):
@@ -11,5 +10,7 @@ def read(run):
     if not prompts:
         return None
     chunk = run["final_stats"].get("prefill_chunk_tokens", 64)
-    needs = roofline.prefill_chunk(run["model"], chunk, mean(prompts) / 2.0)
+    needs = family_needs(run).prefill_chunk(
+        run["model"], chunk, mean(prompts) / 2.0
+    )
     return roofline_share(run, needs, "jit__prefill")

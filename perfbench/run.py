@@ -12,7 +12,11 @@ benchmark's own reference in a child process, and prints one JSON line.
 
 The cell, its configuration, its traffic mix and its metrics are data:
 ``BENCHMARK.json`` names them and the files under this directory hold
-them (see ``harness/manifest.py``).
+them.  What belongs to one architecture family (the env that makes the
+program build it, its weight tree, its plain reference, what its calls
+need of the chip) is four files under ``families/<family>/``, found by
+the ``"family"`` key of the configuration's file; of a configuration
+this file itself reads ``vocab_size`` alone (see ``harness/manifest.py``).
 """
 
 import time
@@ -22,6 +26,7 @@ PROCESS_START_WALL = time.time()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import subprocess  # noqa: E402
@@ -57,41 +62,26 @@ def say(message: str) -> None:
     print(message, flush=True)
 
 
-def sizing_env(model: dict, mix: dict) -> dict:
-    """The env that SIZES the deployment; every other knob of the
-    program stays at the repo's default."""
-    if model["head_dim"] * model["num_attention_heads"] != model["hidden_size"]:
-        raise RunFailure(
-            "the program derives head_dim as hidden_size / heads; "
-            "this configuration states another"
-        )
-    templated = {
-        "VOCAB": model["vocab_size"],
-        "D_MODEL": model["hidden_size"],
-        "N_LAYERS": model["num_hidden_layers"],
-    }
-    routed = {
-        "N_HEADS": model["num_attention_heads"],
-        "N_KV_HEADS": model["num_key_value_heads"],
-        "D_FF": model["intermediate_size"],
-        "N_EXPERTS": model.get("num_local_experts", 0),
-    }
-    env = {k: str(v) for k, v in templated.items()}
+def sizing_env(family, model: dict, config_path: str, mix: dict) -> dict:
+    """The env that SIZES the deployment: what the configuration's
+    family says makes the program build it, and the mix's sizes over
+    that; every other knob of the program stays at the repo's default."""
+    try:
+        env = dict(family.program_env(model, config_path))
+    except ValueError as e:
+        raise RunFailure(f"{config_path}: {e}")
     env.update({k: str(v) for k, v in mix["sizing_env"].items()})
-    env.update({f"TASKCFG_ALL_{k}": str(v) for k, v in routed.items()})
     return env
 
 
-def deployment_env(bench, cell: dict, model: dict, mix: dict, seed: int,
+def deployment_env(sizes: dict, config_path: str, seed: int,
                    worker_dir: str) -> dict:
     """What the scheduler is started with: the sizes, the benchmark's
     worker entry in place of the program's, and what that entry needs
     to build the seed's weights."""
-    env = sizing_env(model, mix)
+    env = dict(sizes)
     env["JAX_FRAMEWORK_DIR"] = os.path.abspath(worker_dir)
-    env["TASKCFG_ALL_PERFBENCH_CONFIG_FILE"] = bench.config_path(
-        cell["config"]
-    )
+    env["TASKCFG_ALL_PERFBENCH_CONFIG_FILE"] = config_path
     env["TASKCFG_ALL_PERFBENCH_SEED"] = str(seed)
     return env
 
@@ -225,9 +215,13 @@ def run(args) -> int:
     bench = manifests.Manifest(args.root)
     cell = bench.cell(args.workload)
     model = bench.config(cell["config"])
+    config_path = bench.config_path(cell["config"])
+    family = bench.family(cell["config"])
     mix = bench.traffic(cell["traffic"])
     params = bench.cell_params(cell["name"])
     peaks = bench.peaks()
+    sizes = sizing_env(family, model, config_path, mix)
+    say(f"family {family.name}: sizing env {json.dumps(sizes)}")
     trace = bool(args.trace)
     rehearse = args.rehearse_cpu
 
@@ -246,7 +240,7 @@ def run(args) -> int:
     requests = schedule(
         mix, params, model["vocab_size"], args.seconds, args.seed
     )
-    env = deployment_env(bench, cell, model, mix, args.seed, args.worker_dir)
+    env = deployment_env(sizes, config_path, args.seed, args.worker_dir)
     for item in args.program_env:
         key, _, value = item.partition("=")
         env[f"TASKCFG_ALL_{key}"] = value
@@ -276,7 +270,7 @@ def run(args) -> int:
         say("device " + json.dumps(device))
         if not rehearse:
             check_device(device, cell["chips"], peaks)
-        for key, want in sizing_env(model, mix).items():
+        for key, want in sizes.items():
             field = key.replace("TASKCFG_ALL_", "").lower()
             if field in stats["model"] and str(stats["model"][field]) != want:
                 raise RunFailure(
@@ -366,6 +360,7 @@ def run(args) -> int:
         "trace_window": trace_window,
         "peaks": peaks.get(device["kind"]),
         "device": device,
+        "config_file": config_path,
     }
 
     # the reference check, now that the chip is free
@@ -377,7 +372,7 @@ def run(args) -> int:
     check_file = os.path.join(workdir, "check_in.json")
     with open(check_file, "w") as f:
         json.dump({
-            "config_file": bench.config_path(cell["config"]),
+            "config_file": config_path,
             "seed": args.seed, "limits": params["correct_limits"],
             "routing_margin": params.get("routing_margin", 0.0),
             "wide_gap": params.get("wide_gap", 0.1),
@@ -390,8 +385,6 @@ def run(args) -> int:
         [sys.executable, "-m", "perfbench.harness.check", check_file],
         child_env, 900,
     )
-    for name, (value, limit) in verdict["compared"].items():
-        say(f"correct: {name} {value:.6g} (limit {limit})")
     correct = bool(verdict["correct"]) and failed == 0
 
     device["memory_peak_bytes"] = info["memory_peak_bytes"]
@@ -435,6 +428,16 @@ def run(args) -> int:
                     args.keep, f"{cell['name']}.{args.seed}.{name}"
                 ))
     shutil.rmtree(workdir, ignore_errors=True)
+    # each number compared beside its limit, printed once: the last
+    # lines of stderr, and the last key of the result's line (a number
+    # that could not be read is infinite, which JSON cannot hold)
+    result["compared"] = {
+        name: [value if math.isfinite(value) else str(value), limit]
+        for name, (value, limit) in verdict["compared"].items()
+    }
+    for name, (value, limit) in result["compared"].items():
+        print(f"correct: {name} {value} (limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
     say(json.dumps(result))
     return 0
 

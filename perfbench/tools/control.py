@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """The control of the comparison that decides ``correct``: the
-reference, put in the program's place and computed with int8 weights
+reference of the configuration's family, put in the program's place and computed with int8 weights
 (the nearest precision below the configurations' bf16), has to come out
 as NOT correct.  A SIMULATION of a lower-precision program, not a served
 run: the program's own int8 weights cannot start at the cells' sizes
@@ -33,17 +33,18 @@ def main(argv) -> int:
     import numpy as np
 
     for path in argv[1:]:
-        job, model, weights, platform = check.load_job(path)
+        job, model, family, weights, platform = check.load_job(path)
         config_file = job["config_file"]
         if not os.path.exists(config_file):
             raise SystemExit(f"{config_file} of {path} is not here")
         sound, control, margins = [], [], []
         for r in job["requests"]:
             exact, margin = check.served_logits(
-                model, weights, r["prompt"], r["served"]
+                family.reference, model, weights, r["prompt"], r["served"]
             )
             lower, _ = check.served_logits(
-                model, weights, r["prompt"], r["served"], lower="int8"
+                family.reference, model, weights, r["prompt"], r["served"],
+                lower="int8",
             )
             sound.append(check.chosen_gaps(exact, r["served"]))
             control.append(check.chosen_gaps(

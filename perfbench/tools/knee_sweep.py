@@ -41,9 +41,11 @@ def main() -> int:
     model = bench.config(cell["config"])
     params = bench.cell_params(cell["name"])
     mix = bench.traffic(cell["traffic"])
+    config_path = bench.config_path(cell["config"])
+    family = bench.family(cell["config"])
     env = bench_run.deployment_env(
-        bench, cell, model, mix, args.seed,
-        os.path.join(CHECKOUT, "perfbench", "worker"),
+        bench_run.sizing_env(family, model, config_path, mix), config_path,
+        args.seed, os.path.join(CHECKOUT, "perfbench", "worker"),
     )
     workdir = os.path.join(CHECKOUT, ".perfbench_run")
     shutil.rmtree(workdir, ignore_errors=True)

@@ -31,7 +31,8 @@ sys.path.insert(0, CHECKOUT)
 PROGRAM_NAMES = (
     "frameworks/jax/serve_worker.py: main()",
     "dcos_commons_tpu.models.init_params(config, key), looked up by "
-    "main() when it runs, and config.dtype",
+    "main() when it runs, its tree as the configuration's family states "
+    "it (families/<family>/weight_specs.py), and config.dtype",
     "dcos_commons_tpu.serve.pool.PagedPoolModel.prefill_chunk and .decode",
 )
 
@@ -56,6 +57,7 @@ def _seeded_weights():
     after checking, leaf by leaf, that the benchmark's tree is the one
     the program's own ``init_params`` would have built."""
     import dcos_commons_tpu.models as models
+    from perfbench.harness.manifest import family_of
     from perfbench.harness.weights import make_weights, tree_differences
 
     if not callable(getattr(models, "init_params", None)):
@@ -63,6 +65,8 @@ def _seeded_weights():
     program_init = models.init_params
     with open(os.environ["PERFBENCH_CONFIG_FILE"]) as f:
         model = json.load(f)
+    family = family_of(os.environ["PERFBENCH_CONFIG_FILE"])
+    specs = family.weight_specs(model)
     seed = int(os.environ["PERFBENCH_SEED"])
 
     def init_params(config, key):
@@ -72,14 +76,14 @@ def _seeded_weights():
         SETUP_TIMES["backend_up"] = time.time() - STARTED
         # shapes and dtypes only: nothing is built on the device
         theirs = jax.eval_shape(functools.partial(program_init, config), key)
-        differences = tree_differences(model, config.dtype, theirs)
+        differences = tree_differences(specs, config.dtype, theirs)
         if differences:
             raise ProgramChanged(
                 "the program's parameter tree is not the one "
-                "perfbench/harness/weights.py builds: "
+                f"{family.directory}/weight_specs.py states: "
                 + "; ".join(differences[:8])
             )
-        tree = make_weights(model, seed, config.dtype)
+        tree = make_weights(specs, seed, config.dtype)
         jax.block_until_ready(tree)
         SETUP_TIMES["weights_built"] = time.time() - STARTED
         print(

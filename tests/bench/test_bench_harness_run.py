@@ -1,8 +1,9 @@
 """A whole run of the harness on the CPU, at toy size, in its
 rehearsal mode: scheduler -> agent -> worker, traffic, teardown, the
 reference check and the result line.  The cell it runs, its
-configuration, its mix and one per-layer metric were added to a copy
-of the benchmark by files and entries alone."""
+configuration, that configuration's FAMILY (the four files the harness
+finds by its name), its mix and two per-layer metrics were added to a
+copy of the benchmark by files and entries alone."""
 
 import json
 import os
@@ -46,8 +47,8 @@ def test_a_cell_added_by_files_alone_runs_and_is_correct(traced_run):
     assert traced_run.returncode == 0, traced_run.stderr[-3000:]
     lines = traced_run.stdout.splitlines()
     result = json.loads(lines[-1])
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]
     assert result["correct"] is True
     assert result["attempted"] == 20 and result["failed"] == 0
     # the metric added by one reader file and one entry is reported
@@ -56,6 +57,38 @@ def test_a_cell_added_by_files_alone_runs_and_is_correct(traced_run):
     # found (decode_tick_ms.toy reads the trace: nothing in a rehearsal)
     assert "engine_active_slots_mean.chat" in result["metrics"]
     assert result["metrics"]["deploy_plan_s"]["value"] > 0
+
+
+def test_every_hook_the_run_went_through_was_the_toy_familys(traced_run):
+    """One marker a hook, each made by the toy family's own file; and
+    the toy configuration has no key but ``vocab_size`` that the first
+    family or a size-reading harness could have read."""
+    assert not (set(toyroot.TOY_FAMILY_MODEL) - {"vocab_size"}) & {
+        "hidden_size", "head_dim", "num_hidden_layers", "intermediate_size",
+        "num_attention_heads", "num_key_value_heads", "num_local_experts",
+    }
+    out = traced_run.stdout
+    # program_env: its own key beside the sizes, which the worker's
+    # /stats confirmed or the run would have stopped
+    sizing = [line for line in out.splitlines()
+              if line.startswith("family toy_family: sizing env ")]
+    env = json.loads(sizing[0].split("sizing env ", 1)[1])
+    assert env["TASKCFG_ALL_TOY_FAMILY"] == "env-marker"
+    assert (env["D_MODEL"], env["TASKCFG_ALL_N_EXPERTS"]) == ("64", "4")
+    assert env["KV_PAGES"] == "64"  # the mix's sizes, merged over it
+    # weight_specs: the toy reference refuses weights whose final norm
+    # is not the toy family's, and the served tokens agree with it
+    assert "reference of family toy_family on cpu" in out
+    result = json.loads(out.splitlines()[-1])
+    assert result["correct"] is True
+    # reference: only under the toy family's margin (+1000) is a
+    # position steadier than the cell's 999, and `steady_max_gap` reads
+    steady = [line for line in out.splitlines() if "of them steady" in line]
+    read, of_them = [int(w) for w in steady[0].split() if w.isdigit()][-2:]
+    assert read == of_them > 0
+    assert result["compared"]["steady_max_gap"][0] == 0.0
+    # needs: a key that only the toy family's needs.py returns
+    assert result["metrics"]["toy_needs_marker"]["value"] == 26.0
 
 
 def test_rehearsal_says_cpu_and_writes_no_device_metric(traced_run):
@@ -71,10 +104,16 @@ def test_rehearsal_says_cpu_and_writes_no_device_metric(traced_run):
 
 
 def test_every_number_compared_is_printed_beside_its_limit(traced_run):
-    printed = [line for line in traced_run.stdout.splitlines()
+    # once: as the last lines of stderr, and last in the result's line
+    limits = toyroot.TOY_CELL["correct_limits"]
+    printed = [line for line in traced_run.stderr.splitlines()
                if line.startswith("correct: ")]
-    assert {line.split()[1] for line in printed} == set(toyroot.TOY_LIMITS)
+    assert printed == traced_run.stderr.splitlines()[-len(limits):]
+    assert [line.split()[1] for line in printed] == list(limits)
     assert all("(limit " in line for line in printed)
+    assert "correct: " not in traced_run.stdout
+    result = json.loads(traced_run.stdout.splitlines()[-1])
+    assert result["compared"] == {k: [0.0, v] for k, v in limits.items()}
     assert "compilations inside ramp and window: 0" in traced_run.stdout
 
 

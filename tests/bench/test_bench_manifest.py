@@ -1,5 +1,11 @@
 """BENCHMARK.json against the contract it is written to, and every
-name in it against the file the harness finds by that name."""
+name in it against the file the harness finds by that name.  The entry
+tests hold for a configuration of ANY family: they run over the real
+file's entries and over those that ``toyroot.py`` appends, whose
+configuration is of a second family and shares no size's name with the
+first but ``vocab_size``.  What only one configuration promises (its
+published widths) is a test keyed to that configuration's name; a later
+PR brings such a test in a file of its own."""
 
 import json
 import os
@@ -12,7 +18,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)
 )))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import toyroot  # noqa: E402
 from perfbench.harness.manifest import Manifest  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -23,6 +31,19 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 def load():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def roots(tmp_path_factory):
+    """Where each manifest's files lie: the repo, and the throw-away
+    root with the toy family, configuration, mix, cell and metrics."""
+    return {"repo": REPO,
+            "toy": toyroot.build(str(tmp_path_factory.mktemp("entries")))}
+
+
+def manifest_of(root):
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
         return json.load(f)
 
 
@@ -42,12 +63,21 @@ def test_top_level_keys_and_sizes():
 
 
 def entries(kind):
-    return [pytest.param(e, id=e["name"]) for e in load()[kind]]
+    """(which root, entry): every entry of the real file, and every
+    entry that the toy root adds to it."""
+    real = load()[kind]
+    names = {e["name"] for e in real}
+    added = [e for e in toyroot.manifest()[kind] if e["name"] not in names]
+    return [pytest.param("repo", e, id=e["name"]) for e in real] + [
+        pytest.param("toy", e, id="toyroot-" + e["name"]) for e in added
+    ]
 
 
-@pytest.mark.parametrize("metric", entries("end_to_end") + entries("per_layer"))
-def test_metric_entry(metric):
-    data = load()
+@pytest.mark.parametrize(
+    "where,metric", entries("end_to_end") + entries("per_layer"))
+def test_metric_entry(roots, where, metric):
+    root = roots[where]
+    data = manifest_of(root)
     cells = {w["name"] for w in data["workloads"]}
     end_to_end = {m["name"]: m for m in data["end_to_end"]}
     assert NAME.match(metric["name"])
@@ -71,17 +101,17 @@ def test_metric_entry(metric):
         folder = "layer_metrics"
     # a reader file of its own, or its quantity's (the name less its suffix)
     assert any(
-        os.path.isfile(os.path.join(REPO, "perfbench", folder, name + ".py"))
+        os.path.isfile(os.path.join(root, "perfbench", folder, name + ".py"))
         for name in (metric["name"], metric["name"].rsplit(".", 1)[0])
     )
     kind = "end_to_end" if folder == "end_to_end" else "per_layer"
-    assert callable(Manifest(REPO).reader(kind, metric["name"]))
+    assert callable(Manifest(root).reader(kind, metric["name"]))
 
 
-@pytest.mark.parametrize("cell", entries("workloads"))
-def test_cell_entry(cell):
-    data = load()
-    bench = Manifest(REPO)
+@pytest.mark.parametrize("where,cell", entries("workloads"))
+def test_cell_entry(roots, where, cell):
+    data = manifest_of(roots[where])
+    bench = Manifest(roots[where])
     assert set(cell) == {"name", "config", "traffic", "chips", "why"}
     for key in ("name", "config", "traffic"):
         assert NAME.match(cell[key])
@@ -104,32 +134,64 @@ def test_cell_entry(cell):
     assert pairs.count((cell["config"], cell["traffic"])) == 1
 
 
-@pytest.mark.parametrize("config", entries("configs"))
-def test_config_entry(config):
-    data = load()
+@pytest.mark.parametrize("where,config", entries("configs"))
+def test_config_entry(roots, where, config):
+    root = roots[where]
+    data = manifest_of(root)
     assert set(config) == {"name", "source", "file", "reduced", "why"}
     assert NAME.match(config["name"])
-    assert config["file"].startswith("perfbench/") and PATH.match(config["file"])
+    assert PATH.match(config["file"]) and any(
+        config["file"].startswith(path + "/") for path in data["paths"])
     assert [c["file"] for c in data["configs"]].count(config["file"]) == 1
     assert config["name"] in {w["config"] for w in data["workloads"]}
-    with open(os.path.join(REPO, config["file"])) as f:
+    with open(os.path.join(root, config["file"])) as f:
         model = json.load(f)
     widths = ("size", "_dim", "_rank", "per_tok")
+    published = model.get("published", {})
     for key in config["reduced"]:
         assert NAME.match(key)
         assert not any(w in key for w in widths), f"{key} is a width"
         # what was changed states what was published
-        assert key in model["published"] and model["published"][key] != model[key]
-    assert set(model["published"]) == set(config["reduced"])
-    # published widths (Mistral 7B v0.3 / Mixtral 8x7B v0.1 config.json)
-    assert (model["hidden_size"], model["intermediate_size"]) == (4096, 14336)
-    assert (model["num_attention_heads"], model["num_key_value_heads"],
-            model["head_dim"]) == (32, 8, 128)
-    if model["model_type"] == "mixtral":
-        assert (model["num_local_experts"], model["num_experts_per_tok"],
-                model["vocab_size"]) == (8, 2, 32000)
-    else:
-        assert model["vocab_size"] == 32768
+        assert key in published and published[key] != model[key]
+    assert set(published) == set(config["reduced"])
+    # its family is a directory that holds the four hooks, and the one
+    # size the harness itself reads is there; every other key of the
+    # file is its family's to name
+    family = Manifest(root).family(config["name"])
+    assert family.name == model["family"] and NAME.match(family.name)
+    assert isinstance(model["vocab_size"], int) and model["vocab_size"] > 0
+
+
+# What one configuration promises of its own: the widths its source
+# published, by the names ITS family reads.  Keyed by the
+# configuration's name; one that a later PR adds is held to its widths
+# by a test in a file of that PR's.
+PUBLISHED_WIDTHS = {
+    # mistralai/Mixtral-8x7B-v0.1 config.json
+    "mixtral-8x7b-v0.1": {
+        "family": "gqa_decoder", "model_type": "mixtral",
+        "hidden_size": 4096, "intermediate_size": 14336,
+        "num_attention_heads": 32, "num_key_value_heads": 8,
+        "head_dim": 128, "num_local_experts": 8, "num_experts_per_tok": 2,
+        "vocab_size": 32000,
+    },
+}
+
+
+@pytest.mark.parametrize("name", sorted(PUBLISHED_WIDTHS))
+def test_a_configuration_keeps_its_published_widths(name):
+    model = Manifest(REPO).config(name)
+    assert {k: model[k] for k in PUBLISHED_WIDTHS[name]} == (
+        PUBLISHED_WIDTHS[name])
+
+
+def test_the_toy_configuration_shares_no_size_with_the_first_family():
+    """So the entry tests above, run over it, prove that they read no
+    family's names: a test that did would stop on a KeyError."""
+    shared = set(toyroot.TOY_FAMILY_MODEL) & set(
+        PUBLISHED_WIDTHS["mixtral-8x7b-v0.1"])
+    assert shared == {"family", "vocab_size"}
+    assert toyroot.TOY_FAMILY_MODEL["family"] != "gqa_decoder"
 
 
 def test_names_are_unique_and_four_chip_cells_are_few():

@@ -1,4 +1,5 @@
-"""The plain reference against the program's own forward pass at toy
+"""The first family's plain reference (``perfbench/families/
+gqa_decoder/reference.py``) against the program's own forward pass at toy
 size in float32, and the control: the same comparison fails when the
 tokens come from int8 weights (the nearest precision below, and a
 path the program has of its own)."""
@@ -15,10 +16,20 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from toyroot import TOY_LIMITS, TOY_MODEL  # noqa: E402
+from toyroot import TOY_LIMITS, TOY_MODEL, family  # noqa: E402
 
 DENSE = dict(TOY_MODEL, num_local_experts=0, model_type="mistral")
 SEEDS = [3, 2**31 + 11, 77]
+
+
+def make_weights(model, seed):
+    """The seed's tree for ``model`` as the first family states it, in
+    float32."""
+    import jax.numpy as jnp
+
+    from perfbench.harness import weights as w
+
+    return w.make_weights(family().weight_specs(model), seed, jnp.float32)
 
 
 def program_config(model):
@@ -56,9 +67,9 @@ def test_reference_agrees_with_the_program_in_float32(model):
     import jax.numpy as jnp
 
     from dcos_commons_tpu.models import forward
-    from perfbench.harness import reference, weights as w
 
-    weights = w.make_weights(model, 2**31 + 77, jnp.float32)
+    reference = family().reference
+    weights = make_weights(model, 2**31 + 77)
     tokens = np.random.default_rng(0).integers(0, model["vocab_size"], 40)
     with jax.default_matmul_precision("highest"):
         got = forward(program_config(model), weights, jnp.asarray([tokens]))[0]
@@ -71,9 +82,8 @@ def test_reference_agrees_with_the_program_in_float32(model):
 def test_padding_behind_the_sequence_changes_nothing():
     import jax.numpy as jnp
 
-    from perfbench.harness import reference, weights as w
-
-    weights = w.make_weights(TOY_MODEL, 5, jnp.float32)
+    reference = family().reference
+    weights = make_weights(TOY_MODEL, 5)
     tokens = np.arange(20) % TOY_MODEL["vocab_size"]
     padded = np.concatenate([tokens, np.zeros(12, tokens.dtype)])
     a = reference.logits(TOY_MODEL, weights, tokens)
@@ -85,17 +95,16 @@ def test_weights_come_from_the_seed_alone():
     import jax
     import jax.numpy as jnp
 
-    from perfbench.harness import weights as w
-
-    a = w.make_weights(TOY_MODEL, 2**31 + 77, jnp.float32)
-    b = w.make_weights(TOY_MODEL, 2**31 + 77, jnp.float32)
-    c = w.make_weights(TOY_MODEL, 2**31 + 78, jnp.float32)
+    a = make_weights(TOY_MODEL, 2**31 + 77)
+    b = make_weights(TOY_MODEL, 2**31 + 77)
+    c = make_weights(TOY_MODEL, 2**31 + 78)
     same = jax.tree.map(lambda x, y: bool((x == y).all()), a, b)
     assert all(jax.tree.leaves(same))
     assert not bool((a["embed"] == c["embed"]).all())
     assert a["layers"]["router"].dtype == jnp.float32
     shapes = {
-        "/".join(p): s for p, s, _k, _s in w.weight_specs(TOY_MODEL)
+        "/".join(p): s
+        for p, s, _k, _s, _d in family().weight_specs(TOY_MODEL)
     }
     assert shapes["layers/w_gate"] == (2, 4, 64, 96)
     assert shapes["layers/wk"] == (2, 64, 32)
@@ -109,10 +118,11 @@ def test_sound_choices_pass_and_int8_choices_fail(seed):
     import jax.numpy as jnp
 
     from dcos_commons_tpu.models import quantize_params_int8
-    from perfbench.harness import check, reference, weights as w
+    from perfbench.harness import check
 
+    reference = family().reference
     model = TOY_MODEL
-    weights = w.make_weights(model, seed, jnp.float32)
+    weights = make_weights(model, seed)
     tokens = np.random.default_rng(seed).integers(
         0, model["vocab_size"], (6, 64)
     )
@@ -166,11 +176,10 @@ def test_steady_numbers_leave_out_positions_with_a_narrow_routing_margin():
 
 
 def test_compare_reads_every_served_position_of_every_request():
-    import jax.numpy as jnp
+    from perfbench.harness import check
 
-    from perfbench.harness import check, weights as w
-
-    weights = w.make_weights(TOY_MODEL, 9, jnp.float32)
+    reference = family().reference
+    weights = make_weights(TOY_MODEL, 9)
     tokens = np.random.default_rng(9).integers(0, 128, (1, 30))
     served = first_choices(TOY_MODEL, weights, tokens)[0]
     # teacher forcing: position i of the sequence predicts token i + 1,
@@ -178,17 +187,19 @@ def test_compare_reads_every_served_position_of_every_request():
     # reads as sound only where the program's choice was followed
     prompt, rest = tokens[0, :20].tolist(), tokens[0, 20:].tolist()
     ok, compared, n, steady = check.compare(
-        TOY_MODEL, weights, [{"prompt": prompt, "served": rest}], TOY_LIMITS
+        reference, TOY_MODEL, weights, [{"prompt": prompt, "served": rest}],
+        TOY_LIMITS,
     )
     assert n == 10 and not ok  # random continuations are not first choices
     assert steady == 10  # a margin of 0 leaves every position in
     follow = [int(served[19])]
     ok, compared, n, _ = check.compare(
-        TOY_MODEL, weights, [{"prompt": prompt, "served": follow}], TOY_LIMITS
+        reference, TOY_MODEL, weights,
+        [{"prompt": prompt, "served": follow}], TOY_LIMITS,
     )
     assert n == 1 and ok, compared
     _, _, _, steady = check.compare(
-        TOY_MODEL, weights, [{"prompt": prompt, "served": rest}], TOY_LIMITS,
-        routing_margin=0.2,
+        reference, TOY_MODEL, weights, [{"prompt": prompt, "served": rest}],
+        TOY_LIMITS, routing_margin=0.2,
     )
     assert 0 < steady < 10

@@ -1,4 +1,5 @@
-"""The roofline functions against numbers worked by hand."""
+"""The first family's needs (``perfbench/families/gqa_decoder/
+needs.py``) and the roofline share against numbers worked by hand."""
 
 import json
 import os
@@ -10,8 +11,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)
 )))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from perfbench.harness import readers, roofline  # noqa: E402
+from toyroot import family  # noqa: E402
+
+needs_of = family().needs
 
 
 # a dense configuration at Mistral-7B-v0.3's published widths, 16 layers
@@ -39,7 +44,7 @@ KV_TOKEN_LAYER = 2 * 8 * 128 * 2                  # bytes: k and v, bf16
 
 def test_mistral_decode_tick_needs():
     m = config("mistral-7b-v0.3")
-    needs = roofline.decode_tick(m, live_rows=40, live_tokens=20000)
+    needs = needs_of.decode_tick(m, live_rows=40, live_tokens=20000)
     weights = (16 * (ATTN + FFN) + 32768 * 4096) * 2
     assert weights == 7_247_757_312
     assert needs["weight_bytes"] == weights
@@ -58,16 +63,16 @@ def test_mistral_decode_tick_needs():
 def test_mixtral_decode_tick_reads_the_experts_its_rows_choose():
     m = config("mixtral-8x7b-v0.1")
     # one row chooses 2 of 8 experts; forty rows choose all but 8*0.75^40
-    assert roofline.experts_touched(m, 1) == pytest.approx(2.0)
-    assert roofline.experts_touched(m, 40) == pytest.approx(
+    assert needs_of.experts_touched(m, 1) == pytest.approx(2.0)
+    assert needs_of.experts_touched(m, 40) == pytest.approx(
         8 * (1 - 0.75 ** 40)
     )
-    one = roofline.decode_tick(m, live_rows=1, live_tokens=500)
+    one = needs_of.decode_tick(m, live_rows=1, live_tokens=500)
     router = 4096 * 8
     assert one["weight_bytes"] == pytest.approx(
         (3 * (ATTN + router + 2 * FFN) + 32000 * 4096) * 2
     )
-    full = roofline.decode_tick(m, live_rows=64, live_tokens=30000)
+    full = needs_of.decode_tick(m, live_rows=64, live_tokens=30000)
     assert full["weight_bytes"] == pytest.approx(
         (3 * (ATTN + router + 8 * FFN) + 32000 * 4096) * 2, rel=1e-6
     )
@@ -86,7 +91,7 @@ def test_mixtral_decode_tick_reads_the_experts_its_rows_choose():
 ])
 def test_prefill_chunk_needs(name, layers, vocab, experts):
     m = config(name)
-    needs = roofline.prefill_chunk(m, chunk_tokens=64, context_tokens=1024)
+    needs = needs_of.prefill_chunk(m, chunk_tokens=64, context_tokens=1024)
     per_layer = ATTN + (4096 * experts) + (experts or 1) * FFN
     weights = (layers * per_layer + vocab * 4096) * 2
     assert needs["weight_bytes"] == pytest.approx(weights, rel=1e-6)
@@ -102,7 +107,7 @@ def test_prefill_chunk_needs(name, layers, vocab, experts):
 
 def test_roofline_share_is_least_time_over_measured_time():
     m = config("mistral-7b-v0.3")
-    needs = roofline.decode_tick(m, 40, 20000)
+    needs = needs_of.decode_tick(m, 40, 20000)
     least = needs["bytes"] / 819e9
     run = {"peaks": PEAK,
            "trace": {"programs": {"jit__decode": {"median_ms": 50.0}}}}

@@ -18,7 +18,7 @@ sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from test_bench_reference import DENSE, program_config  # noqa: E402
-from toyroot import TOY_MODEL  # noqa: E402
+from toyroot import TOY_MODEL, family  # noqa: E402
 
 
 def program_shapes(model):
@@ -38,7 +38,8 @@ def test_the_benchmarks_tree_is_the_programs(model):
 
     from perfbench.harness.weights import tree_differences
 
-    assert tree_differences(model, jnp.float32, program_shapes(model)) == []
+    specs = family().weight_specs(model)
+    assert tree_differences(specs, jnp.float32, program_shapes(model)) == []
 
 
 def test_a_program_that_changed_its_tree_is_named_leaf_by_leaf():
@@ -49,14 +50,15 @@ def test_a_program_that_changed_its_tree_is_named_leaf_by_leaf():
     theirs = program_shapes(TOY_MODEL)
     layers = dict(theirs["layers"])
     layers["w_gate_up"] = layers.pop("w_gate")  # a fused feed-forward
+    specs = family().weight_specs
     found = tree_differences(
-        TOY_MODEL, jnp.float32, dict(theirs, layers=layers)
+        specs(TOY_MODEL), jnp.float32, dict(theirs, layers=layers)
     )
     assert any("no leaf layers/w_gate" in line for line in found)
     assert any("layers/w_gate_up" in line and "unknown" in line
                for line in found)
     wider = dict(TOY_MODEL, intermediate_size=128)
-    found = tree_differences(wider, jnp.float32, theirs)
+    found = tree_differences(specs(wider), jnp.float32, theirs)
     assert len(found) == 3 and all("(2, 4, 64, 96)" in line or
                                    "(2, 4, 96, 64)" in line for line in found)
 

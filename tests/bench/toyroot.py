@@ -1,17 +1,29 @@
 """A throw-away benchmark root for the tests: the real BENCHMARK.json
-and the real files under ``perfbench/``, plus a toy configuration, a
-toy mix, a cell of the two, one more per-layer metric with a reader of
-its own and one (``decode_tick_ms.toy``) that an existing reader
-serves under a new suffix, all ADDED as new files and new entries.  No file that is
-there is edited, which is what a later PR is held to."""
+and the real files under ``perfbench/``, plus a SECOND FAMILY (its four
+files), a toy configuration of that family, a toy mix, a cell of the
+two, two more per-layer metrics with readers of their own and one
+(``decode_tick_ms.toy``) that an existing reader serves under a new
+suffix, all ADDED as new files and new entries.  No file that is there
+is edited, which is what a later PR is held to."""
 
 import json
 import os
 import shutil
+import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)
 )))
+
+
+def family(name="gqa_decoder", root=REPO):
+    """The four hooks of a family under ``root``, as the harness loads
+    them."""
+    sys.path.insert(0, REPO)
+    from perfbench.harness.manifest import load_family
+
+    return load_family(os.path.join(root, "perfbench", "families", name))
+
 
 TOY_MODEL = {
     "model_type": "mixtral", "hidden_size": 64, "intermediate_size": 96,
@@ -19,6 +31,81 @@ TOY_MODEL = {
     "num_hidden_layers": 2, "num_local_experts": 4,
     "num_experts_per_tok": 2, "vocab_size": 128, "rope_theta": 10000.0,
     "rms_norm_eps": 1e-06, "tie_word_embeddings": True,
+}
+# The toy cell's configuration, of the family ``toy_family``: no key of
+# it but ``vocab_size`` is one that the first family reads, so a hook of
+# that family, or a harness that read a size itself, stops on a KeyError.
+TOY_FAMILY_NAMES = {
+    "width": "hidden_size", "ffn": "intermediate_size",
+    "heads": "num_attention_heads", "kv_heads": "num_key_value_heads",
+    "head": "head_dim", "depth": "num_hidden_layers",
+    "experts": "num_local_experts", "chosen": "num_experts_per_tok",
+}
+TOY_FAMILY_MODEL = {"family": "toy_family", **{
+    theirs: TOY_MODEL[ours] for theirs, ours in TOY_FAMILY_NAMES.items()
+}, **{k: TOY_MODEL[k] for k in ("vocab_size", "rope_theta", "rms_norm_eps")}}
+# The program serves one architecture, so the second family's files
+# borrow the first's arithmetic under their own names; each hook leaves
+# a marker that only its own file makes.
+TOY_FAMILY_BORROWS = '''
+import functools
+import os
+
+from perfbench.harness.manifest import load_family
+
+NAMES = {names!r}
+
+
+@functools.lru_cache(maxsize=None)
+def first():
+    return load_family(os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "gqa_decoder"))
+
+
+def renamed(model):
+    return {{NAMES.get(k, k): v for k, v in model.items()}}
+'''.format(names=TOY_FAMILY_NAMES)
+TOY_FAMILY = {
+    "program_env.py": '''
+
+def program_env(model, config_path):
+    assert os.path.isfile(config_path)
+    env = first().program_env(renamed(model), config_path)
+    return dict(env, TASKCFG_ALL_TOY_FAMILY="env-marker")
+''',
+    # the marker: a final norm of ones exactly, where the first family
+    # draws it around one
+    "weight_specs.py": '''
+
+def weight_specs(model):
+    return [
+        (path, shape, kind, 0.0 if path == ("final_norm",) else scale, dtype)
+        for path, shape, kind, scale, dtype
+        in first().weight_specs(renamed(model))
+    ]
+''',
+    # it refuses weights without that marker, and its steadiness margin
+    # means something of its own: every position is 1000 steadier
+    "reference.py": '''
+
+def logits(model, weights, tokens, rows=None, lower=None, margins=False):
+    if not bool((weights["final_norm"] == 1).all()):
+        raise SystemExit("these weights are not the toy family's")
+    out, margin = first().reference.logits(
+        renamed(model), weights, tokens, rows, lower, margins=True)
+    return (out, margin + 1000.0) if margins else out
+''',
+    "needs.py": '''
+
+def decode_tick(model, live_rows, live_tokens):
+    needs = first().needs.decode_tick(renamed(model), live_rows, live_tokens)
+    return dict(needs, toy_family=26.0)
+
+
+def prefill_chunk(model, chunk_tokens, context_tokens):
+    return first().needs.prefill_chunk(
+        renamed(model), chunk_tokens, context_tokens)
+''',
 }
 TOY_SIZING = {
     "MAX_LEN": 96, "MAX_NEW_TOKENS": 16, "SERVE_SLOTS": 4,
@@ -36,11 +123,24 @@ TOY_OPEN = {
     "sizing_env": TOY_SIZING,
 }
 TOY_LIMITS = {"max_gap": 1e-3, "mean_gap": 1e-4, "mismatch_share": 0.02}
+# the cell's own: a position is steady only by the toy family's margin
+# (the first family's margins are all far under 999)
+TOY_CELL = {
+    "rate_rps": 4.0, "routing_margin": 999.0,
+    "correct_limits": dict(TOY_LIMITS, steady_max_gap=1e-3),
+}
 TOY_READER = '''"""A per-layer metric added by a file alone."""
 
 
 def read(run):
     return float(len(run["judged"]))
+'''
+TOY_NEEDS_READER = '''"""What the needs of the run's family say beyond bytes and flops."""
+from perfbench.harness.readers import family_needs
+
+
+def read(run):
+    return family_needs(run).decode_tick(run["model"], 1, 1).get("toy_family")
 '''
 
 
@@ -61,11 +161,21 @@ def build(root: str) -> str:
             else:
                 json.dump(payload, f)
 
-    put("configs/toy-moe.json", TOY_MODEL)
+    os.mkdir(os.path.join(bench, "families", "toy_family"))
+    for name, body in TOY_FAMILY.items():
+        put("families/toy_family/" + name, TOY_FAMILY_BORROWS + body)
+    put("configs/toy-moe.json", TOY_FAMILY_MODEL)
     put("traffic/toy-open.json", TOY_OPEN)
-    put("cells/toy.open.json",
-        {"rate_rps": 4.0, "correct_limits": TOY_LIMITS})
+    put("cells/toy.open.json", TOY_CELL)
     put("layer_metrics/toy_judged_requests.py", TOY_READER)
+    put("layer_metrics/toy_needs_marker.py", TOY_NEEDS_READER)
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(manifest(), f)
+    return root
+
+
+def manifest() -> dict:
+    """The real ``BENCHMARK.json`` with the toy entries appended."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     manifest["configs"].append({
@@ -84,11 +194,12 @@ def build(root: str) -> str:
          "better": "higher", "source": "program_counter",
          "layer": "load generator", "moves": "setup_s",
          "workloads": ["toy.open"]},
+        {"name": "toy_needs_marker", "unit": "marks",
+         "better": "higher", "source": "program_counter",
+         "layer": "kernel", "moves": "setup_s", "workloads": ["toy.open"]},
         # a quantity split by a new suffix: read by decode_tick_ms.py
         {"name": "decode_tick_ms.toy", "unit": "ms", "better": "lower",
          "source": "device_trace", "layer": "device step",
          "moves": "setup_s", "workloads": ["toy.open"]},
     ]
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(manifest, f)
-    return root
+    return manifest
