@@ -416,7 +416,11 @@ def _serve_leaves(env, mesh_total_tp: int) -> Tuple[Any, List[AbstractLeaf]]:
     # the serving KV footprint IS the runtime allocation, exactly:
     # by default the PAGED ARENA (serve/paging.py, ISSUE 11) —
     # KV_PAGES usable pages + the trash page, each KV_PAGE_TOKENS
-    # positions, shaped by the SAME paged_config_from_env contract
+    # entries (a position's K/V, or a chunk summary of a windowed
+    # row layout: one shape, so the arena's bytes do not depend on the
+    # layout — only how many rows it admits does, and the default
+    # KV_PAGES follows the layout's table), shaped by the SAME
+    # paged_config_from_env contract
     # the workers and the PR 9 admission gate consume (an
     # under-budgeted arena is a SpecError at derivation, so admission
     # rejects page-budget overcommit at PUT time) — or, when

@@ -33,9 +33,10 @@ from dcos_commons_tpu.models.quantize import dequantize_weight as dq
 from dcos_commons_tpu.models.transformer import (
     TransformerConfig,
     _ffn_block,
+    _norm,
     _rope,
+    head_logits,
 )
-from dcos_commons_tpu.ops.rmsnorm import rms_norm
 
 Params = Dict[str, Any]
 _NEG = -1e30
@@ -75,6 +76,12 @@ def init_paged_kv_cache(
     inactive-row writes land there, and table entry 0 also means
     "virtual page unallocated" — those positions are always masked.
 
+    What an ENTRY of a page is, is the table entry's to say
+    (serve/paging.py RowLayout): a position's K/V, or — in the summary
+    region of an ``attention == "eva"`` row — a chunk's pooled K/V;
+    both have this one shape, so one arena and one layer scan hold
+    both kinds.
+
     Same dict keys as ``init_kv_cache`` (int8 adds per-vector scales),
     so ``kv_dtype`` handling and sharding rules carry over: dims are
     (layers, pages, page_tokens, kv_heads, head_dim) — kv heads stay
@@ -95,6 +102,25 @@ def init_paged_kv_cache(
         "k": jnp.zeros(shape, config.dtype),
         "v": jnp.zeros(shape, config.dtype),
     }
+
+
+def _gqa_only(config: TransformerConfig, what: str) -> None:
+    if config.attention != "gqa":
+        raise NotImplementedError(
+            f"{what}: the slot pool keeps every token of a row; attention "
+            f"{config.attention!r} is served by the paged arena alone "
+            "(KV_PAGE_TOKENS > 0)"
+        )
+
+
+def _last_logits(config: TransformerConfig, params: Params, x: jax.Array):
+    """Normed hidden states [b, d] -> float32 logits [b, vocab]."""
+    if not config.tie_embeddings:
+        return head_logits(config, params, x)
+    return jnp.einsum(
+        "bd,vd->bv", x.astype(jnp.float32),
+        params["embed"].astype(jnp.float32),
+    )
 
 
 def _quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -150,6 +176,7 @@ def prefill(
     b, s = tokens.shape
     if s > max_len:
         raise ValueError(f"prompt {s} exceeds cache max_len {max_len}")
+    _gqa_only(config, "prefill")
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     x = params["embed"][tokens].astype(config.dtype)
     h, kv = config.n_heads, config.n_kv_heads
@@ -157,7 +184,7 @@ def prefill(
     def layer_fn(x, layer):
         from dcos_commons_tpu.ops.attention import flash_attention
 
-        normed = rms_norm(x, layer["attn_norm"])
+        normed = _norm(config, x, layer["attn_norm"])
         q, k, v = _project_kv(config, layer, normed, positions)
         k_full, v_full = k, v
         if kv != h:
@@ -193,7 +220,7 @@ def prefill(
     else:
         x, (ck, cv) = lax.scan(layer_fn, x, params["layers"])
         cache = {"k": ck, "v": cv}
-    x = rms_norm(x, params["final_norm"])
+    x = _norm(config, x, params["final_norm"])
     last = (
         jnp.asarray(true_len, jnp.int32) - 1 if true_len is not None
         else jnp.int32(s - 1)
@@ -205,11 +232,7 @@ def prefill(
         x_last = jnp.take_along_axis(
             x, last[:, None, None], axis=1
         )[:, 0]
-    logits = jnp.einsum(
-        "bd,vd->bv", x_last.astype(jnp.float32),
-        params["embed"].astype(jnp.float32),
-    )
-    return logits, cache
+    return _last_logits(config, params, x_last), cache
 
 
 def prefill_into_slot(
@@ -290,6 +313,7 @@ def decode_step(
     b = token.shape[0]
     h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     max_len = cache["k"].shape[2]
+    _gqa_only(config, "decode_step")
     x = params["embed"][token][:, None, :].astype(config.dtype)
     pos = jnp.asarray(pos, jnp.int32)
     per_row = pos.ndim == 1
@@ -345,7 +369,7 @@ def decode_step(
         else:
             layer, ck, cv = inputs
             cks = cvs = None
-        normed = rms_norm(x, layer["attn_norm"])
+        normed = _norm(config, x, layer["attn_norm"])
         q, k_new, v_new = _project_kv(config, layer, normed, positions)
         if quantized:
             kq, ks_new = _quantize_kv(k_new)
@@ -377,12 +401,8 @@ def decode_step(
             layer_fn, x, (params["layers"], cache["k"], cache["v"])
         )
         new_cache = {"k": ck, "v": cv}
-    x = rms_norm(x, params["final_norm"])
-    logits = jnp.einsum(
-        "bd,vd->bv", x[:, 0].astype(jnp.float32),
-        params["embed"].astype(jnp.float32),
-    )
-    return logits, new_cache
+    x = _norm(config, x, params["final_norm"])
+    return _last_logits(config, params, x[:, 0]), new_cache
 
 
 def _serve_ffn(config: TransformerConfig, layer, x: jax.Array) -> jax.Array:
@@ -470,6 +490,10 @@ def paged_prefill_chunk(
     b, c = tokens.shape
     if b != 1:
         raise ValueError(f"prefill chunks are per-request, got batch {b}")
+    if config.attention == "eva":
+        return _eva_prefill_chunk(
+            config, params, cache, tokens, table, start, true_len
+        )
     h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     p_tok = cache["k"].shape[2]
     m = table.shape[0]
@@ -500,7 +524,7 @@ def paged_prefill_chunk(
         x, arena = carry
         layer, base = inputs           # base: the layer's first page
         with jax.named_scope("attention"):
-            normed = rms_norm(x, layer["attn_norm"])
+            normed = _norm(config, x, layer["attn_norm"])
             q, k_new, v_new = _project_kv(config, layer, normed, positions)
         with jax.named_scope("kv_write"):
             new = _kv_entries(k_new[0], v_new[0], quantized)
@@ -548,14 +572,11 @@ def paged_prefill_chunk(
         layer_fn, x, params["layers"], cache
     )
     with jax.named_scope("logits"):
-        x = rms_norm(x, params["final_norm"])
+        x = _norm(config, x, params["final_norm"])
         x_last = lax.dynamic_index_in_dim(
             x, true_len - 1, axis=1, keepdims=False
         )
-        logits = jnp.einsum(
-            "bd,vd->bv", x_last.astype(jnp.float32),
-            params["embed"].astype(jnp.float32),
-        )
+        logits = _last_logits(config, params, x_last)
     return logits, new_cache
 
 
@@ -577,6 +598,8 @@ def paged_decode_step(
     values into the trash page — and attention gathers each row's
     pages back into virtual order, so the masked-softmax math is
     element-for-element the slot pool's with ``max_len = M * P``."""
+    if config.attention == "eva":
+        return _eva_decode_step(config, params, cache, token, pos, tables)
     b = token.shape[0]
     h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     p_tok = cache["k"].shape[2]
@@ -600,7 +623,7 @@ def paged_decode_step(
         x, arena = carry
         layer, base = inputs           # base: the layer's first page
         with jax.named_scope("attention"):
-            normed = rms_norm(x, layer["attn_norm"])
+            normed = _norm(config, x, layer["attn_norm"])
             q, k_new, v_new = _project_kv(config, layer, normed, positions)
         with jax.named_scope("kv_write"):
             new = _kv_entries(k_new[:, 0], v_new[:, 0], quantized)
@@ -639,11 +662,370 @@ def paged_decode_step(
         layer_fn, x, params["layers"], cache
     )
     with jax.named_scope("logits"):
-        x = rms_norm(x, params["final_norm"])
-        logits = jnp.einsum(
-            "bd,vd->bv", x[:, 0].astype(jnp.float32),
-            params["embed"].astype(jnp.float32),
+        x = _norm(config, x, params["final_norm"])
+        logits = _last_logits(config, params, x[:, 0])
+    return logits, new_cache
+
+
+def _eva_geometry(config: TransformerConfig, cache, table_len: int):
+    """(window pages, summary pages) of an EVA row's table, after
+    checking what the layout rests on: a page IS a chunk, so a ring
+    page holds one chunk's exact keys and a summary page the
+    summaries of ``page_tokens`` chunks (serve/paging.py RowLayout)."""
+    if "k_scale" in cache:
+        raise ValueError("eva attention has no int8 cache")
+    p_tok = cache["k"].shape[2]
+    if p_tok != config.chunk_size:
+        raise ValueError(
+            f"eva attention needs KV_PAGE_TOKENS == chunk_size "
+            f"({config.chunk_size}), got {p_tok}"
         )
+    window_pages = config.window_size // p_tok
+    if table_len <= window_pages:
+        raise ValueError(
+            f"an eva row's table holds {window_pages} window pages and "
+            f"at least one summary page, got {table_len} entries"
+        )
+    return window_pages, table_len - window_pages
+
+
+def _eva_summaries(config: TransformerConfig, layer, k, v):
+    """One summary a chunk: ``k``, ``v`` ``[n, chunk, kv, hd]`` (roped
+    keys) -> ``k~, v~ [n, kv, hd]``.  The chunk's positions are pooled
+    by ``softmax_j(s * phi . k_j)``; the summary key adds ``mu``."""
+    hd = config.head_dim
+    kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
+    phi = layer["eva_phi"].astype(jnp.float32)
+    pool = jax.nn.softmax(
+        jnp.einsum("nckd,kd->nck", kf, phi) * hd ** -0.5, axis=1
+    )
+    k_sum = jnp.einsum("nck,nckd->nkd", pool, kf) + layer["eva_mu"].astype(
+        jnp.float32
+    )
+    v_sum = jnp.einsum("nck,nckd->nkd", pool, vf)
+    return k_sum.astype(k.dtype), v_sum.astype(v.dtype)
+
+
+def _softmax_start(b: int, c: int, kv: int, reps: int, hd: int):
+    """The state of an online softmax that has seen nothing."""
+    return (
+        jnp.full((b, c, kv, reps), _NEG, jnp.float32),
+        jnp.zeros((b, c, kv, reps), jnp.float32),
+        jnp.zeros((b, c, kv, reps, hd), jnp.float32),
+    )
+
+
+def _softmax_block(carry, qg, keys, values, mask, scale):
+    """One block of an online softmax: ``carry`` is ``(m, l, acc)``
+    (running maximum and normaliser ``[b, c, kv, reps]``, weighted
+    values ``[b, c, kv, reps, hd]``, float32) over the blocks seen so
+    far; ``qg [b, c, kv, reps, hd]`` in the serving dtype, unscaled;
+    ``keys``, ``values`` ``[b, L, kv, hd]``, ``mask [b, c, L]``.  A set
+    of keys attended in several blocks, or two sets (the exact keys of
+    the query's window and the summaries of the windows before it),
+    come under ONE softmax this way; ``_softmax_start`` begins it and
+    ``acc / l`` ends it.
+
+    Mixed precision as the checkpoint's ``mixedp_attn``: the products
+    take their operands in the dtype they are stored in and accumulate
+    in float32, the softmax is float32, the probabilities are rounded
+    to the values' dtype for their product."""
+    m, l, acc = carry
+    raw = jnp.einsum(
+        "bqkrd,blkd->bqkrl", qg, keys, preferred_element_type=jnp.float32
+    ) * scale
+    raw = jnp.where(mask[:, :, None, None, :], raw, _NEG)
+    m_new = jnp.maximum(m, raw.max(-1))
+    shrink = jnp.exp(m - m_new)
+    e = jnp.exp(raw - m_new[..., None])
+    l = shrink * l + e.sum(-1)
+    acc = shrink[..., None] * acc + jnp.einsum(
+        "bqkrl,blkd->bqkrd", e.astype(values.dtype), values,
+        preferred_element_type=jnp.float32,
+    )
+    return m_new, l, acc
+
+
+def _eva_prefill_chunk(config, params, cache, tokens, table, start, true_len):
+    """``paged_prefill_chunk`` for ``attention == "eva"``.
+
+    The row's table has two regions (serve/paging.py RowLayout): the
+    first ``window_size / P`` entries are a RING of exact K/V pages
+    (position ``p`` lives in ring page ``(p % window_size) // P``, so a
+    new window writes over the last one in place), the rest hold the
+    chunk summaries (chunk ``c`` at entry ``c`` of that region, ``P`` a
+    page).  A query at ``p`` sees the exact keys ``j <= p`` of its own
+    window and the summaries of every chunk of an EARLIER window.
+
+    The old state is read BEFORE this chunk's writes and the chunk's
+    own keys and finished summaries are attended directly: a chunk
+    that straddles a window's end writes the new window over ring
+    pages its own earlier queries still need.  ``start`` is a multiple
+    of the chunk size (the engine advances by whole prefill chunks,
+    themselves whole EVA chunks).
+
+    The old state is attended a BLOCK of pages at a time under one
+    online softmax, and a block no query of this chunk can see is not
+    even gathered (``lax.cond``): of the ring, the blocks behind
+    ``start`` in its window; of the summaries, those of the windows
+    that are past.  A dense masked softmax over the whole table scores
+    4,096 old entries a query where a chunk half way through an
+    8,192-byte prompt can see 1,300."""
+    b, c = tokens.shape
+    h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    win, chunk = config.window_size, config.chunk_size
+    if c % chunk or c > win:
+        raise ValueError(
+            f"an eva prefill chunk is a whole number of {chunk}-position "
+            f"chunks and at most one window ({win}), got {c}"
+        )
+    p_tok = cache["k"].shape[2]
+    wp, sp = _eva_geometry(config, cache, table.shape[0])
+    per_win, n_c, reps = win // chunk, c // chunk, h // kv
+    start = jnp.asarray(start, jnp.int32)
+    true_len = jnp.asarray(true_len, jnp.int32)
+    offs = jnp.arange(c, dtype=jnp.int32)
+    abs_pos = start + offs
+    positions = abs_pos[None, :]
+    q_win = abs_pos // win                       # each query's window
+    # exact K/V: into the ring (pad positions into the trash page)
+    phys = jnp.where(offs < true_len, table[(abs_pos % win) // p_tok], 0)
+    slot_off = abs_pos % p_tok
+    # the summaries this chunk finishes: into the summary region
+    chunk_id = start // chunk + jnp.arange(n_c, dtype=jnp.int32)
+    finished = (jnp.arange(n_c, dtype=jnp.int32) + 1) * chunk <= true_len
+    sum_phys = jnp.where(
+        finished, table[wp + jnp.minimum(chunk_id // p_tok, sp - 1)], 0
+    )
+    sum_off = chunk_id % p_tok
+    # what each query sees: of this chunk, the causal prefix in its own
+    # window and the finished summaries of an earlier window (a
+    # straddling chunk's alone); of the ring as it was, its own
+    # window's positions before this chunk; of the old summaries, the
+    # chunks of earlier windows
+    seen = q_win[:, None] * per_win
+    mask_new = (
+        (offs[None, :] <= offs[:, None]) & (q_win[None, :] == q_win[:, None])
+    )[None]                                      # [1, c, c]
+    mask_sum_new = (chunk_id[None, :] < seen)[None]   # [1, c, n_c]
+    in_first = q_win == start // win
+    # blocks of the old state, in pages: one prefill chunk's worth
+    # where that divides the window, else the whole window
+    block = (c if win % c == 0 else win) // p_tok
+    ring_blocks = [
+        (lo, min(lo + block, wp)) for lo in range(0, wp, block)
+    ]
+    sum_blocks = [
+        (lo, min(lo + block, sp)) for lo in range(0, sp, block)
+    ]
+    old_sums = jnp.minimum(
+        (start + c - 1) // win * per_win, start // chunk
+    )                                            # the most any query sees
+    x = params["embed"][tokens].astype(config.dtype)
+
+    def layer_fn(carry, inputs):
+        x, arena = carry
+        layer, base = inputs
+        with jax.named_scope("attention"):
+            normed = _norm(config, x, layer["attn_norm"])
+            q, k_new, v_new = _project_kv(config, layer, normed, positions)
+        with jax.named_scope("eva_summarise"):
+            k_sum_new, v_sum_new = _eva_summaries(
+                config, layer,
+                k_new[0].reshape(n_c, chunk, kv, hd),
+                v_new[0].reshape(n_c, chunk, kv, hd),
+            )
+        qg = q.reshape(1, c, kv, reps, hd)
+        scale = hd ** -0.5
+        state = _softmax_start(1, c, kv, reps, hd)
+        with jax.named_scope("eva_window_attention"):
+            state = _softmax_block(
+                state, qg, k_new, v_new, mask_new, scale
+            )
+        with jax.named_scope("eva_summary_attention"):
+            state = _softmax_block(
+                state, qg, k_sum_new[None], v_sum_new[None], mask_sum_new,
+                scale,
+            )
+
+        def old_block(lo, hi, region, mask):
+            """Attend table entries ``[region + lo, region + hi)`` as
+            they are in the arena now (before this layer's writes)."""
+            def attend(state):
+                with jax.named_scope("paged_gather"):
+                    pages = base + lax.dynamic_slice_in_dim(
+                        table, region + lo, hi - lo
+                    )
+                    n = (hi - lo) * p_tok
+                    keys = arena["k"][pages].reshape(1, n, kv, hd)
+                    values = arena["v"][pages].reshape(1, n, kv, hd)
+                return _softmax_block(state, qg, keys, values, mask, scale)
+            return attend
+
+        for lo, hi in ring_blocks:
+            idx = jnp.arange(lo * p_tok, hi * p_tok, dtype=jnp.int32)
+            mask = (in_first[:, None] & (idx[None, :] < start % win))[None]
+            with jax.named_scope("eva_window_attention"):
+                state = lax.cond(
+                    start % win > lo * p_tok,
+                    old_block(lo, hi, 0, mask), lambda st: st, state,
+                )
+        for lo, hi in sum_blocks:
+            idx = jnp.arange(lo * p_tok, hi * p_tok, dtype=jnp.int32)
+            mask = (
+                (idx[None, :] < seen) & (idx[None, :] < start // chunk)
+            )[None]
+            with jax.named_scope("eva_summary_attention"):
+                state = lax.cond(
+                    old_sums > lo * p_tok,
+                    old_block(lo, hi, wp, mask), lambda st: st, state,
+                )
+        with jax.named_scope("kv_write"):
+            arena = {
+                "k": arena["k"].at[base + phys, slot_off].set(k_new[0])
+                .at[base + sum_phys, sum_off].set(k_sum_new),
+                "v": arena["v"].at[base + phys, slot_off].set(v_new[0])
+                .at[base + sum_phys, sum_off].set(v_sum_new),
+            }
+        with jax.named_scope("attention"):
+            _m, norm, acc = state
+            attn = (acc / norm[..., None]).astype(config.dtype)
+            x = x + attn.reshape(1, c, h * hd) @ dq(layer["wo"], x.dtype)
+        x = _serve_ffn(config, layer, x)
+        return (x, arena), None
+
+    x, new_cache = _scan_layers_over_arena(
+        layer_fn, x, params["layers"], cache
+    )
+    with jax.named_scope("logits"):
+        x = _norm(config, x, params["final_norm"])
+        x_last = lax.dynamic_index_in_dim(
+            x, true_len - 1, axis=1, keepdims=False
+        )
+        logits = _last_logits(config, params, x_last)
+    return logits, new_cache
+
+
+def _eva_decode_kernel(config: TransformerConfig):
+    """How a decode step's attention runs: ``"compiled"`` (the Pallas
+    kernel of ops/eva_decode.py, which reads each row's live pages in
+    place) on a TPU where no head is grouped, else ``None`` (gather the
+    whole table, then ``_softmax_block`` over its two regions).  A shape-and-backend rule, as
+    ``ops.rmsnorm`` has one; tests patch it to ``"interpret"``."""
+    if config.n_kv_heads != config.n_heads:
+        return None
+    return "compiled" if jax.default_backend() == "tpu" else None
+
+
+def _eva_decode_step(config, params, cache, token, pos, tables):
+    """``paged_decode_step`` for ``attention == "eva"`` (the table's
+    two regions: ``_eva_prefill_chunk``).  Each row writes its new K/V
+    into its ring, attends to its window's ring entries ``<= pos`` and
+    the summaries of the windows before, and, where ``pos`` ends a
+    chunk, pools that chunk's page into its summary entry (any other
+    row's pooled page goes to the trash page)."""
+    from dcos_commons_tpu.ops.eva_decode import (
+        eva_decode_attention,
+        live_pages,
+    )
+
+    b = token.shape[0]
+    h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    win, chunk = config.window_size, config.chunk_size
+    p_tok = cache["k"].shape[2]
+    wp, sp = _eva_geometry(config, cache, tables.shape[1])
+    per_win, reps = win // chunk, h // kv
+    x = params["embed"][token][:, None, :].astype(config.dtype)
+    pos = jnp.asarray(pos, jnp.int32)
+    positions = pos[:, None]
+    rows = jnp.arange(b)
+    ring_page = (pos % win) // p_tok
+    phys = tables[rows, ring_page]
+    slot_off = pos % p_tok
+    chunk_id = pos // chunk
+    sum_phys = jnp.where(
+        pos % chunk == chunk - 1,
+        tables[rows, wp + jnp.minimum(chunk_id // p_tok, sp - 1)], 0,
+    )
+    sum_off = chunk_id % p_tok
+    kernel = _eva_decode_kernel(config)
+    if kernel:
+        live, n_ring, n_live, n_win, n_sum = live_pages(
+            tables, pos, win, chunk, p_tok
+        )
+    else:
+        mask_win = (
+            lax.broadcasted_iota(jnp.int32, (1, 1, win), 2)
+            <= (pos % win)[:, None, None]
+        )                                        # [b, 1, win]
+        mask_sum = (
+            lax.broadcasted_iota(jnp.int32, (1, 1, sp * p_tok), 2)
+            < ((pos // win) * per_win)[:, None, None]
+        )                                        # [b, 1, sp * P]
+
+    def layer_fn(carry, inputs):
+        x, arena = carry
+        layer, base = inputs
+        with jax.named_scope("attention"):
+            normed = _norm(config, x, layer["attn_norm"])
+            q, k_new, v_new = _project_kv(config, layer, normed, positions)
+        with jax.named_scope("kv_write"):
+            arena = {
+                "k": arena["k"].at[base + phys, slot_off].set(k_new[:, 0]),
+                "v": arena["v"].at[base + phys, slot_off].set(v_new[:, 0]),
+            }
+        if kernel:
+            with jax.named_scope("attention"):
+                attn = eva_decode_attention(
+                    q[:, 0], arena["k"], arena["v"], base + live, n_ring,
+                    n_live, n_win, n_sum, scale=hd ** -0.5,
+                    interpret=kernel == "interpret",
+                )
+            # the page each row has just written into: its chunk
+            with jax.named_scope("paged_gather"):
+                k_page = arena["k"][base + phys]
+                v_page = arena["v"][base + phys]
+        else:
+            with jax.named_scope("paged_gather"):
+                pages = base + tables
+                k_all, v_all = arena["k"][pages], arena["v"][pages]
+                k_page = k_all[rows, ring_page]
+                v_page = v_all[rows, ring_page]
+            qg, scale = q.reshape(b, 1, kv, reps, hd), hd ** -0.5
+            with jax.named_scope("eva_window_attention"):
+                state = _softmax_block(
+                    _softmax_start(b, 1, kv, reps, hd), qg,
+                    k_all[:, :wp].reshape(b, win, kv, hd),
+                    v_all[:, :wp].reshape(b, win, kv, hd), mask_win, scale,
+                )
+            with jax.named_scope("eva_summary_attention"):
+                _m, norm, acc = _softmax_block(
+                    state, qg,
+                    k_all[:, wp:].reshape(b, sp * p_tok, kv, hd),
+                    v_all[:, wp:].reshape(b, sp * p_tok, kv, hd), mask_sum,
+                    scale,
+                )
+            attn = (acc / norm[..., None]).astype(config.dtype)
+        with jax.named_scope("attention"):
+            x = x + attn.reshape(b, 1, h * hd) @ dq(layer["wo"], x.dtype)
+        with jax.named_scope("eva_summarise"):
+            k_sum_new, v_sum_new = _eva_summaries(
+                config, layer, k_page, v_page
+            )
+        with jax.named_scope("kv_write"):
+            arena = {
+                "k": arena["k"].at[base + sum_phys, sum_off].set(k_sum_new),
+                "v": arena["v"].at[base + sum_phys, sum_off].set(v_sum_new),
+            }
+        x = _serve_ffn(config, layer, x)
+        return (x, arena), None
+
+    x, new_cache = _scan_layers_over_arena(
+        layer_fn, x, params["layers"], cache
+    )
+    with jax.named_scope("logits"):
+        x = _norm(config, x, params["final_norm"])
+        logits = _last_logits(config, params, x[:, 0])
     return logits, new_cache
 
 

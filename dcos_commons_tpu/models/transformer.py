@@ -74,6 +74,20 @@ class TransformerConfig:
             raise ValueError(
                 "pick ONE sequence-parallel recipe: ring or ulysses"
             )
+        if self.attention not in ("gqa", "eva"):
+            raise ValueError(
+                f"attention {self.attention!r} not in ('gqa', 'eva')"
+            )
+        if self.attention == "eva" and (
+            self.chunk_size < 1 or self.window_size < self.chunk_size
+            or self.window_size % self.chunk_size
+        ):
+            raise ValueError(
+                "eva attention needs window_size a positive multiple of "
+                f"chunk_size, got {self.window_size} / {self.chunk_size}"
+            )
+        if self.attention == "eva" and self.n_experts > 0:
+            raise ValueError("eva attention serves a dense FFN only")
     use_ring_attention: bool = False     # sp: K/V rotate (ppermute)
     use_ulysses_attention: bool = False  # sp: all_to_all head regroup
     sp_axis: str = "sp"
@@ -102,6 +116,26 @@ class TransformerConfig:
     # flash-attention tile sizes (VMEM-tunable per chip generation)
     attn_block_q: int = 128
     attn_block_k: int = 128
+    # what only a configuration FILE can say (``config_from_env``,
+    # MODEL_CONFIG): the norm's epsilon, a norm that scales by
+    # ``1 + weight`` (the stored weight is the offset from one), an
+    # output head of its own (``[d_model, n_pred_heads * vocab]``;
+    # columns ``[0, vocab)`` are the next-token head, the others are
+    # self-speculation heads that plain decoding does not read), and
+    # the attention class of the serving path
+    rms_norm_eps: float = 1e-6
+    norm_unit_offset: bool = False
+    tie_embeddings: bool = True
+    n_pred_heads: int = 1
+    # "gqa": every token of a row is kept and attended for ever.
+    # "eva" (models/decode.py): an exact window of ``window_size``
+    # positions beside one summary per ``chunk_size`` positions of every
+    # window that is past; ``eva_init_std`` scales its two learned
+    # vectors a head (``eva_phi``, ``eva_mu``) at initialisation
+    attention: str = "gqa"
+    window_size: int = 0
+    chunk_size: int = 0
+    eva_init_std: float = 0.02
 
     @property
     def head_dim(self) -> int:
@@ -109,6 +143,54 @@ class TransformerConfig:
 
 
 Params = Dict[str, Any]
+
+
+# a configuration file's key (the names of a published ``config.json``)
+# -> the TransformerConfig field it sets
+_FILE_KEYS = {
+    "vocab_size": "vocab", "hidden_size": "d_model",
+    "num_hidden_layers": "n_layers", "num_attention_heads": "n_heads",
+    "num_key_value_heads": "n_kv_heads", "intermediate_size": "d_ff",
+    "num_local_experts": "n_experts", "num_experts_per_tok": "moe_top_k",
+    "rope_theta": "rope_theta", "rms_norm_eps": "rms_norm_eps",
+    "norm_add_unit_offset": "norm_unit_offset",
+    "tie_word_embeddings": "tie_embeddings",
+    "num_pred_heads": "n_pred_heads", "window_size": "window_size",
+    "chunk_size": "chunk_size", "init_std": "eva_init_std",
+}
+
+
+def config_fields_from_file(path: str) -> Dict[str, Any]:
+    """The TransformerConfig fields that the configuration file at
+    ``path`` states: a JSON object under the key names of a published
+    ``config.json`` (``_FILE_KEYS``; ``attention_class`` names the
+    attention).  A key this table lacks is not the program's to
+    interpret and is passed over; a ``head_dim`` or an attention class
+    the program cannot build is an error, not a silent other model."""
+    import json
+
+    with open(path, "r", encoding="utf-8") as f:
+        data = json.load(f)
+    fields = {
+        field: type(TransformerConfig.__dataclass_fields__[field].default)(
+            data[key]
+        )
+        for key, field in _FILE_KEYS.items() if data.get(key) is not None
+    }
+    # a name TransformerConfig does not know is refused there
+    fields["attention"] = data.get("attention_class") or "gqa"
+    if fields["attention"] != "eva":
+        fields.pop("window_size", None)
+        fields.pop("chunk_size", None)
+    head_dim = data.get("head_dim")
+    if head_dim is not None and "d_model" in fields and "n_heads" in fields \
+            and head_dim * fields["n_heads"] != fields["d_model"]:
+        raise ValueError(
+            f"{path}: head_dim {head_dim} x {fields['n_heads']} heads is "
+            f"not hidden_size {fields['d_model']}; the program derives "
+            "head_dim as hidden_size / heads"
+        )
+    return fields
 
 
 def config_from_env(env: Dict[str, str], **overrides) -> TransformerConfig:
@@ -120,6 +202,12 @@ def config_from_env(env: Dict[str, str], **overrides) -> TransformerConfig:
     if the mapping drifted between a worker and the analyzer, the
     analyzer would vouch for a model the pod never runs.  ``overrides``
     are keyword fields applied on top (dtype, remat, ...).
+
+    ``MODEL_CONFIG`` names a configuration FILE
+    (``config_fields_from_file``): what it states wins over the eight
+    size names below, which a service YAML always sends with their
+    defaults, and it alone can state what they cannot (rope_theta, the
+    norm's epsilon and offset, an untied head, the attention class).
     """
     fields = dict(
         vocab=int(env.get("VOCAB", "8192")),
@@ -133,6 +221,9 @@ def config_from_env(env: Dict[str, str], **overrides) -> TransformerConfig:
         # ep-sharded mixture (models/moe.py)
         n_experts=int(env.get("N_EXPERTS", "0")),
     )
+    model_config = env.get("MODEL_CONFIG", "")
+    if model_config:
+        fields.update(config_fields_from_file(model_config))
     fields.update(overrides)
     return TransformerConfig(**fields)
 
@@ -154,14 +245,26 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
     def normal(key, shape, scale):
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dt)
 
+    # a unit-offset norm stores its weight's distance from one
+    norm_init = jnp.zeros if config.norm_unit_offset else jnp.ones
     layers = {
-        "attn_norm": jnp.ones((n, d), dt),
+        "attn_norm": norm_init((n, d), dt),
         "wq": normal(keys[1], (n, d, h * hd), d ** -0.5),
         "wk": normal(keys[2], (n, d, kv * hd), d ** -0.5),
         "wv": normal(keys[3], (n, d, kv * hd), d ** -0.5),
         "wo": normal(keys[4], (n, h * hd, d), (h * hd) ** -0.5),
-        "mlp_norm": jnp.ones((n, d), dt),
+        "mlp_norm": norm_init((n, d), dt),
     }
+    if config.attention == "eva":
+        # the two learned vectors a head of the chunk summaries
+        # (models/decode.py ``_eva_summaries``)
+        eva_keys = jax.random.split(jax.random.fold_in(key, 8), 2)
+        layers["eva_phi"] = normal(
+            eva_keys[0], (n, kv, hd), config.eva_init_std
+        )
+        layers["eva_mu"] = normal(
+            eva_keys[1], (n, kv, hd), config.eva_init_std
+        )
     if config.n_experts > 0:
         # one source of truth for the expert init recipe (router-f32
         # policy, scales): moe.init_moe_params, vmapped over layers
@@ -181,11 +284,17 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
             "w_up": normal(keys[6], (n, d, f), d ** -0.5),
             "w_down": normal(keys[7], (n, f, d), f ** -0.5),
         })
-    return {
+    params = {
         "embed": normal(keys[0], (config.vocab, d), d ** -0.5),
         "layers": layers,
-        "final_norm": jnp.ones((d,), dt),
+        "final_norm": norm_init((d,), dt),
     }
+    if not config.tie_embeddings:
+        params["lm_head"] = normal(
+            jax.random.fold_in(key, 9),
+            (d, config.n_pred_heads * config.vocab), d ** -0.5,
+        )
+    return params
 
 
 def sharding_rules(config: TransformerConfig) -> Dict[str, P]:
@@ -202,6 +311,11 @@ def sharding_rules(config: TransformerConfig) -> Dict[str, P]:
         "layers/mlp_norm": P(None, None),
         "final_norm": P(None),
     }
+    if not config.tie_embeddings:
+        rules["lm_head"] = P("fsdp", "tp")
+    if config.attention == "eva":
+        rules["layers/eva_phi"] = P(None, "tp", None)
+        rules["layers/eva_mu"] = P(None, "tp", None)
     if config.n_experts > 0:
         # the expert-axis rules live next to the MoE model so the
         # dispatch layout and its sharding can't drift apart
@@ -235,6 +349,31 @@ def param_shardings(config: TransformerConfig, mesh: Mesh, shapes=None):
     return walk(shapes)
 
 
+def _norm(config: TransformerConfig, x: jax.Array, w: jax.Array) -> jax.Array:
+    """The configuration's RMSNorm: its epsilon, and ``1 + w`` for the
+    weight where the stored one is the offset from one (widened first:
+    ``1 + w`` in bf16 would round the offset away)."""
+    if config.norm_unit_offset:
+        w = 1.0 + w.astype(jnp.float32)
+    return rms_norm(x, w, eps=config.rms_norm_eps)
+
+
+def head_logits(
+    config: TransformerConfig, params: Params, x: jax.Array
+) -> jax.Array:
+    """Final hidden states ``[..., d]`` (already normed) -> float32
+    next-token logits ``[..., vocab]``: against the embedding where the
+    head is tied, else against the first ``vocab`` columns of
+    ``lm_head`` (its other columns are the self-speculation heads)."""
+    x = x.astype(jnp.float32)
+    if config.tie_embeddings:
+        return jnp.einsum(
+            "...d,vd->...v", x, params["embed"].astype(jnp.float32)
+        )
+    head = params["lm_head"][:, :config.vocab]
+    return jnp.einsum("...d,dv->...v", x, head.astype(jnp.float32))
+
+
 def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     """Rotary embeddings; x [b, s, heads, head_dim]."""
     half = x.shape[-1] // 2
@@ -251,7 +390,12 @@ def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 def _attention_block(config: TransformerConfig, layer, x, positions):
     b, s, d = x.shape
     h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
-    normed = rms_norm(x, layer["attn_norm"])
+    if config.attention != "gqa":
+        raise NotImplementedError(
+            f"attention {config.attention!r} has a serving path only "
+            "(models/decode.py paged_prefill_chunk / paged_decode_step)"
+        )
+    normed = _norm(config, x, layer["attn_norm"])
     q = (normed @ dq(layer["wq"], x.dtype)).reshape(b, s, h, hd)
     k = (normed @ dq(layer["wk"], x.dtype)).reshape(b, s, kv, hd)
     v = (normed @ dq(layer["wv"], x.dtype)).reshape(b, s, kv, hd)
@@ -287,8 +431,8 @@ def _attention_block(config: TransformerConfig, layer, x, positions):
     return x + attn @ dq(layer["wo"], x.dtype)
 
 
-def _mlp_block(layer, x):
-    normed = rms_norm(x, layer["mlp_norm"])
+def _mlp_block(config: TransformerConfig, layer, x):
+    normed = _norm(config, x, layer["mlp_norm"])
     gate = jax.nn.silu(normed @ dq(layer["w_gate"], x.dtype))
     up = normed @ dq(layer["w_up"], x.dtype)
     return x + (gate * up) @ dq(layer["w_down"], x.dtype)
@@ -304,7 +448,7 @@ def _ffn_block(config: TransformerConfig, layer, x, decode: bool = False):
     load-balancing pressure; a server must not drop, and drop-free
     routing is also what makes cached decode equal full forwards."""
     if config.n_experts <= 0:
-        return _mlp_block(layer, x), jnp.zeros((), jnp.float32)
+        return _mlp_block(config, layer, x), jnp.zeros((), jnp.float32)
     from dcos_commons_tpu.models.moe import MoEConfig, moe_ffn
 
     b, s, d = x.shape
@@ -319,7 +463,7 @@ def _ffn_block(config: TransformerConfig, layer, x, decode: bool = False):
     moe_params = {
         key: layer[key] for key in ("router", "w_gate", "w_up", "w_down")
     }
-    normed = rms_norm(x, layer["mlp_norm"])
+    normed = _norm(config, x, layer["mlp_norm"])
     # group = a whole number of sequence positions per batch row so
     # groups never straddle rows; fall back to one row per group
     group = s if s <= config.moe_group_size else (
@@ -386,7 +530,9 @@ def _layer_scan(config: TransformerConfig, layers, x, positions):
 
 
 def _logits(config: TransformerConfig, params: Params, x: jax.Array) -> jax.Array:
-    x = rms_norm(x, params["final_norm"])
+    x = _norm(config, x, params["final_norm"])
+    if not config.tie_embeddings:
+        return head_logits(config, params, x)
     # tied embeddings; f32 logits for a stable softmax
     return jnp.einsum(
         "bsd,vd->bsv", x.astype(jnp.float32),

@@ -236,6 +236,16 @@ class SlotEngine:
         self._phase_t = time.monotonic()
         self._decode_calls = 0
         self._prefill_calls = 0
+        # what the decode calls themselves computed for: rows, and the
+        # cache entries those rows read (the gauges `active_slots` /
+        # `kv_live_tokens` are instants, and the second also holds rows
+        # still prefilling, which no decode call reads)
+        self._decode_rows_sum = 0
+        self._decode_entries_sum = 0
+        # a windowed row layout's events (serve/paging.py RowLayout);
+        # both stay 0 where every token of a row is kept
+        self._window_rollovers = 0
+        self._summary_entries = 0
         self._tick_rows = 0  # rows the tick under way decodes for
         # per-request sums over rows that finished normally (cv)
         self._queue_wait_s_sum = 0.0
@@ -425,7 +435,8 @@ class SlotEngine:
                 max(0.0, now - self._last_tick_mono)
                 if self._has_work_locked() else 0.0
             )
-            live_tokens = self._live_tokens_locked()
+            positions = self._live_positions_locked()
+            live_tokens = sum(self._entries_for(n) for n in positions)
             window = [n for (t, n) in self._rate
                       if t > now - _RATE_WINDOW_S]
             ttft = sorted(self._ttft)
@@ -436,7 +447,11 @@ class SlotEngine:
                 "queue_depth": len(self._queue),
                 "active_slots": self._active,
                 "free_slots": len(self._free),
+                # cache ENTRIES the live rows' next steps read (what
+                # occupancy and a roofline need) and the positions
+                # they stand for; equal where every token is kept
                 "kv_live_tokens": live_tokens,
+                "context_live_tokens": sum(positions),
                 "kv_occupancy": round(
                     live_tokens / float(self._kv_capacity()), 4
                 ),
@@ -469,11 +484,16 @@ class SlotEngine:
         out["t"] = time.time()
         return out
 
-    def _live_tokens_locked(self) -> int:
-        return int(sum(
+    def _live_positions_locked(self) -> List[int]:
+        """Positions behind each live row."""
+        return [
             int(self._pos[s])
             for s, row in enumerate(self._rows) if row is not None
-        ))
+        ]
+
+    def _entries_for(self, positions: int) -> int:
+        """Cache entries a row with ``positions`` behind it reads."""
+        return positions
 
     def _kv_capacity(self) -> int:
         """KV positions the cache can hold (the occupancy basis)."""
@@ -492,6 +512,10 @@ class SlotEngine:
         return {
             "decode_calls": self._decode_calls,
             "prefill_calls": self._prefill_calls,
+            "decode_rows_sum": self._decode_rows_sum,
+            "decode_entries_sum": self._decode_entries_sum,
+            "window_rollovers": self._window_rollovers,
+            "summary_entries_written": self._summary_entries,
             "phase_s": {
                 k: round(v, 6) for k, v in self._phase_s.items()
             },
@@ -696,6 +720,12 @@ class SlotEngine:
                 r if (r is not None and not r.frozen) else None
                 for r in self._rows
             ]
+            for slot, row in enumerate(dispatched):
+                if row is not None:
+                    self._decode_rows_sum += 1
+                    self._decode_entries_sum += self._entries_for(
+                        int(self._pos[slot])
+                    )
         try:
             with self._phase("decode_call"):
                 self._decode_calls += 1
@@ -942,6 +972,7 @@ class PagedEngine(SlotEngine):
         pages: int,
         chunk_tokens: int,
         prefix_cache: bool = True,
+        layout=None,
         role: str = "unified",
         read_page: Optional[Callable] = None,
         write_page: Optional[Callable] = None,
@@ -950,16 +981,36 @@ class PagedEngine(SlotEngine):
     ):
         from dcos_commons_tpu.serve.paging import (
             PageAllocator,
-            pages_for,
+            RowLayout,
         )
 
         # subclass state FIRST: the base constructor starts the loop
         # thread as its last act, and the loop reads these
         self._page_tokens = int(page_tokens)
-        self._pages_per_row = pages_for(int(max_len), int(page_tokens))
+        # what a table entry stands for (serve/paging.py RowLayout):
+        # the one thing here that differs between attention classes
+        self._layout = (
+            layout if layout is not None else RowLayout(int(page_tokens))
+        )
+        if self._layout.page_tokens != self._page_tokens:
+            raise ValueError(
+                f"row layout has {self._layout.page_tokens}-token pages, "
+                f"the arena {self._page_tokens}"
+            )
+        self._pages_per_row = self._layout.table_len(int(max_len))
         self._chunk_tokens = int(chunk_tokens)
+        if self._layout.window and (
+            self._chunk_tokens % self._layout.chunk
+            or self._chunk_tokens > self._layout.window
+        ):
+            raise ValueError(
+                f"prefill chunks of {self._chunk_tokens} are not whole "
+                f"{self._layout.chunk}-position chunks within one "
+                f"window of {self._layout.window}"
+            )
         self._allocator = PageAllocator(
-            int(pages), int(page_tokens), prefix_cache
+            int(pages), int(page_tokens), prefix_cache,
+            layout=self._layout,
         )
         self._prefilling: deque = deque()
         # migration state (serve/migration.py, ISSUE 16).  role is
@@ -1012,10 +1063,10 @@ class PagedEngine(SlotEngine):
             row.admission = admission
             row.table = np.zeros(self._pages_per_row, np.int32)
             for i, entry in enumerate(admission.matched):
-                row.table[i] = entry.page
+                row.table[self._layout.share_slot(i)] = entry.page
             # prefill resumes past the cache-served pages
             row.cached = len(admission.matched)
-            row.fill_pos = row.cached * self._page_tokens
+            row.fill_pos = row.cached * self._layout.share_tokens
             row.registered_to = row.cached
             self._admitted_locked(row)
             admits.append(row)
@@ -1085,6 +1136,7 @@ class PagedEngine(SlotEngine):
             now = time.monotonic()
             handoff_row = None
             with self._cv:
+                self._count_layout_events(start, start + clen - 1)
                 row.fill_pos = start + clen
                 self._register_pages_locked(row)
                 if row.fill_pos >= plen:
@@ -1144,27 +1196,37 @@ class PagedEngine(SlotEngine):
 
     def _ensure_pages_locked(self, row, first_pos: int,
                              last_pos: int) -> None:
-        """Allocate the pages covering positions [first_pos,
-        last_pos] — drawn from the row's admission reservation, so
-        this cannot fail for an admitted row."""
-        for v in range(first_pos // self._page_tokens,
-                       last_pos // self._page_tokens + 1):
+        """Allocate the pages that writing positions [first_pos,
+        last_pos] touches — drawn from the row's admission
+        reservation, so this cannot fail for an admitted row.  A
+        windowed row's ring pages are allocated in its first window
+        and written over in place ever after."""
+        for v in self._layout.write_slots(first_pos, last_pos):
             if row.table[v] == 0:
                 page = self._allocator.alloc(row.admission)
                 row.table[v] = page
                 row.private_pages.append(page)
 
+    def _count_layout_events(self, first_pos: int, last_pos: int) -> None:
+        """Window ends crossed and chunk summaries written with
+        positions [first_pos, last_pos] (cv held; 0 for full rows)."""
+        self._window_rollovers += self._layout.rollovers(first_pos, last_pos)
+        self._summary_entries += self._layout.summaries(first_pos, last_pos)
+
     def _register_pages_locked(self, row) -> None:
-        """Publish every newly-completed FULL prompt page into the
-        prefix cache.  The last (partial) prompt page stays private —
-        decode keeps writing into it, and shared pages are read-only
-        by contract."""
-        p = self._page_tokens
+        """Publish every newly-completed FULL shareable prompt page
+        (a page of exact K/V, or of chunk summaries: the layout's)
+        into the prefix cache.  The last (partial) prompt page stays
+        private — decode keeps writing into it, and shared pages are
+        read-only by contract."""
+        p = self._layout.share_tokens
         while ((row.registered_to + 1) * p <= row.fill_pos
                and (row.registered_to + 1) * p <= len(row.tokens)):
-            v = row.registered_to
+            v = self._layout.share_slot(row.registered_to)
             page = int(row.table[v])
-            toks = tuple(row.tokens[v * p:(v + 1) * p])
+            toks = tuple(row.tokens[
+                row.registered_to * p:(row.registered_to + 1) * p
+            ])
             if self._allocator.register(row.admission, toks, page):
                 row.private_pages.remove(page)
             row.registered_to += 1
@@ -1186,6 +1248,7 @@ class PagedEngine(SlotEngine):
                 continue
             pos = int(self._pos[slot])
             self._ensure_pages_locked(row, pos, pos)
+            self._count_layout_events(pos, pos)
         tables = np.zeros(
             (self._slots, self._pages_per_row), np.int32
         )
@@ -1326,9 +1389,13 @@ class PagedEngine(SlotEngine):
                 plen + len(row.out) - 1
                 if row.fill_pos >= plen and row.out else row.fill_pos
             )
+            # a windowed row's past ring pages are dead: not shipped
+            live = (
+                self._layout.live_slots(kv_end) if self._layout.window
+                else range(len(row.table))
+            )
             pages = [
-                (v, int(row.table[v]))
-                for v in range(len(row.table)) if row.table[v] != 0
+                (v, int(row.table[v])) for v in live if row.table[v] != 0
             ]
             meta = (
                 list(row.tokens), row.n, row.temp, row.eos, row.seed,
@@ -1343,6 +1410,7 @@ class PagedEngine(SlotEngine):
             eos=eos, seed=seed, out=out, fill_pos=fill_pos,
             kv_end=kv_end, page_tokens=self._page_tokens,
             pages=payloads, source=self._role,
+            window=self._layout.window, chunk=self._layout.chunk,
         )
 
     def splice(self, snap) -> int:
@@ -1353,7 +1421,6 @@ class PagedEngine(SlotEngine):
         ``activate``; ``abort_splice`` undoes everything.  Returns
         the destination-local rid."""
         from dcos_commons_tpu.serve.migration import MigrationError
-        from dcos_commons_tpu.serve.paging import pages_for
 
         if self._write_page is None:
             raise MigrationError(
@@ -1364,6 +1431,14 @@ class PagedEngine(SlotEngine):
                 f"page geometry mismatch: snapshot has "
                 f"{snap.page_tokens}-token pages, this arena "
                 f"{self._page_tokens}"
+            )
+        if (int(snap.window), int(snap.chunk)) != (
+            self._layout.window, self._layout.chunk
+        ):
+            raise MigrationError(
+                f"row layout mismatch: snapshot has window/chunk "
+                f"{snap.window}/{snap.chunk}, this pool "
+                f"{self._layout.window}/{self._layout.chunk}"
             )
         plen = len(snap.tokens)
         if plen > self._prompt_len or plen + snap.max_new > self._max_len:
@@ -1381,13 +1456,15 @@ class PagedEngine(SlotEngine):
                     "page budget cannot admit the migrated session"
                 )
             m = len(admission.matched)
-            need = (
-                pages_for(int(snap.kv_end), self._page_tokens)
-                if snap.kv_end > 0 else 0
-            )
-            missing = [
-                v for v in range(m, need) if v not in incoming
+            layout = self._layout
+            shared = {layout.share_slot(i) for i in range(m)}
+            # every entry a later step reads that the local prefix
+            # cache does not serve
+            wanted = [
+                v for v in layout.live_slots(int(snap.kv_end))
+                if v not in shared
             ]
+            missing = [v for v in wanted if v not in incoming]
             if missing:
                 self._allocator.retire(admission, [])
                 raise MigrationError(
@@ -1408,16 +1485,16 @@ class PagedEngine(SlotEngine):
             row.admission = admission
             row.table = np.zeros(self._pages_per_row, np.int32)
             for i, entry in enumerate(admission.matched):
-                row.table[i] = entry.page
+                row.table[layout.share_slot(i)] = entry.page
             row.registered_to = m
             # the local cache may hold MORE of the prompt than the
             # source had prefilled — prefill resumes past it
             row.fill_pos = max(int(snap.fill_pos),
-                               m * self._page_tokens)
+                               m * layout.share_tokens)
             row.out = [int(t) for t in snap.out]
             row.frozen = True
             imports = []
-            for v in range(m, need):
+            for v in wanted:
                 page = self._allocator.alloc(admission)
                 row.table[v] = page
                 row.private_pages.append(page)
@@ -1621,10 +1698,13 @@ class PagedEngine(SlotEngine):
             )
         return super()._timeout_reason_locked(group, admitted)
 
-    def _live_tokens_locked(self) -> int:
-        return super()._live_tokens_locked() + sum(
+    def _live_positions_locked(self) -> List[int]:
+        return super()._live_positions_locked() + [
             r.fill_pos for r in self._prefilling
-        )
+        ]
+
+    def _entries_for(self, positions: int) -> int:
+        return self._layout.entries(positions)
 
     def _kv_capacity(self) -> int:
         return self._allocator.pages_total * self._page_tokens

@@ -173,6 +173,11 @@ class SessionSnapshot:
     page_tokens: int
     pages: List[Tuple[int, object]] = field(default_factory=list)
     source: str = ""
+    # the source pool's row layout (serve/paging.py RowLayout; 0/0 =
+    # every token kept): ``pages`` is keyed by TABLE ENTRY, and what an
+    # entry stands for is the layout's to say
+    window: int = 0
+    chunk: int = 0
 
     def nbytes(self) -> int:
         """Approximate wire size (the transport model's basis)."""
@@ -195,6 +200,8 @@ class SessionSnapshot:
             "page_tokens": self.page_tokens,
             "pages": [[v, _enc(p)] for v, p in self.pages],
             "source": self.source,
+            "window": self.window,
+            "chunk": self.chunk,
         }
 
     @classmethod
@@ -212,6 +219,8 @@ class SessionSnapshot:
             page_tokens=int(data["page_tokens"]),
             pages=[(int(v), _dec(p)) for v, p in data["pages"]],
             source=str(data.get("source", "")),
+            window=int(data.get("window", 0)),
+            chunk=int(data.get("chunk", 0)),
         )
 
 

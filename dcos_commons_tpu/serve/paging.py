@@ -39,6 +39,17 @@ thread; the device half (arena tensors, gather-attention) lives in
   construction).  Zero-ref entries stay resident and are evicted
   leaf-first in LRU order only under budget pressure.
 
+* **Row layout** — what a table entry MEANS is the attention's to
+  say (``RowLayout``).  With full attention entry ``v`` is virtual
+  page ``v`` of the row's history, for ever.  With EVA attention
+  (models/decode.py) a row keeps two kinds of entry of one shape in
+  one arena: a RING of ``window / P`` pages of exact K/V, written over
+  in place when a window ends, and behind it the pages of chunk
+  summaries, ``P`` chunks a page, which are written once and are the
+  only pages such a row can share.  A 32,768-position row then needs
+  256 pages where full attention needs 2,048, and the admission rule
+  reserves by that.
+
 ``paged_config_from_env`` is the ONE env -> paged-geometry contract,
 shared by both serve workers, shardcheck's ``_serve_leaves`` footprint
 model, and (through the serve workload profiles) the PR 9 admission
@@ -68,8 +79,155 @@ def worst_case_pages(prompt_len: int, max_new: int, page_tokens: int) -> int:
     """Worst-case pages one request can ever WRITE: positions
     ``[0, prompt_len + max_new - 1)`` — the final sampled token is
     returned but its K/V is never written (nothing decodes after
-    it)."""
+    it).  The full-attention rule; ``RowLayout.worst_case_pages`` is
+    the rule of whichever layout a pool runs."""
     return pages_for(prompt_len + max_new - 1, page_tokens)
+
+
+@dataclass(frozen=True)
+class RowLayout:
+    """How one row's positions map onto its page table, and what that
+    costs: the one place that knows what a table entry stands for.
+
+    ``window == 0``: full attention, entry ``v`` holds positions
+    ``[v*P, (v+1)*P)``.  ``window > 0`` (EVA, with ``chunk == P``):
+    entries ``[0, window/P)`` are the ring of the current window's
+    exact K/V (position ``p`` in ring page ``(p % window) // P``);
+    entry ``window/P + i`` holds the summaries of chunks
+    ``[i*P, (i+1)*P)``, written as each chunk ends."""
+
+    page_tokens: int
+    window: int = 0
+    chunk: int = 0
+
+    def __post_init__(self) -> None:
+        if not self.window:
+            return
+        if self.chunk != self.page_tokens:
+            raise ValueError(
+                f"a windowed row keeps one chunk a page: chunk "
+                f"{self.chunk} != page_tokens {self.page_tokens}"
+            )
+        if self.window % (self.chunk * self.page_tokens):
+            raise ValueError(
+                f"window {self.window} is not a whole number of summary "
+                f"pages ({self.chunk * self.page_tokens} positions each)"
+            )
+
+    @property
+    def window_pages(self) -> int:
+        return self.window // self.page_tokens if self.window else 0
+
+    @property
+    def share_tokens(self) -> int:
+        """Prompt positions one SHAREABLE page stands for: a page of
+        exact K/V, or a page of summaries (``P`` chunks)."""
+        return self.page_tokens * (self.chunk if self.window else 1)
+
+    @property
+    def share_quantum(self) -> int:
+        """Shareable pages a prefix hit comes in: whole windows only
+        (a window's exact K/V is of no use once the window is past,
+        so a hit must end where a window ends)."""
+        return self.window // self.share_tokens if self.window else 1
+
+    def share_slot(self, i: int) -> int:
+        """Table entry of the row's ``i``-th shareable page."""
+        return self.window_pages + i
+
+    def table_len(self, max_len: int) -> int:
+        """Page-table length of a row of up to ``max_len`` positions."""
+        if not self.window:
+            return pages_for(max_len, self.page_tokens)
+        return self.window_pages + pages_for(
+            max_len // self.chunk, self.page_tokens
+        )
+
+    def write_slots(self, first_pos: int, last_pos: int) -> List[int]:
+        """Table entries that writing positions ``[first_pos,
+        last_pos]`` touches, in order of first touch."""
+        p = self.page_tokens
+        if last_pos < first_pos:
+            return []
+        pages = range(first_pos // p, last_pos // p + 1)
+        if not self.window:
+            return list(pages)
+        # consecutive pages of positions are consecutive ring entries
+        ring = [
+            v % self.window_pages
+            for v in pages[:min(len(pages), self.window_pages)]
+        ]
+        # chunks that END inside the span get their summary written
+        chunks = range(first_pos // self.chunk,
+                       (last_pos + 1) // self.chunk)
+        sums = (
+            range(chunks[0] // p, chunks[-1] // p + 1) if chunks else ()
+        )
+        return ring + [self.window_pages + i for i in sums]
+
+    def live_slots(self, kv_end: int) -> List[int]:
+        """Table entries whose pages a later step can still READ once
+        positions ``[0, kv_end)`` are written: what a migration has to
+        carry.  A past window's ring pages are dead."""
+        p = self.page_tokens
+        if not self.window:
+            return list(range(pages_for(kv_end, p)))
+        ring = pages_for(kv_end % self.window, p)
+        sums = pages_for(kv_end // self.chunk, p)
+        return list(range(ring)) + [
+            self.window_pages + i for i in range(sums)
+        ]
+
+    def worst_case_pages(self, prompt_len: int, max_new: int,
+                         cached_pages: int = 0) -> int:
+        """Private pages a request can ever write, behind
+        ``cached_pages`` shared ones."""
+        return len(self.write_slots(
+            cached_pages * self.share_tokens, prompt_len + max_new - 2
+        ))
+
+    def entries(self, positions: int) -> int:
+        """Cache ENTRIES the next step of a row reads when ``positions``
+        are behind it: every one, or the current window's and one a
+        chunk of the windows before."""
+        if not self.window:
+            return positions
+        return positions % self.window + (
+            positions // self.window
+        ) * (self.window // self.chunk)
+
+    def rollovers(self, first_pos: int, last_pos: int) -> int:
+        """Window ends crossed by writing ``[first_pos, last_pos]``."""
+        if not self.window or last_pos < first_pos:
+            return 0
+        return last_pos // self.window - max(first_pos - 1, 0) // self.window
+
+    def summaries(self, first_pos: int, last_pos: int) -> int:
+        """Chunk summaries written with ``[first_pos, last_pos]``."""
+        if not self.window or last_pos < first_pos:
+            return 0
+        return (last_pos + 1) // self.chunk - first_pos // self.chunk
+
+
+def layout_from_env(env, page_tokens: int) -> RowLayout:
+    """The row layout of the model a task env describes: EVA's two
+    regions where ``MODEL_CONFIG`` names a file whose
+    ``attention_class`` is ``"eva"`` (the three keys read here are the
+    ones ``models.config_from_env`` reads; this module stays
+    jax-free), else every token for ever."""
+    import json
+
+    path = env.get("MODEL_CONFIG", "")
+    if not path:
+        return RowLayout(page_tokens)
+    with open(path, "r", encoding="utf-8") as f:
+        data = json.load(f)
+    if data.get("attention_class") != "eva":
+        return RowLayout(page_tokens)
+    return RowLayout(
+        page_tokens, window=int(data["window_size"]),
+        chunk=int(data["chunk_size"]),
+    )
 
 
 @dataclass
@@ -83,11 +241,16 @@ class PagedServeConfig:
     max_len: int           # virtual per-request position cap
     slots: int             # max concurrent decode rows
     prefix_cache: bool     # share read-only prompt pages
+    layout: Optional[RowLayout] = None   # None: full attention
+
+    def __post_init__(self) -> None:
+        if self.layout is None:
+            self.layout = RowLayout(self.page_tokens)
 
     @property
     def pages_per_row(self) -> int:
         """Page-table length per request row."""
-        return pages_for(self.max_len, self.page_tokens)
+        return self.layout.table_len(self.max_len)
 
     @property
     def arena_pages(self) -> int:
@@ -117,14 +280,24 @@ def paged_config_from_env(env) -> Optional[PagedServeConfig]:
     # KV_PAGES below slots x pages_per_row to overcommit on the mean
     # request, or raise SERVE_SLOTS at fixed KV_PAGES for free
     # concurrency on short traffic
-    per_row = pages_for(max_len, page_tokens)
+    try:
+        layout = layout_from_env(env, page_tokens)
+    except (OSError, ValueError, KeyError) as e:
+        raise SpecError(f"MODEL_CONFIG does not give a row layout: {e}")
+    per_row = layout.table_len(max_len)
     pages = int(env.get("KV_PAGES") or 0) or slots * per_row
     chunk = int(env.get("PREFILL_CHUNK_TOKENS") or "64")
     if chunk <= 0:
         raise SpecError(
             f"PREFILL_CHUNK_TOKENS must be >= 1, got {chunk}"
         )
-    need_one = pages_for(max_len - 1, page_tokens)
+    if layout.window and (chunk % layout.chunk or chunk > layout.window):
+        raise SpecError(
+            f"PREFILL_CHUNK_TOKENS {chunk} must be a whole number of "
+            f"{layout.chunk}-position chunks and at most one window "
+            f"({layout.window})"
+        )
+    need_one = layout.worst_case_pages(max_len, 0)
     if pages < need_one:
         raise SpecError(
             f"KV page budget overcommitted: {pages} pages x "
@@ -136,6 +309,7 @@ def paged_config_from_env(env) -> Optional[PagedServeConfig]:
     return PagedServeConfig(
         page_tokens=page_tokens, pages=pages, chunk_tokens=chunk,
         max_len=max_len, slots=slots, prefix_cache=prefix,
+        layout=layout,
     )
 
 
@@ -190,7 +364,8 @@ class PageAllocator:
     """
 
     def __init__(self, pages: int, page_tokens: int,
-                 prefix_cache: bool = True):
+                 prefix_cache: bool = True,
+                 layout: Optional[RowLayout] = None):
         if pages < 1:
             raise ValueError(f"page arena needs >= 1 page, got {pages}")
         if page_tokens < 1:
@@ -199,6 +374,7 @@ class PageAllocator:
             )
         self.pages_total = pages
         self.page_tokens = page_tokens
+        self.layout = layout if layout is not None else RowLayout(page_tokens)
         self._prefix_enabled = prefix_cache
         self._free: List[int] = list(range(pages, 0, -1))  # pop -> 1
         self._free_set = set(self._free)
@@ -270,8 +446,13 @@ class PageAllocator:
         the first token — a fully-cached prompt still needs that
         forward pass."""
         plen = len(prompt)
-        p = self.page_tokens
+        # a shareable page stands for ``p`` prompt positions, and a
+        # hit comes in whole quanta of them (serve/paging.py
+        # RowLayout: one page, or one window's summary pages)
+        p = self.layout.share_tokens
+        quantum = self.layout.share_quantum
         limit = (plen - 1) // p
+        limit -= limit % quantum
         matched: List[_PrefixEntry] = []
         if self._prefix_enabled and limit > 0:
             parent_eid = 0
@@ -282,7 +463,8 @@ class PageAllocator:
                     break
                 matched.append(entry)
                 parent_eid = entry.eid
-        need = worst_case_pages(plen, max_new, p) - len(matched)
+            del matched[len(matched) - len(matched) % quantum:]
+        need = self.layout.worst_case_pages(plen, max_new, len(matched))
         # pinning a zero-ref entry removes it from ``available``, so
         # the admission check must charge for those pins too
         charge = need + sum(1 for e in matched if e.refs == 0)
@@ -389,10 +571,10 @@ class PageAllocator:
         budgeted for."""
         if not self._prefix_enabled or not admission.chain_open:
             return False
-        if len(page_tokens) != self.page_tokens:
+        if len(page_tokens) != self.layout.share_tokens:
             raise RuntimeError(
                 f"registering a partial page ({len(page_tokens)} of "
-                f"{self.page_tokens} tokens)"
+                f"{self.layout.share_tokens} tokens)"
             )
         parent = admission.chain_tail
         key = ((parent.eid if parent else 0), tuple(page_tokens))

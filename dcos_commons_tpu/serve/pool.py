@@ -225,7 +225,7 @@ class PagedPoolModel:
             paged_prefill_chunk,
             sample_token,
         )
-        from dcos_commons_tpu.serve.paging import pages_for
+        from dcos_commons_tpu.serve.paging import RowLayout
 
         self._jax = jax
         self._span = jax.profiler.TraceAnnotation
@@ -236,7 +236,15 @@ class PagedPoolModel:
         self.page_tokens = page_tokens
         self.pages = pages
         self.chunk_tokens = chunk_tokens
-        self.pages_per_row = pages_for(max_len, page_tokens)
+        # what a table entry stands for is the attention's to say
+        # (serve/paging.py RowLayout); the engine is given the same
+        # layout, and the two programs below read it off ``config``
+        eva = config.attention == "eva"
+        self.layout = RowLayout(
+            page_tokens, config.window_size if eva else 0,
+            config.chunk_size if eva else 0,
+        )
+        self.pages_per_row = self.layout.table_len(max_len)
         self._put = put if put is not None else (lambda x: x)
         con = constrain_out if constrain_out is not None else (lambda x: x)
 
@@ -327,7 +335,11 @@ class PagedPoolModel:
     def export_page(self, page: int) -> dict:
         """Snapshot one physical page as host numpy, every cache key
         included (int8 arenas ship their per-vector scales too — a
-        page without its scales decodes to garbage).  Single-caller
+        page without its scales decodes to garbage).  A page is
+        ``page_tokens`` ENTRIES of every layer, whatever they are: a
+        row's exact K/V, or (an EVA row's summary region) its chunk
+        summaries — which, is the table entry's to say, not the
+        page's.  Single-caller
         contract like ``prefill_chunk``/``decode``: only the engine
         loop may call this (serve/engine.py routes it through the
         page-I/O queue), since it reads ``self.cache`` mid-stream."""

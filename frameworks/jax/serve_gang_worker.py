@@ -577,7 +577,7 @@ def main() -> int:
                 prompt_len,
                 page_tokens=paged.page_tokens, pages=paged.pages,
                 chunk_tokens=paged.chunk_tokens,
-                prefix_cache=paged.prefix_cache,
+                prefix_cache=paged.prefix_cache, layout=pool.layout,
                 queue_timeout_s=queue_timeout_s,
                 on_idle=paged_idle_tick, idle_every_s=IDLE_TICK_S,
                 stats_path=stats_path,

@@ -29,7 +29,13 @@ across requests with the same prefix (prefix caching — the system-
 prompt multiplier).  KV_PAGE_TOKENS=0 falls back to the PR 6 slot
 pool (SERVE_SLOTS x MAX_LEN rows).  Mixed prompt lengths, requested
 lengths AND temperatures still share one pool dispatch, and greedy
-outputs are token-identical on both paths.  GET /stats exposes the
+outputs are token-identical on both paths.  MODEL_CONFIG names a configuration
+file (the key names of a published config.json) that sizes the model
+in place of the eight size names and says what they cannot:
+rope_theta, the norm's epsilon and unit offset, an untied head, and
+attention_class "eva", which gives every row a ring of exact window
+pages beside its chunk-summary pages in the same arena
+(models/decode.py, serve/paging.py RowLayout).  GET /stats exposes the
 serving gauges (queue depth, KV occupancy, kv_pages_free,
 prefix_cache_hit_rate, prefill_chunk_backlog, tokens/s) and the
 engine loop's cumulative counters under ``loop``; the same snapshot
@@ -456,7 +462,7 @@ def main() -> int:
             prompt_len,
             page_tokens=paged.page_tokens, pages=paged.pages,
             chunk_tokens=paged.chunk_tokens,
-            prefix_cache=paged.prefix_cache,
+            prefix_cache=paged.prefix_cache, layout=pool.layout,
             queue_timeout_s=queue_timeout_s, stats_path=stats_path,
             role=role, read_page=pool.export_page,
             write_page=pool.import_page, handoff=handoff,
@@ -482,7 +488,8 @@ def main() -> int:
         pool.warm()
         shape = (
             f"paged KV: {paged.pages} pages x {paged.page_tokens} "
-            f"tokens, {slots} rows, chunk {paged.chunk_tokens}, "
+            f"tokens, {slots} rows of {pool.pages_per_row} table "
+            f"entries, chunk {paged.chunk_tokens}, "
             f"prefix cache {'on' if paged.prefix_cache else 'off'}"
         )
     else:
@@ -499,6 +506,10 @@ def main() -> int:
             "n_layers": config.n_layers, "n_heads": config.n_heads,
             "n_kv_heads": config.n_kv_heads, "d_ff": config.d_ff,
             "dtype": jnp.dtype(config.dtype).name,
+            "attention": config.attention,
+            "window_size": config.window_size,
+            "chunk_size": config.chunk_size,
+            "model_config": os.environ.get("MODEL_CONFIG", ""),
         },
         warm_s=round(time.monotonic() - warm_t0, 2),
     )

@@ -1080,7 +1080,7 @@ def _reference_step(config, params, cache, step, args):
     for l in range(config.n_layers):
         layer = jax.tree.map(lambda a: a[l], params["layers"])
         arena = {name: arr[l] for name, arr in cache.items()}
-        normed = D.rms_norm(x, layer["attn_norm"])
+        normed = D._norm(config, x, layer["attn_norm"])
         q, k_new, v_new = D._project_kv(config, layer, normed, positions)
         new = {"k": k_new, "v": v_new}
         if quantized:
@@ -1110,7 +1110,7 @@ def _reference_step(config, params, cache, step, args):
         ).astype(config.dtype)
         x = x + attn.reshape(b, s, h * hd) @ layer["wo"]
         x = D._serve_ffn(config, layer, x)
-    x = D.rms_norm(x, params["final_norm"])
+    x = D._norm(config, x, params["final_norm"])
     if step == "prefill_chunk":
         x_last = lax.dynamic_index_in_dim(
             x, true_len - 1, axis=1, keepdims=False
