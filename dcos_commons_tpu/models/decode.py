@@ -600,6 +600,8 @@ def paged_decode_step(
     element-for-element the slot pool's with ``max_len = M * P``."""
     if config.attention == "eva":
         return _eva_decode_step(config, params, cache, token, pos, tables)
+    from dcos_commons_tpu.ops.paged_decode import paged_decode_attention
+
     b = token.shape[0]
     h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     p_tok = cache["k"].shape[2]
@@ -612,12 +614,14 @@ def paged_decode_step(
     vpage = jnp.minimum(pos // p_tok, m - 1)
     phys = tables[rows, vpage]                   # [b]
     slot_off = pos % p_tok
-    valid = (
-        lax.broadcasted_iota(jnp.int32, (1, 1, length), 2)
-        <= pos[:, None, None]
-    )                                            # [b, 1, L]
     quantized = "k_scale" in cache
     reps = h // kv
+    kernel = decode_attention_kernel(config, cache)
+    if not kernel:
+        valid = (
+            lax.broadcasted_iota(jnp.int32, (1, 1, length), 2)
+            <= pos[:, None, None]
+        )                                        # [b, 1, L]
 
     def layer_fn(carry, inputs):
         x, arena = carry
@@ -631,29 +635,41 @@ def paged_decode_step(
                 name: arr.at[base + phys, slot_off].set(new[name])
                 for name, arr in arena.items()
             }
-        with jax.named_scope("paged_gather"):
-            pages = base + tables
-            k_all = arena["k"][pages].reshape(b, length, kv, hd)
-            v_all = arena["v"][pages].reshape(b, length, kv, hd)
-            if quantized:
-                ks_all = arena["k_scale"][pages].reshape(b, length, kv)
-                vs_all = arena["v_scale"][pages].reshape(b, length, kv)
+        if kernel:
+            # the row's new K/V is read back through the page it was
+            # just written into
+            with jax.named_scope("attention"):
+                attn = paged_decode_attention(
+                    q[:, 0], arena["k"], arena["v"], base + tables, pos,
+                    scale=hd ** -0.5, interpret=kernel == "interpret",
+                )
+        else:
+            with jax.named_scope("paged_gather"):
+                pages = base + tables
+                k_all = arena["k"][pages].reshape(b, length, kv, hd)
+                v_all = arena["v"][pages].reshape(b, length, kv, hd)
+                if quantized:
+                    ks_all = arena["k_scale"][pages].reshape(b, length, kv)
+                    vs_all = arena["v_scale"][pages].reshape(b, length, kv)
+            with jax.named_scope("attention"):
+                qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(
+                    b, kv, reps, hd
+                )
+                scores = jnp.einsum(
+                    "bkrd,blkd->bkrl", qg, k_all.astype(jnp.float32)
+                )
+                if quantized:
+                    scores = (
+                        scores * ks_all.transpose(0, 2, 1)[:, :, None, :]
+                    )
+                scores = jnp.where(valid[:, :, None, :], scores, _NEG)
+                probs = jax.nn.softmax(scores, axis=-1)
+                if quantized:
+                    probs = probs * vs_all.transpose(0, 2, 1)[:, :, None, :]
+                attn = jnp.einsum(
+                    "bkrl,blkd->bkrd", probs, v_all.astype(jnp.float32)
+                ).astype(config.dtype)
         with jax.named_scope("attention"):
-            qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(
-                b, kv, reps, hd
-            )
-            scores = jnp.einsum(
-                "bkrd,blkd->bkrl", qg, k_all.astype(jnp.float32)
-            )
-            if quantized:
-                scores = scores * ks_all.transpose(0, 2, 1)[:, :, None, :]
-            scores = jnp.where(valid[:, :, None, :], scores, _NEG)
-            probs = jax.nn.softmax(scores, axis=-1)
-            if quantized:
-                probs = probs * vs_all.transpose(0, 2, 1)[:, :, None, :]
-            attn = jnp.einsum(
-                "bkrl,blkd->bkrd", probs, v_all.astype(jnp.float32)
-            ).astype(config.dtype)
             x = x + attn.reshape(b, 1, h * hd) @ dq(layer["wo"], x.dtype)
         x = _serve_ffn(config, layer, x)
         return (x, arena), None
@@ -906,13 +922,26 @@ def _eva_prefill_chunk(config, params, cache, tokens, table, start, true_len):
     return logits, new_cache
 
 
-def _eva_decode_kernel(config: TransformerConfig):
-    """How a decode step's attention runs: ``"compiled"`` (the Pallas
-    kernel of ops/eva_decode.py, which reads each row's live pages in
-    place) on a TPU where no head is grouped, else ``None`` (gather the
-    whole table, then ``_softmax_block`` over its two regions).  A shape-and-backend rule, as
-    ``ops.rmsnorm`` has one; tests patch it to ``"interpret"``."""
-    if config.n_kv_heads != config.n_heads:
+def decode_attention_kernel(config: TransformerConfig, cache):
+    """How a paged decode step's attention runs: ``"compiled"`` (the
+    page walk of ops/paged_decode.py, which reads each row's live pages
+    in place from the arena) or ``None`` (gather every row's whole
+    table into a dense buffer, then a masked softmax over all of it).
+
+    A rule over what the code can observe, as ``ops.rmsnorm`` has one;
+    tests patch it to ``"interpret"``.  The gather path stays where the
+    kernel cannot run or would not compute the same thing: off a TPU;
+    over a quantized arena (the kernel takes no scales); for an EVA
+    model whose heads are grouped; and under an ambient mesh of more
+    than one device (``PagedPoolModel`` enters the mesh its arena is
+    laid over, the serving gang's tp mesh), because a ``pallas_call``
+    under a multi-device jit raises unless it is wrapped per shard
+    (parallel/mesh.py ``per_shard``), which this call is not."""
+    if "k_scale" in cache:
+        return None
+    if config.attention == "eva" and config.n_kv_heads != config.n_heads:
+        return None
+    if jax.sharding.get_abstract_mesh().size > 1:
         return None
     return "compiled" if jax.default_backend() == "tpu" else None
 
@@ -948,7 +977,7 @@ def _eva_decode_step(config, params, cache, token, pos, tables):
         tables[rows, wp + jnp.minimum(chunk_id // p_tok, sp - 1)], 0,
     )
     sum_off = chunk_id % p_tok
-    kernel = _eva_decode_kernel(config)
+    kernel = decode_attention_kernel(config, cache)
     if kernel:
         live, n_ring, n_live, n_win, n_sum = live_pages(
             tables, pos, win, chunk, p_tok

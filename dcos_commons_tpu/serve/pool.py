@@ -39,6 +39,7 @@ over the tp axis when divisible.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Callable, Optional
 
@@ -271,10 +272,22 @@ class PagedPoolModel:
                 )
                 return con(sample_token(logits[0], temp, key)), cache
 
-        def _decode(params, cache, tok, pos, temps, seeds, tables):
-            logits, cache = paged_decode_step(
-                config, params, cache, tok, pos, tables
+        # the mesh the arena is laid over is the ambient mesh while the
+        # decode step is traced: its attention kernel is chosen by it
+        # (models/decode.py decode_attention_kernel)
+        arena_mesh = (
+            functools.partial(
+                jax.sharding.use_abstract_mesh,
+                cache_sharding.mesh.abstract_mesh,
             )
+            if cache_sharding is not None else contextlib.nullcontext
+        )
+
+        def _decode(params, cache, tok, pos, temps, seeds, tables):
+            with arena_mesh():
+                logits, cache = paged_decode_step(
+                    config, params, cache, tok, pos, tables
+                )
 
             def pick_row(lg, temp, seed, p):
                 key = jax.random.fold_in(jax.random.key(seed), p)

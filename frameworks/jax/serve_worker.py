@@ -122,6 +122,7 @@ def main() -> int:
     import jax.numpy as jnp
 
     from dcos_commons_tpu.models import config_from_env, init_params
+    from dcos_commons_tpu.models.decode import decode_attention_kernel
     from dcos_commons_tpu.serve.pool import PagedPoolModel, PoolModel
     from dcos_commons_tpu.utils import (
         claim_devices,
@@ -495,6 +496,15 @@ def main() -> int:
     else:
         pool.warm(prompt_len)
         shape = f"slot pool: {slots} slots x {max_len}"
+    # which path a decode step's attention takes: the page walk that
+    # reads live pages in place, the gather of every row's whole table,
+    # or the slot pool's dense cache
+    if paged is None:
+        decode_attention = "dense"
+    elif decode_attention_kernel(config, pool.cache):
+        decode_attention = "kernel"
+    else:
+        decode_attention = "gather"
     # /stats and the sandbox snapshot state what answers the requests
     # and what warming it cost (XLA compile, or persistent-cache read)
     engine.annotate_stats(
@@ -507,6 +517,7 @@ def main() -> int:
             "n_kv_heads": config.n_kv_heads, "d_ff": config.d_ff,
             "dtype": jnp.dtype(config.dtype).name,
             "attention": config.attention,
+            "decode_attention": decode_attention,
             "window_size": config.window_size,
             "chunk_size": config.chunk_size,
             "model_config": os.environ.get("MODEL_CONFIG", ""),

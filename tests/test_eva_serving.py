@@ -634,7 +634,9 @@ def test_the_decode_kernel_reads_what_the_gather_path_reads(toy, monkeypatch):
     config, params = toy
     plen, n_new, chunk_tokens = 44, 30, 8
     tokens, plain = _served(config, params, _prompt(plen), n_new, chunk_tokens)
-    monkeypatch.setattr(decode, "_eva_decode_kernel", lambda c: "interpret")
+    monkeypatch.setattr(
+        decode, "decode_attention_kernel", lambda config, cache: "interpret"
+    )
     row = Row(config, params)
     out = [row.prefill(tokens[:plen], chunk_tokens)]
     for token in tokens[plen:]:

@@ -1154,3 +1154,98 @@ def test_arena_in_place_equals_per_layer_slice_and_restack(
     np.testing.assert_array_equal(
         np.asarray(logits), np.asarray(want_logits)
     )
+
+
+# -- the decode step's attention: no gathered copy of the tables -------
+
+
+def _avals(jaxpr):
+    """Every value a jaxpr makes, nested jaxprs too."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield var.aval
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from _avals(inner)
+
+
+def _gathered_values(config, jaxpr, rows, table_len):
+    """The values of ``jaxpr`` as large as every row's whole table of
+    K or of V: ``[b * M, P, kv, hd]``, ``[b, M * P, kv, hd]`` or any
+    other shape of that many elements."""
+    size = (
+        rows * table_len * A_PTOK * config.n_kv_heads * config.head_dim
+    )
+    return [
+        aval.str_short() for aval in _avals(jaxpr)
+        if getattr(aval, "size", 0) == size
+    ]
+
+
+def test_decode_with_the_kernel_holds_no_value_of_rows_x_table_pages(
+    arena_model, monkeypatch
+):
+    """With the page walk in the path (ops/paged_decode.py, which reads
+    live pages in place) ``jit__decode`` makes no array of rows x table
+    pages pages: the gather that cost 5.6 ms of an 18.4 ms tick
+    (PERF.md §6, PR 29) cannot come back unseen.  The gather path's
+    jaxpr does hold such values, so the guard is not empty."""
+    import jax
+
+    from dcos_commons_tpu.models import decode
+
+    config, params = arena_model
+    cache, (token, pos, tables) = _arena_case(config, "decode_step", "native")
+
+    def jaxpr():
+        return jax.make_jaxpr(
+            lambda cache: decode.paged_decode_step(
+                config, params, cache, token, pos, tables
+            )
+        )(cache).jaxpr
+
+    rows, table_len = tables.shape
+    # the arena itself must not be mistaken for a gathered buffer
+    assert A_LAYERS * A_PAGES != rows * table_len
+    assert _gathered_values(config, jaxpr(), rows, table_len)
+    monkeypatch.setattr(
+        decode, "decode_attention_kernel", lambda config, cache: "interpret"
+    )
+    assert _gathered_values(config, jaxpr(), rows, table_len) == []
+
+
+def test_a_quantized_arena_takes_the_gather_path_on_a_tpu(
+    arena_model, monkeypatch
+):
+    """Where the backend says TPU a native arena would go through the
+    kernel; an int8 arena (the kernel
+    takes no scales) still takes the gather path and is bit-equal to
+    the plain per-layer reference, as at the parent commit."""
+    import jax
+
+    from dcos_commons_tpu.models import decode
+
+    config, params = arena_model
+    cache, args = _arena_case(config, "decode_step", "int8")
+    native, _ = _arena_case(config, "decode_step", "native")
+    want_logits, want_cache = jax.jit(
+        lambda cache, *args: _reference_step(
+            config, params, cache, "decode_step", args
+        )
+    )(cache, *args)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert decode.decode_attention_kernel(config, native) == "compiled"
+    assert decode.decode_attention_kernel(config, cache) is None
+    logits, new_cache = jax.jit(
+        lambda cache, *args: decode.paged_decode_step(
+            config, params, cache, *args
+        )
+    )(cache, *args)
+    np.testing.assert_array_equal(
+        np.asarray(logits), np.asarray(want_logits)
+    )
+    for name in cache:
+        np.testing.assert_array_equal(
+            np.asarray(new_cache[name]), np.asarray(want_cache[name])
+        )

@@ -48,3 +48,30 @@ def test_eva_decode_attention_compiles_at_the_published_widths(one_chip):
     assert "tpu_custom_call" in text and "eva_decode_attention" in text
     # the arena is read in place: no copy of it, no gathered buffer
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def test_paged_decode_attention_compiles_at_the_mixture_cells_sizes(one_chip):
+    """64 rows of 128 table entries over an arena of 3 x 4097 pages of
+    ``bf16[16, 8, 128]`` (8 KV heads), 32 query heads of 128: the sizes
+    of ``mixtral8x7b.chat``.  Mosaic takes a page of 8 bfloat16
+    sublanes as it is."""
+    import jax
+    import jax.numpy as jnp
+
+    from dcos_commons_tpu.ops.paged_decode import paged_decode_attention
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, table, pages = 64, 128, 3 * 4097
+    arena = shaped((pages, 16, 8, 128), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda *a: paged_decode_attention(*a, scale=128 ** -0.5)
+    ).lower(
+        shaped((rows, 32, 128), jnp.bfloat16), arena, arena,
+        shaped((rows, table), jnp.int32), shaped((rows,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "paged_decode_attention" in text
+    # the arena is read in place: no copy of it, no gathered buffer
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
