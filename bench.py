@@ -1437,487 +1437,6 @@ def bench_multislice() -> dict:
     }
 
 
-def bench_continuous_serve() -> dict:
-    """Continuous batching vs dispatch-per-group serving (ISSUE 6),
-    CPU-runnable: the SAME open-loop load — staggered arrivals, mixed
-    generation lengths — driven through (a) the slot-pool engine
-    (serve/engine.py + serve/pool.py: admit at every decode step,
-    retire per-row) and (b) the dispatch-per-group baseline this PR
-    replaced (MicroBatcher + one whole jitted generate per group,
-    every row padded to MAX_NEW steps).  Three numbers are fenced:
-
-    * GREEDY EQUALITY — both paths must produce token-identical
-      continuations per request (correctness before speed);
-    * tokens/s — useful tokens / makespan must IMPROVE: the baseline
-      burns MAX_NEW steps per dispatch while the mean request wants
-      ~half that (the mean-to-max ratio IS the headroom), and a
-      request arriving mid-dispatch serializes behind it;
-    * p95 TTFT — time to first token must DROP from O(a whole
-      preceding generation) to O(one decode tick + own prefill).
-
-    Open-loop: arrival times come from a fixed seeded schedule, never
-    from completions — a saturating server cannot slow the offered
-    load, exactly like production traffic."""
-    import random
-    import statistics
-    import threading
-
-    import numpy as np
-
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-    import jax.numpy as jnp
-
-    from dcos_commons_tpu.models import (
-        TransformerConfig,
-        generate,
-        init_params,
-    )
-    from dcos_commons_tpu.serve.engine import SlotEngine
-    from dcos_commons_tpu.serve.pool import PoolModel
-    from dcos_commons_tpu.utils.microbatch import (
-        MicroBatcher,
-        WorkItem,
-        pack_mixed_rows,
-        unpack_results,
-    )
-
-    # big enough that per-step compute dominates dispatch overhead on
-    # CPU even in a CONTENDED window (the continuous path pays one
-    # dispatch per TOKEN where the baseline scans inside one jit, so
-    # inflated dispatch costs hit it ~5x harder — r6 tuning found
-    # d256 bimodal on a shared box), small enough to compile fast
-    config = TransformerConfig(
-        vocab=512, d_model=512, n_layers=4, n_heads=8, n_kv_heads=8,
-        d_ff=1376, max_seq=128, dtype=jnp.float32, remat=False,
-    )
-    params = init_params(config, jax.random.key(0))
-    # a short prompt region keeps the per-request prefill ~one decode
-    # tick: the bench isolates the SCHEDULING difference (per-step
-    # admission + early retirement), which is what this PR changed —
-    # chunked/batched prefill is its own future lever
-    slots, max_new, max_len = 8, 32, 48
-    prompt_len = max_len - max_new
-    n_requests = 24
-
-    # the offered load, shared by both paths: mixed generation
-    # lengths (mean ~= half of max: the baseline's padding waste) and
-    # staggered open-loop arrivals at roughly the continuous path's
-    # service rate (the baseline saturates and queues)
-    rng = random.Random(0)
-    requests = []
-    for i in range(n_requests):
-        plen = rng.randint(3, 10)
-        requests.append({
-            "prompt": [rng.randrange(config.vocab) for _ in range(plen)],
-            # mean 13.25 vs max 32: the mean-to-max ratio is the
-            # baseline's padding waste (it decodes 32 steps per
-            # dispatch no matter what its rows asked for)
-            "n": [3, 6, 12, max_new][i % 4],
-        })
-
-    def run_load(submit, reqs=None, arrivals=None):
-        """Drive an open-loop schedule; returns (per-request results,
-        per-request completion latencies, makespan).  Defaults to the
-        legacy round's request set and arrival schedule."""
-        if reqs is None:
-            reqs = requests
-        if arrivals is None:
-            arrivals = []
-            t = 0.0
-            for i in range(len(reqs)):
-                arrivals.append(t)
-                t += rng_arrival[i]
-        results = [None] * len(reqs)
-        done_s = [0.0] * len(reqs)
-        errors = []
-        t0 = time.monotonic()
-
-        def client(i):
-            delay = arrivals[i] - (time.monotonic() - t0)
-            if delay > 0:
-                time.sleep(delay)
-            try:
-                results[i] = submit(
-                    reqs[i]["prompt"], reqs[i]["n"]
-                )
-                done_s[i] = (time.monotonic() - t0) - arrivals[i]
-            except Exception as e:  # noqa: BLE001
-                errors.append(e)
-
-        threads = [
-            threading.Thread(target=client, args=(i,))
-            for i in range(len(reqs))
-        ]
-        for th in threads:
-            th.start()
-        for th in threads:
-            th.join(timeout=600)
-        assert not errors, errors
-        makespan = time.monotonic() - t0
-        return results, done_s, makespan
-
-    # calibrate one decode-step's cost to set the arrival cadence
-    # (absolute wall clocks vary 10x across hosts; the SCHEDULE must
-    # stress both paths identically relative to the chip's speed)
-    pool = PoolModel(config, params, slots, max_len)
-    pool.warm(prompt_len)
-    t0 = time.monotonic()
-    for _ in range(5):
-        pool.decode(
-            np.zeros(slots, np.int32),
-            np.full(slots, prompt_len, np.int32),
-            np.zeros(slots, np.float32), np.zeros(slots, np.int32),
-        )
-    step_s = (time.monotonic() - t0) / 5
-    # ~1 tick between arrivals SATURATES both servers: the makespan
-    # then measures each scheduler's sustained service rate, not the
-    # shared arrival window — and the baseline's head-of-line wait
-    # (a whole dispatch) shows up undiluted in its TTFT
-    rng_arrival = [rng.expovariate(1.0 / step_s)
-                   for _ in range(n_requests)]
-
-    # -- the two servers ------------------------------------------
-    ticks = [0, 0]  # (decode ticks, active-row steps) across rounds
-
-    def counted_decode(tok, pos, temps, seeds, n_active):
-        ticks[0] += 1
-        ticks[1] += n_active
-        return pool.decode(tok, pos, temps, seeds)
-
-    gen = jax.jit(lambda p, t, n: generate(
-        config, p, t, max_new_tokens=max_new, max_len=max_len,
-        true_len=n,
-    ))
-    lock = threading.Lock()
-
-    def run_group(items):
-        padded, lens, _used = pack_mixed_rows(items, slots, prompt_len)
-        with lock:
-            out = gen(params, jnp.asarray(padded), jnp.asarray(lens))
-        unpack_results(items, np.asarray(jax.device_get(out)))
-
-    # warm the baseline compile outside the measured windows too
-    run_group([WorkItem([[0] * prompt_len], max_new, 0.0)])
-    useful_tokens = sum(r["n"] for r in requests)
-
-    from dcos_commons_tpu.metrics.registry import (
-        percentile as _nearest_rank,
-    )
-
-    def percentile(samples, q):
-        # the one shared nearest-rank convention (metrics/registry.py)
-        return _nearest_rank(sorted(samples), q)
-
-    def measure_continuous():
-        engine = SlotEngine(
-            pool.prefill, counted_decode, slots, max_len, prompt_len,
-            queue_timeout_s=600,
-        )
-        try:
-            results, done, makespan = run_load(
-                lambda prompt, n: engine.submit([prompt], n)[0]
-            )
-            stats = engine.stats()
-        finally:
-            engine.stop()
-        return results, {
-            "tps": useful_tokens / makespan,
-            "p50": stats["ttft_p50_s"], "p95": stats["ttft_p95_s"],
-            "mean": statistics.mean(done),
-        }
-
-    def measure_baseline():
-        batcher = MicroBatcher(
-            run_group, capacity=slots, window_s=0.0,
-            queue_timeout_s=600,
-        )
-        results, done, makespan = run_load(
-            lambda prompt, n: batcher.submit(
-                WorkItem([prompt], n, 0.0)
-            )[0]
-        )
-        # baseline TTFT = completion: dispatch-per-group cannot
-        # stream a first token before its whole generate finishes
-        return results, {
-            "tps": useful_tokens / makespan,
-            "p50": percentile(done, 50), "p95": percentile(done, 95),
-            "mean": statistics.mean(done),
-        }
-
-    # ALTERNATING adjacent pairs, fenced on the MEDIAN per-pair ratio
-    # (the PR 5 lesson: this host's CPU availability swings 2-3x
-    # between windows; a continuous-then-baseline pair runs ~seconds
-    # apart, so the ratio inside a pair mostly cancels the swing and
-    # the median rejects the pair a preemption spike lands in — a
-    # noisy box cannot fake a systematic win, only hide one)
-    cont_rounds, base_rounds = [], []
-    for _round in range(3):
-        cont_results, cont_m = measure_continuous()
-        base_results, base_m = measure_baseline()
-        # correctness first, EVERY round: token-identical greedy
-        # continuations or the perf numbers mean nothing
-        assert cont_results == base_results, (
-            "continuous batching changed a greedy continuation"
-        )
-        cont_rounds.append(cont_m)
-        base_rounds.append(base_m)
-    speedup = statistics.median(
-        c["tps"] / b["tps"] for c, b in zip(cont_rounds, base_rounds)
-    )
-    ttft_improvement = statistics.median(
-        b["p95"] / max(c["p95"], 1e-9)
-        for c, b in zip(cont_rounds, base_rounds)
-    )
-    # absolutes reported from each path's best window
-    cont_tps = max(m["tps"] for m in cont_rounds)
-    base_tps = max(m["tps"] for m in base_rounds)
-    cont_p50 = min(m["p50"] for m in cont_rounds)
-    cont_p95 = min(m["p95"] for m in cont_rounds)
-    base_p50 = min(m["p50"] for m in base_rounds)
-    base_p95 = min(m["p95"] for m in base_rounds)
-    utilization = ticks[1] / float(max(1, ticks[0]) * slots)
-
-    # ---- ISSUE 11: paged arena vs slot pool at the SAME HBM budget
-    # geometry: slot pool 8 rows x 64 positions == paged 64 pages x 8
-    # tokens (byte-identical KV bytes); the paged arm runs 2x the
-    # decode rows over that budget — the capacity multiplier block-
-    # granular allocation buys when most requests use a fraction of a
-    # MAX_LEN row.  Load: one LONG-prompt request followed hot by
-    # n=1 short probes (a probe's completion time IS its TTFT — the
-    # head-of-line scenario chunked prefill exists to fix), then a
-    # saturating mixed tail, part of it sharing an 8-token system
-    # prefix (the prefix-cache traffic shape).  Three fences:
-    # greedy token-equality (every round), >= 1.3x peak concurrent
-    # requests sustained, and no p95 TTFT regression for the short
-    # probes behind the long prefill (median of adjacent pairs, same
-    # methodology as above).
-    from dcos_commons_tpu.serve.engine import PagedEngine
-    from dcos_commons_tpu.serve.pool import PagedPoolModel
-
-    p_tok = 8
-    chunk_p = 8
-    max_len_p = 64
-    prompt_len_p = max_len_p - max_new           # 32: 4 chunks
-    pages_p = slots * max_len_p // p_tok         # 64 pages: same bytes
-    slots_p = slots * 2                          # 16 decode rows
-    sys_prefix = [rng.randrange(config.vocab) for _ in range(8)]
-    long_prompt = [
-        rng.randrange(config.vocab) for _ in range(prompt_len_p)
-    ]
-    paged_reqs = [{"prompt": long_prompt, "n": max_new}]
-    short_idx = []
-    for i in range(8):
-        short_idx.append(len(paged_reqs))
-        paged_reqs.append({
-            "prompt": [rng.randrange(config.vocab)
-                       for _ in range(3 + i % 2)],
-            "n": 1,
-        })
-    for i in range(21):
-        if i % 2:
-            prompt = sys_prefix + [
-                rng.randrange(config.vocab) for _ in range(2 + i % 5)
-            ]
-        else:
-            prompt = [
-                rng.randrange(config.vocab) for _ in range(3 + i % 8)
-            ]
-        paged_reqs.append({
-            "prompt": prompt, "n": [max_new, 6, max_new, 12][i % 4],
-        })
-    # the long at t=0, probes hot on its heels, the tail at a
-    # saturating ~half-step cadence
-    arrivals_p = [0.0] + [0.02 * (i + 1) * step_s for i in range(8)]
-    t_arr = arrivals_p[-1]
-    for _ in range(21):
-        t_arr += 0.5 * step_s
-        arrivals_p.append(t_arr)
-    useful_p = sum(r["n"] for r in paged_reqs)
-
-    slot_pool_p = PoolModel(config, params, slots, max_len_p)
-    slot_pool_p.warm(prompt_len_p)
-    paged_pool = PagedPoolModel(
-        config, params, slots_p, max_len_p, p_tok, pages_p, chunk_p
-    )
-    paged_pool.warm()
-
-    def measure_slot_arm():
-        peak = [0]
-
-        def decode(tok, pos, temps, seeds, n_active):
-            peak[0] = max(peak[0], n_active)
-            return slot_pool_p.decode(tok, pos, temps, seeds)
-
-        engine = SlotEngine(
-            slot_pool_p.prefill, decode, slots, max_len_p,
-            prompt_len_p, queue_timeout_s=600,
-        )
-        try:
-            results, done, makespan = run_load(
-                lambda prompt, n: engine.submit([prompt], n)[0],
-                paged_reqs, list(arrivals_p),
-            )
-        finally:
-            engine.stop()
-        return results, {
-            "tps": useful_p / makespan,
-            "peak": peak[0],
-            "short_p95": percentile(
-                [done[i] for i in short_idx], 95
-            ),
-        }
-
-    def measure_paged_arm():
-        peak = [0]
-
-        def decode(tok, pos, temps, seeds, tables, n_active):
-            peak[0] = max(peak[0], n_active)
-            return paged_pool.decode(tok, pos, temps, seeds, tables)
-
-        engine = PagedEngine(
-            paged_pool.prefill_chunk, decode, slots_p, max_len_p,
-            prompt_len_p, page_tokens=p_tok, pages=pages_p,
-            chunk_tokens=chunk_p, queue_timeout_s=600,
-        )
-        try:
-            results, done, makespan = run_load(
-                lambda prompt, n: engine.submit([prompt], n)[0],
-                paged_reqs, list(arrivals_p),
-            )
-            stats = engine.stats()
-        finally:
-            engine.stop()
-        return results, {
-            "tps": useful_p / makespan,
-            "peak": peak[0],
-            "short_p95": percentile(
-                [done[i] for i in short_idx], 95
-            ),
-            "prefix_hit_rate": stats["prefix_cache_hit_rate"],
-        }
-
-    paged_rounds, slotp_rounds = [], []
-    for _round in range(3):
-        p_res, p_m = measure_paged_arm()
-        s_res, s_m = measure_slot_arm()
-        # correctness first, EVERY round: the paged arena must not
-        # change a single greedy token vs the slot pool
-        assert p_res == s_res, (
-            "paged arena changed a greedy continuation"
-        )
-        paged_rounds.append(p_m)
-        slotp_rounds.append(s_m)
-    paged_peak = max(m["peak"] for m in paged_rounds)
-    slotp_peak = max(m["peak"] for m in slotp_rounds)
-    paged_tps_x = statistics.median(
-        p["tps"] / s["tps"]
-        for p, s in zip(paged_rounds, slotp_rounds)
-    )
-    paged_short_ttft_ratio = statistics.median(
-        p["short_p95"] / max(s["short_p95"], 1e-9)
-        for p, s in zip(paged_rounds, slotp_rounds)
-    )
-
-    out = {
-        "continuous_serve_requests": n_requests,
-        "continuous_serve_slots": slots,
-        "continuous_serve_rounds": len(cont_rounds),
-        "continuous_serve_step_s": round(step_s, 5),
-        "continuous_serve_tokens_per_s": round(cont_tps, 1),
-        "continuous_serve_baseline_tokens_per_s": round(base_tps, 1),
-        "continuous_serve_speedup_x": round(speedup, 2),
-        "continuous_serve_ttft_p50_s": round(cont_p50, 4),
-        "continuous_serve_ttft_p95_s": round(cont_p95, 4),
-        "continuous_serve_baseline_ttft_p50_s": round(base_p50, 4),
-        "continuous_serve_baseline_ttft_p95_s": round(base_p95, 4),
-        "continuous_serve_ttft_p95_improvement_x": round(
-            ttft_improvement, 2
-        ),
-        "continuous_serve_slot_utilization": round(utilization, 3),
-        "continuous_serve_mean_latency_s": round(
-            min(m["mean"] for m in cont_rounds), 4
-        ),
-        "continuous_serve_baseline_mean_latency_s": round(
-            min(m["mean"] for m in base_rounds), 4
-        ),
-        # paged arena vs slot pool at the SAME HBM budget (ISSUE 11)
-        "continuous_serve_paged_pages": pages_p,
-        "continuous_serve_paged_page_tokens": p_tok,
-        "continuous_serve_paged_rows": slots_p,
-        "continuous_serve_paged_chunk_tokens": chunk_p,
-        "continuous_serve_paged_requests": len(paged_reqs),
-        "continuous_serve_paged_peak_concurrent": paged_peak,
-        "continuous_serve_paged_slot_peak_concurrent": slotp_peak,
-        "continuous_serve_paged_concurrency_x": round(
-            paged_peak / max(slotp_peak, 1), 2
-        ),
-        "continuous_serve_paged_tokens_per_s": round(
-            max(m["tps"] for m in paged_rounds), 1
-        ),
-        "continuous_serve_paged_slot_tokens_per_s": round(
-            max(m["tps"] for m in slotp_rounds), 1
-        ),
-        "continuous_serve_paged_tps_x": round(paged_tps_x, 2),
-        "continuous_serve_paged_short_ttft_p95_s": round(
-            min(m["short_p95"] for m in paged_rounds), 4
-        ),
-        "continuous_serve_paged_slot_short_ttft_p95_s": round(
-            min(m["short_p95"] for m in slotp_rounds), 4
-        ),
-        "continuous_serve_paged_short_ttft_ratio": round(
-            paged_short_ttft_ratio, 3
-        ),
-        "continuous_serve_paged_prefix_hit_rate": round(
-            max(m["prefix_hit_rate"] for m in paged_rounds), 4
-        ),
-    }
-    print(  # the human summary (stderr: stdout carries bench JSON)
-        f"[continuous-serve] tokens/s {base_tps:.1f} -> {cont_tps:.1f} "
-        f"(median pairwise {speedup:.2f}x), p95 TTFT "
-        f"{base_p95:.3f}s -> {cont_p95:.3f}s "
-        f"(median pairwise {ttft_improvement:.2f}x), "
-        f"slot utilization {utilization:.0%}",
-        file=sys.stderr, flush=True,
-    )
-    print(
-        f"[continuous-serve/paged] same {pages_p * p_tok}-token KV "
-        f"budget: peak concurrent {slotp_peak} -> {paged_peak} "
-        f"({paged_peak / max(slotp_peak, 1):.2f}x), tokens/s median "
-        f"pairwise {paged_tps_x:.2f}x, short-probe p95 TTFT ratio "
-        f"{paged_short_ttft_ratio:.2f} (<1 = paged faster), prefix "
-        f"hit rate "
-        f"{max(m['prefix_hit_rate'] for m in paged_rounds):.0%}",
-        file=sys.stderr, flush=True,
-    )
-    # the tentpole's bound, asserted: continuous batching must beat
-    # dispatch-per-group on BOTH throughput and p95 TTFT under the
-    # same open-loop load (median of adjacent-pair ratios)
-    assert speedup > 1.0, (
-        f"continuous batching tokens/s did not beat dispatch-per-"
-        f"group: median pairwise ratio {speedup:.2f}"
-    )
-    assert ttft_improvement > 1.0, (
-        f"continuous batching p95 TTFT did not beat dispatch-per-"
-        f"group: median pairwise ratio {ttft_improvement:.2f}"
-    )
-    # ISSUE 11 fences: at the SAME HBM budget the paged arm must
-    # sustain >= 1.3x the slot pool's concurrent requests, and the
-    # short probes admitted behind the long prefill must show no p95
-    # TTFT regression (small collar for pairwise residual noise —
-    # chunked prefill should WIN here, and the reported ratio tracks
-    # by how much)
-    assert paged_peak >= 1.3 * slotp_peak, (
-        f"paged arena sustained {paged_peak} concurrent vs the slot "
-        f"pool's {slotp_peak} at the same KV budget (< 1.3x)"
-    )
-    assert paged_short_ttft_ratio <= 1.1, (
-        f"short requests behind a long prefill regressed: paged/slot "
-        f"p95 TTFT ratio {paged_short_ttft_ratio:.2f}"
-    )
-    return out
-
-
 def bench_router_scale() -> dict:
     """Serving front door (ISSUE 12), CPU-runnable and jax-free: an
     open-loop load sweep through the multi-pod RequestRouter over 1,
@@ -2691,9 +2210,8 @@ def bench_train_step() -> dict:
     this deterministic config (donation, dispatch order, and snapshot
     copies may move buffers, never values — PR 6's token-equality
     discipline); (2) the fast loop must WIN the median of alternating
-    legacy/fast pairs (bench_continuous_serve methodology: ratios
-    inside an adjacent pair mostly cancel this host's 2-3x load
-    swings); (3) the COST-MODEL GATE — shardcheck.stepcompare holds
+    legacy/fast pairs (ratios inside an adjacent pair mostly cancel
+    this host's 2-3x load swings); (3) the COST-MODEL GATE — shardcheck.stepcompare holds
     the fast loop's measured p50 step time (records from the SAVE
     rounds) against the calibrated no-save device floor + wire model
     (0 wire on one chip): a save that stopped the world, or any step
@@ -3382,7 +2900,7 @@ def bench_serve() -> dict:
             _latency, n = one_request(serve_batch)
             tokens_total += n
         wall = time.monotonic() - t_start
-        # concurrent single-prompt CLIENTS: the worker's slot engine
+        # concurrent single-prompt CLIENTS: the worker's engine
         # admits them into shared pool decode steps — the multi-client
         # number, vs the single-client full-batch number above
         import concurrent.futures as _fut
@@ -3802,18 +3320,6 @@ def main() -> None:
     except Exception as e:
         extras["slo_recovery_error"] = repr(e)[:200]
     _mark("slo_recovery")
-    # CPU-runnable serving data-plane trend (ISSUE 6): subprocess so
-    # the forced-cpu jax init cannot leak into the chip sections
-    try:
-        extras.update(_run_subprocess_section(
-            # 900s: the ISSUE 11 paged-vs-slot-pool round added two
-            # more compiled pools and three more load pairs
-            "bench_continuous_serve", timeout_s=900,
-            env={"JAX_PLATFORMS": "cpu"},
-        ))
-    except Exception as e:
-        extras["continuous_serve_error"] = repr(e)[:200]
-    _mark("continuous_serve")
     # CPU-runnable routing-tier trend (ISSUE 12): the multi-pod front
     # door's 1/2/4-pod open-loop sweep, affinity-vs-spray prefix hit
     # rate, and the mid-sweep drain round — jax-free, subprocess for

@@ -2,7 +2,7 @@
 detection — the concurrency half of the analysis suite.
 
 The reference SDK's scheduler is a single-threaded offer loop; this
-rebuild is deliberately not.  SlotEngine/PagedEngine loop threads,
+rebuild is deliberately not.  PagedEngine loop threads,
 HTTP verb threads, the async checkpoint writer, replication pullers,
 the health monitor's telemetry collector, and router poll loops all
 share mutable state, and the repo's worst latent bugs have been

@@ -13,7 +13,7 @@ validation moved ahead of the scheduler: for every
 it rebuilds the EXACT workload the task command would run —
 ``models.config_from_env`` for the model, ``parallel.mesh.derive``
 for the mesh (both the very functions the worker calls), real
-``sharding_rules`` / ``init_params`` / ``init_kv_cache`` evaluated
+``sharding_rules`` / ``init_params`` / ``init_paged_kv_cache`` evaluated
 ABSTRACTLY via ``jax.eval_shape`` (shape/dtype only: no devices, no
 FLOPs, JAX_PLATFORMS=cpu-safe) — and walks params + optimizer state
 + gradient + activation/KV estimates through the PartitionSpec rules.
@@ -49,7 +49,7 @@ gradients mirroring params (training), optimizer state via
 ``jax.eval_shape(optimizer.init)`` with param-shaped leaves
 inheriting the param's sharding, live activations = per-layer
 residual boundaries (remat's floor) + the f32 logits block, and the
-KV cache via the real ``init_kv_cache`` (serving).  Per-chip bytes
+KV cache via the real ``init_paged_kv_cache`` (serving).  Per-chip bytes
 divide each dim by the product of its mesh-axis sizes; everything a
 spec does not shard replicates.
 """
@@ -404,8 +404,9 @@ def _mnist_profile(env, tpu, pod, task) -> Workload:
 def _serve_leaves(env, mesh_total_tp: int) -> Tuple[Any, List[AbstractLeaf]]:
     import jax
 
-    from dcos_commons_tpu.models.decode import init_kv_cache
+    from dcos_commons_tpu.models.decode import init_paged_kv_cache
     from dcos_commons_tpu.models.transformer import config_from_env
+    from dcos_commons_tpu.serve.paging import paged_config_from_env
 
     config = config_from_env(env, remat=False)
     shapes, rules = _abstract_params(config)
@@ -414,48 +415,31 @@ def _serve_leaves(env, mesh_total_tp: int) -> Tuple[Any, List[AbstractLeaf]]:
         quantized=env.get("WEIGHT_DTYPE", "native") == "int8",
     )
     # the serving KV footprint IS the runtime allocation, exactly:
-    # by default the PAGED ARENA (serve/paging.py, ISSUE 11) —
-    # KV_PAGES usable pages + the trash page, each KV_PAGE_TOKENS
-    # entries (a position's K/V, or a chunk summary of a windowed
-    # row layout: one shape, so the arena's bytes do not depend on the
-    # layout — only how many rows it admits does, and the default
-    # KV_PAGES follows the layout's table), shaped by the SAME
-    # paged_config_from_env contract
-    # the workers and the PR 9 admission gate consume (an
-    # under-budgeted arena is a SpecError at derivation, so admission
-    # rejects page-budget overcommit at PUT time) — or, when
-    # KV_PAGE_TOKENS=0 selects the legacy slot pool, the SLOTS x
-    # MAX_LEN carve.  Both honor KV_DTYPE (int8 halves the bytes).
+    # the PAGED ARENA (serve/paging.py, ISSUE 11) — KV_PAGES usable
+    # pages + the trash page, each KV_PAGE_TOKENS entries (a
+    # position's K/V, or a chunk summary of a windowed row layout:
+    # one shape, so the arena's bytes do not depend on the layout —
+    # only how many rows it admits does, and the default KV_PAGES
+    # follows the layout's table), shaped by the SAME
+    # paged_config_from_env contract the workers and the PR 9
+    # admission gate consume (a geometry that cannot serve is a
+    # SpecError at derivation, so admission rejects page-budget
+    # overcommit at PUT time).  It honors KV_DTYPE (int8 halves the
+    # bytes).
     # A managed budget, not a per-request guess: occupancy within
     # this allocation is the runtime gauge (kv_occupancy /
     # kv_pages_free), the allocation itself is what HBM must hold.
-    from dcos_commons_tpu.serve.paging import paged_config_from_env
-
-    slots = int(env.get("SERVE_SLOTS") or 0) or int(
-        # mirrors the serve workers' conservative single-request
-        # fallback, not the options.json deploy default
-        # sdklint: disable=config-default-drift — dev fallback
-        env.get("SERVE_BATCH", "1")
-    )
-    max_len = int(env.get("MAX_LEN", "256"))
-    kv_dtype = env.get("KV_DTYPE", "native")
     paged = paged_config_from_env(env)
-    if paged is not None:
-        from dcos_commons_tpu.models.decode import init_paged_kv_cache
-
-        cache_shapes = jax.eval_shape(functools.partial(
-            init_paged_kv_cache, config, paged.arena_pages,
-            paged.page_tokens, kv_dtype,
-        ))
-    else:
-        cache_shapes = jax.eval_shape(functools.partial(
-            init_kv_cache, config, slots, max_len, kv_dtype
-        ))
-    # cache dims (layers, pages-or-slots, tokens, kv_heads, head_dim):
-    # heads ride tp like the attention weights when divisible (the
-    # gang worker's cache_sharding — kv heads sit on dim 3 in BOTH
-    # layouts), else the cache replicates; pages/slots replicate
-    # across the gang (every rank steps the same broadcast pool)
+    slots = paged.slots
+    cache_shapes = jax.eval_shape(functools.partial(
+        init_paged_kv_cache, config, paged.arena_pages,
+        paged.page_tokens, env.get("KV_DTYPE", "native"),
+    ))
+    # cache dims (layers, pages, tokens, kv_heads, head_dim): heads
+    # ride tp like the attention weights when divisible (the gang
+    # worker's cache_sharding — kv heads sit on dim 3), else the
+    # cache replicates; pages replicate across the gang (every rank
+    # steps the same broadcast pool)
     kv_sharded = (
         mesh_total_tp > 1 and config.n_kv_heads % mesh_total_tp == 0
     )

@@ -129,11 +129,13 @@ def scale_out_target(
     (value/threshold; >= 1).  MONOTONE in severity by construction:
     the step is floor(log2(severity)) + 1, clamped to
     [1, step_max] — a 2x breach adds up to 2 instances, a marginal
-    one adds 1 — and the target is clamped to ``max_instances``
+    one adds 1 — and the target is clamped to ``max_instances``,
+    but never under ``count``: a pod set already past the cap (the
+    cap was lowered under it) is not scaled IN by a breach
     (hypothesis-tested in test_health_actions)."""
     sev = max(1.0, float(severity))
     step = max(1, min(int(step_max), int(math.floor(math.log2(sev))) + 1))
-    return min(int(max_instances), int(count) + step)
+    return max(int(count), min(int(max_instances), int(count) + step))
 
 
 def decide(

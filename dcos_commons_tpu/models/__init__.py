@@ -29,7 +29,6 @@ from dcos_commons_tpu.models.decode import (
     generate,
     init_kv_cache,
     prefill,
-    prefill_into_slot,
     sample_token,
 )
 from dcos_commons_tpu.models.moe import (
@@ -59,7 +58,6 @@ __all__ = [
     "init_moe_params",
     "init_params",
     "prefill",
-    "prefill_into_slot",
     "sample_token",
     "loss_fn",
     "make_train_step",

@@ -107,9 +107,9 @@ def init_paged_kv_cache(
 def _gqa_only(config: TransformerConfig, what: str) -> None:
     if config.attention != "gqa":
         raise NotImplementedError(
-            f"{what}: the slot pool keeps every token of a row; attention "
-            f"{config.attention!r} is served by the paged arena alone "
-            "(KV_PAGE_TOKENS > 0)"
+            f"{what}: the dense cache keeps every token of a row; "
+            f"attention {config.attention!r} is served by the paged "
+            "arena alone"
         )
 
 
@@ -233,51 +233,6 @@ def prefill(
             x, last[:, None, None], axis=1
         )[:, 0]
     return _last_logits(config, params, x_last), cache
-
-
-def prefill_into_slot(
-    config: TransformerConfig,
-    params: Params,
-    cache: Dict[str, jax.Array],
-    tokens: jax.Array,
-    slot: jax.Array,
-    true_len: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Prefill prompt(s) into rows of a PERSISTENT slot-pool cache.
-
-    The continuous-batching form of ``prefill``: the pool cache
-    (``init_kv_cache(config, SLOTS, max_len)``) is allocated once and
-    lives across requests; this runs ``tokens [nb, s]`` through the
-    trunk and scatters the captured per-layer K/V into the pool rows
-    at ``slot`` (a TRACED int32 scalar — ``nb`` consecutive rows, the
-    nb=1 fast path is ONE dynamic_update_slice per leaf — or a [nb]
-    vector of arbitrary rows).  Returns (last-real-position logits
-    [nb, vocab] f32, updated cache).
-
-    The whole row is overwritten (``prefill`` pads its capture out to
-    the static ``max_len``), so a freed slot needs no scrubbing before
-    reuse: nothing of the previous occupant survives admission, and
-    ``decode_step``'s per-row valid mask (``<= pos``) never reads past
-    what this prefill + subsequent decode writes wrote.  Shapes stay
-    static — one compile serves every (slot, prompt content, length)
-    the server admits.
-    """
-    kv_dtype = "int8" if "k_scale" in cache else "native"
-    max_len = cache["k"].shape[2]
-    logits, row_cache = prefill(
-        config, params, tokens, max_len, true_len, kv_dtype=kv_dtype
-    )
-    slot = jnp.asarray(slot, jnp.int32)
-    out = {}
-    for name, buf in cache.items():
-        new = row_cache[name].astype(buf.dtype)
-        if slot.ndim == 0:
-            out[name] = lax.dynamic_update_slice(
-                buf, new, (0, slot, 0, 0, 0)
-            )
-        else:
-            out[name] = buf.at[:, slot].set(new)
-    return logits, out
 
 
 def sample_token(
@@ -597,7 +552,7 @@ def paged_decode_step(
     pos % P)`` — inactive rows (all-zero tables) write identical
     values into the trash page — and attention gathers each row's
     pages back into virtual order, so the masked-softmax math is
-    element-for-element the slot pool's with ``max_len = M * P``."""
+    element-for-element ``decode_step``'s with ``max_len = M * P``."""
     if config.attention == "eva":
         return _eva_decode_step(config, params, cache, token, pos, tables)
     from dcos_commons_tpu.ops.paged_decode import paged_decode_attention

@@ -1,23 +1,19 @@
 """Continuous-batching serving engine (the serve data plane's core).
 
-``engine.py`` is the model-agnostic half: an admission loop that
-admits waiting requests at EVERY decode step and retires finished
-rows immediately (per-row EOS / max-token), so a batch never pads out
-to its longest row and a new request's time-to-first-token is one
-decode tick + its own prefill instead of a whole preceding
-generation.  Two engines share that loop:
+``engine.py`` is the model-agnostic half: ``PagedEngine``, an
+admission loop that admits waiting requests at EVERY tick and retires
+finished rows immediately (per-row EOS / max-token), so a batch never
+pads out to its longest row and a new request's time-to-first-token
+is one decode tick + its own prefill instead of a whole preceding
+generation.  KV memory is a paged arena: block-granular KV with
+per-request page tables (``paging.py``: free-list allocator,
+admission-time page budgeting, refcounted prefix cache, the row
+layout an attention class asks for), chunked prefill interleaved with
+decode ticks, and read-only shared prompt pages.
 
-* ``SlotEngine`` — the original SLOTS x MAX_LEN slot pool (one
-  contiguous KV row per request);
-* ``PagedEngine`` — the paged arena (ISSUE 11): block-granular KV
-  with per-request page tables (``paging.py``: free-list allocator,
-  admission-time page budgeting, refcounted prefix cache), chunked
-  prefill interleaved with decode ticks, and read-only shared prompt
-  pages — the serving default.
-
-``pool.py`` is the device half: the jitted prefill/decode pair over
-the persistent cache (models/decode.py), shared by the single-chip
-server and the multi-host gang driver.
+``pool.py`` is the device half: the jitted prefill-chunk/decode pair
+over the persistent arena (models/decode.py), shared by the
+single-chip server and the multi-host gang driver.
 
 ``migration.py`` (ISSUE 16) makes the KV page the unit of MOBILITY:
 live sessions move pod-to-pod mid-generation under a fenced cutover
@@ -28,7 +24,7 @@ rebalancing, and prefill/decode disaggregation.
 from dcos_commons_tpu.serve.engine import (
     SERVESTATS_NAME,
     PagedEngine,
-    SlotEngine,
+    QueueTimeoutError,
     read_servestats,
 )
 from dcos_commons_tpu.serve.migration import (
@@ -60,11 +56,11 @@ __all__ = [
     "PagedEngine",
     "PagedServeConfig",
     "PrefillHandoff",
+    "QueueTimeoutError",
     "ReleasePendingError",
     "SessionMigratedError",
     "SessionSnapshot",
     "SimulatedDcnTransport",
-    "SlotEngine",
     "drain_sessions",
     "migrate_session",
     "paged_config_from_env",

@@ -1,36 +1,23 @@
-"""Continuous-batching engines (model-agnostic half): the slot pool
-and the paged KV arena that replaced it as the serving default.
+"""Continuous-batching engine (the model-agnostic half): per-step
+scheduling over a paged KV arena.
 
-The dispatch-per-group serve loop ran one whole ``generate`` per
-micro-batch: a request arriving one step after a dispatch started
-waited the FULL previous generation before its prefill even began,
-and every row padded out to the group's longest generation.
-``SlotEngine`` replaces that loop with per-step scheduling over a
-persistent slot pool; ``PagedEngine`` (ISSUE 11) further replaces
-the pool's row carve with a page-budgeted arena — block-granular KV
-through per-request page tables, chunked prefill interleaved with
-decode, refcounted prefix caching (serve/paging.py) — while sharing
-this loop's admission/retirement/telemetry machinery and keeping
-greedy outputs token-identical.  The slot-pool shape:
+``PagedEngine`` admits waiting requests at EVERY tick and retires
+finished rows (per-row EOS / max-token / cache-exhausted) at once, so
+a new request's time-to-first-token is O(one decode tick + its own
+prefill) and a short answer never pads out to the longest row.  KV
+memory is a fixed budget of pages handed out through per-request page
+tables (serve/paging.py: admission-time reservation, refcounted
+prefix cache, what a table entry stands for), prompts prefill one
+chunk per row per tick between decode steps, and shapes are static:
+XLA never recompiles as occupancy changes.
 
-* the KV cache is allocated ONCE at ``SLOTS x max_len`` (static
-  shapes — XLA never recompiles as occupancy changes);
-* waiting requests are admitted into free slots at EVERY decode step
-  (prefill-into-slot, models/decode.py), so p95 time-to-first-token is
-  O(one decode tick + own prefill) instead of O(a whole generation);
-* finished rows (per-row EOS / max-token / cache-exhausted) retire
-  their slot IMMEDIATELY — the pool never pads a short answer out to
-  the longest row, which is where the mean-to-max generation-length
-  throughput win comes from (bench.py bench_continuous_serve).
-
-The engine is model-agnostic and jax-free: the device half is two
-injected callables (the single-chip server binds them straight to a
-``serve.pool.PoolModel``; the gang driver wraps them in ADMIT/DECODE
-broadcast ticks so every rank steps the same program).  Liveness
-rules inherited from ``utils/microbatch.py`` (which this subsumes for
-both servers): FIFO admission order, queue-timeout removal (abandoned
-work never reaches the chip — an active abandoned row retires at the
-next tick, freeing its slot early), and an ``on_idle`` hook so an
+The engine is jax-free: the device half is two injected callables
+(the single-chip server binds them straight to a
+``serve.pool.PagedPoolModel``; the gang driver wraps them in
+ADMIT/DECODE broadcast ticks so every rank steps the same program).
+Liveness rules: FIFO admission, queue-timeout removal (abandoned work
+never reaches the chip: an active abandoned row retires at the next
+tick, freeing its row and pages early), and an ``on_idle`` hook so an
 SPMD gang keeps meeting in collectives with no traffic.
 
 Serving load telemetry: ``stats()`` reports queue depth, active
@@ -69,8 +56,8 @@ from typing import Callable, ContextManager, List, Optional, Sequence
 
 import numpy as np
 
+from dcos_commons_tpu.serve.paging import PageAllocator, RowLayout
 from dcos_commons_tpu.trace import NULL_TRACER, Span, TraceRecorder
-from dcos_commons_tpu.utils.microbatch import QueueTimeoutError
 
 SERVESTATS_NAME = "servestats.json"
 _TTFT_WINDOW = 512      # TTFT samples kept for the percentile gauges
@@ -90,6 +77,29 @@ PHASES = (
 _NO_SPAN = contextlib.nullcontext()
 
 
+class QueueTimeoutError(RuntimeError):
+    """A request expired waiting for chip capacity in the engine's
+    admission queue.  This is server SATURATION, not caller error:
+    HTTP handlers map it to 503 so load generators and clients can
+    tell overload apart from a 400 bad request.
+
+    ``kind`` names the starved resource so operators can tell
+    saturation-by-memory from saturation-by-compute in the 503 body
+    and the split timeout counters (``stats()``):
+
+    * ``kv-page-budget`` — the request's worst-case KV page need
+      never fit the arena's budget (memory saturation: add
+      pages/HBM, shrink MAX_LEN, or rely on prefix caching);
+    * ``kv-slot`` — no decode row freed up (concurrency saturation);
+    * ``stalled`` — admitted but the pool produced no new token for a
+      full window (compute saturation or a wedged device).
+    """
+
+    def __init__(self, message: str = "", kind: str = "kv-slot"):
+        super().__init__(message)
+        self.kind = kind
+
+
 class _Phase:
     """``with engine._phase(name)``: one reusable object a phase (the
     loop thread is the only user and a phase never nests).  Entry
@@ -98,7 +108,7 @@ class _Phase:
 
     __slots__ = ("_engine", "_name", "_span_name", "_span")
 
-    def __init__(self, engine: "SlotEngine", name: str):
+    def __init__(self, engine: "PagedEngine", name: str):
         self._engine = engine
         self._name = name
         self._span_name = "engine." + name
@@ -135,13 +145,18 @@ class _Group:
 
 
 class _Row:
-    """One prompt riding one KV slot."""
+    """One prompt riding one decode row and its PAGE TABLE
+    (serve/paging.py): ``table[v]`` is the physical arena page behind
+    table entry ``v`` (what an entry stands for is the layout's to
+    say); 0 = unallocated."""
 
     __slots__ = (
         "tokens", "n", "temp", "eos", "seed", "out", "group",
         "arrival", "slot", "rid", "frozen",
         "admit_t", "first_t", "first_tick", "chunks", "cached",
         "trace_id", "parent_id",
+        "table", "fill_pos", "admission", "private_pages",
+        "registered_to",
     )
 
     def __init__(self, tokens, n, temp, eos, seed, group):
@@ -171,32 +186,65 @@ class _Row:
         self.cached = 0        # prompt pages the prefix cache served
         self.trace_id = 0      # the request's trace (0 = recorder off)
         self.parent_id = 0     # the caller's ``request`` span
+        self.table = None            # np.int32 [M], built at admission
+        self.fill_pos = 0            # next prompt position to prefill
+        self.admission = None        # paging.Admission while admitted
+        self.private_pages: List[int] = []
+        self.registered_to = 0       # next prompt page to publish
 
 
-class SlotEngine:
-    """Admission loop over a persistent slot-pool KV cache.
+class PagedEngine:
+    """Continuous batching over a PAGED KV arena: block-granular
+    allocation, chunked prefill, and prefix caching (vLLM's
+    PagedAttention + SGLang's RadixAttention shape).
 
-    ``prefill_fn(padded [1, prompt_len] i32, slot=, true_len=, temp=,
-    seed=) -> first token`` runs one prompt into a pool row (the
-    scalars are passed by KEYWORD — transposing slot and true_len is
-    a silent cache corruption);
-    ``decode_fn(tok [S] i32, pos [S] i32, temps [S] f32, seeds [S]
-    i32, n_active) -> next tokens [S] i32`` advances EVERY row one
-    step (inactive rows are parked at slot state (0, 0) — their
-    computation is discarded and their cache row is fully overwritten
-    by the next admission's prefill).  Both run OUTSIDE the engine
-    lock; only host-side bookkeeping holds it.
+    * **Admission is page-budgeted** (serve/paging.py): a request
+      enters the pool only when a free decode row exists AND its
+      worst-case page need fits ``available - reserved`` — admitted
+      work can never OOM mid-generation, and a short reply returns
+      its unused pages immediately instead of stranding a MAX_LEN
+      row.  FIFO stays strict: a budget-blocked head is never jumped
+      by a smaller later request.
+    * **Prefill is chunked**: prompts run ``chunk_tokens`` at a time
+      — one chunk per PREFILLING REQUEST per engine tick, interleaved
+      with decode — so a long prompt does not block the tick it
+      rides and queued requests do not pay head-of-line TTFT.
+      Chunk progress counts as progress for the 503 timeout (a long
+      prefill is not a stall).
+    * **Prefix caching**: fully-prefilled prompt pages are published
+      read-only; an identical later prefix pins them instead of
+      recomputing (COW-by-recompute on mid-page divergence — shared
+      pages are never written; see serve/paging.py).
+
+    ``prefill_chunk_fn(padded [1, C] i32, slot=, table= [M] i32,
+    start=, true_len=, temp=, seed=) -> first token`` runs one chunk
+    (the return value is consumed only when the chunk completes the
+    prompt; the scalars are passed by KEYWORD — transposing two of
+    them is a silent cache corruption); ``decode_fn(tok [S], pos [S],
+    temps [S], seeds [S], tables [S, M] i32, n_active) -> next tokens
+    [S]`` advances EVERY row one step through its page table
+    (inactive and frozen rows ride a zero table: their writes land in
+    the trash page and their samples are discarded).  Both run
+    OUTSIDE the engine lock; only host-side bookkeeping holds it.
     """
-
-    _row_cls = _Row
 
     def __init__(
         self,
-        prefill_fn: Callable,
+        prefill_chunk_fn: Callable,
         decode_fn: Callable,
         slots: int,
         max_len: int,
         prompt_len: int,
+        *,
+        page_tokens: int,
+        pages: int,
+        chunk_tokens: int,
+        prefix_cache: bool = True,
+        layout: Optional[RowLayout] = None,
+        role: str = "unified",
+        read_page: Optional[Callable] = None,
+        write_page: Optional[Callable] = None,
+        handoff: Optional[Callable] = None,
         queue_timeout_s: float = 600.0,
         on_idle: Optional[Callable[[], None]] = None,
         idle_every_s: float = 0.05,
@@ -208,8 +256,8 @@ class SlotEngine:
         tracer: TraceRecorder = NULL_TRACER,
     ):
         if slots < 1:
-            raise ValueError(f"slot pool needs >= 1 slot, got {slots}")
-        self._prefill_fn = prefill_fn
+            raise ValueError(f"the pool needs >= 1 decode row, got {slots}")
+        self._prefill_fn = prefill_chunk_fn
         self._decode_fn = decode_fn
         self._slots = slots
         self._max_len = max_len
@@ -220,6 +268,50 @@ class SlotEngine:
         self._stats_path = stats_path
         self._stats_every_s = stats_every_s
         self._log = log
+        self._page_tokens = int(page_tokens)
+        # what a table entry stands for (serve/paging.py RowLayout):
+        # the one thing here that differs between attention classes
+        self._layout = (
+            layout if layout is not None else RowLayout(int(page_tokens))
+        )
+        if self._layout.page_tokens != self._page_tokens:
+            raise ValueError(
+                f"row layout has {self._layout.page_tokens}-token pages, "
+                f"the arena {self._page_tokens}"
+            )
+        self._pages_per_row = self._layout.table_len(int(max_len))
+        self._chunk_tokens = int(chunk_tokens)
+        if self._layout.window and (
+            self._chunk_tokens % self._layout.chunk
+            or self._chunk_tokens > self._layout.window
+        ):
+            raise ValueError(
+                f"prefill chunks of {self._chunk_tokens} are not whole "
+                f"{self._layout.chunk}-position chunks within one "
+                f"window of {self._layout.window}"
+            )
+        self._allocator = PageAllocator(
+            int(pages), int(page_tokens), prefix_cache,
+            layout=self._layout,
+        )
+        self._prefilling: deque = deque()
+        # migration state (serve/migration.py, ISSUE 16).  role is
+        # the pod's advertised serving posture (unified / prefill /
+        # decode) — telemetry and routing read it; the HANDOFF hook's
+        # presence is what actually diverts finished prefills.
+        # read_page/write_page are the device half of page mobility
+        # (PagedPoolModel.export_page/import_page on real pods); both
+        # run ONLY on the engine loop thread (_device_io), preserving
+        # the single-device-caller discipline.
+        self._role = str(role)
+        self._read_page = read_page
+        self._write_page = write_page
+        self._handoff = handoff
+        self._page_io: deque = deque()
+        self._spliced: dict = {}    # rid -> parked row (pre-cutover)
+        self._migrated: dict = {}   # rid -> spliced row (collectable)
+        self._migrated_in = 0
+        self._migrated_out = 0
         # the timeline's sinks: ``annotate(name)`` opens a host span
         # on the profiler's clock (jax.profiler.TraceAnnotation in the
         # workers: a flag test outside a profiler session), ``tracer``
@@ -331,7 +423,7 @@ class SlotEngine:
                 )
         group = _Group([])
         group.rows = [
-            self._row_cls(
+            _Row(
                 [int(t) for t in row], max_new_tokens, float(temperature),
                 eos_id,
                 int.from_bytes(os.urandom(4), "little") % (2 ** 31),
@@ -398,19 +490,48 @@ class SlotEngine:
         self._thread.join(timeout=10)
 
     def _progress_locked(self, group: _Group) -> int:
-        """Monotone per-group progress measure for the timeout loop
-        (tokens produced; the paged engine adds prefilled positions —
-        a long prompt mid-chunked-prefill IS making progress)."""
-        return sum(len(r.out) for r in group.rows)
+        """Monotone per-group progress measure for the timeout loop:
+        tokens produced plus prompt positions prefilled (a long
+        prompt mid-chunked-prefill IS making progress and must not be
+        cut off as "stalled" just because no token landed yet)."""
+        return sum(len(r.out) + r.fill_pos for r in group.rows)
 
     def _timeout_reason_locked(self, group, admitted: bool):
         """(reason string, QueueTimeoutError kind) for a timed-out
         group — the 503 body and the split timeout counters."""
-        if not admitted:
-            return "request timed out waiting for a KV slot", "kv-slot"
-        return (
-            f"no decode progress in {self._queue_timeout_s}s", "stalled"
+        if admitted:
+            return (
+                f"no decode progress in {self._queue_timeout_s}s",
+                "stalled",
+            )
+        alloc = self._allocator
+        budget_reason = (
+            "request timed out waiting for the KV page budget "
+            f"({alloc.free_pages} pages free of "
+            f"{alloc.pages_total}, {alloc.reserved_pages} "
+            "reserved)",
+            "kv-page-budget",
         )
+        slot_reason = (
+            "request timed out waiting for a KV slot", "kv-slot"
+        )
+        own = next(
+            (r for r in group.rows if r.slot < 0), group.rows[0]
+        )
+        if not alloc.would_admit(own.tokens, own.n):
+            return budget_reason
+        if not self._free:
+            return slot_reason
+        # our own rows fit and decode rows are free, so the
+        # starvation came from strict FIFO behind a blocked HEAD
+        # (our rows left the queue before this ran): classify by
+        # what blocks the head — a small request stuck behind a
+        # big budget-blocked one is memory saturation too
+        head = self._queue[0] if self._queue else None
+        if head is not None and not alloc.would_admit(
+                head.tokens, head.n):
+            return budget_reason
+        return slot_reason
 
     # -- telemetry ---------------------------------------------------
 
@@ -435,8 +556,13 @@ class SlotEngine:
                 max(0.0, now - self._last_tick_mono)
                 if self._has_work_locked() else 0.0
             )
-            positions = self._live_positions_locked()
-            live_tokens = sum(self._entries_for(n) for n in positions)
+            # positions behind each live row, decoding or prefilling
+            positions = [
+                int(self._pos[s])
+                for s, row in enumerate(self._rows) if row is not None
+            ] + [r.fill_pos for r in self._prefilling]
+            live_tokens = sum(self._layout.entries(n) for n in positions)
+            alloc = self._allocator
             window = [n for (t, n) in self._rate
                       if t > now - _RATE_WINDOW_S]
             ttft = sorted(self._ttft)
@@ -452,8 +578,17 @@ class SlotEngine:
                 # they stand for; equal where every token is kept
                 "kv_live_tokens": live_tokens,
                 "context_live_tokens": sum(positions),
+                # PHYSICAL occupancy: shared prefix pages count
+                # once, not once per pinning row — under heavy
+                # sharing the virtual sum can exceed the arena and
+                # would falsely breach kv_occupancy_slo while
+                # headroom exists.  Occupied = pages neither free nor
+                # reclaimable-by-admission.
                 "kv_occupancy": round(
-                    live_tokens / float(self._kv_capacity()), 4
+                    (alloc.pages_total - alloc.free_pages
+                     - alloc.reclaimable_pages)
+                    / float(alloc.pages_total),
+                    4,
                 ),
                 "tokens_per_s": round(
                     sum(window) / _RATE_WINDOW_S, 2
@@ -461,8 +596,8 @@ class SlotEngine:
                 "requests_admitted": self._admitted,
                 "requests_completed": self._completed,
                 "requests_timed_out": self._timeouts,
-                # the saturation split (utils/microbatch.py kinds):
-                # memory = the paged arena's page budget never fit;
+                # the saturation split (QueueTimeoutError kinds):
+                # memory = the arena's page budget never fit;
                 # compute = no decode row freed / admitted but stalled
                 "requests_timed_out_memory": kinds.get(
                     "kv-page-budget", 0
@@ -471,11 +606,30 @@ class SlotEngine:
                     kinds.get("kv-slot", 0) + kinds.get("stalled", 0)
                 ),
                 "tokens_out": self._tokens_out,
+                "stats_age_s": round(stats_age, 4),
+                "loop": self._loop_stats_locked(),
+                **alloc.stats(),
+                "kv_page_tokens": self._page_tokens,
+                "prefill_chunk_tokens": self._chunk_tokens,
+                # prompt tokens not yet prefilled (queued +
+                # mid-chunk): the chunked-prefill pressure signal —
+                # sustained growth means prefill demand outruns the
+                # chunk-per-tick budget
+                "prefill_chunk_backlog": int(
+                    sum(len(r.tokens) - r.fill_pos
+                        for r in self._prefilling)
+                    + sum(len(r.tokens) for r in self._queue)
+                ),
+                # migration surfaces (ISSUE 16): the pod's serving
+                # posture — the router's role-aware placement and the
+                # role-aware health gating (health/detectors.py) key
+                # on serving_role — and the protocol's traffic
+                # counters for /v1/debug/serving
+                "serving_role": self._role,
+                "migrations_in": self._migrated_in,
+                "migrations_out": self._migrated_out,
+                **self._extra_stats,
             }
-            out["stats_age_s"] = round(stats_age, 4)
-            out["loop"] = self._loop_stats_locked()
-            out.update(self._stats_extra_locked())
-            out.update(self._extra_stats)
         if ttft:
             from dcos_commons_tpu.metrics.registry import percentile
 
@@ -483,24 +637,6 @@ class SlotEngine:
             out["ttft_p95_s"] = round(percentile(ttft, 95), 4)
         out["t"] = time.time()
         return out
-
-    def _live_positions_locked(self) -> List[int]:
-        """Positions behind each live row."""
-        return [
-            int(self._pos[s])
-            for s, row in enumerate(self._rows) if row is not None
-        ]
-
-    def _entries_for(self, positions: int) -> int:
-        """Cache entries a row with ``positions`` behind it reads."""
-        return positions
-
-    def _kv_capacity(self) -> int:
-        """KV positions the cache can hold (the occupancy basis)."""
-        return self._slots * self._max_len
-
-    def _stats_extra_locked(self) -> dict:
-        return {}
 
     def _loop_stats_locked(self) -> dict:
         """The timeline's counters, all cumulative since the engine
@@ -540,7 +676,6 @@ class SlotEngine:
         while True:
             idle = False
             flush_now = False
-            admits: List[_Row] = []
             with self._cv:
                 self._last_tick_mono = time.monotonic()
                 while not self._has_work_locked() and not self._stopped:
@@ -569,7 +704,7 @@ class SlotEngine:
                 idle = not self._has_work_locked()
                 if not idle:
                     flushed_idle = False  # work resumed: re-arm
-                    admits = self._pop_admits_locked()
+                    self._admit_locked()
             if flush_now:
                 self._write_stats(force=True)
                 continue
@@ -581,7 +716,7 @@ class SlotEngine:
                 self._tick_rows = 0
                 chunks_before = self._prefill_calls
                 with self._tracer.span("engine.tick", track="loop") as tick:
-                    self._work_tick(admits)
+                    self._work_tick()
                     tick.set_attr("rows", self._tick_rows)
                     tick.set_attr(
                         "chunks", self._prefill_calls - chunks_before
@@ -597,27 +732,46 @@ class SlotEngine:
                     self._fail_all_locked(e)
 
     def _has_work_locked(self) -> bool:
-        return bool(self._queue) or self._active > 0
+        return bool(
+            self._queue or self._active or self._prefilling
+            or self._page_io
+        )
 
-    def _work_tick(self, admits: List[_Row]) -> None:
-        """One scheduling round (loop thread, OUTSIDE the cv): admit,
-        then advance every active row one decode step."""
-        self._admit_all(admits)
+    def _work_tick(self) -> None:
+        """One scheduling round (loop thread, OUTSIDE the cv): page
+        IO, one chunk for every prefilling row, then one decode step
+        for every active row."""
+        self._run_page_io()
+        self._prefill_tick()
         if self._active:  # loop thread is the only writer
             self._decode_tick()
 
-    def _pop_admits_locked(self) -> List[_Row]:
-        """FIFO admission: oldest waiting rows take the free slots —
-        a row can never starve behind later arrivals."""
-        admits: List[_Row] = []
+    def _admit_locked(self) -> None:
+        """FIFO admission under BOTH constraints — a free decode row
+        and the page budget — into the prefilling set.  Strictly in
+        order: the first request that does not fit blocks the queue
+        (admitting a smaller later one would starve large requests
+        forever)."""
         while self._queue and self._free:
-            row = self._queue.popleft()
+            row = self._queue[0]
             if row.group.abandoned:
+                self._queue.popleft()
                 continue
+            admission = self._allocator.admit(row.tokens, row.n)
+            if admission is None:
+                break
+            self._queue.popleft()
             row.slot = self._free.pop()
+            row.admission = admission
+            row.table = np.zeros(self._pages_per_row, np.int32)
+            for i, entry in enumerate(admission.matched):
+                row.table[self._layout.share_slot(i)] = entry.page
+            # prefill resumes past the cache-served pages
+            row.cached = len(admission.matched)
+            row.fill_pos = row.cached * self._layout.share_tokens
+            row.registered_to = row.cached
             self._admitted_locked(row)
-            admits.append(row)
-        return admits
+            self._prefilling.append(row)
 
     def _admitted_locked(self, row: _Row) -> None:
         """The row holds its slot (and its page budget) from now on:
@@ -628,37 +782,6 @@ class SlotEngine:
             trace_id=row.trace_id, parent_id=row.parent_id, track="req",
             rid=row.rid,
         )
-
-    def _admit_all(self, admits: List[_Row]) -> None:
-        for i, row in enumerate(admits):
-            padded = np.zeros((1, self._prompt_len), np.int32)
-            padded[0, : len(row.tokens)] = row.tokens
-            try:
-                with self._phase("prefill_call"):
-                    self._prefill_calls += 1
-                    row.chunks += 1
-                    first = int(self._prefill_fn(
-                        padded, slot=row.slot, true_len=len(row.tokens),
-                        temp=row.temp, seed=row.seed,
-                    ))
-            except Exception as e:  # noqa: BLE001 — fan out, keep serving
-                with self._cv:
-                    # the popped-but-not-installed rows (this one and
-                    # the rest of the batch) are invisible to both the
-                    # queue and the active set: return their slots and
-                    # fail their groups explicitly, or each failure
-                    # would leak a slot and leave its client waiting
-                    # out the full timeout for a model error
-                    for r in admits[i:]:
-                        self._free.append(r.slot)
-                        r.slot = -1
-                    self._fail_all_locked(
-                        e, extra_groups={r.group for r in admits[i:]}
-                    )
-                return
-            now = time.monotonic()
-            with self._cv:
-                self._apply_admit_locked(row, first, now)
 
     def _first_token_locked(self, row: _Row, first: int, now: float):
         """Admission and TTFT are counted where the first token
@@ -697,17 +820,31 @@ class SlotEngine:
         self._temps[slot] = row.temp
         self._seeds[slot] = row.seed
 
-    _MERGE_NOUN = "slot pool"
-
-    def _decode_prep_locked(self) -> tuple:
-        """Extra positional args for ``decode_fn`` (before
-        ``n_active``), prepared under the cv — the paged engine
-        allocates write pages and snapshots the page tables here."""
-        return ()
+    def _decode_prep_locked(self) -> np.ndarray:
+        """Allocate this tick's write pages and snapshot every row's
+        page table for the decode dispatch."""
+        for slot, row in enumerate(self._rows):
+            if row is None or row.group.abandoned or row.frozen:
+                # an abandoned row retires at apply; its write this
+                # tick lands in the trash page (table may miss the
+                # next page — masked, discarded).  A FROZEN row gets
+                # a zero table below: its pages must stop changing
+                # the moment the migration fence drops
+                continue
+            pos = int(self._pos[slot])
+            self._ensure_pages_locked(row, pos, pos)
+            self._count_layout_events(pos, pos)
+        tables = np.zeros(
+            (self._slots, self._pages_per_row), np.int32
+        )
+        for slot, row in enumerate(self._rows):
+            if row is not None and not row.frozen:
+                tables[slot] = row.table
+        return tables
 
     def _decode_tick(self) -> None:
         with self._phase("decode_prep"), self._cv:
-            extra = self._decode_prep_locked()
+            tables = self._decode_prep_locked()
             active = self._active
             # who this tick actually computes for: a row installed
             # into a slot AFTER this point (a splice activation or a
@@ -723,7 +860,7 @@ class SlotEngine:
             for slot, row in enumerate(dispatched):
                 if row is not None:
                     self._decode_rows_sum += 1
-                    self._decode_entries_sum += self._entries_for(
+                    self._decode_entries_sum += self._layout.entries(
                         int(self._pos[slot])
                     )
         try:
@@ -733,7 +870,7 @@ class SlotEngine:
                 nxt = np.asarray(self._decode_fn(
                     self._tok.copy(), self._pos.copy(),
                     self._temps.copy(), self._seeds.copy(),
-                    *extra, active,
+                    tables, active,
                 ))
         except Exception as e:  # noqa: BLE001 — fan out, keep serving
             with self._cv:
@@ -751,7 +888,7 @@ class SlotEngine:
         if merged is not None and self._log is not None:
             self._log(
                 f"continuous-batch: {merged} rows sharing one decode "
-                f"step over the {self._MERGE_NOUN}"
+                "step over the paged arena"
             )
 
     def _apply_decode_locked(self, nxt: np.ndarray, now: float,
@@ -841,33 +978,46 @@ class SlotEngine:
             self._temps[slot] = 0.0
             self._seeds[slot] = 0
         self._free.append(slot)
+        if row.admission is not None:
+            self._allocator.retire(row.admission, row.private_pages)
+            row.admission = None
+            row.private_pages = []
+            row.table = None
         group = row.group
         group.remaining -= 1
         if group.remaining <= 0 and not group.abandoned:
             self._completed += 1
             group.done.set()
 
-    def _fail_all_locked(
-        self, error: BaseException, extra_groups=(),
-    ) -> None:
-        """A model-call failure fans out to every waiting and active
-        request (the MicroBatcher contract) and clears the pool.
-        ``extra_groups``: groups of rows in admission limbo (popped
-        from the queue, not yet installed in the pool) — the caller
-        has already returned their slots."""
-        groups = {r.group for r in self._queue}
-        groups |= {r.group for r in self._rows if r is not None}
-        groups |= set(extra_groups)
+    def _fail_all_locked(self, error: BaseException) -> None:
+        """A model-call failure fans out to every waiting, prefilling,
+        parked and active request and clears the pool: every row
+        returns its slot, and the arena's bookkeeping is rebuilt."""
+        held = (
+            [r for r in self._rows if r is not None]
+            + list(self._prefilling)
+            # parked spliced rows die with everything else: their
+            # groups error out so a blocked collect() unblocks
+            + list(self._spliced.values())
+        )
+        groups = {r.group for r in self._queue} | {r.group for r in held}
+        for row in held:
+            self._free.append(row.slot)
+            row.slot = -1
+            row.admission = None
         self._queue.clear()
-        for slot, row in enumerate(self._rows):
-            if row is not None:
-                self._rows[slot] = None
-                self._active -= 1
-                self._free.append(slot)
+        self._prefilling.clear()
+        self._spliced.clear()
+        self._rows[:] = [None] * self._slots
+        self._active = 0
         self._tok[:] = 0
         self._pos[:] = 0
         self._temps[:] = 0.0
         self._seeds[:] = 0
+        # every admission died with its group: rebuild the arena
+        # bookkeeping (the prefix cache's pages may hold K/V written
+        # before the failure — integrity unknown, so drop them too)
+        self._allocator.reset()
         for group in groups:
             group.error = error
             group.done.set()
@@ -905,182 +1055,6 @@ class SlotEngine:
         except OSError:
             pass  # sdklint: disable=swallowed-exception — telemetry must never take the server down
 
-
-class _PagedRow(_Row):
-    """A request riding a PAGE TABLE instead of a contiguous slot row
-    (serve/paging.py): ``table[v]`` is the physical arena page holding
-    virtual positions ``[v*P, (v+1)*P)``; 0 = unallocated."""
-
-    __slots__ = (
-        "table", "fill_pos", "admission", "private_pages",
-        "registered_to",
-    )
-
-    def __init__(self, tokens, n, temp, eos, seed, group):
-        super().__init__(tokens, n, temp, eos, seed, group)
-        self.table = None            # np.int32 [M], built at admission
-        self.fill_pos = 0            # next prompt position to prefill
-        self.admission = None        # paging.Admission while admitted
-        self.private_pages: List[int] = []
-        self.registered_to = 0       # next prompt page to publish
-
-
-class PagedEngine(SlotEngine):
-    """Continuous batching over a PAGED KV arena: block-granular
-    allocation, chunked prefill, and prefix caching (the ISSUE 11
-    tentpole; vLLM's PagedAttention + SGLang's RadixAttention shape).
-
-    Differences from the slot pool it replaces:
-
-    * **Admission is page-budgeted** (serve/paging.py): a request
-      enters the pool only when a free decode row exists AND its
-      worst-case page need fits ``available - reserved`` — admitted
-      work can never OOM mid-generation, and a short reply returns
-      its unused pages immediately instead of stranding a MAX_LEN
-      row.  FIFO stays strict: a budget-blocked head is never jumped
-      by a smaller later request.
-    * **Prefill is chunked**: prompts run ``chunk_tokens`` at a time
-      — one chunk per PREFILLING REQUEST per engine tick, interleaved
-      with decode — so a long prompt no longer blocks the tick it
-      rides and queued requests stop paying head-of-line TTFT.
-      Chunk progress counts as progress for the 503 timeout (a long
-      prefill is not a stall).
-    * **Prefix caching**: fully-prefilled prompt pages are published
-      read-only; an identical later prefix pins them instead of
-      recomputing (COW-by-recompute on mid-page divergence — shared
-      pages are never written; see serve/paging.py).
-
-    ``prefill_chunk_fn(padded [1, C] i32, slot=, table= [M] i32,
-    start=, true_len=, temp=, seed=) -> first token`` runs one chunk
-    (the return value is consumed only when the chunk completes the
-    prompt); ``decode_fn(tok [S], pos [S], temps [S], seeds [S],
-    tables [S, M] i32, n_active) -> next tokens [S]`` advances every
-    row through its page table.  Scalars by KEYWORD, as ever.
-    """
-
-    _row_cls = _PagedRow
-
-    def __init__(
-        self,
-        prefill_chunk_fn: Callable,
-        decode_fn: Callable,
-        slots: int,
-        max_len: int,
-        prompt_len: int,
-        *,
-        page_tokens: int,
-        pages: int,
-        chunk_tokens: int,
-        prefix_cache: bool = True,
-        layout=None,
-        role: str = "unified",
-        read_page: Optional[Callable] = None,
-        write_page: Optional[Callable] = None,
-        handoff: Optional[Callable] = None,
-    **kw,
-    ):
-        from dcos_commons_tpu.serve.paging import (
-            PageAllocator,
-            RowLayout,
-        )
-
-        # subclass state FIRST: the base constructor starts the loop
-        # thread as its last act, and the loop reads these
-        self._page_tokens = int(page_tokens)
-        # what a table entry stands for (serve/paging.py RowLayout):
-        # the one thing here that differs between attention classes
-        self._layout = (
-            layout if layout is not None else RowLayout(int(page_tokens))
-        )
-        if self._layout.page_tokens != self._page_tokens:
-            raise ValueError(
-                f"row layout has {self._layout.page_tokens}-token pages, "
-                f"the arena {self._page_tokens}"
-            )
-        self._pages_per_row = self._layout.table_len(int(max_len))
-        self._chunk_tokens = int(chunk_tokens)
-        if self._layout.window and (
-            self._chunk_tokens % self._layout.chunk
-            or self._chunk_tokens > self._layout.window
-        ):
-            raise ValueError(
-                f"prefill chunks of {self._chunk_tokens} are not whole "
-                f"{self._layout.chunk}-position chunks within one "
-                f"window of {self._layout.window}"
-            )
-        self._allocator = PageAllocator(
-            int(pages), int(page_tokens), prefix_cache,
-            layout=self._layout,
-        )
-        self._prefilling: deque = deque()
-        # migration state (serve/migration.py, ISSUE 16).  role is
-        # the pod's advertised serving posture (unified / prefill /
-        # decode) — telemetry and routing read it; the HANDOFF hook's
-        # presence is what actually diverts finished prefills.
-        # read_page/write_page are the device half of page mobility
-        # (PagedPoolModel.export_page/import_page on real pods); both
-        # run ONLY on the engine loop thread (_device_io), preserving
-        # the single-device-caller discipline.
-        self._role = str(role)
-        self._read_page = read_page
-        self._write_page = write_page
-        self._handoff = handoff
-        self._page_io: deque = deque()
-        self._spliced: dict = {}    # rid -> parked row (pre-cutover)
-        self._migrated: dict = {}   # rid -> spliced row (collectable)
-        self._migrated_in = 0
-        self._migrated_out = 0
-        super().__init__(
-            prefill_chunk_fn, decode_fn, slots, max_len, prompt_len,
-            **kw,
-        )
-
-    # -- admission ---------------------------------------------------
-
-    def _has_work_locked(self) -> bool:
-        return (
-            super()._has_work_locked()
-            or bool(self._prefilling)
-            or bool(self._page_io)
-        )
-
-    def _pop_admits_locked(self) -> List[_Row]:
-        """FIFO admission under BOTH constraints — a free decode row
-        and the page budget.  Strictly in order: the first request
-        that does not fit blocks the queue (admitting a smaller later
-        one would starve large requests forever)."""
-        admits: List[_Row] = []
-        while self._queue and self._free:
-            row = self._queue[0]
-            if row.group.abandoned:
-                self._queue.popleft()
-                continue
-            admission = self._allocator.admit(row.tokens, row.n)
-            if admission is None:
-                break
-            self._queue.popleft()
-            row.slot = self._free.pop()
-            row.admission = admission
-            row.table = np.zeros(self._pages_per_row, np.int32)
-            for i, entry in enumerate(admission.matched):
-                row.table[self._layout.share_slot(i)] = entry.page
-            # prefill resumes past the cache-served pages
-            row.cached = len(admission.matched)
-            row.fill_pos = row.cached * self._layout.share_tokens
-            row.registered_to = row.cached
-            self._admitted_locked(row)
-            admits.append(row)
-        return admits
-
-    def _work_tick(self, admits: List[_Row]) -> None:
-        self._run_page_io()
-        if admits:
-            with self._cv:
-                self._prefilling.extend(admits)
-        self._prefill_tick()
-        if self._active:
-            self._decode_tick()
-
     def _run_page_io(self) -> None:
         """Drain queued migration page reads/writes (loop thread,
         outside the cv — these are device calls like any dispatch)."""
@@ -1102,9 +1076,8 @@ class PagedEngine(SlotEngine):
         BURST of short prompts still admits in one tick (each is one
         cheap chunk; serializing them across decode ticks would tax
         every short request one full decode per queue position).
-        Per-tick prefill work stays bounded by the slot count — the
-        same bound the slot pool's admit-all batch had, at chunk
-        width instead of full prompt width."""
+        Per-tick prefill work stays bounded by the slot count: one
+        chunk-wide call a row."""
         with self._cv:
             rows = list(self._prefilling)
         for row in rows:
@@ -1230,32 +1203,6 @@ class PagedEngine(SlotEngine):
             if self._allocator.register(row.admission, toks, page):
                 row.private_pages.remove(page)
             row.registered_to += 1
-
-    # -- decode ------------------------------------------------------
-
-    _MERGE_NOUN = "paged arena"
-
-    def _decode_prep_locked(self) -> tuple:
-        """Allocate this tick's write pages and snapshot every row's
-        page table for the decode dispatch."""
-        for slot, row in enumerate(self._rows):
-            if row is None or row.group.abandoned or row.frozen:
-                # an abandoned row retires at apply; its write this
-                # tick lands in the trash page (table may miss the
-                # next page — masked, discarded).  A FROZEN row gets
-                # a zero table below: its pages must stop changing
-                # the moment the migration fence drops
-                continue
-            pos = int(self._pos[slot])
-            self._ensure_pages_locked(row, pos, pos)
-            self._count_layout_events(pos, pos)
-        tables = np.zeros(
-            (self._slots, self._pages_per_row), np.int32
-        )
-        for slot, row in enumerate(self._rows):
-            if row is not None and not row.frozen:
-                tables[slot] = row.table
-        return (tables,)
 
     # -- migration (serve/migration.py, ISSUE 16) --------------------
 
@@ -1471,7 +1418,7 @@ class PagedEngine(SlotEngine):
                     f"snapshot is missing pages {missing}"
                 )
             group = _Group([])
-            row = self._row_cls(
+            row = _Row(
                 list(snap.tokens), snap.max_new, snap.temperature,
                 snap.eos, snap.seed, group,
             )
@@ -1622,124 +1569,6 @@ class PagedEngine(SlotEngine):
         if row.group.error is not None:
             raise row.group.error
         return list(row.out)
-
-    # -- retirement / failure ----------------------------------------
-
-    def _retire_locked(self, row) -> None:
-        super()._retire_locked(row)
-        if row.admission is not None:
-            self._allocator.retire(row.admission, row.private_pages)
-            row.admission = None
-            row.private_pages = []
-            row.table = None
-
-    def _fail_all_locked(self, error, extra_groups=()) -> None:
-        extra = set(extra_groups)
-        extra |= {r.group for r in self._prefilling}
-        for row in self._prefilling:
-            self._free.append(row.slot)
-            row.slot = -1
-        self._prefilling.clear()
-        # parked spliced rows die with everything else: their groups
-        # error out so a blocked collect() unblocks, and their slots
-        # return (allocator.reset() below reclaims the pages)
-        extra |= {r.group for r in self._spliced.values()}
-        for row in self._spliced.values():
-            self._free.append(row.slot)
-            row.slot = -1
-            row.admission = None
-        self._spliced.clear()
-        super()._fail_all_locked(error, extra_groups=extra)
-        # every admission died with its group: rebuild the arena
-        # bookkeeping (the prefix cache's pages may hold K/V written
-        # before the failure — integrity unknown, so drop them too)
-        self._allocator.reset()
-
-    # -- timeout basis / telemetry -----------------------------------
-
-    def _progress_locked(self, group) -> int:
-        # chunk progress counts: a long prompt mid-prefill must not
-        # be cut off as "stalled" just because no token landed yet
-        return super()._progress_locked(group) + sum(
-            r.fill_pos for r in group.rows
-        )
-
-    def _timeout_reason_locked(self, group, admitted: bool):
-        if not admitted:
-            alloc = self._allocator
-            budget_reason = (
-                "request timed out waiting for the KV page budget "
-                f"({alloc.free_pages} pages free of "
-                f"{alloc.pages_total}, {alloc.reserved_pages} "
-                "reserved)",
-                "kv-page-budget",
-            )
-            own = next(
-                (r for r in group.rows if r.slot < 0), group.rows[0]
-            )
-            if not alloc.would_admit(own.tokens, own.n):
-                return budget_reason
-            if not self._free:
-                return (
-                    "request timed out waiting for a KV slot",
-                    "kv-slot",
-                )
-            # our own rows fit and decode rows are free, so the
-            # starvation came from strict FIFO behind a blocked HEAD
-            # (our rows left the queue before this ran): classify by
-            # what blocks the head — a small request stuck behind a
-            # big budget-blocked one is memory saturation too
-            head = self._queue[0] if self._queue else None
-            if head is not None and not alloc.would_admit(
-                    head.tokens, head.n):
-                return budget_reason
-            return (
-                "request timed out waiting for a KV slot", "kv-slot"
-            )
-        return super()._timeout_reason_locked(group, admitted)
-
-    def _live_positions_locked(self) -> List[int]:
-        return super()._live_positions_locked() + [
-            r.fill_pos for r in self._prefilling
-        ]
-
-    def _entries_for(self, positions: int) -> int:
-        return self._layout.entries(positions)
-
-    def _kv_capacity(self) -> int:
-        return self._allocator.pages_total * self._page_tokens
-
-    def _stats_extra_locked(self) -> dict:
-        out = self._allocator.stats()
-        # PHYSICAL occupancy (overrides the base virtual-positions
-        # gauge): shared prefix pages count once, not once per
-        # pinning row — under heavy sharing the virtual sum can
-        # exceed the arena and would falsely breach kv_occupancy_slo
-        # while headroom exists.  Occupied = pages neither free nor
-        # reclaimable-by-admission.
-        alloc = self._allocator
-        out["kv_occupancy"] = round(
-            (alloc.pages_total - alloc.free_pages
-             - alloc.reclaimable_pages) / float(alloc.pages_total),
-            4,
-        )
-        out["kv_page_tokens"] = self._page_tokens
-        out["prefill_chunk_tokens"] = self._chunk_tokens
-        # prompt tokens not yet prefilled (queued + mid-chunk): the
-        # chunked-prefill pressure signal — sustained growth means
-        # prefill demand outruns the chunk-per-tick budget
-        out["prefill_chunk_backlog"] = int(
-            sum(len(r.tokens) - r.fill_pos for r in self._prefilling)
-            + sum(len(r.tokens) for r in self._queue)
-        )
-        # migration surfaces (ISSUE 16): the pod's serving posture —
-        # the router's role-aware placement and the role-aware health
-        # gating (health/detectors.py) key on serving_role — and the
-        # protocol's traffic counters for /v1/debug/serving
-        out["serving_role"] = self._role
-        out["migrations_in"] = self._migrated_in
-        out["migrations_out"] = self._migrated_out
-        return out
 
 
 def read_servestats(path: str) -> dict:

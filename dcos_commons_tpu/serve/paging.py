@@ -1,14 +1,13 @@
 """Paged KV memory for the serving engine: page allocator, admission
 budget, and the refcounted prefix cache.
 
-The slot pool (ISSUE 6) carved KV memory as SLOTS x MAX_LEN rows: a
-10-token reply stranded an entire MAX_LEN row.  This module is the
-host-side half of the replacement — KV memory becomes a fixed arena
-of ``page_tokens``-sized pages and each request holds a PAGE TABLE
-(virtual position ``p`` lives in physical page ``table[p //
-page_tokens]``), so a short request holds exactly the pages its
-tokens need and the freed remainder admits more concurrent requests
-under the SAME HBM budget (vLLM's PagedAttention shape).
+KV memory is a fixed arena of ``page_tokens``-sized pages and each
+request holds a PAGE TABLE (virtual position ``p`` lives in physical
+page ``table[p // page_tokens]``), so a short request holds exactly
+the pages its tokens need — a 10-token reply does not strand a
+MAX_LEN row — and the freed remainder admits more concurrent requests
+under the SAME HBM budget (vLLM's PagedAttention shape).  This module
+is the host-side half.
 
 Everything here is jax-free bookkeeping driven by the engine's loop
 thread; the device half (arena tensors, gather-attention) lives in
@@ -258,16 +257,19 @@ class PagedServeConfig:
         return self.pages + 1
 
 
-def paged_config_from_env(env) -> Optional[PagedServeConfig]:
-    """Derive the paged-serving geometry from a task env; ``None``
-    when ``KV_PAGE_TOKENS=0`` selects the legacy slot pool.  Raises
+def paged_config_from_env(env) -> PagedServeConfig:
+    """Derive the paged-serving geometry from a task env.  Raises
     ``SpecError`` for a geometry that cannot serve (so admission and
     CI reject the spec and a worker fails deploy loudly)."""
     from dcos_commons_tpu.specification.specs import SpecError
 
     page_tokens = int(env.get("KV_PAGE_TOKENS") or "16")
-    if page_tokens <= 0:
-        return None
+    if page_tokens < 1:
+        raise SpecError(
+            f"KV_PAGE_TOKENS must be >= 1, got {page_tokens}: the slot "
+            "pool that 0 selected is gone, serving.kv_page_tokens is "
+            "only the arena's page size"
+        )
     max_len = int(env.get("MAX_LEN", "256"))
     # unset SERVE_BATCH means a bare/dev launch; fall back to one
     # slot rather than the deploy default 8 (see options.json
@@ -276,7 +278,7 @@ def paged_config_from_env(env) -> Optional[PagedServeConfig]:
     batch = int(env.get("SERVE_BATCH", "1"))
     slots = int(env.get("SERVE_SLOTS") or 0) or batch
     # default budget = full residency for every row (NO overcommit:
-    # byte-identical to the slot pool it replaces); operators lower
+    # SERVE_SLOTS rows of MAX_LEN positions); operators lower
     # KV_PAGES below slots x pages_per_row to overcommit on the mean
     # request, or raise SERVE_SLOTS at fixed KV_PAGES for free
     # concurrency on short traffic

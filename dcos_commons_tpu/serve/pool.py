@@ -1,40 +1,40 @@
 """Device half of the continuous-batching engine: the jitted
-prefill-into-slot / decode-step pair over a persistent slot-pool KV
-cache (models/decode.py).
+prefill-chunk / decode-step pair over a persistent page arena
+(models/decode.py).
 
-TWO compiles cover the server's whole life: ``prefill`` admits one
-right-padded prompt (traced true_len/slot/temperature/seed — no
-recompile per request) into a pool row, ``decode`` advances EVERY
-row one step with per-row positions, temperatures and PRNG seeds (a
-mixed greedy/sampling pool shares one dispatch).  The cache is
-allocated ONCE at ``slots x max_len`` with static shapes and threaded
-through both functions; on non-CPU backends the cache argument is
-DONATED so XLA updates it in place instead of holding two pool-sized
-buffers live across the call.
+TWO compiles cover the server's whole life: ``prefill_chunk`` runs
+one right-padded prompt chunk through a request's page table (traced
+start/true_len/temperature/seed — no recompile per request),
+``decode`` advances EVERY row one step with per-row positions,
+temperatures, PRNG seeds and page tables (a mixed greedy/sampling
+pool shares one dispatch).  The arena is allocated ONCE with static
+shapes and threaded through both functions; on non-CPU backends the
+cache argument is DONATED so XLA updates it in place instead of
+holding two arena-sized buffers live across the call.
 
 Per-row sampling keys: each request carries its own 31-bit seed and
 every step folds the row's current position into it
 (``fold_in(key(seed), pos)``) — rows never share randomness, a row's
 stream does not depend on which slot it landed in or who its pool
 neighbors are, and no key is ever reused across steps (the prefill
-pick folds ``true_len - 1``, the first decode folds ``true_len``).
+pick folds ``prompt_len - 1``, the first decode folds ``prompt_len``).
 Greedy rows (temperature 0) ignore the keys entirely and argmax —
 token-identical to whole-batch ``generate`` on the same prompts
-(tests/test_continuous_batching.py holds the equivalence under
-arbitrary admission orders).
+(tests/test_paged_kv.py holds the equivalence under arbitrary
+admission orders).
 
 Both entry points close a host span on the profiler's clock
-(``pool.prefill`` / ``pool.prefill_chunk`` / ``pool.decode``) with an
-inner ``.fetch`` around the one blocking ``device_get``: in a
-profile the part of a call before its fetch is the host dispatching
-the program, the fetch is the host waiting for the device.  Outside a
-profiler session a ``TraceAnnotation`` is a flag test.
+(``pool.prefill_chunk`` / ``pool.decode``) with an inner ``.fetch``
+around the one blocking ``device_get``: in a profile the part of a
+call before its fetch is the host dispatching the program, the fetch
+is the host waiting for the device.  Outside a profiler session a
+``TraceAnnotation`` is a flag test.
 
-The gang driver reuses this class unchanged: ``put`` lifts host
+The gang driver reuses the class unchanged: ``put`` lifts host
 arrays to global (broadcast_one_to_all hands every rank identical
 numpy), ``constrain_out`` pins token outputs replicated so rank 0
-can bulk-fetch them, and ``cache_sharding`` lays the pool's KV heads
-over the tp axis when divisible.
+can bulk-fetch them, and ``cache_sharding`` lays the arena's KV heads
+(dim 3) over the tp axis when divisible.
 """
 
 from __future__ import annotations
@@ -46,161 +46,24 @@ from typing import Any, Callable, Optional
 import numpy as np
 
 
-class PoolModel:
-    """Owns the slot-pool cache and the two compiled entry points.
+class PagedPoolModel:
+    """Owns the page arena and the two compiled entry points
+    (models/decode.py ``init_paged_kv_cache`` / ``paged_prefill_chunk``
+    / ``paged_decode_step``).
+
+    ONE prefill-chunk program (chunk width ``chunk_tokens`` static;
+    start position, true length, page table, temperature and seed all
+    traced — a request resuming after a prefix-cache hit is the same
+    program as one starting cold) and ONE decode program (per-row
+    positions/temps/seeds/page tables traced) cover every request the
+    server ever admits.  The arena holds ``pages`` usable pages plus
+    the TRASH page (physical page 0): padding and inactive-row writes
+    land there, so ``warm()`` — which runs both programs over
+    all-zero tables — never dirties a real page.
 
     Not thread-safe by itself: exactly one thread (the engine loop, or
-    a gang rank's tick executor) may call ``prefill``/``decode`` —
-    both advance ``self.cache``.
-    """
-
-    def __init__(
-        self,
-        config,
-        params,
-        slots: int,
-        max_len: int,
-        kv_dtype: str = "native",
-        cache_sharding: Optional[Any] = None,
-        put: Optional[Callable] = None,
-        constrain_out: Optional[Callable] = None,
-    ):
-        import jax
-        import jax.numpy as jnp
-
-        from dcos_commons_tpu.models.decode import (
-            decode_step,
-            init_kv_cache,
-            prefill_into_slot,
-            sample_token,
-        )
-
-        self._jax = jax
-        self._span = jax.profiler.TraceAnnotation
-        self._np = np
-        self.config = config
-        self.params = params
-        self.slots = slots
-        self.max_len = max_len
-        self._put = put if put is not None else (lambda x: x)
-        con = constrain_out if constrain_out is not None else (lambda x: x)
-
-        init = functools.partial(
-            init_kv_cache, config, slots, max_len, kv_dtype
-        )
-        if cache_sharding is not None:
-            self.cache = jax.jit(init, out_shardings=cache_sharding)()
-        else:
-            self.cache = jax.jit(init)()
-
-        def _prefill(params, cache, tokens, slot, true_len, temp, seed):
-            logits, cache = prefill_into_slot(
-                config, params, cache, tokens, slot, true_len
-            )
-            with jax.named_scope("sample"):
-                key = jax.random.fold_in(
-                    jax.random.key(seed), true_len - 1
-                )
-                return con(sample_token(logits[0], temp, key)), cache
-
-        def _decode(params, cache, tok, pos, temps, seeds):
-            logits, cache = decode_step(config, params, cache, tok, pos)
-
-            def pick_row(lg, temp, seed, p):
-                key = jax.random.fold_in(jax.random.key(seed), p)
-                return sample_token(lg, temp, key)
-
-            with jax.named_scope("sample"):
-                nxt = jax.vmap(pick_row)(logits, temps, seeds, pos)
-            return con(nxt), cache
-
-        # donate the pool cache (argnums 1): decode streams it every
-        # step — holding input AND output pools live would double the
-        # dominant HBM term.  CPU has no donation; skip the warning.
-        donate = {}
-        if jax.default_backend() != "cpu":
-            donate = {"donate_argnums": (1,)}
-        self._prefill_c = jax.jit(_prefill, **donate)
-        self._decode_c = jax.jit(_decode, **donate)
-        self._jnp = jnp
-
-    def prefill(
-        self, tokens: np.ndarray, slot: int, true_len: int,
-        temp: float, seed: int,
-    ) -> int:
-        """Admit one right-padded [1, prompt_len] prompt into pool row
-        ``slot``; returns the first generated token."""
-        with self._span("pool.prefill"):
-            first, self.cache = self._prefill_c(
-                self.params, self.cache,
-                self._put(np.asarray(tokens, np.int32)),
-                np.int32(slot), np.int32(true_len),
-                np.float32(temp), np.int32(seed),
-            )
-            with self._span("pool.prefill.fetch"):
-                return int(self._jax.device_get(first))
-
-    def decode(
-        self, tok: np.ndarray, pos: np.ndarray,
-        temps: np.ndarray, seeds: np.ndarray,
-        n_active: Optional[int] = None,
-    ) -> np.ndarray:
-        """One decode step over the WHOLE pool; returns next tokens
-        [slots] (inactive rows' outputs are discarded by the engine).
-        ``n_active`` is the engine's bookkeeping rider (the gang
-        driver stamps it into the broadcast head); the computation
-        always covers every slot — static shapes.  ONE bulk device
-        fetch — per-element reads are a transfer each."""
-        with self._span("pool.decode"):
-            nxt, self.cache = self._decode_c(
-                self.params, self.cache,
-                self._put(np.asarray(tok, np.int32)),
-                self._put(np.asarray(pos, np.int32)),
-                self._put(np.asarray(temps, np.float32)),
-                self._put(np.asarray(seeds, np.int32)),
-            )
-            with self._span("pool.decode.fetch"):
-                return np.asarray(self._jax.device_get(nxt))
-
-    def warm(self, prompt_len: int) -> None:
-        """Compile + execute both entry points before readiness: the
-        first request must not pay the compile, and a rank that cannot
-        compile must fail deploy, not the first client."""
-        self.prefill(
-            np.zeros((1, prompt_len), np.int32),
-            slot=0, true_len=prompt_len, temp=0.0, seed=0,
-        )
-        out = self.decode(
-            np.zeros(self.slots, np.int32),
-            np.full(self.slots, prompt_len, np.int32),
-            np.zeros(self.slots, np.float32),
-            np.zeros(self.slots, np.int32),
-        )
-        self._jax.block_until_ready(out)
-
-
-class PagedPoolModel:
-    """Device half of the PAGED engine: the jitted prefill-chunk /
-    decode-step pair over a persistent page arena (models/decode.py
-    ``init_paged_kv_cache`` / ``paged_prefill_chunk`` /
-    ``paged_decode_step``).
-
-    The two-compiles-per-lifetime property carries over from the slot
-    pool: ONE prefill-chunk program (chunk width ``chunk_tokens``
-    static; start position, true length, page table, temperature and
-    seed all traced — a request resuming after a prefix-cache hit is
-    the same program as one starting cold) and ONE decode program
-    (per-row positions/temps/seeds/page tables traced) cover every
-    request the server ever admits.  The arena holds ``pages`` usable
-    pages plus the TRASH page (physical page 0): padding and
-    inactive-row writes land there, so ``warm()`` — which runs both
-    programs over all-zero tables — never dirties a real page.
-
-    Not thread-safe by itself (the engine loop or a gang rank's tick
-    executor is the single caller); the gang driver reuses it via the
-    same ``put``/``constrain_out``/``cache_sharding`` riders as
-    ``PoolModel`` — kv heads sit on dim 3 of the arena, exactly where
-    the slot pool carried the tp axis.
+    a gang rank's tick executor) may call ``prefill_chunk``/``decode``
+    — both advance ``self.cache``.
     """
 
     def __init__(
@@ -263,9 +126,9 @@ class PagedPoolModel:
             logits, cache = paged_prefill_chunk(
                 config, params, cache, tokens, table, start, true_len
             )
-            # the fold matches the slot pool's: the chunk's last real
-            # position is start + true_len - 1 == prompt_len - 1 on
-            # the final chunk — same key, same sampled token
+            # the chunk's last real position is start + true_len - 1
+            # == prompt_len - 1 on the final chunk: however a prompt
+            # was chunked, its first token is sampled under one key
             with jax.named_scope("sample"):
                 key = jax.random.fold_in(
                     jax.random.key(seed), start + true_len - 1
@@ -332,7 +195,12 @@ class PagedPoolModel:
         tables: np.ndarray, n_active: Optional[int] = None,
     ) -> np.ndarray:
         """One decode step over the whole pool through per-row page
-        tables; ONE bulk device fetch, same as the slot pool."""
+        tables; returns next tokens [slots] (inactive rows' outputs
+        are discarded by the engine).  ``n_active`` is the engine's
+        bookkeeping rider (the gang driver stamps it into the
+        broadcast head); the computation always covers every slot —
+        static shapes.  ONE bulk device fetch — per-element reads are
+        a transfer each."""
         with self._span("pool.decode"):
             nxt, self.cache = self._decode_c(
                 self.params, self.cache,

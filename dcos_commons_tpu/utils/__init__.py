@@ -11,23 +11,13 @@ from dcos_commons_tpu.utils.checkpoint import (
 )
 from dcos_commons_tpu.utils.compile_cache import enable_compilation_cache
 from dcos_commons_tpu.utils.devices import claim_devices
-from dcos_commons_tpu.utils.microbatch import (
-    MicroBatcher,
-    WorkItem,
-    pack_mixed_rows,
-    unpack_results,
-)
 
 __all__ = [
     "AsyncCheckpointer",
-    "MicroBatcher",
     "StaleWriterError",
-    "WorkItem",
     "claim_devices",
     "claim_incarnation",
     "enable_compilation_cache",
-    "pack_mixed_rows",
-    "unpack_results",
     "param_bytes",
     "param_count",
     "restore_checkpoint",

@@ -3,17 +3,17 @@ jax.distributed gang, fronted by rank 0's HTTP server.
 
 The serving half of the flagship at GANG scale: the model's parameters
 are tensor-parallel-sharded across every chip of the gang (a model too
-big for one host serves from the whole slice), and the slot-pool KV
-cache (dcos_commons_tpu/serve/) is laid over the same mesh.  SPMD
+big for one host serves from the whole slice), and the paged KV
+arena (dcos_commons_tpu/serve/) is laid over the same mesh.  SPMD
 serving needs every process in every collective, but requests arrive
 only at the VIP'd rank — so rank 0 drives the gang with PER-TICK
 broadcast ops and every rank executes the identical payload:
 
     NOOP    keep the gang meeting in a collective while idle
-    ADMIT   prefill ONE request (paged: ONE CHUNK of one request's
-            prompt, through its page table) into the pool
-    DECODE  advance EVERY pool row one step (per-row pos/temp/seed;
-            paged: through per-row page tables)
+    ADMIT   prefill ONE CHUNK of one request's prompt, through its
+            page table, into the pool
+    DECODE  advance EVERY pool row one step (per-row pos/temp/seed,
+            through per-row page tables)
 
 Requests therefore join and leave MID-FLIGHT: a request arriving
 while others decode is admitted at the next tick (TTFT = one tick +
@@ -23,12 +23,10 @@ stepping.  The driver/follower shape is unchanged from the
 dispatch-per-group protocol this replaces (spmdcheck-clean: followers
 just execute the broadcast payload), only the op vocabulary grew.
 
-PAGED KV (ISSUE 11, the default — KV_PAGE_TOKENS=0 selects the
-legacy slot pool): the broadcast payload grows the chunk/page
-fields — ADMIT carries a PREFILL_CHUNK_TOKENS-wide prompt chunk, its
-traced start position/true length, and the request's page table;
-DECODE carries every row's page table alongside its (token, position,
-temp, seed) state.  Page allocation, budgeting and the prefix cache
+The broadcast payload: ADMIT carries a PREFILL_CHUNK_TOKENS-wide
+prompt chunk, its traced start position/true length, and the
+request's page table; DECODE carries every row's page table alongside
+its (token, position, temp, seed) state.  Page allocation, budgeting and the prefix cache
 are rank 0's HOST-side bookkeeping (serve/paging.py): followers only
 ever see physical page ids in the broadcast tables, so every rank
 still executes the identical tick and the collective schedules never
@@ -63,11 +61,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
 from dcos_commons_tpu.serve import (  # noqa: E402
     SERVESTATS_NAME,
     PagedEngine,
-    SlotEngine,
+    QueueTimeoutError,
     paged_config_from_env,
 )
 from dcos_commons_tpu.trace.steplog import StepLog  # noqa: E402
-from dcos_commons_tpu.utils.microbatch import QueueTimeoutError  # noqa: E402
 
 # how often idle ranks meet in a noop collective: the gang must stay
 # in lockstep even with no traffic, or a request would wait on ranks
@@ -86,57 +83,9 @@ OP_DECODE = 2
 _STEPLOG_EVERY = 64
 
 
-def _zero_payload(slots, prompt_len):
-    return (
-        np.zeros(6, np.int64),                # head [op, a, b, c, d, e]
-        np.zeros((slots, 4), np.int64),       # rows [tok, pos, temp_u, seed]
-        np.zeros((1, prompt_len), np.int32),  # ADMIT prompt
-    )
-
-
-def _broadcast_tick(multihost_utils, payload, slots, prompt_len):
-    """One gang-wide broadcast: rank 0 passes (head, rows, prompt),
-    the followers pass None and receive rank 0's payload.  Every
-    tick's payload has the same byte shape regardless of op, so the
-    broadcast cost is flat and the follower loop is shape-stable.
-
-    head by op: ADMIT = [op, slot, true_len, seed, temp_micro, 0];
-    DECODE = [op, n_active, 0, 0, 0, 0]; NOOP = zeros.  ``rows``
-    carries the DECODE pool state (token, position, temperature in
-    micro-units, per-row PRNG seed)."""
-    if payload is None:
-        payload = _zero_payload(slots, prompt_len)
-    head, rows, prompt = multihost_utils.broadcast_one_to_all(payload)
-    return np.asarray(head), np.asarray(rows), np.asarray(prompt)
-
-
-def _execute_tick(pool, head, rows, prompt):
-    """Run the broadcast op — EVERY rank (driver included) executes
-    the identical payload, so traced operands are byte-identical
-    across the gang and the collective schedules never diverge.
-    Returns the op's result (first token for ADMIT, next-token vector
-    for DECODE, None for NOOP)."""
-    op = int(head[0])
-    if op == OP_ADMIT:
-        return pool.prefill(
-            prompt, slot=int(head[1]), true_len=int(head[2]),
-            temp=int(head[4]) / 1e6, seed=int(head[3]),
-        )
-    if op == OP_DECODE:
-        return pool.decode(
-            rows[:, 0].astype(np.int32),
-            rows[:, 1].astype(np.int32),
-            (rows[:, 2] / 1e6).astype(np.float32),
-            rows[:, 3].astype(np.int32),
-        )
-    return None
-
-
-# -- paged protocol (ISSUE 11) ----------------------------------------
-# the legacy payload grew chunk/page fields: ADMIT is now one prompt
-# CHUNK through the request's page table, DECODE rides every row's
-# table.  Same flat-byte-shape discipline: every tick broadcasts the
-# same tuple of arrays regardless of op.
+# -- the broadcast protocol -------------------------------------------
+# every tick broadcasts the same tuple of arrays regardless of op: the
+# broadcast cost is flat and the follower loop is shape-stable
 
 
 def _zero_paged_payload(slots, pages_per_row, chunk_tokens):
@@ -203,7 +152,7 @@ def main() -> int:
     from dcos_commons_tpu.models import config_from_env, init_params
     from dcos_commons_tpu.models.transformer import param_shardings
     from dcos_commons_tpu.parallel.mesh import MeshSpec, make_mesh
-    from dcos_commons_tpu.serve.pool import PagedPoolModel, PoolModel
+    from dcos_commons_tpu.serve.pool import PagedPoolModel
     from dcos_commons_tpu.utils import (
         claim_devices,
         enable_compilation_cache,
@@ -274,44 +223,30 @@ def main() -> int:
         kv_dtype = os.environ.get("KV_DTYPE", "native")
         # the pool's KV heads ride the tp axis like the attention
         # weights when they divide it; otherwise the cache replicates
-        # (tiny-head test configs on wide meshes).  The paged arena
-        # keeps kv heads on dim 3 — (layers, pages, page_tokens, kv,
-        # hd) — so the SAME spec lays both pools
+        # (tiny-head test configs on wide meshes).  The arena keeps
+        # kv heads on dim 3 — (layers, pages, page_tokens, kv, hd)
         kv_spec = (
             P(None, None, None, "tp", None)
             if config.n_kv_heads % n_devices == 0 else P()
         )
         paged = paged_config_from_env(os.environ)
-        if paged is not None:
-            pool = PagedPoolModel(
-                config, params, slots, max_len, paged.page_tokens,
-                paged.pages, paged.chunk_tokens, kv_dtype=kv_dtype,
-                cache_sharding=NamedSharding(mesh, kv_spec),
-                put=to_global,
-                constrain_out=lambda x: (
-                    jax.lax.with_sharding_constraint(x, replicated)
-                ),
-            )
-        else:
-            pool = PoolModel(
-                config, params, slots, max_len, kv_dtype=kv_dtype,
-                cache_sharding=NamedSharding(mesh, kv_spec),
-                put=to_global,
-                constrain_out=lambda x: (
-                    jax.lax.with_sharding_constraint(x, replicated)
-                ),
-            )
+        pool = PagedPoolModel(
+            config, params, slots, max_len, paged.page_tokens,
+            paged.pages, paged.chunk_tokens, kv_dtype=kv_dtype,
+            cache_sharding=NamedSharding(mesh, kv_spec),
+            put=to_global,
+            constrain_out=lambda x: (
+                jax.lax.with_sharding_constraint(x, replicated)
+            ),
+        )
 
         # warm the compiled pool as a GANG before readiness: the first
         # request must not pay the compiles, and a rank that cannot
         # compile must fail deploy, not the first client.  Every rank
         # reaches this call at the same program point (pre-loop).
-        if paged is not None:
-            pool.warm()
-        else:
-            pool.warm(prompt_len)
-        pages_per_row = paged.pages_per_row if paged is not None else 0
-        chunk_tokens = paged.chunk_tokens if paged is not None else 0
+        pool.warm()
+        pages_per_row = paged.pages_per_row
+        chunk_tokens = paged.chunk_tokens
 
         # per-tick step telemetry ($SANDBOX/steplog.jsonl): sampled
         # decode ticks on every rank — wall seconds, active rows, and
@@ -338,10 +273,11 @@ def main() -> int:
             )
 
         # Intentional driver/follower split: BOTH sides of this branch
-        # run the identical collective sequence (one _broadcast_tick
-        # per tick; _execute_tick runs the same op payload on every
-        # rank), so the schedules never diverge; the branch only
-        # decides who PRODUCES the payload that every rank consumes.
+        # run the identical collective sequence (one
+        # _broadcast_paged_tick per tick; _execute_paged_tick runs
+        # the same op payload on every rank), so the schedules never
+        # diverge; the branch only decides who PRODUCES the payload
+        # that every rank consumes.
         # sdklint: disable=spmd-host-branch — driver loops meet in the broadcast
         if rank != 0:
             # follower loop: meet rank 0 in every broadcast tick and
@@ -349,80 +285,29 @@ def main() -> int:
             with open("ready", "w") as f:
                 f.write("warm\n")
             print(f"rank {rank}: following gang broadcasts", flush=True)
-            if paged is not None:
-                while True:
-                    b0 = _time.time()
-                    head, rows, tables, chunk = _broadcast_paged_tick(
-                        multihost_utils, None, slots, pages_per_row,
-                        chunk_tokens,
-                    )
-                    blocked_s = _time.time() - b0
-                    t0 = _time.time()
-                    _execute_paged_tick(pool, head, rows, tables, chunk)
-                    if int(head[0]) == OP_DECODE:
-                        _log_tick(
-                            _time.time() - t0, blocked_s, int(head[1])
-                        )
             while True:
                 b0 = _time.time()
-                head, rows, prompt = _broadcast_tick(
-                    multihost_utils, None, slots, prompt_len
+                head, rows, tables, chunk = _broadcast_paged_tick(
+                    multihost_utils, None, slots, pages_per_row,
+                    chunk_tokens,
                 )
                 blocked_s = _time.time() - b0
                 t0 = _time.time()
-                _execute_tick(pool, head, rows, prompt)
+                _execute_paged_tick(pool, head, rows, tables, chunk)
                 if int(head[0]) == OP_DECODE:
-                    _log_tick(_time.time() - t0, blocked_s, int(head[1]))
+                    _log_tick(
+                        _time.time() - t0, blocked_s, int(head[1])
+                    )
 
-        # ---- rank 0: HTTP front end + the slot engine ---------------
+        # ---- rank 0: HTTP front end + the engine --------------------
         # engine callbacks broadcast the op, then execute it exactly
         # like a follower would (one code path = no divergence);
         # on_idle keeps the followers meeting in noop collectives.
-        def prefill_fn(padded, slot, true_len, temp, seed):
-            # round() like decode_fn does: truncation would give a
-            # request's FIRST token a different temperature than its
-            # later tokens (0.07*1e6 truncates to 69999)
-            head = np.asarray(
-                [OP_ADMIT, slot, true_len, seed, round(temp * 1e6), 0],
-                np.int64,
-            )
-            _, zero_rows, _ = _zero_payload(slots, prompt_len)
-            head, rows, prompt = _broadcast_tick(
-                multihost_utils,
-                (head, zero_rows, padded.astype(np.int32)),
-                slots, prompt_len,
-            )
-            return _execute_tick(pool, head, rows, prompt)
-
-        def decode_fn(tok, pos, temps, seeds, n_active):
-            head = np.asarray(
-                [OP_DECODE, n_active, 0, 0, 0, 0], np.int64
-            )
-            rows = np.stack([
-                tok.astype(np.int64),
-                pos.astype(np.int64),
-                np.round(temps.astype(np.float64) * 1e6).astype(np.int64),
-                seeds.astype(np.int64),
-            ], axis=1)
-            zero_prompt = np.zeros((1, prompt_len), np.int32)
-            head, rows, prompt = _broadcast_tick(
-                multihost_utils, (head, rows, zero_prompt),
-                slots, prompt_len,
-            )
-            t0 = _time.time()
-            out = _execute_tick(pool, head, rows, prompt)
-            # rank 0 paces the gang; it never waits in the broadcast
-            _log_tick(_time.time() - t0, 0.0, n_active)
-            return out
-
-        def idle_tick():
-            _broadcast_tick(multihost_utils, None, slots, prompt_len)
-
-        # -- paged protocol callbacks (ISSUE 11): same shape, the
-        # payload carries chunk/page fields and every rank executes
-        # the identical _execute_paged_tick
         def paged_prefill_fn(padded, slot, table, start, true_len,
                              temp, seed):
+            # round() like decode does: truncation would give a
+            # request's FIRST token a different temperature than its
+            # later tokens (0.07*1e6 truncates to 69999)
             head = np.asarray(
                 [OP_ADMIT, slot, start, true_len, seed,
                  round(temp * 1e6)],
@@ -571,36 +456,24 @@ def main() -> int:
                 flush=True,
             )
         bound_port = int(server.server_address[1])
-        if paged is not None:
-            engine = PagedEngine(
-                paged_prefill_fn, paged_decode_fn, slots, max_len,
-                prompt_len,
-                page_tokens=paged.page_tokens, pages=paged.pages,
-                chunk_tokens=paged.chunk_tokens,
-                prefix_cache=paged.prefix_cache, layout=pool.layout,
-                queue_timeout_s=queue_timeout_s,
-                on_idle=paged_idle_tick, idle_every_s=IDLE_TICK_S,
-                stats_path=stats_path,
-                log=lambda msg: print(msg, flush=True),
-                extra_stats={"http_port": bound_port},
-                annotate=jax.profiler.TraceAnnotation,
-            )
-        else:
-            engine = SlotEngine(
-                prefill_fn, decode_fn, slots, max_len, prompt_len,
-                queue_timeout_s=queue_timeout_s,
-                on_idle=idle_tick, idle_every_s=IDLE_TICK_S,
-                stats_path=stats_path,
-                log=lambda msg: print(msg, flush=True),
-                extra_stats={"http_port": bound_port},
-                annotate=jax.profiler.TraceAnnotation,
-            )
+        engine = PagedEngine(
+            paged_prefill_fn, paged_decode_fn, slots, max_len,
+            prompt_len,
+            page_tokens=paged.page_tokens, pages=paged.pages,
+            chunk_tokens=paged.chunk_tokens,
+            prefix_cache=paged.prefix_cache, layout=pool.layout,
+            queue_timeout_s=queue_timeout_s,
+            on_idle=paged_idle_tick, idle_every_s=IDLE_TICK_S,
+            stats_path=stats_path,
+            log=lambda msg: print(msg, flush=True),
+            extra_stats={"http_port": bound_port},
+            annotate=jax.profiler.TraceAnnotation,
+        )
         with open("ready", "w") as f:
             f.write("warm\n")
         shape = (
             f"{paged.pages}-page arena (pages of {paged.page_tokens}, "
             f"{slots} rows, chunk {paged.chunk_tokens})"
-            if paged is not None else f"{slots}-slot pool"
         )
         print(
             f"rank 0: serving sharded generate over a {shape} "

@@ -1,8 +1,8 @@
 """Inference serving task: the flagship behind an HTTP endpoint.
 
 The scheduler deploys this like any other task (svc_serve.yml): it
-builds the model, warms the slot-pool decode path (two compiles —
-prefill-into-slot and one pool decode step), then serves POST
+builds the model, warms the pool's two programs (one prefill chunk
+and one pool decode step), then serves POST
 /generate on the scheduler-assigned port — discoverable via
 /v1/endpoints and the VIP.  Readiness: the task's readiness check
 passes once the warmup file exists, so the deploy plan completes only
@@ -26,11 +26,10 @@ or compute saturated), prompts prefill PREFILL_CHUNK_TOKENS at a time
 interleaved with decode ticks (a long prompt no longer blocks the
 tick it rides), and fully-prefilled prompt pages are shared read-only
 across requests with the same prefix (prefix caching — the system-
-prompt multiplier).  KV_PAGE_TOKENS=0 falls back to the PR 6 slot
-pool (SERVE_SLOTS x MAX_LEN rows).  Mixed prompt lengths, requested
-lengths AND temperatures still share one pool dispatch, and greedy
-outputs are token-identical on both paths.  MODEL_CONFIG names a configuration
-file (the key names of a published config.json) that sizes the model
+prompt multiplier).  Mixed prompt lengths, requested lengths AND
+temperatures share one pool dispatch, and greedy outputs are
+token-identical to whole-batch generate.  MODEL_CONFIG names a
+configuration file (the key names of a published config.json) that sizes the model
 in place of the eight size names and says what they cannot:
 rope_theta, the norm's epsilon and unit offset, an untied head, and
 attention_class "eva", which gives every row a ring of exact window
@@ -70,7 +69,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
 from dcos_commons_tpu.serve import (  # noqa: E402
     SERVESTATS_NAME,
     PagedEngine,
-    SlotEngine,
+    QueueTimeoutError,
     paged_config_from_env,
 )
 from dcos_commons_tpu.serve.migration import (  # noqa: E402
@@ -86,16 +85,6 @@ from dcos_commons_tpu.trace import (  # noqa: E402
     chrome_json,
     to_text,
 )
-from dcos_commons_tpu.utils.microbatch import (  # noqa: E402
-    MicroBatcher,
-    QueueTimeoutError,
-    WorkItem,
-)
-
-# back-compat aliases (unit tests drive the legacy batcher through
-# this module's names; the slot engine subsumed it for serving)
-_MicroBatcher = MicroBatcher
-_WorkItem = WorkItem
 
 PROFILE_MAX_S = 30.0
 
@@ -123,7 +112,7 @@ def main() -> int:
 
     from dcos_commons_tpu.models import config_from_env, init_params
     from dcos_commons_tpu.models.decode import decode_attention_kernel
-    from dcos_commons_tpu.serve.pool import PagedPoolModel, PoolModel
+    from dcos_commons_tpu.serve.pool import PagedPoolModel
     from dcos_commons_tpu.utils import (
         claim_devices,
         enable_compilation_cache,
@@ -147,8 +136,8 @@ def main() -> int:
     # serving.batch description)
     # sdklint: disable=config-default-drift — dev fallback
     batch = int(os.environ.get("SERVE_BATCH", "1"))
-    # the slot POOL defaults to the request cap; SERVE_SLOTS decouples
-    # them (more concurrent residents than any one request may carry);
+    # the pool's decode rows default to the request cap; SERVE_SLOTS
+    # decouples them (more concurrent residents than any one request may carry);
     # "" and 0 both mean "use SERVE_BATCH" (the options.json default)
     slots = int(os.environ.get("SERVE_SLOTS") or 0) or batch
     new_tokens = int(os.environ.get("MAX_NEW_TOKENS", "32"))
@@ -172,11 +161,10 @@ def main() -> int:
         params = jax.device_put(quantize_params_int8(params))
         print("weights quantized to int8 (per-channel)", flush=True)
 
-    # TWO compiles cover every request on EITHER path: the paged
-    # arena's prefill-chunk + decode-step (page tables, start
-    # positions, true lengths, temps, seeds all traced) or the legacy
-    # slot pool's prefill-into-slot + decode-step — novel requests
-    # never recompile.  KV_DTYPE=int8 halves the cache bytes per
+    # TWO compiles cover every request: the paged arena's
+    # prefill-chunk + decode-step (page tables, start positions, true
+    # lengths, temps, seeds all traced) — novel requests never
+    # recompile.  KV_DTYPE=int8 halves the cache bytes per
     # decode step: the lever for many resident requests on a full
     # chip (models/decode.py)
     prompt_len = max_len - new_tokens
@@ -358,11 +346,6 @@ def main() -> int:
             try:
                 body = json.loads(self.rfile.read(length))
                 verb = body.get("verb")
-                if paged is None:
-                    raise MigrationError(
-                        "slot-pool pods cannot host migrations "
-                        "(KV_PAGE_TOKENS=0)"
-                    )
                 if verb == "splice":
                     snap = SessionSnapshot.from_wire(body["snapshot"])
                     dest_rid = engine.splice(snap)
@@ -430,81 +413,59 @@ def main() -> int:
         )
     bound_port = int(server.server_address[1])
 
-    if paged is not None:
-        # the paged arena (ISSUE 11): page-budgeted admission,
-        # chunked prefill, prefix caching — the serving default.
-        # SERVE_ROLE (ISSUE 16) declares this pod's place in a
-        # disaggregated topology; a prefill pod with SERVE_DECODE_PODS
-        # peers hands finished prompts to the decode pool over the
-        # /migrate lane, and degrades to unified when it cannot.
-        role = (os.environ.get("SERVE_ROLE") or "").strip() or "unified"
-        handoff = None
-        if role == "prefill":
-            decode_pods = {}
-            for item in os.environ.get("SERVE_DECODE_PODS",
-                                       "").split(","):
-                if "=" not in item:
-                    continue
-                peer, addr = item.split("=", 1)
-                peer, addr = peer.strip(), addr.strip()
-                if peer and addr:
-                    decode_pods[peer] = HttpEngineClient(peer, addr)
-            if decode_pods:
-                handoff = PrefillHandoff(
-                    lambda: decode_pods,
-                    log=lambda msg: print(msg, flush=True),
-                )
-        pool = PagedPoolModel(
-            config, params, slots, max_len, paged.page_tokens,
-            paged.pages, paged.chunk_tokens, kv_dtype=kv_dtype,
-        )
-        engine = PagedEngine(
-            pool.prefill_chunk, pool.decode, slots, max_len,
-            prompt_len,
-            page_tokens=paged.page_tokens, pages=paged.pages,
-            chunk_tokens=paged.chunk_tokens,
-            prefix_cache=paged.prefix_cache, layout=pool.layout,
-            queue_timeout_s=queue_timeout_s, stats_path=stats_path,
-            role=role, read_page=pool.export_page,
-            write_page=pool.import_page, handoff=handoff,
-            log=lambda msg: print(msg, flush=True),
-            extra_stats={"http_port": bound_port},
-            annotate=jax.profiler.TraceAnnotation, tracer=tracer,
-        )
-    else:
-        # KV_PAGE_TOKENS=0: the PR 6 slot pool, kept as the
-        # operator's escape hatch and the bench baseline
-        pool = PoolModel(
-            config, params, slots, max_len, kv_dtype=kv_dtype
-        )
-        engine = SlotEngine(
-            pool.prefill, pool.decode, slots, max_len, prompt_len,
-            queue_timeout_s=queue_timeout_s, stats_path=stats_path,
-            log=lambda msg: print(msg, flush=True),
-            extra_stats={"http_port": bound_port},
-            annotate=jax.profiler.TraceAnnotation, tracer=tracer,
-        )
+    # SERVE_ROLE (ISSUE 16) declares this pod's place in a
+    # disaggregated topology; a prefill pod with SERVE_DECODE_PODS
+    # peers hands finished prompts to the decode pool over the
+    # /migrate lane, and degrades to unified when it cannot.
+    role = (os.environ.get("SERVE_ROLE") or "").strip() or "unified"
+    handoff = None
+    if role == "prefill":
+        decode_pods = {}
+        for item in os.environ.get("SERVE_DECODE_PODS",
+                                   "").split(","):
+            if "=" not in item:
+                continue
+            peer, addr = item.split("=", 1)
+            peer, addr = peer.strip(), addr.strip()
+            if peer and addr:
+                decode_pods[peer] = HttpEngineClient(peer, addr)
+        if decode_pods:
+            handoff = PrefillHandoff(
+                lambda: decode_pods,
+                log=lambda msg: print(msg, flush=True),
+            )
+    pool = PagedPoolModel(
+        config, params, slots, max_len, paged.page_tokens,
+        paged.pages, paged.chunk_tokens, kv_dtype=kv_dtype,
+    )
+    engine = PagedEngine(
+        pool.prefill_chunk, pool.decode, slots, max_len,
+        prompt_len,
+        page_tokens=paged.page_tokens, pages=paged.pages,
+        chunk_tokens=paged.chunk_tokens,
+        prefix_cache=paged.prefix_cache, layout=pool.layout,
+        queue_timeout_s=queue_timeout_s, stats_path=stats_path,
+        role=role, read_page=pool.export_page,
+        write_page=pool.import_page, handoff=handoff,
+        log=lambda msg: print(msg, flush=True),
+        extra_stats={"http_port": bound_port},
+        annotate=jax.profiler.TraceAnnotation, tracer=tracer,
+    )
     warm_t0 = time.monotonic()
-    if paged is not None:
-        pool.warm()
-        shape = (
-            f"paged KV: {paged.pages} pages x {paged.page_tokens} "
-            f"tokens, {slots} rows of {pool.pages_per_row} table "
-            f"entries, chunk {paged.chunk_tokens}, "
-            f"prefix cache {'on' if paged.prefix_cache else 'off'}"
-        )
-    else:
-        pool.warm(prompt_len)
-        shape = f"slot pool: {slots} slots x {max_len}"
+    pool.warm()
+    shape = (
+        f"paged KV: {paged.pages} pages x {paged.page_tokens} "
+        f"tokens, {slots} rows of {pool.pages_per_row} table "
+        f"entries, chunk {paged.chunk_tokens}, "
+        f"prefix cache {'on' if paged.prefix_cache else 'off'}"
+    )
     # which path a decode step's attention takes: the page walk that
-    # reads live pages in place, the gather of every row's whole table,
-    # or the slot pool's dense cache
-    if paged is None:
-        decode_attention = "dense"
-    elif decode_attention_kernel(config, pool.cache):
-        decode_attention = "kernel"
-    else:
-        decode_attention = "gather"
+    # reads live pages in place, or the gather of every row's whole
+    # table
+    decode_attention = (
+        "kernel" if decode_attention_kernel(config, pool.cache)
+        else "gather"
+    )
     # /stats and the sandbox snapshot state what answers the requests
     # and what warming it cost (XLA compile, or persistent-cache read)
     engine.annotate_stats(

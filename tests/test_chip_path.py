@@ -222,7 +222,7 @@ def test_bench_parent_stays_off_jax_and_fails_on_a_chip_error(tmp_path):
     assert parent["backend"] is False
     assert parent["cache_env"] == str(cache)
     assert parent["children"][:3] == [
-        "bench_continuous_serve", "bench_router_scale", "bench_disagg",
+        "bench_router_scale", "bench_disagg", "bench_train_step",
     ]
     chip = parent["children"][parent["children"].index("bench_rooflines"):]
     assert chip[:3] == ["bench_rooflines", "bench_transformer",

@@ -18,14 +18,17 @@ import time
 import numpy as np
 import pytest
 
-from dcos_commons_tpu.serve.engine import PHASES, PagedEngine, SlotEngine
+from dcos_commons_tpu.serve.engine import (
+    PHASES,
+    PagedEngine,
+    QueueTimeoutError,
+)
 from dcos_commons_tpu.serve.migration import (
     InProcessTransport,
     SessionMigratedError,
     migrate_session,
 )
 from dcos_commons_tpu.trace import NULL_TRACER, TraceRecorder, to_text
-from dcos_commons_tpu.utils.microbatch import QueueTimeoutError
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 P = 4  # page tokens
@@ -48,10 +51,6 @@ class FakePool:
                       temp, seed):
         time.sleep(self.step_s)
         return int(padded[0, :true_len].sum() + start) % 97
-
-    def prefill(self, padded, slot, true_len, temp, seed):
-        time.sleep(self.step_s)
-        return int(padded[0, :true_len].sum()) % 97
 
     def decode(self, tok, pos, temps, seeds, *rest):
         if self.gate is not None:
@@ -144,31 +143,27 @@ def minted(monkeypatch):
 # -- the phases ----------------------------------------------------------
 
 
-def lived(engine_cls):
-    """``engine_cls`` with its loop thread's life stamped by the
-    thread itself (a busy test host starts and joins threads late)."""
+class Lived(PagedEngine):
+    """The engine with its loop thread's life stamped by the thread
+    itself (a busy test host starts and joins threads late)."""
 
-    class Lived(engine_cls):
-        def _loop(self):
-            self.born = time.monotonic()
-            try:
-                super()._loop()
-            finally:
-                self.died = time.monotonic()
-
-    return Lived
+    def _loop(self):
+        self.born = time.monotonic()
+        try:
+            super()._loop()
+        finally:
+            self.died = time.monotonic()
 
 
-@pytest.mark.parametrize("kind", ["paged", "slot"])
-def test_the_phases_partition_the_loop_threads_life(kind):
+@pytest.mark.parametrize("chunk", [8, 48], ids=["paged", "one-chunk"])
+def test_the_phases_partition_the_loop_threads_life(chunk):
+    """Chunked prefill under a page budget, and the prompt in one
+    chunk with every row resident: the loop's two extremes."""
     pool = FakePool()
-    if kind == "paged":
-        engine = lived(PagedEngine)(
-            pool.prefill_chunk, pool.decode, 3, 64, 48,
-            page_tokens=P, pages=48, chunk_tokens=8,
-        )
-    else:
-        engine = lived(SlotEngine)(pool.prefill, pool.decode, 3, 64, 48)
+    engine = Lived(
+        pool.prefill_chunk, pool.decode, 3, 64, 48,
+        page_tokens=P, pages=48, chunk_tokens=chunk,
+    )
     swarm(engine, JOBS)
     time.sleep(0.15)  # parked: waiting is a phase like any other
     swarm(engine, JOBS[:2])

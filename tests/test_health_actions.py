@@ -146,6 +146,8 @@ def test_scale_out_target_monotone_and_clamped():
     # the step cap and the instance cap both bind
     assert scale_out_target(1, 8, 1e9, step_max=2) == 3
     assert scale_out_target(7, 8, 1e9, step_max=4) == 8
+    # a pod set already past a lowered cap: a breach never scales it IN
+    assert scale_out_target(8, 1, 2.0, step_max=2) == 8
 
 
 def test_decide_breach_path():

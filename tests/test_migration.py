@@ -54,7 +54,13 @@ from dcos_commons_tpu.serve.migration import (
     migrate_session,
 )
 
-_V = 97
+from dcos_commons_tpu.testing.chain_model import (
+    V as _V,
+    chain_first as _chain_first,
+    chain_next as _chain_next,
+    chain_oracle as _chain_oracle,
+)
+
 P = 4  # page tokens
 
 
@@ -64,29 +70,9 @@ def _racecheck_probes():
     state into a live decode loop from a foreign thread — watch the
     engine classes' shared-write set so any unordered splice/tick pair
     fails the run (the PR 16 bug class).  No-op in the fast tier."""
-    from dcos_commons_tpu.serve.engine import SlotEngine
-    from dcos_commons_tpu.utils.microbatch import MicroBatcher
-
     from conftest import racecheck_watch_guard
 
-    yield from racecheck_watch_guard(PagedEngine, SlotEngine, MicroBatcher)
-
-
-def _chain_first(prompt):
-    return (sum(prompt) * 31 + len(prompt)) % _V
-
-
-def _chain_next(tok, pos):
-    return (tok * 7 + pos * 3 + 1) % _V
-
-
-def _chain_oracle(prompt, n, eos=None):
-    out = [_chain_first(prompt)]
-    pos = len(prompt)
-    while len(out) < n and (eos is None or out[-1] != eos):
-        out.append(_chain_next(out[-1], pos))
-        pos += 1
-    return out
+    yield from racecheck_watch_guard(PagedEngine)
 
 
 class ChainArena:

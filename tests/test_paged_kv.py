@@ -1,5 +1,5 @@
 """Paged KV serving (ISSUE 11): allocator/prefix-cache soundness and
-the paged engine's greedy equivalence to the slot pool.
+the engine's greedy equivalence to whole-batch ``generate``.
 
 Three layers of coverage:
 
@@ -21,8 +21,7 @@ Three layers of coverage:
 * REAL-MODEL equivalence (tiny flagship on CPU): tokens produced by
   the paged engine — chunked prefill, page-table attention, prefix-
   cache hits, mixed chunked/unchunked admission — are IDENTICAL to
-  whole-batch ``generate`` / the slot-pool path on the same prompts,
-  including through the gang driver's paged broadcast protocol
+  whole-batch ``generate`` on the same prompts, including through the gang driver's paged broadcast protocol
   executed for real in a single-process gang sim.
 """
 
@@ -34,13 +33,20 @@ import time
 import numpy as np
 import pytest
 
-from dcos_commons_tpu.serve.engine import PagedEngine
+from dcos_commons_tpu.serve.engine import PagedEngine, QueueTimeoutError
 from dcos_commons_tpu.serve.paging import (
     PageAllocator,
     paged_config_from_env,
     worst_case_pages,
 )
-from dcos_commons_tpu.utils.microbatch import QueueTimeoutError
+from dcos_commons_tpu.testing.chain_model import (
+    ChainModel as FakePagedModel,
+    V as _V,
+    chain_first as _chain_first,
+    chain_next as _chain_next,
+    chain_oracle as _chain_oracle,
+    swarm as _swarm,
+)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -223,7 +229,6 @@ def test_paged_config_from_env_contract():
     cfg = paged_config_from_env({"MAX_LEN": "64", "SERVE_BATCH": "4"})
     assert cfg.page_tokens == 16 and cfg.pages == 16  # 4 * ceil(64/16)
     assert cfg.pages_per_row == 4 and cfg.arena_pages == 17
-    assert paged_config_from_env({"KV_PAGE_TOKENS": "0"}) is None
     with pytest.raises(SpecError, match="overcommitted"):
         paged_config_from_env({
             "MAX_LEN": "64", "KV_PAGES": "2", "KV_PAGE_TOKENS": "16",
@@ -237,69 +242,6 @@ def test_paged_config_from_env_contract():
 # -- engine vs a deterministic fake model ------------------------------
 
 
-_V = 97
-
-
-def _chain_first(prompt):
-    return (sum(prompt) * 31 + len(prompt)) % _V
-
-
-def _chain_next(tok, pos):
-    return (tok * 7 + pos * 3 + 1) % _V
-
-
-def _chain_oracle(prompt, n, eos=None):
-    out = [_chain_first(prompt)]
-    pos = len(prompt)
-    while len(out) < n and (eos is None or out[-1] != eos):
-        out.append(_chain_next(out[-1], pos))
-        pos += 1
-    if eos is not None and eos in out:
-        out = out[: out.index(eos) + 1]
-    return out
-
-
-class FakePagedModel:
-    """Chunk-accumulating fake: chunks of one slot's prompt arrive in
-    order (prefix cache OFF keeps start=0 on the first chunk), the
-    final chunk's return is the chain's first token.  Decode asserts
-    every live row's write page is allocated (nonzero)."""
-
-    def __init__(self, step_gate=None):
-        self.partial = {}
-        self.step_gate = step_gate
-        self.decode_calls = 0
-        self.max_active = 0
-
-    def prefill_chunk(self, padded, slot, table, start, true_len,
-                      temp, seed):
-        if start == 0:
-            self.partial[slot] = []
-        buf = self.partial[slot]
-        assert len(buf) == start, "chunks arrived out of order"
-        buf.extend(int(t) for t in padded[0, :true_len])
-        # the chunk's pages must be allocated before the model runs
-        p = 4  # matches the engines below
-        for pos in range(start, start + true_len):
-            assert table[pos // p] != 0, "write into unallocated page"
-        return _chain_first(buf)
-
-    def decode(self, tok, pos, temps, seeds, tables, n_active):
-        if self.step_gate is not None:
-            assert self.step_gate.wait(10), "tick never released"
-            self.step_gate.clear()
-        self.decode_calls += 1
-        self.max_active = max(self.max_active, n_active)
-        p = 4
-        for s in range(len(tok)):
-            if pos[s] > 0:  # live row: write page must exist
-                assert tables[s][int(pos[s]) // p] != 0
-        return np.asarray(
-            [_chain_next(int(t), int(q)) for t, q in zip(tok, pos)],
-            np.int32,
-        )
-
-
 def _paged_engine(model, slots, pages, max_len=32, prompt_len=24,
                   chunk=5, prefix=False, **kw):
     return PagedEngine(
@@ -307,29 +249,6 @@ def _paged_engine(model, slots, pages, max_len=32, prompt_len=24,
         page_tokens=4, pages=pages, chunk_tokens=chunk,
         prefix_cache=prefix, **kw,
     )
-
-
-def _swarm(engine, jobs):
-    results = [None] * len(jobs)
-    errors = []
-
-    def client(i):
-        rows, n, eos = jobs[i]
-        try:
-            results[i] = engine.submit(rows, n, eos_id=eos)
-        except Exception as e:  # noqa: BLE001 — surfaced via assert
-            errors.append(e)
-
-    threads = [
-        threading.Thread(target=client, args=(i,))
-        for i in range(len(jobs))
-    ]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(timeout=30)
-    assert not errors, errors
-    return results
 
 
 def test_paged_engine_chunked_prefill_matches_oracle():
@@ -429,6 +348,65 @@ def test_paged_timeout_names_the_starved_resource():
     finally:
         gate2.set()
         engine2.stop()
+
+
+def test_paged_prefill_failure_mid_prompt_frees_rows_and_pages():
+    """A model failure on a prompt's SECOND chunk, with another row
+    already decoding: both clients get the error at once (not their
+    timeout), every row and page comes back, and the engine serves
+    the next request from a clean arena."""
+    model = FakePagedModel()
+    orig = model.prefill_chunk
+    boom = RuntimeError("chunk exploded")
+
+    def chunk(padded, slot, table, start, true_len, temp, seed):
+        if start > 0 and boom is not None:
+            raise boom
+        return orig(padded, slot, table, start, true_len, temp, seed)
+
+    def slow_decode(*args, decode=model.decode):
+        time.sleep(0.01)  # the short row outlives the long prompt
+        return decode(*args)
+
+    model.prefill_chunk = chunk
+    model.decode = slow_decode
+    engine = _paged_engine(model, slots=3, pages=24, queue_timeout_s=30)
+    try:
+        t0 = time.monotonic()
+        errors = []
+
+        def client(prompt, n):
+            try:
+                engine.submit([prompt], n)
+            except RuntimeError as e:
+                errors.append(e)
+
+        short = threading.Thread(target=client, args=([1, 2], 30))
+        short.start()
+        deadline = time.monotonic() + 10
+        while (engine.stats()["active_slots"] < 1
+               and time.monotonic() < deadline):
+            time.sleep(0.005)
+        # the long prompt's second chunk (5 of 12 tokens a chunk) is
+        # the one that raises, a tick after its first
+        long_ = threading.Thread(
+            target=client, args=(list(range(1, 13)), 4)
+        )
+        long_.start()
+        long_.join(timeout=10)
+        short.join(timeout=10)
+        assert time.monotonic() - t0 < 8.0  # the error, not a timeout
+        assert errors and all(e is boom for e in errors), errors
+        stats = engine.stats()
+        assert stats["active_slots"] == 0 and stats["free_slots"] == 3
+        assert stats["kv_pages_free"] == 24
+        assert stats["kv_pages_reserved"] == 0
+        engine._allocator.check_invariants()
+        boom = None
+        assert engine.submit([list(range(1, 13))], 4)[0] == \
+            _chain_oracle(list(range(1, 13)), 4)
+    finally:
+        engine.stop()
 
 
 def test_paged_long_prefill_is_progress_not_a_stall():
@@ -586,15 +564,7 @@ def test_paged_engine_property_any_request_mix_matches_oracle():
 # -- admission gate: page-budget overcommit is a 422, not a 503 --------
 
 
-def test_admission_gate_rejects_page_budget_overcommit():
-    """The PR 9 admission gate runs the serve workload builders, so
-    an arena that cannot hold one MAX_LEN request (a permanent-503
-    misconfiguration) is a line-anchored 422 finding at PUT time."""
-    jax = pytest.importorskip("jax")  # noqa: F841 — builder needs it
-
-    from dcos_commons_tpu.multi.admission import validate_service_yaml
-
-    yaml_text = """
+_BADSERVE = """
 name: badserve
 pods:
   server:
@@ -617,15 +587,72 @@ pods:
           KV_PAGE_TOKENS: "16"
           KV_PAGES: "3"
 """
+
+
+def _put_findings(yaml_text):
+    pytest.importorskip("jax")  # the workload builder needs it
+
+    from dcos_commons_tpu.multi.admission import validate_service_yaml
+
     _spec, findings = validate_service_yaml(yaml_text, "badserve")
-    assert any(
-        "overcommitted" in f.render() for f in findings
-    ), [f.render() for f in findings]
-    good = yaml_text.replace('KV_PAGES: "3"', 'KV_PAGES: "64"')
-    _spec, findings = validate_service_yaml(good, "badserve")
-    assert not [
-        f for f in findings if "overcommit" in f.render()
-    ], [f.render() for f in findings]
+    return [f.render() for f in findings]
+
+
+def test_admission_gate_rejects_page_budget_overcommit():
+    """The PR 9 admission gate runs the serve workload builders, so
+    an arena that cannot hold one MAX_LEN request (a permanent-503
+    misconfiguration) is a line-anchored 422 finding at PUT time."""
+    findings = _put_findings(_BADSERVE)
+    assert any("overcommitted" in f for f in findings), findings
+    good = _BADSERVE.replace('KV_PAGES: "3"', 'KV_PAGES: "64"')
+    findings = _put_findings(good)
+    assert not [f for f in findings if "overcommit" in f], findings
+
+
+@pytest.mark.parametrize("where", ["env", "put", "shard", "schema"])
+def test_kv_page_tokens_zero_is_refused(where):
+    """``KV_PAGE_TOKENS`` is a page size and nothing else: 0, which
+    selected the slot pool, is refused once, where the geometry is
+    derived — so at PUT (the admission gate derives it) and in
+    shardcheck's footprint model as at worker start — and the
+    option's schema refuses it before a render."""
+    from dcos_commons_tpu.specification.specs import SpecError
+
+    if where == "env":
+        with pytest.raises(SpecError, match="slot pool .* is gone"):
+            paged_config_from_env({"KV_PAGE_TOKENS": "0"})
+    elif where == "shard":
+        pytest.importorskip("jax")
+
+        from dcos_commons_tpu.analysis.shardcheck import _serve_leaves
+
+        env = {"VOCAB": "512", "D_MODEL": "64", "N_LAYERS": "2",
+               "MAX_LEN": "64", "SERVE_BATCH": "2"}
+        _config, leaves = _serve_leaves(env, 1)
+        assert any(leaf.section == "kv" for leaf in leaves)
+        with pytest.raises(SpecError, match="KV_PAGE_TOKENS"):
+            _serve_leaves(dict(env, KV_PAGE_TOKENS="0"), 1)
+    elif where == "put":
+        findings = _put_findings(
+            _BADSERVE.replace('KV_PAGE_TOKENS: "16"',
+                              'KV_PAGE_TOKENS: "0"')
+        )
+        assert any(
+            "KV_PAGE_TOKENS must be >= 1" in f for f in findings
+        ), findings
+    else:
+        from dcos_commons_tpu.tools.options import (
+            OptionsError,
+            load_schema,
+            render_options,
+        )
+
+        schema = load_schema(os.path.join(REPO, "frameworks", "jax"))
+        with pytest.raises(OptionsError, match="kv_page_tokens"):
+            render_options(schema, {"serving": {"kv_page_tokens": 0}})
+        assert render_options(
+            schema, {"serving": {"kv_page_tokens": 1}}
+        )["KV_PAGE_TOKENS"] == "1"
 
 
 # -- SLO watcher: the min-direction kv_pages_free signal ---------------
@@ -655,7 +682,7 @@ def test_slo_watcher_kv_pages_free_breaches_below_minimum():
     assert len(events) == 1
 
 
-# -- real model: token-identical to the slot pool ----------------------
+# -- real model: token-identical to whole-batch generate ---------------
 
 
 @pytest.fixture(scope="module")
@@ -716,8 +743,7 @@ def _real_paged(config, params, kv_dtype="native", slots=3, pages=30,
 def test_paged_engine_greedy_equals_whole_batch_generate(tiny, kv_dtype):
     """Staggered concurrent admission over the paged arena — mixed
     chunked/unchunked prompts, page tables, early retirement —
-    reproduces whole-batch generate token for token (the slot pool's
-    own equivalence bar, held by the paged path)."""
+    reproduces whole-batch generate token for token."""
     config, params = tiny
     _pool, engine = _real_paged(config, params, kv_dtype=kv_dtype)
     try:
@@ -746,8 +772,9 @@ def test_paged_engine_greedy_equals_whole_batch_generate(tiny, kv_dtype):
             ]
             assert results == oracles
         else:
-            # int8 equivalence is engine-vs-engine determinism, as in
-            # the slot-pool tests
+            # int8 equivalence is engine-vs-engine determinism: the
+            # quantization error vs the native oracle is expected, but
+            # the pool path must be self-consistent per prompt
             again = [engine.submit([p], NEW)[0] for p in PROMPTS]
             assert results == again
         engine._allocator.check_invariants()
@@ -784,27 +811,24 @@ def test_paged_prefix_cache_hit_is_token_identical(tiny):
         engine.stop()
 
 
-def test_paged_vs_slot_pool_same_tokens_same_load(tiny):
-    """The two engines, same prompts, same greedy request mix: token
-    outputs must be IDENTICAL (the bench's equality fence, held as a
-    unit test)."""
-    from dcos_commons_tpu.serve.engine import SlotEngine
-    from dcos_commons_tpu.serve.pool import PoolModel
-
+def test_pool_engine_early_retirement_and_eos_prefixes(tiny):
+    """Mixed requested lengths retire rows early; an EOS cut is a
+    PREFIX of the whole-batch generation (plus the eos token)."""
     config, params = tiny
-    slot_pool = PoolModel(config, params, 3, MAX_LEN)
-    slot_engine = SlotEngine(
-        slot_pool.prefill, slot_pool.decode, 3, MAX_LEN, PROMPT_LEN,
-        queue_timeout_s=120,
-    )
-    _pool, paged_engine = _real_paged(config, params)
+    _pool, engine = _real_paged(config, params, slots=2)
     try:
-        slot_out = [slot_engine.submit([p], NEW)[0] for p in PROMPTS]
-        paged_out = [paged_engine.submit([p], NEW)[0] for p in PROMPTS]
-        assert slot_out == paged_out
+        full = [_oracle(config, params, p, NEW) for p in PROMPTS[:3]]
+        # mixed lengths in ONE submit: 3 rows > 2 slots exercises
+        # queue + retirement interleaving; each row a prefix
+        mixed = engine.submit(PROMPTS[:3], 3)
+        assert mixed == [row[:3] for row in full]
+        # eos: pick each row's 3rd token as its stop token
+        for prompt, row in zip(PROMPTS[:3], full):
+            eos = row[2]
+            got = engine.submit([prompt], NEW, eos_id=eos)[0]
+            assert got == row[: row.index(eos) + 1]
     finally:
-        slot_engine.stop()
-        paged_engine.stop()
+        engine.stop()
 
 
 def test_paged_gang_sim_broadcast_protocol_equivalence(tiny):

@@ -625,7 +625,7 @@ def test_pr16_real_engine_splice_mid_tick_is_ordered():
     Every engine-state write the static pass calls shared must be
     ordered by the cv — zero unordered pairs, and the migrated
     session still completes."""
-    from dcos_commons_tpu.serve.engine import PagedEngine, SlotEngine
+    from dcos_commons_tpu.serve.engine import PagedEngine
     from dcos_commons_tpu.serve.migration import (
         SessionMigratedError,
         migrate_session,
@@ -646,10 +646,9 @@ def test_pr16_real_engine_splice_mid_tick_is_ordered():
 
     def case():
         shared = racecheck.shared_write_map(REPO)
-        for cls in (SlotEngine, PagedEngine):
-            attrs = shared.get(cls.__name__)
-            if attrs:
-                racecheck.watch_type(cls, attrs)
+        attrs = shared.get(PagedEngine.__name__)
+        if attrs:
+            racecheck.watch_type(PagedEngine, attrs)
         src = make_pod("source")
         dst = make_pod("dest")
         try:

@@ -12,7 +12,7 @@ Four layers of coverage:
   affinity-follows-the-cache with the load-slack override, honest
   retry budgets, and application errors passing through un-retried.
 
-* FAILOVER against REAL slot engines (the satellite): kill a pod
+* FAILOVER against REAL engines (the satellite): kill a pod
   mid-stream — queued and in-flight requests complete on survivors,
   every greedy continuation arrives exactly once (no duplicates), and
   the dead/drained pod receives zero new admissions.
@@ -30,7 +30,6 @@ import urllib.error
 import urllib.request
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
-import numpy as np
 import pytest
 
 from dcos_commons_tpu.router import (
@@ -40,44 +39,23 @@ from dcos_commons_tpu.router import (
     RequestRouter,
     prefix_chain_keys,
 )
-from dcos_commons_tpu.serve.engine import SlotEngine
-
-# -- the deterministic chain model (test_continuous_batching's fake) --
-
-_V = 97
-
-
-def _chain_first(prompt):
-    return (sum(prompt) * 31 + len(prompt)) % _V
+from dcos_commons_tpu.serve.engine import PagedEngine
+from dcos_commons_tpu.testing.chain_model import (
+    ChainModel,
+    chain_oracle as _chain_oracle,
+)
 
 
-def _chain_next(tok, pos):
-    return (tok * 7 + pos * 3 + 1) % _V
 
-
-def _chain_oracle(prompt, n, eos=None):
-    out = [_chain_first(prompt)]
-    pos = len(prompt)
-    while len(out) < n and (eos is None or out[-1] != eos):
-        out.append(_chain_next(out[-1], pos))
-        pos += 1
-    if eos is not None and eos in out:
-        out = out[: out.index(eos) + 1]
-    return out
-
-
-class FakeModel:
-    def __init__(self, slots):
-        self.slots = slots
-
-    def prefill(self, padded, slot, true_len, temp, seed):
-        return _chain_first([int(t) for t in padded[0, :true_len]])
-
-    def decode(self, tok, pos, temps, seeds, n_active):
-        return np.asarray(
-            [_chain_next(int(t), int(p)) for t, p in zip(tok, pos)],
-            np.int32,
-        )
+def _chain_engine(model, slots, **kw):
+    """A real engine over the chain model, a whole prompt a chunk and
+    every row resident: a request waits for a row, never a page."""
+    return PagedEngine(
+        model.prefill_chunk, model.decode, slots, 64, 32,
+        page_tokens=model.page_tokens,
+        pages=slots * (64 // model.page_tokens), chunk_tokens=32,
+        prefix_cache=False, **kw,
+    )
 
 
 # -- affinity units ----------------------------------------------------
@@ -313,15 +291,14 @@ def test_router_operator_drain_survives_discovery_refresh():
 
 
 class EnginePod:
-    """One in-process 'serve pod': a SlotEngine over the chain model,
+    """One in-process 'serve pod': an engine over the chain model,
     dialable through a send() that can be killed mid-stream."""
 
     def __init__(self, name, slots=4):
         self.name = name
-        self.model = FakeModel(slots)
-        self.engine = SlotEngine(
-            self.model.prefill, self.model.decode, slots, 64, 32,
-            queue_timeout_s=60,
+        self.model = ChainModel()
+        self.engine = _chain_engine(
+            self.model, slots, queue_timeout_s=60
         )
         self.killed = threading.Event()
         self.admitted = 0
@@ -423,11 +400,8 @@ class HttpPod:
 
     def __init__(self, name):
         self.name = name
-        self.model = FakeModel(4)
-        self.engine = SlotEngine(
-            self.model.prefill, self.model.decode, 4, 64, 32,
-            queue_timeout_s=30,
-        )
+        self.model = ChainModel()
+        self.engine = _chain_engine(self.model, 4, queue_timeout_s=30)
         engine = self.engine
 
         class Handler(BaseHTTPRequestHandler):
@@ -573,14 +547,12 @@ def test_frontdoor_end_to_end_over_http(tmp_path):
 def test_engine_stats_age_tracks_loop_liveness():
     gate = threading.Event()  # never set: decode wedges
 
-    class WedgedModel(FakeModel):
-        def decode(self, tok, pos, temps, seeds, n_active):
+    class WedgedModel(ChainModel):
+        def decode(self, *args):
             assert gate.wait(30)
-            return super().decode(tok, pos, temps, seeds, n_active)
+            return super().decode(*args)
 
-    model = WedgedModel(2)
-    engine = SlotEngine(model.prefill, model.decode, 2, 64, 32,
-                        queue_timeout_s=60)
+    engine = _chain_engine(WedgedModel(), 2, queue_timeout_s=60)
     try:
         # idle: trivially responsive, age pinned at zero
         assert engine.stats()["stats_age_s"] == 0.0
@@ -605,9 +577,9 @@ def test_engine_stats_age_tracks_loop_liveness():
 
 
 def test_engine_extra_stats_annotation_rides_every_snapshot():
-    model = FakeModel(2)
-    engine = SlotEngine(model.prefill, model.decode, 2, 64, 32,
-                        extra_stats={"http_port": 4242})
+    engine = _chain_engine(
+        ChainModel(), 2, extra_stats={"http_port": 4242}
+    )
     try:
         assert engine.stats()["http_port"] == 4242
         engine.annotate_stats(zone="z1")

@@ -198,7 +198,7 @@ def test_inference_pod_serves_generate(tmp_path):
 
 def test_continuous_batching_merges_concurrent_clients(tmp_path):
     """SERVE_BATCH > 1: concurrent single-prompt clients — of MIXED
-    prompt lengths — share the slot pool (each rides its own slot,
+    prompt lengths — share the pool (each rides its own row,
     admitted mid-flight; per-row true_len/temperature/seed) with each
     client's own correct greedy continuation — concurrency must not
     change any answer.
@@ -330,124 +330,3 @@ def test_continuous_batching_merges_concurrent_clients(tmp_path):
         assert merged["requests_completed"] >= 7
     finally:
         agent.shutdown()
-
-
-def _load_serve_worker_module():
-    """Import serve_worker WITHOUT running main() (no jax needed:
-    model imports live inside main)."""
-    import importlib.util
-
-    path = os.path.join(REPO, "frameworks", "jax", "serve_worker.py")
-    spec = importlib.util.spec_from_file_location("serve_worker_ut", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_microbatcher_head_always_dispatches():
-    """A head whose group key never equals itself (NaN temperature)
-    must still dispatch — grouping by key equality alone would starve
-    it AND every request queued behind it until the queue timeout
-    (advisor r4).  The handler rejects NaN, so this guards the batcher
-    itself against any future non-self-equal key."""
-    import threading
-
-    sw = _load_serve_worker_module()
-    groups = []
-
-    def run_group(items):
-        groups.append(items)
-        for item in items:
-            item.result = [[0] * item.n for _ in item.rows]
-
-    batcher = sw._MicroBatcher(
-        run_group, capacity=4, window_s=0.0, queue_timeout_s=5.0
-    )
-    poison = sw._WorkItem([[1, 2]], 4, float("nan"))
-    normal = sw._WorkItem([[3, 4]], 4, 0.0)
-    threads = [
-        threading.Thread(target=batcher.submit, args=(item,))
-        for item in (poison, normal)
-    ]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(timeout=10)
-    assert poison.done.is_set(), "NaN-keyed head never dispatched"
-    assert normal.done.is_set(), "request behind the NaN head starved"
-    # the NaN item formed its own group; it never merged with normal
-    assert all(
-        len({id(i) for i in g} & {id(poison), id(normal)}) <= 1
-        or len(g) == 1
-        for g in groups
-    )
-
-
-def test_microbatcher_queue_timeout_configurable():
-    """SERVE_QUEUE_TIMEOUT_S plumbs through: a submit against a
-    wedged run_group raises after the configured timeout, not 600s."""
-    import threading
-
-    sw = _load_serve_worker_module()
-    wedge = threading.Event()
-
-    def run_group(items):
-        wedge.wait(30)  # simulate a wedged generate
-
-    batcher = sw._MicroBatcher(
-        run_group, capacity=2, window_s=0.0, queue_timeout_s=0.3
-    )
-    item = sw._WorkItem([[1]], 2, 0.0)
-    t0 = time.monotonic()
-    try:
-        batcher.submit(item)
-        raise AssertionError("submit should have timed out")
-    except RuntimeError as e:
-        assert "timed out" in str(e)
-    assert time.monotonic() - t0 < 5.0
-    wedge.set()
-
-
-def test_microbatcher_fifo_and_idle_callback():
-    """Shared-batcher liveness (advisor r5): a temp-mismatched head
-    keeps its queue position and dispatches next (no back-requeue
-    starvation), and on_idle fires between requests without stealing
-    work — the gang server's followers depend on both."""
-    import threading
-    import time as _time
-
-    from dcos_commons_tpu.utils.microbatch import MicroBatcher, WorkItem
-
-    served_groups = []
-    idle_calls = []
-
-    def run_group(items):
-        served_groups.append([item.temp for item in items])
-        for item in items:
-            item.result = [[0] * item.n for _ in item.rows]
-
-    batcher = MicroBatcher(
-        run_group, capacity=4, window_s=0.0, queue_timeout_s=5.0,
-        on_idle=lambda: idle_calls.append(1), idle_every_s=0.01,
-    )
-    deadline = _time.monotonic() + 5
-    while not idle_calls and _time.monotonic() < deadline:
-        _time.sleep(0.01)
-    assert idle_calls, "on_idle never fired while the queue was idle"
-    # an odd-temperature item arriving FIRST is served before a stream
-    # of mergeable peers that arrive behind it
-    odd = WorkItem([[1]], 2, 0.7)
-    peers = [WorkItem([[2]], 2, 0.0) for _ in range(4)]
-    threads = [
-        threading.Thread(target=batcher.submit, args=(item,))
-        for item in [odd] + peers
-    ]
-    for t in threads:
-        t.start()
-        _time.sleep(0.005)  # preserve arrival order
-    for t in threads:
-        t.join(timeout=10)
-    assert odd.done.is_set() and odd.error is None
-    assert served_groups[0][0] == 0.7, (
-        f"head lost its position: {served_groups}"
-    )
