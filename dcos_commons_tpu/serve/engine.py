@@ -27,6 +27,19 @@ sandbox, where the scheduler's ``GET /v1/debug/serving`` collects
 them per pod — the load signal ROADMAP item 2 names for scale-out
 decisions.
 
+The loop runs ONE DEVICE CALL AHEAD (ISSUE 31): a tick dispatches
+the next decode step and only then resolves the one before it, and a
+prefill chunk is fetched only when it is its prompt's last.  What a
+step needs of the step before it is known without reading that
+step's tokens (a continuing row's position is ``pos + 1``, its pages
+come from its reservation by position, and its input token is carried
+on the device), so the device always has a program queued behind the
+one that runs.  A device half that cannot carry a token on the device
+(no ``resolve_decode_fn``: the gang driver, the tests' fakes) is the
+same loop at depth 0: its step is resolved when the call returns.
+Everything that reads a row's host state or fences it first resolves
+and applies the outstanding step (``_drain``).
+
 The engine's own timeline (ISSUE 24) has one instrumentation point at
 each boundary of the loop and of a request's life, feeding three
 sinks.  ``_phase(name)`` wraps the parts of the loop thread's work
@@ -66,7 +79,9 @@ _RATE_WINDOW_S = 10.0   # tokens/s sliding window
 # where the loop thread's life goes.  ``wait`` is the loop parked on
 # its cv (and the gang's idle tick); the two ``_call`` phases are the
 # injected device callables, whose time is the device's and the
-# blocking fetch's; ``decode_prep`` / ``decode_apply`` the host's work
+# blocking fetch's (``decode_call`` holds the wait for the PREVIOUS
+# step's tokens, ``prefill_call`` the dispatch of an unfetched chunk);
+# ``decode_prep`` / ``decode_apply`` the host's work
 # on either side of the decode call, ``stats`` a ``servestats.json``
 # write.  ``other`` is every instant outside a ``with`` block:
 # admission, page IO, a chunk's padding and bookkeeping, the glue
@@ -226,6 +241,16 @@ class PagedEngine:
     (inactive and frozen rows ride a zero table: their writes land in
     the trash page and their samples are discarded).  Both run
     OUTSIDE the engine lock; only host-side bookkeeping holds it.
+
+    With ``resolve_decode_fn`` (``PagedPoolModel.resolve_decode``) the
+    device half runs one call ahead: ``prefill_chunk_fn`` is also told
+    ``final=`` and fetches only a prompt's last chunk; ``decode_fn``
+    is also given ``carry=`` (bool [S]: rows whose input token is the
+    previous step's output, still on the device) and returns the
+    PREVIOUS step's tokens, or an empty array when none was
+    outstanding; ``resolve_decode_fn()`` fetches the outstanding step
+    without dispatching another.  Without it the two callables are called as
+    above and a step is resolved when its call returns.
     """
 
     def __init__(
@@ -245,6 +270,7 @@ class PagedEngine:
         read_page: Optional[Callable] = None,
         write_page: Optional[Callable] = None,
         handoff: Optional[Callable] = None,
+        resolve_decode_fn: Optional[Callable] = None,
         queue_timeout_s: float = 600.0,
         on_idle: Optional[Callable[[], None]] = None,
         idle_every_s: float = 0.05,
@@ -259,6 +285,12 @@ class PagedEngine:
             raise ValueError(f"the pool needs >= 1 decode row, got {slots}")
         self._prefill_fn = prefill_chunk_fn
         self._decode_fn = decode_fn
+        self._resolve_fn = resolve_decode_fn
+        # decode steps dispatched and not yet applied, oldest first:
+        # each is its ``dispatched`` rows by slot.  At most one between
+        # ticks, and none without a ``resolve_decode_fn``.  Only the
+        # loop thread changes it, under the cv
+        self._inflight: deque = deque()
         self._slots = slots
         self._max_len = max_len
         self._prompt_len = prompt_len
@@ -328,6 +360,13 @@ class PagedEngine:
         self._phase_t = time.monotonic()
         self._decode_calls = 0
         self._prefill_calls = 0
+        # the loop one call ahead: decode calls dispatched while the
+        # previous call's tokens were unread, chunks dispatched with no
+        # fetch, and rows of a step whose sample was dropped because
+        # the step before ended them
+        self._decode_ahead_calls = 0
+        self._prefill_unfetched_calls = 0
+        self._ahead_discarded_rows = 0
         # what the decode calls themselves computed for: rows, and the
         # cache entries those rows read (the gauges `active_slots` /
         # `kv_live_tokens` are instants, and the second also holds rows
@@ -648,6 +687,9 @@ class PagedEngine:
         return {
             "decode_calls": self._decode_calls,
             "prefill_calls": self._prefill_calls,
+            "decode_ahead_calls": self._decode_ahead_calls,
+            "prefill_unfetched_calls": self._prefill_unfetched_calls,
+            "ahead_discarded_rows": self._ahead_discarded_rows,
             "decode_rows_sum": self._decode_rows_sum,
             "decode_entries_sum": self._decode_entries_sum,
             "window_rollovers": self._window_rollovers,
@@ -700,7 +742,7 @@ class PagedEngine:
                         if not self._has_work_locked():
                             break  # fire on_idle OUTSIDE the lock
                 if self._stopped:
-                    return
+                    break
                 idle = not self._has_work_locked()
                 if not idle:
                     flushed_idle = False  # work resumed: re-arm
@@ -728,22 +770,27 @@ class PagedEngine:
                 # client would then block its full timeout and the
                 # gang's followers would wedge in a stale collective.
                 # Fan the error out and keep the loop alive.
-                with self._cv:
-                    self._fail_all_locked(e)
+                self._fail_all(e)
+        # stopped: leave no step behind on the device half
+        with contextlib.suppress(Exception):
+            self._drain()
 
     def _has_work_locked(self) -> bool:
+        # a step still outstanding is work: the loop never parks on
+        # its cv before resolving it
         return bool(
             self._queue or self._active or self._prefilling
-            or self._page_io
+            or self._page_io or self._inflight
         )
 
     def _work_tick(self) -> None:
         """One scheduling round (loop thread, OUTSIDE the cv): page
-        IO, one chunk for every prefilling row, then one decode step
-        for every active row."""
+        IO, one chunk for every prefilling row, then the next decode
+        step for every active row and the outstanding one's tokens."""
         self._run_page_io()
         self._prefill_tick()
-        if self._active:  # loop thread is the only writer
+        # loop thread is the only writer of both
+        if self._active or self._inflight:
             self._decode_tick()
 
     def _admit_locked(self) -> None:
@@ -820,66 +867,107 @@ class PagedEngine:
         self._temps[slot] = row.temp
         self._seeds[slot] = row.seed
 
-    def _decode_prep_locked(self) -> np.ndarray:
-        """Allocate this tick's write pages and snapshot every row's
-        page table for the decode dispatch."""
-        for slot, row in enumerate(self._rows):
-            if row is None or row.group.abandoned or row.frozen:
-                # an abandoned row retires at apply; its write this
-                # tick lands in the trash page (table may miss the
-                # next page — masked, discarded).  A FROZEN row gets
-                # a zero table below: its pages must stop changing
-                # the moment the migration fence drops
-                continue
-            pos = int(self._pos[slot])
-            self._ensure_pages_locked(row, pos, pos)
-            self._count_layout_events(pos, pos)
+    def _decode_prep_locked(self):
+        """Build the next decode step from what the loop knows BEFORE
+        the outstanding step's tokens are read: allocate its write
+        pages, snapshot every row's page table, and say which rows it
+        computes for (kept as the newest of ``_inflight``).  Returns
+        ``(tok, pos, tables, carry)``, or None when no row rides a
+        next step and the outstanding one only has to be resolved.
+
+        A row that rides the outstanding step and continues takes its
+        token from that step's output on the device (``carry``), its
+        position is ``pos + 1`` and its page comes from its
+        reservation by position alone.  A row whose outstanding token
+        is its last by ``n`` or ``max_len``, or that was abandoned, is
+        left out; one that can still end by ``eos`` rides, and if it
+        did end there its sample is dropped at apply and its write
+        lands in pages freed only after it (in device order any new
+        owner writes later)."""
+        riding = self._inflight[-1] if self._inflight else None
+        tok = self._tok.copy()
+        pos = self._pos.copy()
+        carry = np.zeros(self._slots, np.bool_)
         tables = np.zeros(
             (self._slots, self._pages_per_row), np.int32
         )
+        # who this step actually computes for: a row installed into a
+        # slot AFTER this point (a splice activation or an unfreeze,
+        # both on peer threads, or a prompt's final chunk) must not
+        # be credited this step's sample — it was computed from the
+        # slot's previous state.  A FROZEN row is not dispatched and
+        # rides a zero table: its pages must stop changing the moment
+        # the migration fence drops
+        dispatched: List[Optional[_Row]] = [None] * self._slots
         for slot, row in enumerate(self._rows):
-            if row is not None and not row.frozen:
-                tables[slot] = row.table
-        return tables
+            if row is None or row.frozen:
+                continue
+            if riding is not None and riding[slot] is row:
+                if (row.group.abandoned
+                        or len(row.out) + 1 >= row.n
+                        or int(pos[slot]) + 1 >= self._max_len):
+                    tok[slot] = pos[slot] = 0  # as the empty slot it
+                    continue                   # is about to become
+                carry[slot] = True
+                pos[slot] += 1
+            at = int(pos[slot])
+            if not row.group.abandoned:
+                # an abandoned row retires at apply; its write this
+                # step lands in the trash page or a page it still
+                # holds (masked, discarded)
+                self._ensure_pages_locked(row, at, at)
+                self._count_layout_events(at, at)
+            tables[slot] = row.table
+            dispatched[slot] = row
+            self._decode_rows_sum += 1
+            self._decode_entries_sum += self._layout.entries(at)
+        if riding is not None and not any(
+            row is not None for row in dispatched
+        ):
+            return None
+        if riding is not None:
+            self._decode_ahead_calls += 1
+        self._decode_calls += 1
+        self._inflight.append(dispatched)
+        return tok, pos, tables, carry
 
     def _decode_tick(self) -> None:
+        """Dispatch the next step, then resolve the oldest outstanding
+        one: at depth 0 that is the step just dispatched."""
         with self._phase("decode_prep"), self._cv:
-            tables = self._decode_prep_locked()
+            step = self._decode_prep_locked()
             active = self._active
-            # who this tick actually computes for: a row installed
-            # into a slot AFTER this point (a splice activation or a
-            # migration-abort unfreeze, both peer threads) must not
-            # be credited this tick's sample — it was computed from
-            # the slot's previous state.  Frozen rows count as
-            # not-dispatched: their table was zeroed above, so the
-            # sample is trash even if they unfreeze mid-tick.
-            dispatched = [
-                r if (r is not None and not r.frozen) else None
-                for r in self._rows
-            ]
-            for slot, row in enumerate(dispatched):
-                if row is not None:
-                    self._decode_rows_sum += 1
-                    self._decode_entries_sum += self._layout.entries(
-                        int(self._pos[slot])
-                    )
         try:
             with self._phase("decode_call"):
-                self._decode_calls += 1
-                self._tick_rows = active
-                nxt = np.asarray(self._decode_fn(
-                    self._tok.copy(), self._pos.copy(),
-                    self._temps.copy(), self._seeds.copy(),
-                    tables, active,
-                ))
+                if step is None:
+                    nxt = self._resolve_fn()
+                else:
+                    tok, pos, tables, carry = step
+                    self._tick_rows = active
+                    ahead = (
+                        {} if self._resolve_fn is None
+                        else {"carry": carry}
+                    )
+                    nxt = self._decode_fn(
+                        tok, pos, self._temps.copy(),
+                        self._seeds.copy(), tables, active, **ahead,
+                    )
         except Exception as e:  # noqa: BLE001 — fan out, keep serving
-            with self._cv:
-                self._fail_all_locked(e)
+            self._fail_all(e)
+            return
+        self._apply_decode(nxt)
+
+    def _apply_decode(self, nxt) -> None:
+        """Apply the oldest outstanding step's tokens (loop thread,
+        outside the cv).  From a device half that runs ahead, an
+        empty ``nxt`` says it had no step to resolve yet."""
+        nxt = np.asarray(nxt)
+        if self._resolve_fn is not None and not nxt.size:
             return
         now = time.monotonic()
         merged = None
         with self._phase("decode_apply"), self._cv:
-            self._apply_decode_locked(nxt, now, dispatched)
+            self._apply_decode_locked(nxt, now, self._inflight.popleft())
             if self._active >= 2 and not self._merge_logged:
                 self._merge_logged = True
                 merged = self._active
@@ -891,24 +979,34 @@ class PagedEngine:
                 "step over the paged arena"
             )
 
+    def _drain(self) -> None:
+        """Resolve and apply the outstanding step, if there is one
+        (loop thread, outside the cv).  Everything that reads a row's
+        host state or fences it comes after this: the migration verbs
+        and queued page IO (``_on_loop``), the prefill hand-off,
+        ``_fail_all``, ``stop`` and parking on the cv (a step
+        outstanding counts as work)."""
+        if not self._inflight or self._resolve_fn is None:
+            return
+        with self._phase("decode_call"):
+            nxt = self._resolve_fn()
+        self._apply_decode(nxt)
+
     def _apply_decode_locked(self, nxt: np.ndarray, now: float,
-                             dispatched=None) -> None:
+                             dispatched) -> None:
         produced = 0
         for slot in range(self._slots):
             row = self._rows[slot]
+            if dispatched[slot] is not row:
+                # not this step's row.  The slot was filled or its row
+                # unfrozen after the step was built (its first real
+                # sample is the next step's), or the step's row is
+                # gone: the step before ended it while this one was
+                # already queued
+                if dispatched[slot] is not None:
+                    self._ahead_discarded_rows += 1
+                continue
             if row is None:
-                continue
-            if dispatched is not None and dispatched[slot] is not row:
-                # not this tick's row (installed or unfrozen mid-tick
-                # by a migration thread): its first real sample is
-                # next tick's
-                continue
-            if row.frozen:
-                # fenced for migration: this tick dispatched it with
-                # a zero (trash) table row, so the sampled token is
-                # discarded and (tok, pos) stand still — decode
-                # resumes from the exact frozen state on whichever
-                # pod ends up owning the session
                 continue
             if row.group.abandoned:
                 self._retire_locked(row)
@@ -989,6 +1087,17 @@ class PagedEngine:
             self._completed += 1
             group.done.set()
 
+    def _fail_all(self, error: BaseException) -> None:
+        """Loop thread, outside the cv: what the outstanding step
+        still produced is applied first (a row it finished is
+        answered, not failed), and no handle stays behind on the
+        device half.  An asynchronous dispatch's own error surfaces
+        at the later fetch and lands here like any other."""
+        with contextlib.suppress(Exception):
+            self._drain()
+        with self._cv:
+            self._fail_all_locked(error)
+
     def _fail_all_locked(self, error: BaseException) -> None:
         """A model-call failure fans out to every waiting, prefilling,
         parked and active request and clears the pool: every row
@@ -1009,6 +1118,7 @@ class PagedEngine:
         self._prefilling.clear()
         self._spliced.clear()
         self._rows[:] = [None] * self._slots
+        self._inflight.clear()
         self._active = 0
         self._tok[:] = 0
         self._pos[:] = 0
@@ -1056,12 +1166,16 @@ class PagedEngine:
             pass  # sdklint: disable=swallowed-exception — telemetry must never take the server down
 
     def _run_page_io(self) -> None:
-        """Drain queued migration page reads/writes (loop thread,
-        outside the cv — these are device calls like any dispatch)."""
+        """Run the queued loop jobs: migration verbs and page
+        reads/writes (loop thread, outside the cv — device calls like
+        any dispatch).  The outstanding step is resolved first: a job
+        finds every row's host state settled."""
         while True:
             with self._cv:
                 if not self._page_io:
                     return
+            self._drain()
+            with self._cv:
                 job = self._page_io.popleft()
             job()
 
@@ -1099,12 +1213,22 @@ class PagedEngine:
                 table = row.table.copy()
             padded = np.zeros((1, self._chunk_tokens), np.int32)
             padded[0, :clen] = row.tokens[start:start + clen]
+            # only a prompt's last chunk is fetched: its token is the
+            # one anybody reads.  The pages an unfetched chunk fills
+            # are published below while their write may still be
+            # queued: every later reader is a later device program
+            ahead = {}
+            if self._resolve_fn is not None:
+                ahead["final"] = start + clen >= plen
+                if not ahead["final"]:
+                    self._prefill_unfetched_calls += 1
             with self._phase("prefill_call"):
                 self._prefill_calls += 1
                 row.chunks += 1
                 first = self._prefill_fn(
                     padded, slot=row.slot, table=table, start=start,
                     true_len=clen, temp=row.temp, seed=row.seed,
+                    **ahead,
                 )
             now = time.monotonic()
             handoff_row = None
@@ -1146,6 +1270,7 @@ class PagedEngine:
             ReleasePendingError,
         )
 
+        self._drain()
         try:
             ok = self._handoff(self, row.rid)
         except ReleasePendingError:
@@ -1206,11 +1331,15 @@ class PagedEngine:
 
     # -- migration (serve/migration.py, ISSUE 16) --------------------
 
-    def _device_io(self, fn):
-        """Run a page read/write on the loop thread (the engine's one
-        device caller) and return its result.  Called FROM the loop
-        thread (prefill handoff) it runs inline; from a migration
-        thread it queues and blocks until the loop executes it."""
+    def _on_loop(self, fn):
+        """Run ``fn`` on the loop thread (the engine's one device
+        caller) BETWEEN ticks, with no decode step outstanding, and
+        return its result: page reads/writes, and the migration verbs
+        that fence a row or hand a slot over.  Called FROM the loop
+        thread (prefill handoff, which drained before it began) it
+        runs inline; from a migration thread it queues and blocks
+        until the loop executes it.  A caller that gives up waiting
+        takes its job back: a verb either ran or never will."""
         from dcos_commons_tpu.serve.migration import MigrationError
 
         if threading.current_thread() is self._thread:
@@ -1219,6 +1348,10 @@ class PagedEngine:
         box: dict = {}
 
         def job():
+            with self._cv:
+                if "cancelled" in box:
+                    return
+                box["started"] = True
             try:
                 box["result"] = fn()
             except BaseException as e:  # noqa: BLE001 — re-raised in the waiter
@@ -1232,7 +1365,13 @@ class PagedEngine:
             self._page_io.append(job)
             self._cv.notify_all()
         if not done.wait(timeout=60.0):
-            raise MigrationError("page io stalled on the engine loop")
+            with self._cv:
+                if "started" not in box:
+                    box["cancelled"] = True
+                    raise MigrationError(
+                        "page io stalled on the engine loop"
+                    )
+            done.wait()
         if "error" in box:
             raise box["error"]
         return box["result"]
@@ -1270,10 +1409,16 @@ class PagedEngine:
         return out
 
     def freeze(self, rid: int) -> None:
-        """Fence a session: decode/prefill stop at the next tick
-        boundary and its pages stop changing (the in-flight tick's
-        write is idempotent — K/V at a position is a pure function of
-        token and position — and its sampled token is discarded)."""
+        """Fence a session: the fence drops BETWEEN ticks, after the
+        outstanding decode step was resolved and applied (a step
+        queued behind it may already have overwritten a windowed
+        row's ring, so a sample in flight is never discarded): from
+        then on decode/prefill leave the row out, its pages stop
+        changing, and ``(tok, pos)`` say exactly what its pages
+        hold."""
+        self._on_loop(lambda: self._freeze(rid))
+
+    def _freeze(self, rid: int) -> None:
         from dcos_commons_tpu.serve.migration import MigrationError
 
         with self._cv:
@@ -1348,7 +1493,7 @@ class PagedEngine:
                 list(row.tokens), row.n, row.temp, row.eos, row.seed,
                 list(row.out), row.fill_pos,
             )
-        payloads = self._device_io(
+        payloads = self._on_loop(
             lambda: [(v, self._read_page(p)) for v, p in pages]
         )
         tokens, n, temp, eos, seed, out, fill_pos = meta
@@ -1367,6 +1512,9 @@ class PagedEngine:
         cache cannot serve, and PARK the row.  Nothing decodes until
         ``activate``; ``abort_splice`` undoes everything.  Returns
         the destination-local rid."""
+        return self._on_loop(lambda: self._splice(snap))
+
+    def _splice(self, snap) -> int:
         from dcos_commons_tpu.serve.migration import MigrationError
 
         if self._write_page is None:
@@ -1458,7 +1606,7 @@ class PagedEngine:
                     self._migrated.pop(old_rid, None)
             self._cv.notify_all()
         try:
-            self._device_io(lambda: [
+            self._on_loop(lambda: [
                 self._write_page(p, payload) for p, payload in imports
             ])
         except BaseException:
@@ -1471,6 +1619,9 @@ class PagedEngine:
         prompt pages it carried are published to the prefix cache
         only now — after their payloads landed (registering sooner
         would let a concurrent admission pin an unwritten page)."""
+        self._on_loop(lambda: self._activate(rid))
+
+    def _activate(self, rid: int) -> None:
         from dcos_commons_tpu.serve.migration import MigrationError
 
         with self._cv:
@@ -1506,6 +1657,9 @@ class PagedEngine:
         """Undo a splice that never activated: pages and slot return
         to the arena.  No-op when the rid is unknown (already
         activated or never spliced) — abort is best-effort."""
+        self._on_loop(lambda: self._abort_splice(rid))
+
+    def _abort_splice(self, rid: int) -> None:
         with self._cv:
             row = self._spliced.pop(rid, None)
             if row is None:

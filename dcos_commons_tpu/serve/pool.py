@@ -23,12 +23,32 @@ token-identical to whole-batch ``generate`` on the same prompts
 (tests/test_paged_kv.py holds the equivalence under arbitrary
 admission orders).
 
+Neither call blocks on a result its caller does not need yet (JAX
+dispatch is asynchronous, the device runs its queue in order, and the
+arena is threaded from call to call as a donated value, so the host
+may queue one program behind the one that runs):
+
+* ``prefill_chunk(..., final=False)`` dispatches the chunk and
+  returns ``None`` with no fetch; ``final=True`` (the default, and
+  what a prompt's last chunk asks) fetches and returns the sampled
+  first token.
+* ``decode(..., carry=mask)`` dispatches this step, taking each
+  ``carry`` row's input token from the PREVIOUS step's output where it
+  lies, on the device, then fetches and returns the previous step's
+  tokens (an EMPTY array when none was outstanding): the wait for
+  step N is spent while the device runs step N + 1.
+  ``resolve_decode()`` fetches the outstanding step without
+  dispatching another.  With ``carry=None`` the call is synchronous
+  and returns its own step's tokens (the gang driver, whose ticks
+  broadcast host arrays).
+
 Both entry points close a host span on the profiler's clock
 (``pool.prefill_chunk`` / ``pool.decode``) with an inner ``.fetch``
-around the one blocking ``device_get``: in a profile the part of a
-call before its fetch is the host dispatching the program, the fetch
-is the host waiting for the device.  Outside a profiler session a
-``TraceAnnotation`` is a flag test.
+around the blocking ``device_get`` where there is one: in a profile
+the part of a call before its fetch is the host dispatching the
+program, the fetch is the host waiting for the device (for a step
+that mostly finished while the host prepared the next).  Outside a
+profiler session a ``TraceAnnotation`` is a flag test.
 
 The gang driver reuses the class unchanged: ``put`` lifts host
 arrays to global (broadcast_one_to_all hands every rank identical
@@ -146,7 +166,11 @@ class PagedPoolModel:
             if cache_sharding is not None else contextlib.nullcontext
         )
 
-        def _decode(params, cache, tok, pos, temps, seeds, tables):
+        def _decode(params, cache, prev, carry, tok, pos, temps, seeds,
+                    tables):
+            # a row that rode the previous step reads its token where
+            # that step left it: the host has not seen it yet
+            tok = jnp.where(carry, prev, tok)
             with arena_mesh():
                 logits, cache = paged_decode_step(
                     config, params, cache, tok, pos, tables
@@ -166,17 +190,28 @@ class PagedPoolModel:
         self._prefill_c = jax.jit(_prefill, **donate)
         self._decode_c = jax.jit(_decode, **donate)
         self._jnp = jnp
+        # the tokens of a step dispatched ahead that nobody has fetched
+        # yet, as the device holds them: what a carried row reads.
+        # Where there is none the program is still handed tokens, of
+        # the same kind (a device value), so that it is traced once
+        self._outstanding = None
+        self._no_tokens = jax.jit(
+            lambda: con(jnp.zeros(slots, jnp.int32))
+        )()
+        self._no_carry = np.zeros(slots, np.bool_)
 
     def prefill_chunk(
         self, tokens: np.ndarray, slot: int, table: np.ndarray,
         start: int, true_len: int, temp: float, seed: int,
-    ) -> int:
+        final: bool = True,
+    ) -> Optional[int]:
         """Run one [1, chunk_tokens] prompt chunk at virtual positions
-        [start, start + true_len) through ``table``; returns the
-        sampled token at the chunk's last real position (meaningful
-        only on the prompt's final chunk).  ``slot`` is the engine's
-        row id — a protocol rider (the gang driver broadcasts it), the
-        math needs only the table."""
+        [start, start + true_len) through ``table``.  On a prompt's
+        ``final`` chunk, returns the token sampled at its last real
+        position; any other chunk is dispatched and NOT fetched
+        (returns None): the host goes on while the device writes.
+        ``slot`` is the engine's row id — a protocol rider (the gang
+        driver broadcasts it), the math needs only the table."""
         del slot
         with self._span("pool.prefill_chunk"):
             first, self.cache = self._prefill_c(
@@ -186,6 +221,12 @@ class PagedPoolModel:
                 np.int32(start), np.int32(true_len),
                 np.float32(temp), np.int32(seed),
             )
+            if not final:
+                # nobody reads this chunk's sample, and its writes
+                # need no fence: whatever reads these pages later (a
+                # chunk or decode step sharing them, export_page) is
+                # a later program on the same in-order queue
+                return None
             with self._span("pool.prefill_chunk.fetch"):
                 return int(self._jax.device_get(first))
 
@@ -193,25 +234,65 @@ class PagedPoolModel:
         self, tok: np.ndarray, pos: np.ndarray,
         temps: np.ndarray, seeds: np.ndarray,
         tables: np.ndarray, n_active: Optional[int] = None,
+        carry: Optional[np.ndarray] = None,
     ) -> np.ndarray:
-        """One decode step over the whole pool through per-row page
-        tables; returns next tokens [slots] (inactive rows' outputs
-        are discarded by the engine).  ``n_active`` is the engine's
-        bookkeeping rider (the gang driver stamps it into the
-        broadcast head); the computation always covers every slot —
-        static shapes.  ONE bulk device fetch — per-element reads are
-        a transfer each."""
+        """Dispatch one decode step over the whole pool through
+        per-row page tables (inactive rows' outputs are discarded by
+        the engine).  ``n_active`` is the engine's bookkeeping rider
+        (the gang driver stamps it into the broadcast head); the
+        computation always covers every slot — static shapes.
+
+        ``carry=None``: synchronous.  Returns THIS step's next tokens
+        [slots] after ONE bulk device fetch — per-element reads are a
+        transfer each.
+
+        ``carry`` a bool [slots]: a row it marks takes its input token
+        from the previous step's output on the device (``tok`` there
+        is ignored), so this step is queued before anybody has read
+        that output.  Then the PREVIOUS step's tokens are fetched,
+        while the device runs this one, and returned; an empty array
+        when no step was outstanding.  This step's stay on the device
+        for the next call, or ``resolve_decode``."""
         with self._span("pool.decode"):
+            ahead = carry is not None
+            previous = self._outstanding
+            if not ahead and previous is not None:
+                raise RuntimeError(
+                    "synchronous decode with a step outstanding: "
+                    "resolve_decode() first"
+                )
             nxt, self.cache = self._decode_c(
                 self.params, self.cache,
+                self._no_tokens if previous is None else previous,
+                self._put(np.asarray(carry, np.bool_) if ahead
+                          else self._no_carry),
                 self._put(np.asarray(tok, np.int32)),
                 self._put(np.asarray(pos, np.int32)),
                 self._put(np.asarray(temps, np.float32)),
                 self._put(np.asarray(seeds, np.int32)),
                 self._put(np.asarray(tables, np.int32)),
             )
+            self._outstanding = nxt if ahead else None
+            return self._fetch(previous if ahead else nxt)
+
+    def resolve_decode(self) -> np.ndarray:
+        """Fetch the outstanding step's tokens without dispatching
+        another; an empty array when nothing is outstanding."""
+        with self._span("pool.decode"):
+            previous, self._outstanding = self._outstanding, None
+            return self._fetch(previous)
+
+    def _fetch(self, tokens) -> np.ndarray:
+        if tokens is None:
+            return np.zeros(0, np.int32)
+        try:
             with self._span("pool.decode.fetch"):
-                return np.asarray(self._jax.device_get(nxt))
+                return np.asarray(self._jax.device_get(tokens))
+        except BaseException:
+            # an asynchronous dispatch's error surfaces here: whatever
+            # was queued behind it is lost with it
+            self._outstanding = None
+            raise
 
     def export_page(self, page: int) -> dict:
         """Snapshot one physical page as host numpy, every cache key
@@ -245,21 +326,34 @@ class PagedPoolModel:
                 self._jnp.asarray(payload[key], arr.dtype)
             )
 
-    def warm(self) -> None:
-        """Compile + execute both entry points before readiness.  All
+    def warm(self, ahead: bool = True) -> None:
+        """Compile + execute both entry points before readiness, in
+        every way the engine loop will call them, so that nothing is
+        traced under traffic: a chunk unfetched and fetched, a decode
+        step dispatched behind one still unread (the carried tokens
+        that step's own output, a device value) and the resolve.
+        ``ahead=False`` warms the synchronous calls alone: the gang
+        driver's, whose ticks are resolved as they return.  All
         tables are zero, so every write lands in the trash page and
         every gather is masked — warmup leaves no residue a real
         request could attend to."""
-        self.prefill_chunk(
-            np.zeros((1, self.chunk_tokens), np.int32), slot=0,
-            table=np.zeros(self.pages_per_row, np.int32),
-            start=0, true_len=self.chunk_tokens, temp=0.0, seed=0,
-        )
-        out = self.decode(
+        for final in (False, True) if ahead else (True,):
+            self.prefill_chunk(
+                np.zeros((1, self.chunk_tokens), np.int32), slot=0,
+                table=np.zeros(self.pages_per_row, np.int32),
+                start=0, true_len=self.chunk_tokens, temp=0.0, seed=0,
+                final=final,
+            )
+        step = (
             np.zeros(self.slots, np.int32),
             np.zeros(self.slots, np.int32),
             np.zeros(self.slots, np.float32),
             np.zeros(self.slots, np.int32),
             np.zeros((self.slots, self.pages_per_row), np.int32),
         )
-        self._jax.block_until_ready(out)
+        if not ahead:
+            self.decode(*step)
+            return
+        for carry in (self._no_carry, ~self._no_carry):
+            self.decode(*step, carry=carry)
+        self.resolve_decode()

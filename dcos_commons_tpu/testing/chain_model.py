@@ -9,6 +9,7 @@ The engine, router and migration tests all drive this one fake.
 """
 
 import threading
+import time
 
 import numpy as np
 
@@ -83,6 +84,79 @@ class ChainModel:
             [chain_next(int(t), int(p)) for t, p in zip(tok, pos)],
             np.int32,
         )
+
+
+class OneAhead:
+    """Any of the synchronous fakes behind the protocol of a device
+    half that runs one call ahead (``serve.pool.PagedPoolModel``):
+    ``prefill_chunk(..., final=)`` hands its token over only on a
+    prompt's last chunk, ``decode(..., carry=)`` takes a carried
+    row's token from the step before it and returns THAT step's
+    tokens (none outstanding: an empty array), ``resolve_decode()``
+    hands over the outstanding step.  The host never sees a step's
+    tokens before it resolved it.
+
+    ``log`` records, in the order the engine made them, ("chunk",
+    slot, start, fetched), ("dispatch", step) and ("resolve", step);
+    hand the engine ``engine_kwargs()`` beside the two callables."""
+
+    def __init__(self, model):
+        self.model = model
+        self.log = []
+        self.steps = 0
+        self._outstanding = None  # the unresolved step's tokens
+
+    def engine_kwargs(self):
+        return {"resolve_decode_fn": self.resolve_decode}
+
+    def prefill_chunk(self, padded, slot, table, start, true_len,
+                      temp, seed, final=True):
+        first = self.model.prefill_chunk(
+            padded, slot, table, start, true_len, temp, seed
+        )
+        self.log.append(("chunk", slot, start, bool(final)))
+        return first if final else None
+
+    def decode(self, tok, pos, temps, seeds, tables, n_active, carry):
+        carry = np.asarray(carry, bool)
+        if carry.any():
+            assert self._outstanding is not None, (
+                "a carried token with no step outstanding"
+            )
+            tok = np.where(carry, self._outstanding, tok)
+        self.log.append(("dispatch", self.steps))
+        previous = self._take()
+        self._outstanding = np.asarray(
+            self.model.decode(tok, pos, temps, seeds, tables, n_active)
+        )
+        self.steps += 1
+        return previous
+
+    def resolve_decode(self):
+        return self._take()
+
+    def _take(self):
+        previous, self._outstanding = self._outstanding, None
+        if previous is None:
+            return np.zeros(0, np.int32)
+        self.log.append(("resolve", self.steps - 1))
+        return previous
+
+
+def settled_stats(engine, timeout=10.0):
+    """``engine.stats()`` once the loop has nothing left to do.  A
+    client is answered when its last token is applied; a step that
+    was queued behind that one (a row ended by ``eos``) is resolved
+    and counted a moment later."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        with engine._cv:
+            if not engine._has_work_locked():
+                break
+        time.sleep(0.001)
+    else:
+        raise AssertionError("the engine loop never came to rest")
+    return engine.stats()
 
 
 def swarm(engine, jobs):

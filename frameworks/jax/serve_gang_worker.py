@@ -243,8 +243,10 @@ def main() -> int:
         # warm the compiled pool as a GANG before readiness: the first
         # request must not pay the compiles, and a rank that cannot
         # compile must fail deploy, not the first client.  Every rank
-        # reaches this call at the same program point (pre-loop).
-        pool.warm()
+        # reaches this call at the same program point (pre-loop).  The
+        # gang's ticks broadcast host arrays and are resolved as they
+        # return: the synchronous calls are the ones to warm
+        pool.warm(ahead=False)
         pages_per_row = paged.pages_per_row
         chunk_tokens = paged.chunk_tokens
 

@@ -447,6 +447,9 @@ def main() -> int:
         queue_timeout_s=queue_timeout_s, stats_path=stats_path,
         role=role, read_page=pool.export_page,
         write_page=pool.import_page, handoff=handoff,
+        # this device half carries a token on the device, so the
+        # loop runs one call ahead of it
+        resolve_decode_fn=pool.resolve_decode,
         log=lambda msg: print(msg, flush=True),
         extra_stats={"http_port": bound_port},
         annotate=jax.profiler.TraceAnnotation, tracer=tracer,

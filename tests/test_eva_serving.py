@@ -20,6 +20,7 @@ import pytest
 from dcos_commons_tpu.serve.engine import PagedEngine
 from dcos_commons_tpu.serve.migration import (
     InProcessTransport,
+    MigrationError,
     SessionMigratedError,
     migrate_session,
 )
@@ -29,6 +30,7 @@ from dcos_commons_tpu.serve.paging import (
     paged_config_from_env,
     worst_case_pages,
 )
+from dcos_commons_tpu.testing.chain_model import settled_stats
 
 WINDOW, CHUNK = 32, 4
 MAX_LEN = 160
@@ -365,7 +367,7 @@ def test_config_from_a_file_wins_over_the_size_names(tmp_path):
 
 
 def _pod(config, params, slots=3, pages=80, chunk_tokens=8, step_s=0.0,
-         prefix=True):
+         prefix=True, ahead=False):
     from dcos_commons_tpu.serve.pool import PagedPoolModel
 
     pool = PagedPoolModel(
@@ -383,6 +385,8 @@ def _pod(config, params, slots=3, pages=80, chunk_tokens=8, step_s=0.0,
         page_tokens=CHUNK, pages=pages, chunk_tokens=chunk_tokens,
         prefix_cache=prefix, layout=pool.layout, queue_timeout_s=120,
         read_page=pool.export_page, write_page=pool.import_page,
+        # the loop one call ahead of the pool (ISSUE 31)
+        **({"resolve_decode_fn": pool.resolve_decode} if ahead else {}),
     )
     return pool, engine
 
@@ -394,19 +398,24 @@ def _private_pages(engine):
         return [p for r in rows for p in r.private_pages]
 
 
-def test_engine_serves_rows_across_windows_with_ring_reuse(toy):
+@pytest.mark.parametrize("ahead", [False, True],
+                         ids=["sync", "one-ahead"])
+def test_engine_serves_rows_across_windows_with_ring_reuse(toy, ahead):
     """Three rows in flight, each crossing one to three window ends:
     the engine's tables (ring pages reused in place, summary pages
     appended) give the tokens of the row served by hand, the
     allocator's invariants hold while rows are live and after, and the
-    counters count what happened."""
+    counters count what happened; one call ahead (a window's first
+    position queued before its last one's token is read) as
+    synchronously."""
     config, params = toy
     jobs = [(_prompt(40, 1), 30), (_prompt(70, 2), 40), (_prompt(9, 3), 45)]
     # the first n - 1 tokens of each answer, served by hand
     want = [
         _served(config, params, p, n, 8)[0][len(p):] for p, n in jobs
     ]
-    _pool, engine = _pod(config, params, step_s=0.002, prefix=False)
+    _pool, engine = _pod(config, params, step_s=0.002, prefix=False,
+                         ahead=ahead)
     try:
         results, errors = [None] * len(jobs), []
 
@@ -462,6 +471,106 @@ def test_engine_serves_rows_across_windows_with_ring_reuse(toy):
             LAYOUT.entries(pos)
             for p, n in jobs for pos in range(len(p), len(p) + n - 1)
         )
+        # every end was known by ``n`` before it was read
+        assert loop["ahead_discarded_rows"] == 0
+        assert bool(loop["decode_ahead_calls"]) == ahead
+        assert bool(loop["prefill_unfetched_calls"]) == ahead
+    finally:
+        engine.stop()
+
+
+def _held_to_the_reference(params, prompt, out):
+    """The served tokens are the reference's own first choices, read
+    teacher-forced over the whole sequence (where its first choice
+    leads by more than the rounding between the two)."""
+    want = _reference(params, prompt + out[:-1], len(prompt) - 1)
+    assert want.shape[0] == len(out)
+    top = np.sort(want, axis=-1)
+    clear = top[:, -1] - top[:, -2] > 10 * TOLERANCE
+    assert clear.mean() > 0.9
+    assert (np.argmax(want, axis=-1) == np.asarray(out))[clear].all()
+
+
+def test_fences_while_a_window_ends_one_ahead_match_the_reference(toy):
+    """A row is frozen, exported and released again and again while it
+    crosses three window ends one call ahead.  A step queued behind
+    the one in flight may already have written a new window's first
+    position over the ring: so a fence waits for what is in flight and
+    applies it, and the tokens stay the float32 reference's."""
+    config, params = toy
+    prompt, n = _prompt(44, 11), 100
+    _pool, engine = _pod(config, params, step_s=0.001, prefix=False,
+                         ahead=True)
+    try:
+        result = {}
+        t = threading.Thread(target=lambda: result.update(
+            out=engine.submit([prompt], n)[0]
+        ))
+        t.start()
+        fences, ends_seen = 0, set()
+        while t.is_alive():
+            sess = engine.sessions()
+            if not sess or sess[0]["state"] != "decode":
+                time.sleep(0.001)
+                continue
+            rid = sess[0]["rid"]
+            try:
+                engine.freeze(rid)
+                snap = engine.export_frozen(rid)
+            except MigrationError:  # the row finished first
+                break
+            assert snap.kv_end == len(prompt) + len(snap.out) - 1
+            assert sorted(v for v, _ in snap.pages) == LAYOUT.live_slots(
+                snap.kv_end
+            )
+            ends_seen.add(snap.kv_end // WINDOW)
+            fences += 1
+            engine.unfreeze(rid)
+            time.sleep(0.004)
+        t.join(timeout=60)
+        out = result["out"]
+        assert len(out) == n and fences >= 5 and len(ends_seen) >= 3
+        _held_to_the_reference(params, prompt, out)
+        stats = engine.stats()
+        assert stats["loop"]["ahead_discarded_rows"] == 0
+        assert stats["loop"]["window_rollovers"] == LAYOUT.rollovers(
+            0, len(prompt) + n - 2
+        )
+        engine._allocator.check_invariants()
+    finally:
+        engine.stop()
+
+
+def test_eos_at_a_window_end_with_the_next_window_already_queued(toy):
+    """The row's last token is read while the step behind it, the
+    first position of the NEXT window, is queued: that step writes
+    over the ring's first page and is dropped.  The answer is cut at
+    the eos, the pages come home, and the next occupant of the one
+    slot and of those pages serves the reference's tokens."""
+    config, params = toy
+    prompt = _prompt(40, 21)
+    full = _served(config, params, prompt, 40, 8)[0][len(prompt):]
+    j = 2 * WINDOW - len(prompt)        # the token at position 64
+    eos = full[j]
+    assert eos not in full[:j]
+    _pool, engine = _pod(config, params, slots=1, pages=30, prefix=False,
+                         ahead=True)
+    try:
+        free0 = engine.stats()["kv_pages_free"]
+        got = engine.submit([prompt], 40, eos_id=eos)[0]
+        assert got == full[:j + 1]
+        stats = settled_stats(engine)
+        assert stats["loop"]["ahead_discarded_rows"] == 1
+        # the dropped step crossed the window's end
+        assert stats["loop"]["window_rollovers"] == LAYOUT.rollovers(
+            0, len(prompt) + j
+        )
+        assert stats["kv_pages_free"] == free0
+        after = _prompt(50, 22)
+        out = engine.submit([after], 30)[0]
+        _held_to_the_reference(params, after, out)
+        engine._allocator.check_invariants()
+        assert engine.stats()["kv_pages_free"] == free0
     finally:
         engine.stop()
 
@@ -547,16 +656,19 @@ def test_export_then_import_mid_window_reproduces_the_next_logits(toy):
         nxt = int(np.argmax(a))
 
 
-def test_migration_of_a_windowed_row_between_engines(toy):
+@pytest.mark.parametrize("ahead", [False, True],
+                         ids=["sync", "one-ahead"])
+def test_migration_of_a_windowed_row_between_engines(toy, ahead):
     """The fenced cutover protocol over an EVA row past its first
     window: the destination finishes the source's own continuation, the
     snapshot carries no dead ring page, and a pool of another layout
-    refuses it."""
+    refuses it.  One call ahead the fence finds a step in flight on
+    the source, and the destination resumes one call ahead."""
     config, params = toy
     prompt, n = _prompt(50, 7), 40
     want = _served(config, params, prompt, n, 8)[0][len(prompt):]
-    _sp, src = _pod(config, params, step_s=0.01)
-    _dp, dst = _pod(config, params)
+    _sp, src = _pod(config, params, step_s=0.01, ahead=ahead)
+    _dp, dst = _pod(config, params, ahead=ahead)
     other = PagedEngine(
         lambda *a, **k: 0, lambda *a, **k: np.zeros(3, np.int32), 3,
         MAX_LEN, MAX_LEN - 48, page_tokens=CHUNK, pages=80, chunk_tokens=8,

@@ -55,6 +55,7 @@ from dcos_commons_tpu.serve.migration import (
 )
 
 from dcos_commons_tpu.testing.chain_model import (
+    OneAhead,
     V as _V,
     chain_first as _chain_first,
     chain_next as _chain_next,
@@ -127,15 +128,36 @@ class ChainArena:
             self.cells[page] = dict(payload)
 
 
+# How far ahead of its device half an engine of this module runs: 0,
+# a step is resolved when its call returns; 1 (ISSUE 31), the next step
+# is dispatched before the one before it is read, so every fence below
+# finds a step in flight.  The protocol's tests take ``depth`` and run
+# at both: what they hold — where a row stands when its fence drops,
+# what its pages hold, what the client reads — is the same.
+_DEPTH = 0
+
+
+@pytest.fixture(params=[0, 1], ids=["sync", "one-ahead"])
+def depth(request):
+    global _DEPTH
+    _DEPTH = request.param
+    yield request.param
+    _DEPTH = 0
+
+
 def _make_pod(role="unified", handoff=None, pages=40, slots=3,
-              step_s=0.004):
-    arena = ChainArena(step_s=step_s)
+              step_s=0.004, arena=None):
+    arena = arena if arena is not None else ChainArena(step_s=step_s)
+    half, ahead = arena, {}
+    if _DEPTH:
+        half = OneAhead(arena)
+        ahead = half.engine_kwargs()
     eng = PagedEngine(
-        arena.prefill_chunk, arena.decode, slots, 64, 48,
+        half.prefill_chunk, half.decode, slots, 64, 48,
         page_tokens=P, pages=pages, chunk_tokens=8, prefix_cache=True,
         role=role, read_page=arena.read_page,
         write_page=arena.write_page, handoff=handoff,
-        queue_timeout_s=30,
+        queue_timeout_s=30, **ahead,
     )
     return arena, eng
 
@@ -195,7 +217,7 @@ def test_snapshot_wire_roundtrip_is_json_safe():
 # -- the protocol ------------------------------------------------------
 
 
-def test_mid_generation_migration_greedy_equal():
+def test_mid_generation_migration_greedy_equal(depth):
     """The tentpole contract: freeze mid-decode, move, and the
     destination finishes the EXACT oracle continuation — zero tokens
     lost, none doubled — while both arenas stay invariant-clean and
@@ -239,7 +261,7 @@ def test_mid_generation_migration_greedy_equal():
 
 @pytest.mark.parametrize("stage", ["snapshot", "stream", "splice",
                                    "cutover"])
-def test_chaos_kill_before_cutover_resumes_source(stage):
+def test_chaos_kill_before_cutover_resumes_source(stage, depth):
     """A death at any PRE-cutover boundary aborts cleanly: the
     destination keeps nothing, the source resumes exactly where it
     froze, and the client's reply is the untouched oracle — the
@@ -280,7 +302,7 @@ def test_chaos_kill_before_cutover_resumes_source(stage):
         dst.stop()
 
 
-def test_chaos_kill_at_release_is_exactly_once():
+def test_chaos_kill_at_release_is_exactly_once(depth):
     """The worst boundary: cutover landed, release died.  The source
     must NOT resume (that would double-decode); the only legal
     continuation is retrying the release — after which the client is
@@ -327,6 +349,115 @@ def test_chaos_kill_at_release_is_exactly_once():
         dst.stop()
 
 
+def _frozen_state(eng, rid):
+    """(tok, pos, out) of a fenced decoding row, as the loop holds
+    them."""
+    with eng._cv:
+        row = eng._find_rid_locked(rid)
+        assert row is not None and row.frozen
+        assert eng._rows[row.slot] is row
+        # whatever the loop has in flight, this row does not ride it
+        assert all(step[row.slot] is None for step in eng._inflight)
+        return (
+            int(eng._tok[row.slot]), int(eng._pos[row.slot]),
+            list(row.out),
+        )
+
+
+def test_a_fence_finds_its_row_settled(depth):
+    """Freeze, export and unfreeze one session again and again while
+    it decodes (at depth 1 every fence meets a step in flight): when
+    the fence has dropped ``(tok, pos)`` say exactly what the row's
+    pages hold, nothing moves until it is lifted, no sample is lost,
+    and the client reads the oracle."""
+    arena, src = _make_pod()
+    try:
+        prompt, n = list(range(3, 14)), 48
+        plen = len(prompt)
+        result = {}
+        t = _submit_async(src, prompt, n, result)
+        rid = _wait_mid_decode(src, min_out=3)
+        fences = 0
+        while t.is_alive() and fences < 6:
+            try:
+                src.freeze(rid)
+            except MigrationError:
+                break  # the session finished first
+            tok, pos, out = _frozen_state(src, rid)
+            assert (tok, pos) == (out[-1], plen + len(out) - 1)
+            snap = src.export_frozen(rid)
+            assert snap.out == out and snap.kv_end == pos
+            seq = prompt + out
+            held = {
+                v * P + off: cell
+                for v, cells in snap.pages for off, cell in cells.items()
+            }
+            # every position behind the row's next one, and what the
+            # sequence has there: the step in flight at the fence was
+            # applied, not dropped, so nothing is written ahead of
+            # ``pos`` that ``out`` does not account for
+            assert {q: held[q] for q in range(pos)} == dict(
+                enumerate(seq[:pos])
+            )
+            time.sleep(0.02)  # five steps' worth: a fenced row stands
+            assert _frozen_state(src, rid) == (tok, pos, out)
+            src.unfreeze(rid)
+            fences += 1
+            time.sleep(0.01)
+        assert fences >= 2
+        t.join(timeout=15)
+        assert result["r"] == [_chain_oracle(prompt, n)]
+        src._allocator.check_invariants()
+        assert src.stats()["loop"]["ahead_discarded_rows"] == 0
+    finally:
+        src.stop()
+
+
+@pytest.mark.parametrize("end", ["activate", "abort"])
+def test_splice_into_a_destination_that_is_decoding(depth, end):
+    """The destination's own request has a step in flight while a
+    session is spliced in and then activated, or aborted at cutover:
+    both requests read their oracle either way, and an abort leaves
+    the destination's arena as it was."""
+    _sa, src = _make_pod()
+    _da, dst = _make_pod()
+    try:
+        own_prompt, own_n = [8, 8, 2, 1], 44
+        own = {}
+        t_own = _submit_async(dst, own_prompt, own_n, own)
+        _wait_mid_decode(dst, min_out=2)
+        prompt, n = [3, 1, 4, 1, 5, 9, 2, 6], 30
+        result = {}
+        t = _submit_async(src, prompt, n, result)
+        rid = _wait_mid_decode(src)
+
+        def chaos(at):
+            if end == "abort" and at == "cutover":
+                raise RuntimeError("killed at cutover")
+
+        if end == "abort":
+            with pytest.raises(RuntimeError, match="cutover"):
+                migrate_session(src, dst, rid, dest_name="dst",
+                                chaos=chaos)
+            t.join(timeout=15)
+            assert result["r"] == [_chain_oracle(prompt, n)]
+            assert dst.stats()["migrations_in"] == 0
+        else:
+            record = migrate_session(src, dst, rid, dest_name="dst")
+            assert record.ok
+            t.join(timeout=15)
+            out = dst.collect(result["r"].dest_rid, timeout=20)
+            assert out == _chain_oracle(prompt, n)
+        t_own.join(timeout=15)
+        assert own["r"] == [_chain_oracle(own_prompt, own_n)]
+        for pod in (src, dst):
+            pod._allocator.check_invariants()
+            assert pod.sessions() == []
+    finally:
+        src.stop()
+        dst.stop()
+
+
 # -- splice transactionality (hypothesis) ------------------------------
 
 
@@ -366,7 +497,7 @@ def _engine_private_pages(eng):
         return [p for r in rows for p in r.private_pages]
 
 
-def test_splice_preserves_allocator_invariants():
+def test_splice_preserves_allocator_invariants(depth):
     """Property: any sequence of splice/abort against a pod under
     arbitrary fabricated-session geometry preserves the allocator
     invariants at EVERY step, and a full abort pass restores the free
@@ -413,7 +544,7 @@ def test_splice_preserves_allocator_invariants():
     run()
 
 
-def test_splice_abort_sweep_restores_arena():
+def test_splice_abort_sweep_restores_arena(depth):
     """Deterministic complement to the hypothesis property (runs even
     where hypothesis is absent): a seeded sweep of splice/abort under
     varied geometry and arena pressure leaves zero residue."""
@@ -451,7 +582,7 @@ def test_splice_abort_sweep_restores_arena():
             pod.stop()
 
 
-def test_splice_rejects_incompatible_snapshots_cleanly():
+def test_splice_rejects_incompatible_snapshots_cleanly(depth):
     _a, pod = _make_pod(pages=10)
     try:
         free0 = pod.stats()["kv_pages_free"]
@@ -480,7 +611,7 @@ def test_splice_rejects_incompatible_snapshots_cleanly():
 # -- drain-with-migration ----------------------------------------------
 
 
-def test_drain_sessions_moves_every_live_session():
+def test_drain_sessions_moves_every_live_session(depth):
     _sa, src = _make_pod()
     _d1, dst_big = _make_pod(pages=40)
     _d2, dst_small = _make_pod(pages=12)
@@ -528,7 +659,7 @@ def test_drain_sessions_moves_every_live_session():
         dst_small.stop()
 
 
-def test_drain_with_no_viable_destination_resumes_sessions():
+def test_drain_with_no_viable_destination_resumes_sessions(depth):
     """A drain that cannot place a session reports ok=False and the
     legacy wait-out covers it — migration never strands a client."""
     _sa, src = _make_pod()
@@ -733,7 +864,7 @@ def test_quiet_watcher_ignores_prefill_idle_decode_gauges():
 # -- prefill/decode disaggregation -------------------------------------
 
 
-def test_prefill_handoff_streams_finished_pages_to_decode_pool():
+def test_prefill_handoff_streams_finished_pages_to_decode_pool(depth):
     pods = {}
     handoff = PrefillHandoff(lambda: pods)
     _pa, prefill = _make_pod(role="prefill", handoff=handoff)
@@ -762,7 +893,7 @@ def test_prefill_handoff_streams_finished_pages_to_decode_pool():
         decode_b.stop()
 
 
-def test_prefill_pod_degrades_to_local_decode_without_pool():
+def test_prefill_pod_degrades_to_local_decode_without_pool(depth):
     """No decode pod answers: the handoff falls back and the prefill
     pod decodes locally — disaggregation degrades to unified, never
     to a failed request."""

@@ -228,5 +228,5 @@ def test_a_pool_laid_over_a_tp_mesh_decodes_through_the_gather_path(
                 mesh, P(None, None, None, "tp", None)
             ),
         )
-        pool.warm()
+        pool.warm(ahead=False)  # as the gang worker warms it
     assert chosen == [None]
