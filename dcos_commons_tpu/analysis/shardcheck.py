@@ -404,7 +404,10 @@ def _mnist_profile(env, tpu, pod, task) -> Workload:
 def _serve_leaves(env, mesh_total_tp: int) -> Tuple[Any, List[AbstractLeaf]]:
     import jax
 
-    from dcos_commons_tpu.models.decode import init_paged_kv_cache
+    from dcos_commons_tpu.models.decode import (
+        init_paged_kv_cache,
+        whole_lanes,
+    )
     from dcos_commons_tpu.models.transformer import config_from_env
     from dcos_commons_tpu.serve.paging import paged_config_from_env
 
@@ -433,7 +436,9 @@ def _serve_leaves(env, mesh_total_tp: int) -> Tuple[Any, List[AbstractLeaf]]:
     slots = paged.slots
     cache_shapes = jax.eval_shape(functools.partial(
         init_paged_kv_cache, config, paged.arena_pages,
-        paged.page_tokens, env.get("KV_DTYPE", "native"),
+        paged.page_tokens, env.get("KV_DTYPE", "native"), slots,
+        # as the chip holds it: entries in whole 128-lane rows
+        whole_lanes(config.head_dim),
     ))
     # cache dims (layers, pages, tokens, kv_heads, head_dim): heads
     # ride tp like the attention weights when divisible (the gang
@@ -444,7 +449,10 @@ def _serve_leaves(env, mesh_total_tp: int) -> Tuple[Any, List[AbstractLeaf]]:
         mesh_total_tp > 1 and config.n_kv_heads % mesh_total_tp == 0
     )
     kv_spec = {
-        name: ((), (), (), ("tp",) if kv_sharded else (), ())
+        # what a row keeps outside its pages (layers, slots, taps - 1,
+        # d_model) is one chip's: the gang refuses such a pattern
+        name: ((), (), (), ()) if name == "conv_state"
+        else ((), (), (), ("tp",) if kv_sharded else (), ())
         for name in cache_shapes
     }
     leaves += _walk_shapes(cache_shapes, kv_spec, "kv")

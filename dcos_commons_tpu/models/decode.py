@@ -22,7 +22,6 @@ drop-free server by construction.
 
 from __future__ import annotations
 
-import contextlib
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -32,11 +31,13 @@ from jax import lax
 from dcos_commons_tpu.models.quantize import dequantize_weight as dq
 from dcos_commons_tpu.models.transformer import (
     TransformerConfig,
-    _ffn_block,
+    _mlp_block,
     _norm,
     _rope,
     head_logits,
+    moe_config_of,
 )
+from dcos_commons_tpu.ops.rmsnorm import rms_norm
 
 Params = Dict[str, Any]
 _NEG = -1e30
@@ -64,13 +65,34 @@ def init_kv_cache(
     }
 
 
+def whole_lanes(head_dim: int) -> int:
+    """``head_dim`` rounded up to whole 128-lane rows."""
+    return -(-head_dim // 128) * 128
+
+
+def arena_lanes(config: TransformerConfig) -> int:
+    """The minor dimension of the arena's entries: ``head_dim``, but on
+    a TPU whole 128-lane rows (a head of 64 is stored in the lower half
+    of a row of 128, the upper half zeros).  The device pads the minor
+    dimension to 128 lanes whatever the shape says; said in the shape,
+    the arena keeps its row-major layout across calls (left to itself
+    the compiler lays ``[.., 8, 64]`` out pages-minor and copies the
+    whole arena in and out of every call) and the page walk of
+    ops/paged_decode.py can copy a page (Mosaic refuses a slice of 64
+    of 128 lanes).  Zeros add nothing to a score or to an output."""
+    if jax.default_backend() != "tpu":
+        return config.head_dim
+    return whole_lanes(config.head_dim)
+
+
 def init_paged_kv_cache(
     config: TransformerConfig, n_pages: int, page_tokens: int,
-    kv_dtype: str = "native",
+    kv_dtype: str = "native", slots: int = 0, lanes: int = 0,
 ) -> Dict[str, jax.Array]:
     """The paged arena: K/V stored as fixed-size pages instead of
-    per-request rows.  Shape [n_layers, n_pages, page_tokens,
-    n_kv_heads, head_dim]; a request's virtual position ``p`` lives at
+    per-request rows.  Shape [n_attention_layers, n_pages, page_tokens,
+    n_kv_heads, head_dim]: only a layer whose operator is attention
+    owns pages.  A request's virtual position ``p`` lives at
     ``(table[p // page_tokens], p % page_tokens)`` through its page
     table.  Page 0 is the TRASH page (serve/paging.py): padding and
     inactive-row writes land there, and table entry 0 also means
@@ -82,34 +104,55 @@ def init_paged_kv_cache(
     both have this one shape, so one arena and one layer scan hold
     both kinds.
 
+    Beside the pages, what a row keeps that no page holds: where the
+    pattern has conv layers, ``conv_state [n_conv_layers, slots,
+    conv_l_cache - 1, d_model]``, a row's last gated inputs a conv
+    layer, by the row's SLOT and however long the row (zeroed inside
+    the first chunk's program of whoever is admitted to the slot).
+
     Same dict keys as ``init_kv_cache`` (int8 adds per-vector scales),
     so ``kv_dtype`` handling and sharding rules carry over: dims are
-    (layers, pages, page_tokens, kv_heads, head_dim) — kv heads stay
-    dim 3, exactly where the gang lays the tp axis."""
+    (layers, pages, page_tokens, kv_heads, ``lanes``) — kv heads stay
+    dim 3, exactly where the gang lays the tp axis; ``lanes`` is
+    ``head_dim`` unless the caller says more (``arena_lanes``: the
+    pool does on a TPU)."""
     shape = (
-        config.n_layers, n_pages, page_tokens, config.n_kv_heads,
-        config.head_dim,
+        config.n_layers_of("attention"), n_pages, page_tokens,
+        config.n_kv_heads, lanes or config.head_dim,
     )
     if kv_dtype == "int8":
         scale_shape = shape[:-1] + (1,)
-        return {
+        cache = {
             "k": jnp.zeros(shape, jnp.int8),
             "v": jnp.zeros(shape, jnp.int8),
             "k_scale": jnp.zeros(scale_shape, jnp.float32),
             "v_scale": jnp.zeros(scale_shape, jnp.float32),
         }
-    return {
-        "k": jnp.zeros(shape, config.dtype),
-        "v": jnp.zeros(shape, config.dtype),
-    }
+    else:
+        cache = {
+            "k": jnp.zeros(shape, config.dtype),
+            "v": jnp.zeros(shape, config.dtype),
+        }
+    if config.n_layers_of("conv"):
+        if slots < 1:
+            raise ValueError(
+                "conv layers keep their state by slot: the arena of a "
+                "pattern that has them is built for a number of slots"
+            )
+        cache["conv_state"] = jnp.zeros(
+            (config.n_layers_of("conv"), slots, config.conv_l_cache - 1,
+             config.d_model), config.dtype,
+        )
+    return cache
 
 
 def _gqa_only(config: TransformerConfig, what: str) -> None:
-    if config.attention != "gqa":
+    if config.attention != "gqa" or not config.one_kind:
         raise NotImplementedError(
-            f"{what}: the dense cache keeps every token of a row; "
-            f"attention {config.attention!r} is served by the paged "
-            "arena alone"
+            f"{what}: the dense cache keeps every token of a row and "
+            f"scans one kind of layer; attention {config.attention!r} "
+            f"in the pattern {sorted(set(config.layer_kinds))} is "
+            "served by the paged arena alone"
         )
 
 
@@ -147,6 +190,11 @@ def _project_kv(config, layer, normed, positions):
     q = (normed @ dq(layer["wq"], normed.dtype)).reshape(b, s, h, hd)
     k = (normed @ dq(layer["wk"], normed.dtype)).reshape(b, s, kv, hd)
     v = (normed @ dq(layer["wv"], normed.dtype)).reshape(b, s, kv, hd)
+    if config.qk_norm:
+        # a head at a time, over head_dim, before the rotation: the
+        # cache holds the normed, rotated keys
+        q = rms_norm(q, layer["q_norm"], eps=config.rms_norm_eps)
+        k = rms_norm(k, layer["k_norm"], eps=config.rms_norm_eps)
     q = _rope(q, positions, config.rope_theta)
     k = _rope(k, positions, config.rope_theta)
     return q, k, v
@@ -201,7 +249,7 @@ def prefill(
         # drop-free MoE routing: serving must not drop prompt tokens
         # (capacity pressure is a training behavior), and the decode
         # steps that continue this cache are drop-free too
-        x, _moe_aux = _ffn_block(config, layer, x, decode=True)
+        x, _counts = _serve_ffn(config, layer, x)
         # pad the captured K/V out to the static cache length
         pad = [(0, 0), (0, max_len - s), (0, 0), (0, 0)]
         if kv_dtype == "int8":
@@ -338,7 +386,7 @@ def decode_step(
             cv = _cache_write(cv, v_new)
         attn = _attend(q, ck, cv, cks, cvs)
         x = x + attn.reshape(b, 1, h * hd) @ dq(layer["wo"], x.dtype)
-        x, _moe_aux = _ffn_block(config, layer, x, decode=True)
+        x, _counts = _serve_ffn(config, layer, x)
         if quantized:
             return x, (ck, cv, cks, cvs)
         return x, (ck, cv)
@@ -360,16 +408,53 @@ def decode_step(
     return _last_logits(config, params, x[:, 0]), new_cache
 
 
-def _serve_ffn(config: TransformerConfig, layer, x: jax.Array) -> jax.Array:
-    """The FFN of a paged serving layer under its profile scope: the
-    dense block is ``mlp``; the mixture names its own two parts
-    (``moe_router`` / ``moe_experts``, models/moe.py)."""
-    scope = (
-        contextlib.nullcontext() if config.n_experts > 0
-        else jax.named_scope("mlp")
+# the leaves of a mixture's stack that hold its experts: never a scanned
+# array of a serving program (``_held_experts``)
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def _held_experts(stack):
+    """``stack`` (a mixture's leaves over its layers) split into the
+    experts, which stay WHOLE outside any scan and are read in place
+    (ops/grouped_matmul.py: a layer's slice in front of the kernel
+    would be a copy of the layer's experts), and the rest, which is
+    small and scanned or indexed a layer at a time."""
+    if "router" not in stack:
+        return None, stack
+    return (
+        {name: stack[name] for name in EXPERT_LEAVES},
+        {k: v for k, v in stack.items() if k not in EXPERT_LEAVES},
     )
-    with scope:
-        return _ffn_block(config, layer, x, decode=True)[0]
+
+
+def _serve_ffn(config: TransformerConfig, layer, x: jax.Array, live=None,
+               experts=None, index=0):
+    """The FFN of a serving layer under its profile scope, with what
+    the mixture counted (int32 ``[2]``: live assignments, expert groups
+    touched; None for a dense block).  The dense block is ``mlp``; the
+    mixture (``layer`` holds a ``router``) names its own two parts
+    (``moe_router`` / ``moe_experts``, models/moe.py ``moe_serve_ffn``).
+    ``live [b * s]`` marks the rows that stand for something; the
+    others reach no expert.  ``experts`` are the held stacks with
+    ``index`` this layer's place in them; without them the layer's own
+    leaves are a stack of one."""
+    if "router" not in layer:
+        with jax.named_scope("mlp"):
+            return _mlp_block(config, layer, x), None
+    from dcos_commons_tpu.models.moe import moe_serve_ffn
+
+    if experts is None:
+        experts = {
+            name: jax.tree.map(lambda a: a[None], layer[name])
+            for name in EXPERT_LEAVES
+        }
+    b, s, d = x.shape
+    normed = _norm(config, x, layer["mlp_norm"])
+    y, counts = moe_serve_ffn(
+        moe_config_of(config), layer, experts, index,
+        normed.reshape(b * s, d), live,
+    )
+    return x + y.reshape(b, s, d), counts
 
 
 def _scan_layers_over_arena(layer_fn, x, layers, cache):
@@ -380,7 +465,8 @@ def _scan_layers_over_arena(layer_fn, x, layers, cache):
     stored layout that moves no byte) and ``base``, the layer's first
     row of that axis, so layer ``l`` reaches its page ``p`` at row
     ``base + p`` with ONE scatter into the whole buffer and ONE gather
-    out of it.  Returns (x, the cache in its stored layout).
+    out of it.  Returns (x, the cache in its stored layout, what
+    ``layer_fn`` returned beside its carry, stacked over the layers).
 
     The arena must never be a scanned array (an ``xs``/``ys`` of this
     scan): XLA then slices a whole layer out of the stacked buffer,
@@ -394,19 +480,198 @@ def _scan_layers_over_arena(layer_fn, x, layers, cache):
         for name, arr in cache.items()
     }
     bases = jnp.arange(n_layers, dtype=jnp.int32) * n_pages
-    (x, arena), _ = lax.scan(layer_fn, (x, arena), (layers, bases))
+    (x, arena), ys = lax.scan(layer_fn, (x, arena), (layers, bases))
     return x, {
         name: arr.reshape(cache[name].shape)
         for name, arr in arena.items()
+    }, ys
+
+
+def layer_plan(kinds) -> Tuple[int, int, int, int]:
+    """How a serving program walks the pattern ``kinds`` (one entry a
+    layer): ``(lead, period, reps, tail)`` — ``lead`` layers one by
+    one, then ``reps`` whole periods of ``period`` layers under ONE
+    ``lax.scan`` (the period's layers unrolled in its body), then
+    ``tail`` layers one by one.  Of the ways to cut the pattern so, the
+    one that compiles the fewest layer bodies (``lead + period +
+    tail``; ties to the shorter lead, then the shorter period)."""
+    n = len(kinds)
+    best = None
+    for lead in range(n):
+        for period in range(1, n - lead + 1):
+            reps = 1
+            while kinds[lead + reps * period:lead + (reps + 1) * period] \
+                    == kinds[lead:lead + period]:
+                reps += 1
+            tail = n - lead - reps * period
+            cost = (lead + period + tail, lead, period)
+            if best is None or cost < best[0]:
+                best = (cost, (lead, period, reps, tail))
+    return best[1]
+
+
+def _walk_pattern(config, params, cache, x, attend, convolve, live):
+    """Run ``x`` through a MIXED layer pattern (``layer_plan``) over
+    the cache: ``attend(x, arena, layer, base) -> (x, arena)`` is the
+    attention operator over the merged arena (``base = a * n_pages``
+    for the ``a``-th ATTENTION layer: only those own pages),
+    ``convolve(x, conv_state, layer, c) -> (x, conv_state)`` the conv
+    operator of the ``c``-th conv layer.  Arena and conv state are the
+    scan's carry, never scanned arrays (``_scan_layers_over_arena``
+    says why); a layer's weights are read at its index of its part's
+    stack (``init_params``), the experts in place.  Returns (x, the
+    cache in its stored layout, the mixtures' counts summed)."""
+    kinds = config.layer_kinds
+    lead, period, reps, _tail = layer_plan(kinds)
+    n_pages = cache["k"].shape[1]
+    state = {
+        name: arr if name == "conv_state"
+        else arr.reshape((-1,) + arr.shape[2:])
+        for name, arr in cache.items()
     }
+    experts, routing = _held_experts(params["layers"].get("moe", {}))
+    stacks = dict(params["layers"], moe=routing)
+    # each layer's index in its two parts' stacks; what a period adds
+    seen, index = {}, []
+    for kind in kinds:
+        index.append(tuple(seen.get(part, 0) for part in kind))
+        for part in kind:
+            seen[part] = seen.get(part, 0) + 1
+    stride = {
+        part: sum(part in kind for kind in kinds[lead:lead + period])
+        for part in seen
+    }
+
+    def at(stack, i):
+        if isinstance(i, int):
+            return jax.tree.map(lambda a: a[i], stack)
+        return jax.tree.map(
+            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            stack,
+        )
+
+    def one(carry, l, trip):
+        """Layer ``l``, or the layer ``trip`` periods behind it."""
+        x, state, counts = carry
+        op, ffn = kinds[l]
+        op_i = index[l][0] + trip * stride[op]
+        ffn_i = index[l][1] + trip * stride[ffn]
+        if op == "attention":
+            arena = {k: v for k, v in state.items() if k != "conv_state"}
+            x, arena = attend(x, arena, at(stacks[op], op_i), op_i * n_pages)
+            state = dict(state, **arena)
+        else:
+            x, conv = convolve(
+                x, state["conv_state"], at(stacks[op], op_i), op_i
+            )
+            state = dict(state, conv_state=conv)
+        x, c = _serve_ffn(
+            config, at(stacks[ffn], ffn_i), x, live, experts, ffn_i
+        )
+        return x, state, counts if c is None else counts + c
+
+    def whole_period(carry, trip):
+        for l in range(lead, lead + period):
+            carry = one(carry, l, trip)
+        return carry, None
+
+    carry = (x, state, jnp.zeros(2, jnp.int32))
+    for l in range(lead):
+        carry = one(carry, l, 0)
+    carry, _ = lax.scan(
+        whole_period, carry, jnp.arange(reps, dtype=jnp.int32)
+    )
+    for l in range(lead + reps * period, len(kinds)):
+        carry = one(carry, l, 0)
+    x, state, counts = carry
+    return x, {
+        name: arr.reshape(cache[name].shape) for name, arr in state.items()
+    }, counts
+
+
+def _conv_gates(config: TransformerConfig, layer, x):
+    """``x [b, s, d]`` -> the conv operator's gated input ``u = B * X``
+    and its output gate ``C``, each ``[b, s, d]``."""
+    normed = _norm(config, x, layer["conv_norm"])
+    b_gate, c_gate, x_gate = jnp.split(
+        normed @ dq(layer["conv_in"], x.dtype), 3, axis=-1
+    )
+    return b_gate * x_gate, c_gate
+
+
+def _conv_taps(layer, seq, n: int):
+    """The depthwise causal convolution: ``seq [..., taps - 1 + n, d]``
+    (the state, then ``n`` new gated inputs) -> ``[..., n, d]``, tap
+    ``j`` on the input ``taps - 1 - j`` back; summed in float32."""
+    w = layer["conv_w"].astype(jnp.float32)                # [d, taps]
+    out = sum(
+        w[:, j] * lax.slice_in_dim(seq, j, j + n, axis=-2).astype(jnp.float32)
+        for j in range(w.shape[1])
+    )
+    return out.astype(seq.dtype)
+
+
+def _conv_chunk_operator(config: TransformerConfig, start, true_len, slot):
+    """The conv operator of a prefill chunk of the row in ``slot``:
+    reads the row's state (zeros where the chunk is the row's first:
+    whoever held the slot before is gone), convolves ``[state ; u]``,
+    writes back the last ``taps - 1`` of the TRUE positions."""
+    keep = config.conv_l_cache - 1
+
+    def convolve(x, conv, layer, index):
+        d = x.shape[-1]
+        at = (jnp.asarray(index, jnp.int32), slot, jnp.int32(0), jnp.int32(0))
+        with jax.named_scope("short_conv"):
+            u, c_gate = _conv_gates(config, layer, x)
+            state = lax.dynamic_slice(conv, at, (1, 1, keep, d))[0, 0]
+            state = jnp.where(start == 0, jnp.zeros_like(state), state)
+            seq = jnp.concatenate([state, u[0]], axis=0)
+            v = _conv_taps(layer, seq, u.shape[1])
+            x = x + (c_gate * v[None]) @ dq(layer["conv_out"], x.dtype)
+        with jax.named_scope("conv_state_write"):
+            # u_t sits at seq[keep + t]: the inputs behind true_len
+            last = lax.dynamic_slice_in_dim(seq, true_len, keep, axis=0)
+            conv = lax.dynamic_update_slice(conv, last[None, None], at)
+        return x, conv
+
+    return convolve
+
+
+def _conv_step_operator(config: TransformerConfig, live):
+    """The conv operator of a decode step over every slot: one shift
+    and one write a layer; a slot that is not ``live`` (idle, or held
+    by a row that is still prefilling or frozen) keeps its state."""
+
+    def convolve(x, conv, layer, index):
+        with jax.named_scope("short_conv"):
+            u, c_gate = _conv_gates(config, layer, x)
+            state = lax.dynamic_index_in_dim(
+                conv, index, 0, keepdims=False
+            )                                              # [b, keep, d]
+            seq = jnp.concatenate([state, u], axis=1)      # [b, taps, d]
+            v = _conv_taps(layer, seq, 1)
+            x = x + (c_gate * v) @ dq(layer["conv_out"], x.dtype)
+        with jax.named_scope("conv_state_write"):
+            conv = lax.dynamic_update_index_in_dim(
+                conv, jnp.where(live[:, None, None], seq[:, 1:], state),
+                index, 0,
+            )
+        return x, conv
+
+    return convolve
 
 
 def _kv_entries(
-    k_new: jax.Array, v_new: jax.Array, quantized: bool
+    k_new: jax.Array, v_new: jax.Array, quantized: bool, lanes: int
 ) -> Dict[str, jax.Array]:
     """What one layer writes into the arena, keyed like the cache:
-    the new K/V rows, int8 with their per-vector scales when the arena
-    is quantized."""
+    the new K/V rows, widened with zeros to the arena's ``lanes``
+    (``arena_lanes``), int8 with their per-vector scales when the
+    arena is quantized."""
+    short = lanes - k_new.shape[-1]
+    if short:
+        pad = [(0, 0)] * (k_new.ndim - 1) + [(0, short)]
+        k_new, v_new = jnp.pad(k_new, pad), jnp.pad(v_new, pad)
     if not quantized:
         return {"k": k_new, "v": v_new}
     kq, ks_new = _quantize_kv(k_new)
@@ -422,7 +687,8 @@ def paged_prefill_chunk(
     table: jax.Array,
     start: jax.Array,
     true_len: jax.Array,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    slot: jax.Array = 0,
+) -> Tuple[jax.Array, Dict[str, jax.Array], Optional[jax.Array]]:
     """One CHUNK of a prompt through the trunk into a paged arena.
 
     ``tokens [1, C]`` carries up to C prompt tokens at virtual
@@ -433,14 +699,21 @@ def paged_prefill_chunk(
     attends to EVERY earlier virtual position — prior chunks' pages,
     prefix-cache pages, and the in-chunk causal prefix — gathered
     through the same table.  Returns (logits at the chunk's last real
-    position [1, vocab] f32, updated cache).
+    position [1, vocab] f32, updated cache, the mixtures' counts as
+    ``paged_decode_step`` returns them).
 
-    ``start`` and ``true_len`` are TRACED: one compile covers every
-    chunk of every prompt — a request resuming at position k*P after
-    a prefix-cache hit runs the same program as one starting at 0.
+    ``start``, ``true_len`` and ``slot`` are TRACED: one compile covers
+    every chunk of every prompt — a request resuming at position k*P
+    after a prefix-cache hit runs the same program as one starting at
+    0.  ``slot`` is the row's place among the pool's rows: what a
+    pattern with conv layers keeps of a row outside its pages lives
+    there (``init_paged_kv_cache``), and a chunk with ``start == 0``
+    begins it from zeros; no other model reads it.
     This is the chunked-prefill entry: a long prompt costs several
     SMALL dispatches interleaved with decode ticks instead of one
     prompt-wide dispatch that blocks the pool (head-of-line TTFT).
+    A mixture computes for the true positions alone: the padding
+    reaches no expert.
     """
     b, c = tokens.shape
     if b != 1:
@@ -448,9 +721,9 @@ def paged_prefill_chunk(
     if config.attention == "eva":
         return _eva_prefill_chunk(
             config, params, cache, tokens, table, start, true_len
-        )
+        ) + (None,)
     h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
-    p_tok = cache["k"].shape[2]
+    p_tok, lanes = cache["k"].shape[2], cache["k"].shape[-1]
     m = table.shape[0]
     length = m * p_tok
     quantized = "k_scale" in cache
@@ -461,9 +734,10 @@ def paged_prefill_chunk(
     abs_pos = start + offs                       # [c] virtual positions
     positions = abs_pos[None, :]                 # [1, c]
     vpage = jnp.minimum(abs_pos // p_tok, m - 1)
+    live = offs < true_len
     # pad positions (>= true_len) scatter into the trash page: their
     # K/V must never land in a real page a later chunk would attend to
-    phys = jnp.where(offs < true_len, table[vpage], 0)
+    phys = jnp.where(live, table[vpage], 0)
     slot_off = abs_pos % p_tok
     # causal across the whole virtual sequence: key position <= query
     # position — covers prior chunks, cached prefix pages, and the
@@ -475,14 +749,13 @@ def paged_prefill_chunk(
     )                                            # [c, L]
     x = params["embed"][tokens].astype(config.dtype)
 
-    def layer_fn(carry, inputs):
-        x, arena = carry
-        layer, base = inputs           # base: the layer's first page
+    def attend(x, arena, layer, base):
+        """The attention operator; ``base``: the layer's first page."""
         with jax.named_scope("attention"):
             normed = _norm(config, x, layer["attn_norm"])
             q, k_new, v_new = _project_kv(config, layer, normed, positions)
         with jax.named_scope("kv_write"):
-            new = _kv_entries(k_new[0], v_new[0], quantized)
+            new = _kv_entries(k_new[0], v_new[0], quantized, lanes)
             arena = {
                 name: arr.at[base + phys, slot_off].set(new[name])
                 for name, arr in arena.items()
@@ -492,8 +765,8 @@ def paged_prefill_chunk(
         # path as prior pages — one attention covers both)
         with jax.named_scope("paged_gather"):
             pages = base + table
-            k_all = arena["k"][pages].reshape(1, length, kv, hd)
-            v_all = arena["v"][pages].reshape(1, length, kv, hd)
+            k_all = arena["k"][pages].reshape(1, length, kv, lanes)[..., :hd]
+            v_all = arena["v"][pages].reshape(1, length, kv, lanes)[..., :hd]
             if quantized:
                 ks_all = arena["k_scale"][pages].reshape(1, length, kv)
                 vs_all = arena["v_scale"][pages].reshape(1, length, kv)
@@ -520,19 +793,37 @@ def paged_prefill_chunk(
                 "bqkrl,blkd->bqkrd", probs, v_all.astype(jnp.float32)
             ).astype(config.dtype)
             x = x + attn.reshape(1, c, h * hd) @ dq(layer["wo"], x.dtype)
-        x = _serve_ffn(config, layer, x)
-        return (x, arena), None
+        return x, arena
 
-    x, new_cache = _scan_layers_over_arena(
-        layer_fn, x, params["layers"], cache
-    )
+    if config.one_kind:
+        n_pages = cache["k"].shape[1]
+        experts, scanned = _held_experts(params["layers"])
+
+        def layer_fn(carry, inputs):
+            layer, base = inputs
+            x, arena = attend(*carry, layer, base)
+            x, counts = _serve_ffn(
+                config, layer, x, live, experts, base // n_pages
+            )
+            return (x, arena), counts
+
+        x, new_cache, counts = _scan_layers_over_arena(
+            layer_fn, x, scanned, cache
+        )
+        counts = None if counts is None else counts.sum(0)
+    else:
+        x, new_cache, counts = _walk_pattern(
+            config, params, cache, x, attend, _conv_chunk_operator(
+                config, start, true_len, jnp.asarray(slot, jnp.int32)
+            ), live,
+        )
     with jax.named_scope("logits"):
         x = _norm(config, x, params["final_norm"])
         x_last = lax.dynamic_index_in_dim(
             x, true_len - 1, axis=1, keepdims=False
         )
         logits = _last_logits(config, params, x_last)
-    return logits, new_cache
+    return logits, new_cache, counts
 
 
 def paged_decode_step(
@@ -542,24 +833,34 @@ def paged_decode_step(
     token: jax.Array,
     pos: jax.Array,
     tables: jax.Array,
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+) -> Tuple[jax.Array, Dict[str, jax.Array], Optional[jax.Array]]:
     """One autoregressive step over the whole pool, KV indirected
     through per-row page tables: ``token [S]`` at per-row positions
     ``pos [S]``, ``tables [S, M]`` mapping each row's virtual pages to
-    arena pages -> (logits [S, vocab] f32, updated cache).
+    arena pages -> (logits [S, vocab] f32, updated cache, counts).
 
     The row's new K/V is scattered to ``(tables[s, pos // P],
     pos % P)`` — inactive rows (all-zero tables) write identical
     values into the trash page — and attention gathers each row's
     pages back into virtual order, so the masked-softmax math is
-    element-for-element ``decode_step``'s with ``max_len = M * P``."""
+    element-for-element ``decode_step``'s with ``max_len = M * P``.
+
+    A row whose table is all zeros holds no live request: it reaches no
+    expert of a mixture and leaves its slot's conv state as it was
+    (the slot may belong to a row that is still prefilling).
+    ``counts`` is int32 ``[2]``: the live (token, expert) assignments
+    the step's mixtures routed and the expert groups that held at
+    least one, summed over the expert layers; None where the model
+    routes nothing."""
     if config.attention == "eva":
-        return _eva_decode_step(config, params, cache, token, pos, tables)
+        return _eva_decode_step(
+            config, params, cache, token, pos, tables
+        ) + (None,)
     from dcos_commons_tpu.ops.paged_decode import paged_decode_attention
 
     b = token.shape[0]
     h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
-    p_tok = cache["k"].shape[2]
+    p_tok, lanes = cache["k"].shape[2], cache["k"].shape[-1]
     m = tables.shape[1]
     length = m * p_tok
     x = params["embed"][token][:, None, :].astype(config.dtype)
@@ -569,6 +870,8 @@ def paged_decode_step(
     vpage = jnp.minimum(pos // p_tok, m - 1)
     phys = tables[rows, vpage]                   # [b]
     slot_off = pos % p_tok
+    # a live row holds at least its first page
+    live = tables[:, 0] > 0
     quantized = "k_scale" in cache
     reps = h // kv
     kernel = decode_attention_kernel(config, cache)
@@ -578,14 +881,13 @@ def paged_decode_step(
             <= pos[:, None, None]
         )                                        # [b, 1, L]
 
-    def layer_fn(carry, inputs):
-        x, arena = carry
-        layer, base = inputs           # base: the layer's first page
+    def attend(x, arena, layer, base):
+        """The attention operator; ``base``: the layer's first page."""
         with jax.named_scope("attention"):
             normed = _norm(config, x, layer["attn_norm"])
             q, k_new, v_new = _project_kv(config, layer, normed, positions)
         with jax.named_scope("kv_write"):
-            new = _kv_entries(k_new[:, 0], v_new[:, 0], quantized)
+            new = _kv_entries(k_new[:, 0], v_new[:, 0], quantized, lanes)
             arena = {
                 name: arr.at[base + phys, slot_off].set(new[name])
                 for name, arr in arena.items()
@@ -595,14 +897,19 @@ def paged_decode_step(
             # just written into
             with jax.named_scope("attention"):
                 attn = paged_decode_attention(
-                    q[:, 0], arena["k"], arena["v"], base + tables, pos,
+                    jnp.pad(q[:, 0], ((0, 0), (0, 0), (0, lanes - hd))),
+                    arena["k"], arena["v"], base + tables, pos,
                     scale=hd ** -0.5, interpret=kernel == "interpret",
-                )
+                )[..., :hd]
         else:
             with jax.named_scope("paged_gather"):
                 pages = base + tables
-                k_all = arena["k"][pages].reshape(b, length, kv, hd)
-                v_all = arena["v"][pages].reshape(b, length, kv, hd)
+                k_all = arena["k"][pages].reshape(
+                    b, length, kv, lanes
+                )[..., :hd]
+                v_all = arena["v"][pages].reshape(
+                    b, length, kv, lanes
+                )[..., :hd]
                 if quantized:
                     ks_all = arena["k_scale"][pages].reshape(b, length, kv)
                     vs_all = arena["v_scale"][pages].reshape(b, length, kv)
@@ -626,16 +933,33 @@ def paged_decode_step(
                 ).astype(config.dtype)
         with jax.named_scope("attention"):
             x = x + attn.reshape(b, 1, h * hd) @ dq(layer["wo"], x.dtype)
-        x = _serve_ffn(config, layer, x)
-        return (x, arena), None
+        return x, arena
 
-    x, new_cache = _scan_layers_over_arena(
-        layer_fn, x, params["layers"], cache
-    )
+    if config.one_kind:
+        n_pages = cache["k"].shape[1]
+        experts, scanned = _held_experts(params["layers"])
+
+        def layer_fn(carry, inputs):
+            layer, base = inputs
+            x, arena = attend(*carry, layer, base)
+            x, counts = _serve_ffn(
+                config, layer, x, live, experts, base // n_pages
+            )
+            return (x, arena), counts
+
+        x, new_cache, counts = _scan_layers_over_arena(
+            layer_fn, x, scanned, cache
+        )
+        counts = None if counts is None else counts.sum(0)
+    else:
+        x, new_cache, counts = _walk_pattern(
+            config, params, cache, x, attend,
+            _conv_step_operator(config, live), live,
+        )
     with jax.named_scope("logits"):
         x = _norm(config, x, params["final_norm"])
         logits = _last_logits(config, params, x[:, 0])
-    return logits, new_cache
+    return logits, new_cache, counts
 
 
 def _eva_geometry(config: TransformerConfig, cache, table_len: int):
@@ -862,10 +1186,10 @@ def _eva_prefill_chunk(config, params, cache, tokens, table, start, true_len):
             _m, norm, acc = state
             attn = (acc / norm[..., None]).astype(config.dtype)
             x = x + attn.reshape(1, c, h * hd) @ dq(layer["wo"], x.dtype)
-        x = _serve_ffn(config, layer, x)
+        x, _counts = _serve_ffn(config, layer, x)
         return (x, arena), None
 
-    x, new_cache = _scan_layers_over_arena(
+    x, new_cache, _ = _scan_layers_over_arena(
         layer_fn, x, params["layers"], cache
     )
     with jax.named_scope("logits"):
@@ -891,7 +1215,10 @@ def decode_attention_kernel(config: TransformerConfig, cache):
     than one device (``PagedPoolModel`` enters the mesh its arena is
     laid over, the serving gang's tp mesh), because a ``pallas_call``
     under a multi-device jit raises unless it is wrapped per shard
-    (parallel/mesh.py ``per_shard``), which this call is not."""
+    (parallel/mesh.py ``per_shard``), which this call is not.  The
+    kernel copies whole pages, so a TPU's arena keeps its entries in
+    whole 128-lane rows (``arena_lanes``, which the pool applies): over
+    an arena built narrower the kernel does not compile."""
     if "k_scale" in cache:
         return None
     if config.attention == "eva" and config.n_kv_heads != config.n_heads:
@@ -1001,10 +1328,10 @@ def _eva_decode_step(config, params, cache, token, pos, tables):
                 "k": arena["k"].at[base + sum_phys, sum_off].set(k_sum_new),
                 "v": arena["v"].at[base + sum_phys, sum_off].set(v_sum_new),
             }
-        x = _serve_ffn(config, layer, x)
+        x, _counts = _serve_ffn(config, layer, x)
         return (x, arena), None
 
-    x, new_cache = _scan_layers_over_arena(
+    x, new_cache, _ = _scan_layers_over_arena(
         layer_fn, x, params["layers"], cache
     )
     with jax.named_scope("logits"):

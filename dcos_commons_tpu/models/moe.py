@@ -15,7 +15,18 @@ Shapes (per device, inside shard_map over ``ep``):
     expert_out   [E/ep, ep*C, d] --all_to_all--> [E, C, d]
 
 Top-k routing with probability renormalisation over the chosen k, and
-the switch-transformer load-balancing auxiliary loss.
+the switch-transformer load-balancing auxiliary loss.  HOW a token
+chooses is data (``MoEConfig``): a softmax or a sigmoid over the
+router's logits, an ``expert_bias`` that moves the selection and not
+the weights, the renormalisation over the chosen, a scaling factor.
+
+Serving has ONE dispatch, ``moe_serve_ffn``, and it drops nothing:
+the step's assignments are sorted by expert, each expert's rows form a
+group, and a grouped matmul (ops/grouped_matmul.py) runs the groups
+that hold rows.  Rows that stand for nothing (slots with no live
+request, a chunk's padding) are given to no group, so the experts read
+follow the live rows.  The capacity-bound dispatches above are the
+training forward's.
 """
 
 from __future__ import annotations
@@ -40,6 +51,22 @@ class MoEConfig:
     top_k: int = 2
     capacity_factor: float = 1.5
     dtype: Any = jnp.bfloat16
+    # routing, as data: the score of an expert is a ``"softmax"`` over
+    # all experts' logits or a ``"sigmoid"`` of its own; the ``top_k``
+    # largest of score + ``expert_bias`` (a float32 ``[E]`` leaf, where
+    # ``expert_bias`` is set) are chosen; their weights are their
+    # SCORES (never the bias), renormalised over the chosen where
+    # ``norm_topk``, times ``scaling``
+    score: str = "softmax"
+    expert_bias: bool = False
+    norm_topk: bool = True
+    scaling: float = 1.0
+
+    def __post_init__(self) -> None:
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"routing score {self.score!r} not in ('softmax', 'sigmoid')"
+            )
 
     def capacity(self, n_tokens: int) -> int:
         """Static per-expert slot count for an n_tokens batch."""
@@ -58,13 +85,48 @@ def init_moe_params(config: MoEConfig, key: jax.Array) -> MoEParams:
     def normal(key, shape, scale):
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dt)
 
-    return {
+    params = {
         # router stays f32: routing decisions are precision-sensitive
         "router": jax.random.normal(keys[0], (d, e), jnp.float32) * d ** -0.5,
         "w_gate": normal(keys[1], (e, d, f), d ** -0.5),
         "w_up": normal(keys[2], (e, d, f), d ** -0.5),
         "w_down": normal(keys[3], (e, f, d), f ** -0.5),
     }
+    if config.expert_bias:
+        params["expert_bias"] = jax.random.normal(
+            jax.random.fold_in(key, 4), (e,), jnp.float32
+        ) * 0.01
+    return params
+
+
+def route(
+    config: MoEConfig, params: MoEParams, x: jax.Array
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Each token's choice: (weights ``[t, k]`` float32, experts
+    ``[t, k]``, every expert's score ``[t, E]``), as ``MoEConfig``
+    says a token chooses."""
+    k = config.top_k
+    logits = x.astype(jnp.float32) @ params["router"]
+    if config.score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        scores = jax.nn.sigmoid(logits)
+    if config.expert_bias:
+        _, expert_idx = lax.top_k(scores + params["expert_bias"], k)
+        gate_vals = jnp.take_along_axis(scores, expert_idx, axis=-1)
+    else:
+        gate_vals, expert_idx = lax.top_k(scores, k)
+    if config.norm_topk:
+        total = gate_vals.sum(-1, keepdims=True)
+        # the published forms: a sigmoid's sum gets 1e-6 added, a
+        # softmax's is held over 1e-9
+        gate_vals = gate_vals / (
+            jnp.maximum(total, 1e-9) if config.score == "softmax"
+            else total + 1e-6
+        )
+    if config.scaling != 1.0:
+        gate_vals = gate_vals * config.scaling
+    return gate_vals, expert_idx, scores
 
 
 def _routing(
@@ -76,12 +138,7 @@ def _routing(
     """
     t = x.shape[0]
     e, k = config.n_experts, config.top_k
-    logits = x.astype(jnp.float32) @ params["router"]
-    probs = jax.nn.softmax(logits, axis=-1)                    # [t, E]
-    gate_vals, expert_idx = lax.top_k(probs, k)                # [t, k]
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9
-    )
+    gate_vals, expert_idx, probs = route(config, params, x)    # [t, k]
     # switch load-balance loss: fraction-of-tokens * mean-prob per expert
     top1_hot = jax.nn.one_hot(expert_idx[:, 0], e, dtype=jnp.float32)
     aux = e * jnp.mean(top1_hot.mean(0) * probs.mean(0))
@@ -120,12 +177,7 @@ def _routing_sorted(
     row gather/scatter instead of a [t,E*C] matmul."""
     t = x.shape[0]
     e, k = config.n_experts, config.top_k
-    logits = x.astype(jnp.float32) @ params["router"]
-    probs = jax.nn.softmax(logits, axis=-1)                    # [t, E]
-    gate_vals, expert_idx = lax.top_k(probs, k)                # [t, k]
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9
-    )
+    gate_vals, expert_idx, probs = route(config, params, x)    # [t, k]
     top1_hot = jax.nn.one_hot(expert_idx[:, 0], e, dtype=jnp.float32)
     aux = e * jnp.mean(top1_hot.mean(0) * probs.mean(0))
     # choice-major flatten: stable argsort then gives 1st choices
@@ -292,6 +344,76 @@ def moe_ffn(
     return y.astype(x.dtype), aux
 
 
+def moe_serve_ffn(
+    config: MoEConfig,
+    routing: MoEParams,
+    experts: MoEParams,
+    layer,
+    x: jax.Array,
+    live: Optional[jax.Array] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """The serving mixture on ``x [t, d]``: drop-free, and its cost
+    follows the rows that are ``live [t]`` (all, where None).
+
+    ``routing`` holds THIS layer's ``router`` (and ``expert_bias``);
+    ``experts`` holds EVERY expert layer's ``w_gate`` / ``w_up`` /
+    ``w_down`` stacked ``[n, E, ...]`` and ``layer`` says which of the
+    ``n`` this is (ops/grouped_matmul.py reads the layer's experts in
+    place).  The ``t * k`` assignments are sorted by expert, a dead
+    row's behind every group; the three grouped products run over the
+    sorted rows; the results go back to their tokens by the inverse
+    permutation (a gather, no scatter), are weighted in float32 and
+    summed.  Returns (``y [t, d]``, int32 ``[2]``: the live
+    assignments and the expert groups that hold at least one)."""
+    from dcos_commons_tpu.ops.grouped_matmul import grouped_matmul
+
+    t, d = x.shape
+    e, k = config.n_experts, config.top_k
+    dt = config.dtype
+    with jax.named_scope("moe_router"):
+        gate_vals, expert_idx, _scores = route(config, routing, x)
+        flat_expert = expert_idx.reshape(-1)                   # [t * k]
+        if live is not None:
+            flat_expert = jnp.where(jnp.repeat(live, k), flat_expert, e)
+        order = jnp.argsort(flat_expert, stable=True)
+        back = jnp.argsort(order)
+        group_sizes = jnp.bincount(flat_expert, length=e + 1)[:e].astype(
+            jnp.int32
+        )
+        counts = jnp.stack(
+            [group_sizes.sum(), (group_sizes > 0).sum()]
+        ).astype(jnp.int32)
+    with jax.named_scope("moe_experts"):
+        layer = jnp.asarray(layer, jnp.int32)
+
+        def product(rows, name):
+            w = experts[name]
+            if isinstance(w, dict):
+                # weight-only int8 (models/quantize.py): the layer's
+                # experts are widened, never the whole stack
+                w = dq(jax.tree.map(lambda a: lax.dynamic_index_in_dim(
+                    a, layer, axis=0, keepdims=False
+                ), w), dt)
+                return grouped_matmul(rows, w, group_sizes, 0)
+            return grouped_matmul(
+                rows, w.reshape((-1,) + w.shape[-2:]), group_sizes,
+                layer * e,
+            )
+
+        rows = x.astype(dt)[order // k]                        # [t * k, d]
+        gate = jax.nn.silu(product(rows, "w_gate"))
+        out = product(gate * product(rows, "w_up"), "w_down")
+        # a dead row's result is zeros and its weight is left out too
+        weight = gate_vals if live is None else jnp.where(
+            live[:, None], gate_vals, 0.0
+        )
+        y = jnp.sum(
+            out[back].reshape(t, k, d).astype(jnp.float32)
+            * weight[:, :, None], axis=1,
+        )
+    return y.astype(x.dtype), counts
+
+
 def expert_shard_spec():
     """PartitionSpec rules for the param tree under ep sharding."""
     from jax.sharding import PartitionSpec as P
@@ -304,23 +426,28 @@ def expert_shard_spec():
     }
 
 
-def moe_sharding_rules(prefix: str = "", stacked: bool = False):
+def moe_sharding_rules(prefix: str = "", stacked: bool = False,
+                       expert_bias: bool = False):
     """Param path -> PartitionSpec for the jit/GSPMD path: experts over
     ``ep``, then the scaling-book fsdp/tp split within each expert.
 
     This is the layout transformer.sharding_rules consumes for the MoE
     flagship (``stacked=True`` prepends the lax.scan layer axis);
     keeping it beside the dispatch code means a dispatch-layout change
-    and its sharding change land in the same file.  The router stays
-    fully replicated — routing logits are f32 and tiny, and every
-    chip needs them before dispatch.
+    and its sharding change land in the same file.  The router (and
+    the selection bias, where there is one) stays fully replicated —
+    routing logits are f32 and tiny, and every chip needs them before
+    dispatch.
     """
     from jax.sharding import PartitionSpec as P
 
     lead = (None,) if stacked else ()
-    return {
+    rules = {
         f"{prefix}router": P(*lead, None, None),
         f"{prefix}w_gate": P(*lead, "ep", "fsdp", "tp"),
         f"{prefix}w_up": P(*lead, "ep", "fsdp", "tp"),
         f"{prefix}w_down": P(*lead, "ep", "tp", "fsdp"),
     }
+    if expert_bias:
+        rules[f"{prefix}expert_bias"] = P(*lead, None)
+    return rules

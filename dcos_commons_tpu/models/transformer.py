@@ -88,6 +88,31 @@ class TransformerConfig:
             )
         if self.attention == "eva" and self.n_experts > 0:
             raise ValueError("eva attention serves a dense FFN only")
+        unknown = set(self.layer_types) - {"attention", "conv"}
+        if unknown or (
+            self.layer_types and len(self.layer_types) != self.n_layers
+        ):
+            raise ValueError(
+                f"layer_types names {self.n_layers} operators, each "
+                f"'attention' or 'conv', got {self.layer_types!r}"
+            )
+        if "conv" in self.layer_types and (
+            self.attention != "gqa" or self.conv_l_cache < 2
+        ):
+            raise ValueError(
+                "conv layers go with grouped-query attention layers and "
+                f"a kernel of >= 2 taps, got attention {self.attention!r}, "
+                f"conv_l_cache {self.conv_l_cache}"
+            )
+        if self.layer_types and "attention" not in self.layer_types:
+            raise ValueError(
+                "a pattern of conv layers alone is not built: the "
+                "serving programs walk a paged arena of attention layers"
+            )
+        if not 0 <= self.n_dense_layers <= self.n_layers:
+            raise ValueError(
+                f"n_dense_layers {self.n_dense_layers} of {self.n_layers}"
+            )
     use_ring_attention: bool = False     # sp: K/V rotate (ppermute)
     use_ulysses_attention: bool = False  # sp: all_to_all head regroup
     sp_axis: str = "sp"
@@ -136,10 +161,51 @@ class TransformerConfig:
     window_size: int = 0
     chunk_size: int = 0
     eva_init_std: float = 0.02
+    # the layer pattern, as data.  ``layer_types`` names each layer's
+    # OPERATOR: "attention" (the class above) or "conv", a gated short
+    # convolution of ``conv_l_cache`` taps whose whole memory is a
+    # row's last ``conv_l_cache - 1`` gated inputs; () is attention
+    # everywhere.  A layer's FFN is the mixture where there is one
+    # (``n_experts`` > 0), except in the ``n_dense_layers`` leading
+    # layers, which keep the dense block of width ``d_ff``; the experts
+    # have width ``moe_d_ff`` (0: ``d_ff``).  ``layer_kinds`` is the
+    # pattern; models/decode.py ``layer_plan`` walks it.
+    layer_types: tuple = ()
+    n_dense_layers: int = 0
+    conv_l_cache: int = 3
+    moe_d_ff: int = 0
+    # how a token chooses its experts (models/moe.py MoEConfig)
+    moe_score: str = "softmax"
+    moe_expert_bias: bool = False
+    moe_norm_topk: bool = True
+    moe_scaling: float = 1.0
+    # queries and keys RMS-normed a head, over head_dim, before RoPE
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """(operator, ffn) of every layer: "attention" | "conv" and
+        "dense" | "moe"."""
+        operators = self.layer_types or ("attention",) * self.n_layers
+        return tuple(
+            (op, "moe" if self.n_experts > 0 and i >= self.n_dense_layers
+             else "dense")
+            for i, op in enumerate(operators)
+        )
+
+    @property
+    def one_kind(self) -> bool:
+        """Every layer the same operator and the same FFN: the
+        parameter tree is then ONE stack, ``params["layers"]``."""
+        return len(set(self.layer_kinds)) <= 1
+
+    def n_layers_of(self, part: str) -> int:
+        """Layers that have ``part``: an operator or an FFN kind."""
+        return sum(part in kind for kind in self.layer_kinds)
 
 
 Params = Dict[str, Any]
@@ -157,14 +223,24 @@ _FILE_KEYS = {
     "tie_word_embeddings": "tie_embeddings",
     "num_pred_heads": "n_pred_heads", "window_size": "window_size",
     "chunk_size": "chunk_size", "init_std": "eva_init_std",
+    # a second published name for the same field wins where both stand
+    "num_experts": "n_experts", "norm_eps": "rms_norm_eps",
+    "moe_intermediate_size": "moe_d_ff",
+    "num_dense_layers": "n_dense_layers", "conv_L_cache": "conv_l_cache",
+    "norm_topk_prob": "moe_norm_topk", "use_expert_bias": "moe_expert_bias",
+    "routed_scaling_factor": "moe_scaling",
+    "router_activation": "moe_score", "qk_norm": "qk_norm",
 }
+# a published ``layer_types`` entry -> the operator it names
+_OPERATORS = {"full_attention": "attention", "conv": "conv"}
 
 
 def config_fields_from_file(path: str) -> Dict[str, Any]:
     """The TransformerConfig fields that the configuration file at
     ``path`` states: a JSON object under the key names of a published
     ``config.json`` (``_FILE_KEYS``; ``attention_class`` names the
-    attention).  A key this table lacks is not the program's to
+    attention, ``layer_types`` each layer's operator,
+    ``rope_parameters.rope_theta`` is read where it is nested).  A key this table lacks is not the program's to
     interpret and is passed over; a ``head_dim`` or an attention class
     the program cannot build is an error, not a silent other model."""
     import json
@@ -177,6 +253,24 @@ def config_fields_from_file(path: str) -> Dict[str, Any]:
         )
         for key, field in _FILE_KEYS.items() if data.get(key) is not None
     }
+    rope = data.get("rope_parameters") or {}
+    if rope.get("rope_theta") is not None:
+        if rope.get("rope_type", "default") != "default":
+            raise ValueError(
+                f"{path}: rope_type {rope['rope_type']!r} is not built; "
+                "the program rotates by rope_theta alone"
+            )
+        fields["rope_theta"] = float(rope["rope_theta"])
+    if data.get("layer_types") is not None:
+        unknown = set(data["layer_types"]) - set(_OPERATORS)
+        if unknown:
+            raise ValueError(
+                f"{path}: layer_types names {sorted(unknown)}; the "
+                f"program builds {sorted(_OPERATORS)}"
+            )
+        fields["layer_types"] = tuple(
+            _OPERATORS[name] for name in data["layer_types"]
+        )
     # a name TransformerConfig does not know is refused there
     fields["attention"] = data.get("attention_class") or "gqa"
     if fields["attention"] != "eva":
@@ -228,9 +322,28 @@ def config_from_env(env: Dict[str, str], **overrides) -> TransformerConfig:
     return TransformerConfig(**fields)
 
 
+def moe_config_of(config: TransformerConfig):
+    """The mixture of ``config`` as models/moe.py states one."""
+    from dcos_commons_tpu.models.moe import MoEConfig
+
+    return MoEConfig(
+        d_model=config.d_model, d_ff=config.moe_d_ff or config.d_ff,
+        n_experts=config.n_experts, top_k=config.moe_top_k,
+        capacity_factor=config.moe_capacity_factor, dtype=config.dtype,
+        score=config.moe_score, expert_bias=config.moe_expert_bias,
+        norm_topk=config.moe_norm_topk, scaling=config.moe_scaling,
+    )
+
+
 def init_params(config: TransformerConfig, key: jax.Array) -> Params:
-    """Stacked-layer param tree: every per-layer array has a leading
-    n_layers axis consumed by lax.scan."""
+    """One stack a KIND of layer part, each leaf with a leading axis
+    over the layers that have that part: ``attention`` and ``conv``
+    operators, ``dense`` and ``moe`` FFNs.  ``config.layer_kinds`` says
+    which part a layer has; a layer reads index (the layers before it
+    that have the same part) of the part's stack.  Where every layer is
+    of one kind the two stacks it has are ONE, ``params["layers"]``,
+    consumed by lax.scan; a mixed pattern keeps them apart,
+    ``params["layers"][part]``."""
     keys = jax.random.split(key, 8)
     d, h, kv, hd, f = (
         config.d_model,
@@ -239,7 +352,6 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
         config.head_dim,
         config.d_ff,
     )
-    n = config.n_layers
     dt = config.dtype
 
     def normal(key, shape, scale):
@@ -247,43 +359,74 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
 
     # a unit-offset norm stores its weight's distance from one
     norm_init = jnp.zeros if config.norm_unit_offset else jnp.ones
-    layers = {
-        "attn_norm": norm_init((n, d), dt),
-        "wq": normal(keys[1], (n, d, h * hd), d ** -0.5),
-        "wk": normal(keys[2], (n, d, kv * hd), d ** -0.5),
-        "wv": normal(keys[3], (n, d, kv * hd), d ** -0.5),
-        "wo": normal(keys[4], (n, h * hd, d), (h * hd) ** -0.5),
-        "mlp_norm": norm_init((n, d), dt),
-    }
-    if config.attention == "eva":
-        # the two learned vectors a head of the chunk summaries
-        # (models/decode.py ``_eva_summaries``)
-        eva_keys = jax.random.split(jax.random.fold_in(key, 8), 2)
-        layers["eva_phi"] = normal(
-            eva_keys[0], (n, kv, hd), config.eva_init_std
-        )
-        layers["eva_mu"] = normal(
-            eva_keys[1], (n, kv, hd), config.eva_init_std
-        )
-    if config.n_experts > 0:
-        # one source of truth for the expert init recipe (router-f32
-        # policy, scales): moe.init_moe_params, vmapped over layers
-        from dcos_commons_tpu.models.moe import MoEConfig, init_moe_params
 
-        moe_config = MoEConfig(
-            d_model=d, d_ff=f, n_experts=config.n_experts,
-            top_k=config.moe_top_k,
-            capacity_factor=config.moe_capacity_factor, dtype=dt,
-        )
-        layers.update(jax.vmap(
-            lambda k: init_moe_params(moe_config, k)
-        )(jax.random.split(keys[5], n)))
-    else:
-        layers.update({
+    def attention_stack(n):
+        stack = {
+            "attn_norm": norm_init((n, d), dt),
+            "wq": normal(keys[1], (n, d, h * hd), d ** -0.5),
+            "wk": normal(keys[2], (n, d, kv * hd), d ** -0.5),
+            "wv": normal(keys[3], (n, d, kv * hd), d ** -0.5),
+            "wo": normal(keys[4], (n, h * hd, d), (h * hd) ** -0.5),
+        }
+        if config.qk_norm:
+            stack["q_norm"] = jnp.ones((n, hd), dt)
+            stack["k_norm"] = jnp.ones((n, hd), dt)
+        if config.attention == "eva":
+            # the two learned vectors a head of the chunk summaries
+            # (models/decode.py ``_eva_summaries``)
+            eva_keys = jax.random.split(jax.random.fold_in(key, 8), 2)
+            stack["eva_phi"] = normal(
+                eva_keys[0], (n, kv, hd), config.eva_init_std
+            )
+            stack["eva_mu"] = normal(
+                eva_keys[1], (n, kv, hd), config.eva_init_std
+            )
+        return stack
+
+    def conv_stack(n):
+        # ``conv_in`` holds the three gates side by side ([B, C, X]);
+        # ``conv_w [d, taps]`` is depthwise, its last tap the newest
+        conv_keys = jax.random.split(jax.random.fold_in(key, 10), 3)
+        taps = config.conv_l_cache
+        return {
+            "conv_norm": norm_init((n, d), dt),
+            "conv_in": normal(conv_keys[0], (n, d, 3 * d), d ** -0.5),
+            "conv_w": normal(conv_keys[1], (n, d, taps), taps ** -0.5),
+            "conv_out": normal(conv_keys[2], (n, d, d), d ** -0.5),
+        }
+
+    def dense_stack(n):
+        return {
+            "mlp_norm": norm_init((n, d), dt),
             "w_gate": normal(keys[5], (n, d, f), d ** -0.5),
             "w_up": normal(keys[6], (n, d, f), d ** -0.5),
             "w_down": normal(keys[7], (n, f, d), f ** -0.5),
-        })
+        }
+
+    def moe_stack(n):
+        # one source of truth for the expert init recipe (router-f32
+        # policy, scales): moe.init_moe_params, vmapped over layers
+        from dcos_commons_tpu.models.moe import init_moe_params
+
+        moe_config = moe_config_of(config)
+        return {"mlp_norm": norm_init((n, d), dt), **jax.vmap(
+            lambda k: init_moe_params(moe_config, k)
+        )(jax.random.split(keys[5], n))}
+
+    builders = {
+        "attention": attention_stack, "conv": conv_stack,
+        "dense": dense_stack, "moe": moe_stack,
+    }
+    stacks = {
+        part: build(config.n_layers_of(part))
+        for part, build in builders.items() if config.n_layers_of(part)
+    }
+    if config.one_kind:
+        layers = {}
+        for part in ("attention", "conv", "moe", "dense"):
+            layers.update(stacks.get(part, {}))
+    else:
+        layers = stacks
     params = {
         "embed": normal(keys[0], (config.vocab, d), d ** -0.5),
         "layers": layers,
@@ -300,34 +443,52 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
 def sharding_rules(config: TransformerConfig) -> Dict[str, P]:
     """Param path -> PartitionSpec (scaling-book layout):
     heads/ffn over tp, the other big axis over fsdp; MoE expert axes
-    over ep (GSPMD then inserts the dispatch collectives)."""
-    rules = {
-        "embed": P("tp", "fsdp"),
-        "layers/attn_norm": P(None, None),
-        "layers/wq": P(None, "fsdp", "tp"),
-        "layers/wk": P(None, "fsdp", "tp"),
-        "layers/wv": P(None, "fsdp", "tp"),
-        "layers/wo": P(None, "tp", "fsdp"),
-        "layers/mlp_norm": P(None, None),
-        "final_norm": P(None),
+    over ep (GSPMD then inserts the dispatch collectives).  Paths
+    follow ``init_params``: ``layers/<leaf>`` where every layer is of
+    one kind, ``layers/<part>/<leaf>`` in a mixed pattern."""
+    parts = {
+        "attention": {
+            "attn_norm": P(None, None),
+            "wq": P(None, "fsdp", "tp"),
+            "wk": P(None, "fsdp", "tp"),
+            "wv": P(None, "fsdp", "tp"),
+            "wo": P(None, "tp", "fsdp"),
+        },
+        "conv": {
+            "conv_norm": P(None, None),
+            "conv_in": P(None, "fsdp", "tp"),
+            "conv_w": P(None, None, None),
+            "conv_out": P(None, "tp", "fsdp"),
+        },
+        "dense": {
+            "mlp_norm": P(None, None),
+            "w_gate": P(None, "fsdp", "tp"),
+            "w_up": P(None, "fsdp", "tp"),
+            "w_down": P(None, "tp", "fsdp"),
+        },
     }
-    if not config.tie_embeddings:
-        rules["lm_head"] = P("fsdp", "tp")
+    if config.qk_norm:
+        parts["attention"]["q_norm"] = P(None, None)
+        parts["attention"]["k_norm"] = P(None, None)
     if config.attention == "eva":
-        rules["layers/eva_phi"] = P(None, "tp", None)
-        rules["layers/eva_mu"] = P(None, "tp", None)
+        parts["attention"]["eva_phi"] = P(None, "tp", None)
+        parts["attention"]["eva_mu"] = P(None, "tp", None)
     if config.n_experts > 0:
         # the expert-axis rules live next to the MoE model so the
         # dispatch layout and its sharding can't drift apart
         from dcos_commons_tpu.models.moe import moe_sharding_rules
 
-        rules.update(moe_sharding_rules(prefix="layers/", stacked=True))
-    else:
-        rules.update({
-            "layers/w_gate": P(None, "fsdp", "tp"),
-            "layers/w_up": P(None, "fsdp", "tp"),
-            "layers/w_down": P(None, "tp", "fsdp"),
-        })
+        parts["moe"] = {"mlp_norm": P(None, None), **moe_sharding_rules(
+            stacked=True, expert_bias=config.moe_expert_bias
+        )}
+    rules = {"embed": P("tp", "fsdp"), "final_norm": P(None)}
+    for part, leaves in parts.items():
+        if not config.n_layers_of(part):
+            continue
+        prefix = "layers/" if config.one_kind else f"layers/{part}/"
+        rules.update({prefix + name: spec for name, spec in leaves.items()})
+    if not config.tie_embeddings:
+        rules["lm_head"] = P("fsdp", "tp")
     return rules
 
 
@@ -438,30 +599,25 @@ def _mlp_block(config: TransformerConfig, layer, x):
     return x + (gate * up) @ dq(layer["w_down"], x.dtype)
 
 
-def _ffn_block(config: TransformerConfig, layer, x, decode: bool = False):
-    """The per-layer FFN: dense SwiGLU or MoE.  Returns (x, aux).
+def _ffn_block(config: TransformerConfig, layer, x):
+    """The per-layer FFN of the TRAINING forward: dense SwiGLU or MoE.
+    Returns (x, aux).
 
     MoE notes: tokens route in groups of <= moe_group_size (bounding
     the one-hot dispatch tensors; groups never span batch rows, so the
-    slot cumsum stays within a dp shard).  In ``decode`` the capacity
-    covers every token of the step — token dropping is a training-time
-    load-balancing pressure; a server must not drop, and drop-free
-    routing is also what makes cached decode equal full forwards."""
+    slot cumsum stays within a dp shard) under the capacity factor's
+    pressure.  A server must not drop: the serving programs go through
+    models/decode.py ``_serve_ffn`` (moe.py ``moe_serve_ffn``)."""
     if config.n_experts <= 0:
         return _mlp_block(config, layer, x), jnp.zeros((), jnp.float32)
-    from dcos_commons_tpu.models.moe import MoEConfig, moe_ffn
+    from dcos_commons_tpu.models.moe import moe_ffn
 
     b, s, d = x.shape
-    moe_config = MoEConfig(
-        d_model=d,
-        d_ff=config.d_ff,
-        n_experts=config.n_experts,
-        top_k=config.moe_top_k,
-        capacity_factor=config.moe_capacity_factor,
-        dtype=config.dtype,
-    )
+    moe_config = moe_config_of(config)
     moe_params = {
-        key: layer[key] for key in ("router", "w_gate", "w_up", "w_down")
+        key: layer[key] for key in (
+            "router", "w_gate", "w_up", "w_down", "expert_bias"
+        ) if key in layer
     }
     normed = _norm(config, x, layer["mlp_norm"])
     # group = a whole number of sequence positions per batch row so
@@ -470,15 +626,13 @@ def _ffn_block(config: TransformerConfig, layer, x, decode: bool = False):
         config.moe_group_size if s % config.moe_group_size == 0 else s
     )
     tokens = normed.reshape(b * s // group, group, d)
-    capacity = group if decode else None
     # axis_name=None: under jit, GSPMD partitions the expert einsums
     # from the param shardings (expert axis over ep) and inserts the
     # dispatch collectives — the shard_map path stays available for
     # explicit all_to_all control (dryrun's ep section)
     y, aux = jax.vmap(
         lambda g: moe_ffn(
-            moe_config, moe_params, g, capacity=capacity,
-            impl=config.moe_impl,
+            moe_config, moe_params, g, impl=config.moe_impl,
         )
     )(tokens)
     return x + y.reshape(b, s, d), aux.mean()
@@ -494,6 +648,15 @@ def _layer_scan(config: TransformerConfig, layers, x, positions):
     size); every layer that fits its activations in leftover HBM buys
     that fraction of the recompute back.  The non-remat span is the
     tail because those activations die first in backward."""
+
+    if not config.one_kind or config.qk_norm:
+        raise NotImplementedError(
+            "the training forward scans ONE kind of layer, attention "
+            "without a query/key norm; this layer pattern "
+            f"({sorted(set(config.layer_kinds))}, qk_norm "
+            f"{config.qk_norm}) has a serving path only (models/decode.py "
+            "paged_prefill_chunk / paged_decode_step)"
+        )
 
     def layer_fn(x, layer):
         x = _attention_block(config, layer, x, positions)

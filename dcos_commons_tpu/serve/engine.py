@@ -271,6 +271,7 @@ class PagedEngine:
         write_page: Optional[Callable] = None,
         handoff: Optional[Callable] = None,
         resolve_decode_fn: Optional[Callable] = None,
+        device_counters: Optional[Callable[[], dict]] = None,
         queue_timeout_s: float = 600.0,
         on_idle: Optional[Callable[[], None]] = None,
         idle_every_s: float = 0.05,
@@ -286,6 +287,10 @@ class PagedEngine:
         self._prefill_fn = prefill_chunk_fn
         self._decode_fn = decode_fn
         self._resolve_fn = resolve_decode_fn
+        # cumulative sums the device half counted itself and fetched
+        # with its steps' tokens (PagedPoolModel.loop_counters): they
+        # join ``loop`` in ``stats()``
+        self._device_counters = device_counters
         # decode steps dispatched and not yet applied, oldest first:
         # each is its ``dispatched`` rows by slot.  At most one between
         # ticks, and none without a ``resolve_decode_fn``.  Only the
@@ -312,6 +317,14 @@ class PagedEngine:
                 f"the arena {self._page_tokens}"
             )
         self._pages_per_row = self._layout.table_len(int(max_len))
+        # pages that do not say all a row has seen are never shared,
+        # and never travel (RowLayout.carries_state)
+        prefix_cache = prefix_cache and not self._layout.carries_state
+        self._prefix_cache = bool(prefix_cache)
+        if handoff is not None and self._layout.carries_state:
+            raise ValueError(
+                "no prefill hand-off: " + self._layout.carries_state
+            )
         self._chunk_tokens = int(chunk_tokens)
         if self._layout.window and (
             self._chunk_tokens % self._layout.chunk
@@ -649,6 +662,15 @@ class PagedEngine:
                 "loop": self._loop_stats_locked(),
                 **alloc.stats(),
                 "kv_page_tokens": self._page_tokens,
+                # what a row keeps outside its pages, resident for
+                # every slot; where it is not 0 the prefix cache is off
+                # and says why
+                "state_bytes_per_row": self._layout.state_bytes_per_row,
+                "prefix_cache": (
+                    "on" if self._prefix_cache else
+                    "off: " + self._layout.carries_state
+                    if self._layout.carries_state else "off"
+                ),
                 "prefill_chunk_tokens": self._chunk_tokens,
                 # prompt tokens not yet prefilled (queued +
                 # mid-chunk): the chunked-prefill pressure signal —
@@ -702,6 +724,7 @@ class PagedEngine:
             "decode_s_sum": round(self._decode_s_sum, 6),
             "decode_tokens_sum": self._decode_tokens_sum,
             "requests_timed": self._requests_timed,
+            **(self._device_counters() if self._device_counters else {}),
         }
 
     def _phase(self, name: str) -> _Phase:
@@ -1418,9 +1441,18 @@ class PagedEngine:
         hold."""
         self._on_loop(lambda: self._freeze(rid))
 
+    def _pages_travel(self) -> None:
+        """Refuse a migration verb where a session is more than its
+        pages: never pages without their state."""
+        from dcos_commons_tpu.serve.migration import MigrationError
+
+        if self._layout.carries_state:
+            raise MigrationError(self._layout.carries_state)
+
     def _freeze(self, rid: int) -> None:
         from dcos_commons_tpu.serve.migration import MigrationError
 
+        self._pages_travel()
         with self._cv:
             row = self._find_rid_locked(rid)
             if row is None or row.admission is None:
@@ -1463,6 +1495,7 @@ class PagedEngine:
             SessionSnapshot,
         )
 
+        self._pages_travel()
         if self._read_page is None:
             raise MigrationError(
                 "no page reader bound (PagedEngine read_page=...)"
@@ -1517,6 +1550,7 @@ class PagedEngine:
     def _splice(self, snap) -> int:
         from dcos_commons_tpu.serve.migration import MigrationError
 
+        self._pages_travel()
         if self._write_page is None:
             raise MigrationError(
                 "no page writer bound (PagedEngine write_page=...)"

@@ -93,11 +93,21 @@ class RowLayout:
     entries ``[0, window/P)`` are the ring of the current window's
     exact K/V (position ``p`` in ring page ``(p % window) // P``);
     entry ``window/P + i`` holds the summaries of chunks
-    ``[i*P, (i+1)*P)``, written as each chunk ends."""
+    ``[i*P, (i+1)*P)``, written as each chunk ends.
+
+    ``state_bytes_per_row``: what a row keeps on the device OUTSIDE
+    its pages, by its slot and however long it is (a conv layer's last
+    gated inputs, models/decode.py ``init_paged_kv_cache``).  It is
+    resident for every slot, so a free slot is all that admission
+    needs of it; but a row's pages alone then no longer say what the
+    row has seen: such a layout shares no prefix page, and a session
+    of it cannot be exported, spliced or handed off
+    (``carries_state``)."""
 
     page_tokens: int
     window: int = 0
     chunk: int = 0
+    state_bytes_per_row: int = 0
 
     def __post_init__(self) -> None:
         if not self.window:
@@ -116,6 +126,20 @@ class RowLayout:
     @property
     def window_pages(self) -> int:
         return self.window // self.page_tokens if self.window else 0
+
+    @property
+    def carries_state(self) -> str:
+        """Why this layout's pages may not travel or be shared without
+        the row they belong to ("" where they may): the reason a
+        client is given."""
+        if not self.state_bytes_per_row:
+            return ""
+        return (
+            f"a row of this model keeps {self.state_bytes_per_row} bytes "
+            "of recurrent state outside its pages (conv layers), which "
+            "no page carries: its pages are not shared, exported, "
+            "spliced or handed off"
+        )
 
     @property
     def share_tokens(self) -> int:
@@ -211,9 +235,10 @@ class RowLayout:
 def layout_from_env(env, page_tokens: int) -> RowLayout:
     """The row layout of the model a task env describes: EVA's two
     regions where ``MODEL_CONFIG`` names a file whose
-    ``attention_class`` is ``"eva"`` (the three keys read here are the
+    ``attention_class`` is ``"eva"`` (the keys read here are the
     ones ``models.config_from_env`` reads; this module stays
-    jax-free), else every token for ever."""
+    jax-free), else every token for ever, beside the state that the
+    file's conv layers keep outside the pages."""
     import json
 
     path = env.get("MODEL_CONFIG", "")
@@ -222,7 +247,13 @@ def layout_from_env(env, page_tokens: int) -> RowLayout:
     with open(path, "r", encoding="utf-8") as f:
         data = json.load(f)
     if data.get("attention_class") != "eva":
-        return RowLayout(page_tokens)
+        # a conv layer's state, at the 2 bytes an element the chip
+        # serves in (the pool states what its arena really holds)
+        n_conv = sum(t == "conv" for t in data.get("layer_types") or ())
+        return RowLayout(page_tokens, state_bytes_per_row=(
+            n_conv * (int(data.get("conv_L_cache", 3)) - 1)
+            * int(data["hidden_size"]) * 2 if n_conv else 0
+        ))
     return RowLayout(
         page_tokens, window=int(data["window_size"]),
         chunk=int(data["chunk_size"]),
@@ -308,6 +339,8 @@ def paged_config_from_env(env) -> PagedServeConfig:
             f"serving.kv_pages or lower MAX_LEN"
         )
     prefix = (env.get("PREFIX_CACHE", "1") or "1") not in ("0", "false")
+    # pages that do not say all a row has seen are never shared
+    prefix = prefix and not layout.carries_state
     return PagedServeConfig(
         page_tokens=page_tokens, pages=pages, chunk_tokens=chunk,
         max_len=max_len, slots=slots, prefix_cache=prefix,

@@ -104,6 +104,7 @@ class PagedPoolModel:
         import jax.numpy as jnp
 
         from dcos_commons_tpu.models.decode import (
+            arena_lanes,
             init_paged_kv_cache,
             paged_decode_step,
             paged_prefill_chunk,
@@ -124,9 +125,13 @@ class PagedPoolModel:
         # (serve/paging.py RowLayout); the engine is given the same
         # layout, and the two programs below read it off ``config``
         eva = config.attention == "eva"
+        n_conv = config.n_layers_of("conv")
         self.layout = RowLayout(
             page_tokens, config.window_size if eva else 0,
             config.chunk_size if eva else 0,
+            # a row's share of ``cache["conv_state"]``
+            state_bytes_per_row=n_conv * (config.conv_l_cache - 1)
+            * config.d_model * jnp.dtype(config.dtype).itemsize,
         )
         self.pages_per_row = self.layout.table_len(max_len)
         self._put = put if put is not None else (lambda x: x)
@@ -134,18 +139,28 @@ class PagedPoolModel:
 
         init = functools.partial(
             init_paged_kv_cache, config, pages + 1, page_tokens,
-            kv_dtype,
+            kv_dtype, slots, arena_lanes(config),
         )
         if cache_sharding is not None:
+            if n_conv:
+                raise ValueError(
+                    "a sharded arena has no layout for conv state: a "
+                    "pattern with conv layers is served on one device"
+                )
             self.cache = jax.jit(init, out_shardings=cache_sharding)()
         else:
             self.cache = jax.jit(init)()
 
-        def _prefill(params, cache, tokens, table, start, true_len,
-                     temp, seed):
-            logits, cache = paged_prefill_chunk(
-                config, params, cache, tokens, table, start, true_len
+        def _prefill(params, cache, counted, tokens, table, start,
+                     true_len, temp, seed, slot):
+            logits, cache, counts = paged_prefill_chunk(
+                config, params, cache, tokens, table, start, true_len,
+                slot,
             )
+            if counts is not None:
+                # this chunk's mixtures, and the chunk itself, on top
+                # of the chunks nobody has fetched yet
+                counted = counted + jnp.append(counts, 1)
             # the chunk's last real position is start + true_len - 1
             # == prompt_len - 1 on the final chunk: however a prompt
             # was chunked, its first token is sampled under one key
@@ -153,7 +168,8 @@ class PagedPoolModel:
                 key = jax.random.fold_in(
                     jax.random.key(seed), start + true_len - 1
                 )
-                return con(sample_token(logits[0], temp, key)), cache
+                first = sample_token(logits[0], temp, key)
+            return (con(first), con(counted)), cache
 
         # the mesh the arena is laid over is the ambient mesh while the
         # decode step is traced: its attention kernel is chosen by it
@@ -172,9 +188,11 @@ class PagedPoolModel:
             # that step left it: the host has not seen it yet
             tok = jnp.where(carry, prev, tok)
             with arena_mesh():
-                logits, cache = paged_decode_step(
-                    config, params, cache, tok, pos, tables
+                logits, cache, counts = paged_decode_step(
+                    config, params, cache, tok, pos, tables,
                 )
+            if counts is None:
+                counts = jnp.zeros(2, jnp.int32)
 
             def pick_row(lg, temp, seed, p):
                 key = jax.random.fold_in(jax.random.key(seed), p)
@@ -182,7 +200,7 @@ class PagedPoolModel:
 
             with jax.named_scope("sample"):
                 nxt = jax.vmap(pick_row)(logits, temps, seeds, pos)
-            return con(nxt), cache
+            return (con(nxt), con(counts)), cache
 
         donate = {}
         if jax.default_backend() != "cpu":
@@ -199,6 +217,20 @@ class PagedPoolModel:
             lambda: con(jnp.zeros(slots, jnp.int32))
         )()
         self._no_carry = np.zeros(slots, np.bool_)
+        # what the decode steps' mixtures counted on the device, summed
+        # as each step's tokens are fetched (the same ``device_get``):
+        # live (token, expert) assignments, and expert groups that held
+        # at least one, over the expert layers
+        self._moe_counts = np.zeros(2, np.int64)
+        # the same of the prefill chunks, and the chunks counted: the
+        # device adds them up from chunk to chunk (few chunks are
+        # fetched) and a prompt's last chunk brings the sum with its
+        # token, after which the device starts from zero again
+        self._moe_prefill_counts = np.zeros(3, np.int64)
+        self._no_chunks = jax.jit(
+            lambda: con(jnp.zeros(3, jnp.int32))
+        )()
+        self._chunks_unfetched = self._no_chunks
 
     def prefill_chunk(
         self, tokens: np.ndarray, slot: int, table: np.ndarray,
@@ -210,25 +242,29 @@ class PagedPoolModel:
         ``final`` chunk, returns the token sampled at its last real
         position; any other chunk is dispatched and NOT fetched
         (returns None): the host goes on while the device writes.
-        ``slot`` is the engine's row id — a protocol rider (the gang
-        driver broadcasts it), the math needs only the table."""
-        del slot
+        ``slot`` is the engine's row id: where a pattern with conv
+        layers keeps the row's state (models/decode.py); any other
+        model's math needs only the table."""
         with self._span("pool.prefill_chunk"):
-            first, self.cache = self._prefill_c(
-                self.params, self.cache,
+            (first, counted), self.cache = self._prefill_c(
+                self.params, self.cache, self._chunks_unfetched,
                 self._put(np.asarray(tokens, np.int32)),
                 self._put(np.asarray(table, np.int32)),
                 np.int32(start), np.int32(true_len),
-                np.float32(temp), np.int32(seed),
+                np.float32(temp), np.int32(seed), np.int32(slot),
             )
             if not final:
                 # nobody reads this chunk's sample, and its writes
                 # need no fence: whatever reads these pages later (a
                 # chunk or decode step sharing them, export_page) is
                 # a later program on the same in-order queue
+                self._chunks_unfetched = counted
                 return None
+            self._chunks_unfetched = self._no_chunks
             with self._span("pool.prefill_chunk.fetch"):
-                return int(self._jax.device_get(first))
+                first, counted = self._jax.device_get((first, counted))
+            self._moe_prefill_counts += np.asarray(counted, np.int64)
+            return int(first)
 
     def decode(
         self, tok: np.ndarray, pos: np.ndarray,
@@ -263,7 +299,7 @@ class PagedPoolModel:
                 )
             nxt, self.cache = self._decode_c(
                 self.params, self.cache,
-                self._no_tokens if previous is None else previous,
+                self._no_tokens if previous is None else previous[0],
                 self._put(np.asarray(carry, np.bool_) if ahead
                           else self._no_carry),
                 self._put(np.asarray(tok, np.int32)),
@@ -282,12 +318,31 @@ class PagedPoolModel:
             previous, self._outstanding = self._outstanding, None
             return self._fetch(previous)
 
-    def _fetch(self, tokens) -> np.ndarray:
-        if tokens is None:
+    def loop_counters(self) -> dict:
+        """Cumulative sums for the engine's ``loop`` (``/stats``), one
+        step behind the dispatch like the tokens they came with; the
+        prefill chunks' arrive with their prompt's last chunk, so
+        ``moe_prefill_chunks_counted`` (not ``prefill_calls``) is what
+        the two sums beside it are sums over."""
+        return {
+            "moe_assignments_sum": int(self._moe_counts[0]),
+            "moe_groups_touched_sum": int(self._moe_counts[1]),
+            "moe_prefill_assignments_sum": int(self._moe_prefill_counts[0]),
+            "moe_prefill_groups_touched_sum": int(
+                self._moe_prefill_counts[1]
+            ),
+            "moe_prefill_chunks_counted": int(self._moe_prefill_counts[2]),
+        }
+
+    def _fetch(self, step) -> np.ndarray:
+        """One step's (tokens, counts) in ONE ``device_get``."""
+        if step is None:
             return np.zeros(0, np.int32)
         try:
             with self._span("pool.decode.fetch"):
-                return np.asarray(self._jax.device_get(tokens))
+                tokens, counts = self._jax.device_get(step)
+            self._moe_counts += np.asarray(counts, np.int64)
+            return np.asarray(tokens)
         except BaseException:
             # an asynchronous dispatch's error surfaces here: whatever
             # was queued behind it is lost with it
@@ -304,7 +359,11 @@ class PagedPoolModel:
         page's.  Single-caller
         contract like ``prefill_chunk``/``decode``: only the engine
         loop may call this (serve/engine.py routes it through the
-        page-I/O queue), since it reads ``self.cache`` mid-stream."""
+        page-I/O queue), since it reads ``self.cache`` mid-stream.
+        Refused where a page is not all there is to know of its
+        positions (``RowLayout.carries_state``)."""
+        if self.layout.carries_state:
+            raise ValueError(self.layout.carries_state)
         return {
             key: np.asarray(self._jax.device_get(arr[:, page]))
             for key, arr in self.cache.items()
@@ -315,7 +374,10 @@ class PagedPoolModel:
         THIS arena.  Keys must match this pool's cache layout (both
         ends run the same model/kv_dtype — the migration geometry
         check upstream guarantees page_tokens; dtype mismatch raises
-        here).  Same single-caller contract as ``export_page``."""
+        here).  Same single-caller contract as ``export_page``, and
+        the same refusal."""
+        if self.layout.carries_state:
+            raise ValueError(self.layout.carries_state)
         if set(payload) != set(self.cache):
             raise ValueError(
                 f"page payload keys {sorted(payload)} do not match "

@@ -175,6 +175,15 @@ def main() -> int:
         dtype=jnp.bfloat16 if devices["platform"] == "tpu" else jnp.float32,
         remat=False,
     )
+    if not config.one_kind:
+        # per-part stacks and a row's conv state have no layout over
+        # the gang's tp mesh (models/transformer.py init_params,
+        # serve/pool.py): a mixed layer pattern is one chip's
+        raise SystemExit(
+            "serve_gang_worker: the layer pattern "
+            f"{sorted(set(config.layer_kinds))} is not served by a gang; "
+            "deploy it through serve_worker.py on one chip"
+        )
     max_len = int(os.environ.get("MAX_LEN", "256"))
     # unset SERVE_BATCH means a bare/dev launch; fall back to one
     # request rather than the deploy default 8 (see options.json

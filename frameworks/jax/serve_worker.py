@@ -111,7 +111,10 @@ def main() -> int:
     import jax.numpy as jnp
 
     from dcos_commons_tpu.models import config_from_env, init_params
-    from dcos_commons_tpu.models.decode import decode_attention_kernel
+    from dcos_commons_tpu.models.decode import (
+        decode_attention_kernel,
+        layer_plan,
+    )
     from dcos_commons_tpu.serve.pool import PagedPoolModel
     from dcos_commons_tpu.utils import (
         claim_devices,
@@ -450,6 +453,7 @@ def main() -> int:
         # this device half carries a token on the device, so the
         # loop runs one call ahead of it
         resolve_decode_fn=pool.resolve_decode,
+        device_counters=pool.loop_counters,
         log=lambda msg: print(msg, flush=True),
         extra_stats={"http_port": bound_port},
         annotate=jax.profiler.TraceAnnotation, tracer=tracer,
@@ -484,6 +488,16 @@ def main() -> int:
             "decode_attention": decode_attention,
             "window_size": config.window_size,
             "chunk_size": config.chunk_size,
+            # the layer pattern, and how a mixture's tokens choose
+            "layer_types": [op for op, _ffn in config.layer_kinds],
+            # how both programs walk it: [lead, period, reps, tail]
+            "layer_plan": list(layer_plan(config.layer_kinds)),
+            "n_dense_layers": config.n_dense_layers,
+            "n_experts": config.n_experts,
+            "moe_top_k": config.moe_top_k,
+            "moe_d_ff": config.moe_d_ff or config.d_ff,
+            "moe_routing": config.moe_score,
+            "conv_l_cache": config.conv_l_cache,
             "model_config": os.environ.get("MODEL_CONFIG", ""),
         },
         warm_s=round(time.monotonic() - warm_t0, 2),

@@ -129,7 +129,7 @@ class Row:
             padded = np.zeros((1, chunk_tokens), np.int32)
             padded[0, :n] = prompt[start:start + n]
             self._ensure(start, start + n - 1)
-            logits, self.cache = self._prefill(
+            logits, self.cache, _ = self._prefill(
                 self.cache, padded, self.table.copy(), start, n
             )
             start += n
@@ -144,7 +144,7 @@ class Row:
         self._ensure(pos, pos)
         tables = np.zeros((2, len(self.table)), np.int32)
         tables[1] = self.table
-        logits, self.cache = self._decode(
+        logits, self.cache, _ = self._decode(
             self.cache, np.array([0, token], np.int32),
             np.array([0, pos], np.int32), tables,
         )

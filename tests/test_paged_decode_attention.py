@@ -153,11 +153,11 @@ def test_the_decode_step_with_the_kernel_equals_the_gather_path(
             config, params, cache, *args
         )
     )(cache, *args)
-    want_logits, want_cache = step()
+    want_logits, want_cache, _ = step()
     monkeypatch.setattr(
         decode, "decode_attention_kernel", lambda config, cache: "interpret"
     )
-    logits, new_cache = step()
+    logits, new_cache, _ = step()
     live = np.asarray(args[1]) > 0
     got, want = np.asarray(logits)[live], np.asarray(want_logits)[live]
     if dtype == "float32":

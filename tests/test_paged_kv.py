@@ -1133,7 +1133,7 @@ def _reference_step(config, params, cache, step, args):
             f"{qdims}l,blkd->{qdims}d", probs, v_all.astype(jnp.float32)
         ).astype(config.dtype)
         x = x + attn.reshape(b, s, h * hd) @ layer["wo"]
-        x = D._serve_ffn(config, layer, x)
+        x, _counts = D._serve_ffn(config, layer, x)
     x = D._norm(config, x, params["final_norm"])
     if step == "prefill_chunk":
         x_last = lax.dynamic_index_in_dim(
@@ -1160,7 +1160,7 @@ def test_arena_in_place_equals_per_layer_slice_and_restack(
     config, params = arena_model
     cache, args = _arena_case(config, step, kv_dtype)
     fn = _arena_step(step)
-    logits, new_cache = jax.jit(
+    logits, new_cache, _ = jax.jit(
         lambda cache, *args: fn(config, params, cache, *args)
     )(cache, *args)
     want_logits, want_cache = jax.jit(
@@ -1261,7 +1261,7 @@ def test_a_quantized_arena_takes_the_gather_path_on_a_tpu(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert decode.decode_attention_kernel(config, native) == "compiled"
     assert decode.decode_attention_kernel(config, cache) is None
-    logits, new_cache = jax.jit(
+    logits, new_cache, _ = jax.jit(
         lambda cache, *args: decode.paged_decode_step(
             config, params, cache, *args
         )
