@@ -10,8 +10,9 @@ holds no operation of that name (a program without the kernel) or the program
 has no such sums.
 
 The span: the harness stamps `trace_window`'s end when `/trace/stop` has
-returned, and the stop writes the profile for tens of seconds (30.8 s in all
-for 4 s of trace, PR 28's call j5), through which the rows live on and change.
+returned, and the stop collects and writes the profile for some seconds (30.8 s
+in all for 4 s of trace in PR 28's call j5; half of that since PR 34),
+through which the rows live on and change.
 The device's times are of the mix's `trace_s` seconds from the span's start,
 so the sums are read over those seconds and one more, not over the whole
 stamp."""

@@ -26,11 +26,12 @@ move the same weights and operations, so the ones listed stand for all.
 
 Over which samples the sums are taken: `/stats` is sampled once a second and
 the harness stamps the traced span's end when `/trace/stop` has returned,
-which here is two minutes after the 4 recorded seconds ended; through it the
-worker slows and rows pile up.  The trace says itself how many programs it
-holds, so the sums run from the span's first sample to the sample at which the
-program's own count of calls has grown by the number nearest that: a span cut
-at `trace_s` by the clock lost the traced calls' last third in one run (3.6/s,
+which here was two minutes after the 4 recorded seconds ended (under one
+since PR 34); through it the worker slows and rows pile up.  The trace says
+itself how many programs it holds, so the sums run from the span's first
+sample to the sample at which the program's own count of calls has grown by
+the number nearest that: a span cut at `trace_s` by the clock lost the
+traced calls' last third in one run (3.6/s,
 call u1: 382 calls of 572, and they were the short steps of few rows, so the
 share read 98.5% where the 581 calls up to the next sample give 87.6%), and
 never a gauge.

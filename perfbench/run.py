@@ -54,6 +54,18 @@ PROGRAM_FILES = (
 )
 
 
+# What a traced run waits for /trace/stop.  The stop's cost follows the
+# device programs the traced span held (and the operations a program
+# executes), so the wait does, and no fixed one is there for a faster
+# program to outrun.  A program of `lfm2-24b.chat`, which executes the
+# most operations of the three cells', costs the stop 0.05 s, of
+# `evabyte.docqa` and `mixtral8x7b.chat` half that (PERF.md section 6,
+# PR 34).  No decode step on this chip lasts under a millisecond.
+STOP_FLOOR_S = 30.0
+STOP_S_PER_PROGRAM = 0.15
+UNCOUNTED_PROGRAMS_PER_S = 1000.0
+
+
 class RunFailure(Exception):
     pass
 
@@ -115,12 +127,74 @@ class StatsPoller:
         self._thread.join(timeout=10)
 
 
-def control(deployment: Deployment, path: str = "", payload=None):
+def control(deployment: Deployment, path: str = "", payload=None,
+            timeout_s: float = 120.0):
     with open(os.path.join(
         deployment.sandbox(TASK), "perfbench_control.json"
     )) as f:
         port = json.load(f)["port"]
-    return http_json(f"http://127.0.0.1:{port}{path}", payload, timeout=120)[1]
+    return http_json(
+        f"http://127.0.0.1:{port}{path}", payload, timeout=timeout_s
+    )[1]
+
+
+def device_calls(sample: dict):
+    """The device programs the engine has dispatched so far, by its own
+    counters in a ``/stats`` sample; None where it has none."""
+    loop = sample.get("loop") or {}
+    if "decode_calls" not in loop or "prefill_calls" not in loop:
+        return None
+    return loop["decode_calls"] + loop["prefill_calls"]
+
+
+def traced_programs(samples, t0: float, t1: float):
+    """About how many device programs the traced span [t0, t1] held:
+    the rate at which the engine's count of calls grew over the
+    poller's samples from the last one before the span to the newest,
+    times the span.  None where fewer than two samples carry it."""
+    counted = [
+        (s["_t"], calls) for s in samples
+        if (calls := device_calls(s)) is not None
+    ]
+    before = [i for i, (t, _n) in enumerate(counted) if t <= t0]
+    counted = counted[before[-1] if before else 0:]
+    if len(counted) < 2 or counted[-1][0] <= counted[0][0]:
+        return None
+    (ta, na), (tb, nb) = counted[0], counted[-1]
+    return math.ceil((nb - na) / (tb - ta) * (t1 - t0))
+
+
+def stop_limit_s(programs, traced_s: float) -> float:
+    """How long a run waits for ``/trace/stop``: the stop's cost
+    follows the device programs traced, so the wait does.  A span
+    whose programs could not be counted is given the most it could
+    have held."""
+    if programs is None:
+        programs = math.ceil(UNCOUNTED_PROGRAMS_PER_S * traced_s)
+    return STOP_FLOOR_S + STOP_S_PER_PROGRAM * programs
+
+
+def stop_trace(deployment: Deployment, programs, traced_s: float) -> dict:
+    """``/trace/stop``, waited for as long as what was traced asks.
+    Returns what was traced, the seconds the stop took and the bytes of
+    profile it wrote."""
+    limit = stop_limit_s(programs, traced_s)
+    counted = "uncounted" if programs is None else f"about {programs}"
+    began = time.monotonic()
+    try:
+        reply = control(deployment, "/trace/stop", {}, timeout_s=limit)
+    except OSError as e:
+        raise RunFailure(
+            f"/trace/stop gave no answer in {time.monotonic() - began:.1f} s "
+            f"({counted} device programs traced in {traced_s:.1f} s; the "
+            f"limit for them is {limit:.1f} s): {e!r}"
+        )
+    stop_s, written = time.monotonic() - began, reply.get("profile_bytes")
+    say(f"traced {traced_s:.2f}s, {counted} device programs; /trace/stop "
+        f"took {stop_s:.2f}s of the {limit:.1f}s it may and wrote {written} "
+        "bytes")
+    return {"traced_s": traced_s, "programs": programs, "stop_s": stop_s,
+            "profile_bytes": written}
 
 
 def check_device(device: dict, chips: int, peaks: dict) -> None:
@@ -291,14 +365,16 @@ def run(args) -> int:
         load.begin()
         poller.start()
         trace_dir = os.path.join(workdir, "trace")
-        trace_window = None
+        trace_window = trace_stop = None
         if trace:
             begin = start + mix["trace_after_s"]
             time.sleep(max(0.0, begin - time.monotonic()))
             t0 = time.monotonic()
             control(deployment, "/trace/start", {"dir": trace_dir})
             time.sleep(mix["trace_s"])
-            control(deployment, "/trace/stop", {})
+            t1 = time.monotonic()
+            programs = traced_programs(list(poller.samples), t0, t1)
+            trace_stop = stop_trace(deployment, programs, t1 - t0)
             trace_window = (t0, time.monotonic())
         judged = load.drain()
         poller.stop()
@@ -357,7 +433,7 @@ def run(args) -> int:
         "stats_samples": poller.samples,
         "final_stats": final_stats,
         "setup_s": setup_s, "deploy_plan_s": deploy_plan_s,
-        "trace_window": trace_window,
+        "trace_window": trace_window, "trace_stop": trace_stop,
         "peaks": peaks.get(device["kind"]),
         "device": device,
         "config_file": config_path,

@@ -37,6 +37,13 @@ PROGRAM_NAMES = (
 )
 
 CONTROL_FILE = "perfbench_control.json"
+# Where /trace/stop writes the profiler session's bytes, as they come,
+# under the directory /trace/start named: the path jax.profiler's own
+# stop would have put them at.  That stop also exports a trace viewer's
+# JSON, which no reader of the benchmark opens and which was three
+# quarters of a stop's seconds (PERF.md section 6, PR 34).
+PROFILE_FILE = os.path.join("plugins", "profile", "perfbench",
+                            "worker.xplane.pb")
 # every compile or cache read the process makes, by the host's clock
 COMPILE_EVENTS = []
 # seconds from this process's start to each step of its set-up
@@ -148,6 +155,7 @@ def _control_server():
             )
 
     jax.monitoring.register_event_duration_secs_listener(on_duration)
+    profile = {}
 
     class Control(BaseHTTPRequestHandler):
         def log_message(self, fmt, *args):
@@ -175,14 +183,25 @@ def _control_server():
         def do_POST(self):
             length = int(self.headers.get("Content-Length", 0))
             body = json.loads(self.rfile.read(length) or b"{}")
+            reply = {}
             if self.path == "/trace/start":
-                jax.profiler.start_trace(body["dir"])
+                from jax._src.lib import _profiler
+
+                # the backend before the session, as jax.profiler's own
+                # start has it, or the TPU's tracer records nothing
+                jax.devices()
+                profile["path"] = os.path.join(body["dir"], PROFILE_FILE)
+                profile["session"] = _profiler.ProfilerSession()
             elif self.path == "/trace/stop":
-                jax.profiler.stop_trace()
+                data = profile.pop("session").stop()
+                os.makedirs(os.path.dirname(profile["path"]), exist_ok=True)
+                with open(profile["path"], "wb") as f:
+                    f.write(data)
+                reply["profile_bytes"] = len(data)
             else:
                 self.send_error(404)
                 return
-            self._reply({"ok": True, "t": time.time()})
+            self._reply(dict(reply, ok=True, t=time.time()))
 
     server = ThreadingHTTPServer(("127.0.0.1", 0), Control)
     threading.Thread(target=server.serve_forever, daemon=True).start()
@@ -190,6 +209,7 @@ def _control_server():
     with open(tmp, "w") as f:
         json.dump({"port": server.server_address[1]}, f)
     os.replace(tmp, CONTROL_FILE)
+    return server
 
 
 def main() -> int:

@@ -70,5 +70,5 @@ def test_the_entry(bench):
         "name": NAME, "unit": "share", "better": "higher",
         "source": "program_counter", "layer": "engine host loop",
         "moves": "norm_lat_p50_s",
-        "workloads": ["mixtral8x7b.chat", "evabyte.docqa"],
+        "workloads": ["mixtral8x7b.chat", "evabyte.docqa", "lfm2-24b.chat"],
     }

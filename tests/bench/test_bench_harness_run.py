@@ -117,6 +117,27 @@ def test_every_number_compared_is_printed_beside_its_limit(traced_run):
     assert "compilations inside ramp and window: 0" in traced_run.stdout
 
 
+def test_a_traced_run_says_what_it_traced_and_what_the_stop_took(traced_run):
+    """ISSUE 34: the wait for ``/trace/stop`` is reckoned from the
+    device programs the poller saw in the traced seconds."""
+    import re
+
+    sys.path.insert(0, REPO)
+    from perfbench import run
+
+    line, = [line for line in traced_run.stdout.splitlines()
+             if line.startswith("traced ")]
+    found = re.fullmatch(
+        r"traced ([\d.]+)s, about (\d+) device programs; /trace/stop took "
+        r"([\d.]+)s of the ([\d.]+)s it may and wrote (\d+) bytes", line)
+    traced_s, programs, stop_s, limit, written = (
+        float(g) for g in found.groups())
+    assert 1.0 <= traced_s < 1.5  # the toy mix's trace_s
+    assert programs > 0 and stop_s < limit and written > 0
+    assert limit == pytest.approx(
+        run.stop_limit_s(int(programs), traced_s), abs=0.06)
+
+
 def test_the_measurement_path_refuses_to_run_without_a_tpu(toy):
     proc = run_cell(toy, trace="0")  # tests run with JAX_PLATFORMS=cpu
     assert proc.returncode != 0

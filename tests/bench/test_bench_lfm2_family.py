@@ -433,16 +433,12 @@ def test_the_cell_and_its_entries():
     mixtral = {
         m["name"] for m in bench.metrics("per_layer", "mixtral8x7b.chat")
     }
-    # all that Mixtral's cell reports but two: the entry of
-    # engine_decode_ahead_share.chat is held, list and all, by
-    # tests/bench/test_bench_decode_ahead.py, which only a benchmark PR
-    # may edit; decode_step_roofline.chat sets a gauge's rows beside the
-    # traced steps, and here the bytes of a step follow its rows
-    # (families/lfm2_moe/needs.py)
+    # all that Mixtral's cell reports but one (ISSUE 34 appended the
+    # cell to engine_decode_ahead_share.chat): decode_step_roofline.chat
+    # sets a gauge's rows beside the traced steps, and here the bytes
+    # of a step follow its rows (families/lfm2_moe/needs.py)
     assert len(mixtral) == 19
-    assert mixtral - names == {
-        "engine_decode_ahead_share.chat", "decode_step_roofline.chat",
-    }
+    assert mixtral - names == {"decode_step_roofline.chat"}
     assert names - mixtral == {
         "moe_experts_touched_per_layer.chat",
         "moe_grouped_matmul_roofline.chat",
