@@ -232,20 +232,27 @@ class RowLayout:
         return (last_pos + 1) // self.chunk - first_pos // self.chunk
 
 
-def layout_from_env(env, page_tokens: int) -> RowLayout:
-    """The row layout of the model a task env describes: EVA's two
-    regions where ``MODEL_CONFIG`` names a file whose
-    ``attention_class`` is ``"eva"`` (the keys read here are the
-    ones ``models.config_from_env`` reads; this module stays
-    jax-free), else every token for ever, beside the state that the
-    file's conv layers keep outside the pages."""
+def _model_file(env) -> dict:
+    """What the configuration file ``MODEL_CONFIG`` names states, under
+    its published key names; {} where the env names none."""
     import json
 
     path = env.get("MODEL_CONFIG", "")
     if not path:
-        return RowLayout(page_tokens)
+        return {}
     with open(path, "r", encoding="utf-8") as f:
-        data = json.load(f)
+        return json.load(f)
+
+
+def layout_from_env(env, page_tokens: int) -> RowLayout:
+    """The row layout the env's model asks for: the window and chunk of
+    a ``MODEL_CONFIG`` file whose ``attention_class`` is "eva" (read
+    here as JSON, by the file's published key names, so this module stays
+    jax-free), else every token for ever, beside the state that the
+    file's conv layers keep outside the pages."""
+    data = _model_file(env)
+    if not data:
+        return RowLayout(page_tokens)
     if data.get("attention_class") != "eva":
         # a conv layer's state, at the 2 bytes an element the chip
         # serves in (the pool states what its arena really holds)
@@ -260,6 +267,102 @@ def layout_from_env(env, page_tokens: int) -> RowLayout:
     )
 
 
+# a chip's bf16 FLOP/s over its HBM bytes/s: 197e12 / 819e9 on a TPU
+# v5e.  One constant and no table by device kind, because the scheduler
+# that checks a spec has no device: every mixture chooses the ceiling
+# below at any ratio from 140 up, and a dense model 256 from 129 to 256
+# (a v5p reads 166; on a v6e, 560, it would choose 512)
+_CHIP_FLOPS_PER_BYTE = 240
+# the widest chunk the code chooses for itself: every live row's next
+# token waits behind a chunk (20 ms at 512 tokens against a 4-9 ms
+# decode step: PERF.md section 6, PR 36), and it is the widest any
+# cell has run
+_CHOSEN_CHUNK_CEILING = 512
+
+
+def chunk_weights_from_env(env) -> Tuple[int, int]:
+    """(``read``, ``per_token``): the weight elements a prefill chunk
+    READS whatever its width (each layer's attention or conv
+    projections, its router and EVERY expert, or its dense FFN), and
+    those one token MULTIPLIES (the same with ``top_k`` experts in
+    place of all).  From the size names ``models.config_from_env``
+    reads, under its defaults, and the published keys of a
+    ``MODEL_CONFIG`` file, which win; jax-free, so the scheduler's spec
+    check and every worker reckon alike
+    (tests/test_prefill_chunk_choice.py holds ``read`` to the tree
+    ``init_params`` builds)."""
+    data = _model_file(env)
+    sizes = {
+        "hidden_size": int(env.get("D_MODEL", "512")),
+        "num_hidden_layers": int(env.get("N_LAYERS", "4")),
+        "num_attention_heads": int(env.get("N_HEADS", "8")),
+        "num_key_value_heads": int(env.get("N_KV_HEADS", "8")),
+        "intermediate_size": int(env.get("D_FF", "1408")),
+        "num_local_experts": int(env.get("N_EXPERTS", "0")),
+        # what only a file states (TransformerConfig's defaults)
+        "num_experts_per_tok": 2, "num_dense_layers": 0,
+        "moe_intermediate_size": 0, "conv_L_cache": 3,
+    }
+    sizes.update(
+        {key: int(data[key]) for key in sizes if data.get(key) is not None}
+    )
+    d, d_ff = sizes["hidden_size"], sizes["intermediate_size"]
+    heads, kv_heads = (
+        sizes["num_attention_heads"], sizes["num_key_value_heads"]
+    )
+    # a second published name for the same field wins where both stand
+    experts = int(data.get("num_experts", sizes["num_local_experts"]))
+    operators = (
+        data.get("layer_types")
+        or ("full_attention",) * sizes["num_hidden_layers"]
+    )
+    head_dim = d // heads
+    mixer = {
+        # wq and wo, wk and wv
+        "full_attention": 2 * d * heads * head_dim
+        + 2 * d * kv_heads * head_dim,
+        # the three gates' in-projection, the taps, the out-projection
+        "conv": 3 * d * d + d * sizes["conv_L_cache"] + d * d,
+    }
+    expert = 3 * d * (sizes["moe_intermediate_size"] or d_ff)
+    moe_layers = (
+        max(0, len(operators) - sizes["num_dense_layers"])
+        if experts > 0 else 0
+    )
+    read = (
+        sum(mixer[operator] for operator in operators)
+        + (len(operators) - moe_layers) * 3 * d * d_ff
+        + moe_layers * (d * experts + experts * expert)
+    )
+    idle = max(0, experts - sizes["num_experts_per_tok"])
+    per_token = read - moe_layers * idle * expert
+    return read, per_token
+
+
+def chosen_chunk_tokens(
+    read: int, per_token: int, layout: RowLayout, max_len: int,
+) -> int:
+    """The prefill chunk's width where the env states none.
+
+    A chunk of T tokens is bound by its READ of the weights until
+    T x 2 x ``per_token`` FLOPs take as long as ``read`` x 2 bytes:
+    until T = ``read`` / ``per_token`` x the chip's FLOPs a byte.
+    Under that width a wider chunk costs far less than the chunks it
+    saves (on a v5e 64 -> 512 tokens took a mixture's chunk from 12.0
+    to 20.6 ms and from 14.4 to 19.4 ms, for a fifth of the calls:
+    PERF.md section 6, PR 36), so: the smallest power of two at or
+    above it, held to what the geometry serves: at most
+    ``_CHOSEN_CHUNK_CEILING``, ``max_len`` and one window, in whole
+    pages and whole layout chunks (one page at the least)."""
+    ridge = -(-read * _CHIP_FLOPS_PER_BYTE // per_token)
+    width = 1 << (ridge - 1).bit_length()
+    width = min(width, _CHOSEN_CHUNK_CEILING, max_len,
+                layout.window or width)
+    # a windowed layout keeps one chunk a page (RowLayout)
+    unit = layout.page_tokens
+    return max(unit, width - width % unit)
+
+
 @dataclass
 class PagedServeConfig:
     """The env -> paged-serving geometry contract (one source for
@@ -272,6 +375,9 @@ class PagedServeConfig:
     slots: int             # max concurrent decode rows
     prefix_cache: bool     # share read-only prompt pages
     layout: Optional[RowLayout] = None   # None: full attention
+    # what chunk_tokens was chosen from (chunk_weights_from_env) where
+    # the code chose it; None: the env stated PREFILL_CHUNK_TOKENS
+    chunk_weights: Optional[Tuple[int, int]] = None
 
     def __post_init__(self) -> None:
         if self.layout is None:
@@ -286,6 +392,32 @@ class PagedServeConfig:
     def arena_pages(self) -> int:
         """Physical arena size: usable pages + the trash page."""
         return self.pages + 1
+
+    @property
+    def chunk_source(self) -> str:
+        """Who fixed ``chunk_tokens``: "env" or "model"."""
+        return "env" if self.chunk_weights is None else "model"
+
+    @property
+    def chunk_stats(self) -> dict:
+        """What a chosen width was chosen from, as ``/stats``' ``model``
+        carries it ({} where the env stated the width)."""
+        if self.chunk_weights is None:
+            return {}
+        read, per_token = self.chunk_weights
+        return {"chunk_read_weights": read, "chunk_token_weights": per_token}
+
+    @property
+    def chunk_note(self) -> str:
+        """The chunk width, who fixed it and from what: for a worker's
+        start-up line."""
+        if self.chunk_weights is None:
+            return f"chunk {self.chunk_tokens} ({self.chunk_source})"
+        read, per_token = self.chunk_weights
+        return (
+            f"chunk {self.chunk_tokens} ({self.chunk_source}: a chunk "
+            f"reads {read} weights, a token multiplies {per_token})"
+        )
 
 
 def paged_config_from_env(env) -> PagedServeConfig:
@@ -319,11 +451,23 @@ def paged_config_from_env(env) -> PagedServeConfig:
         raise SpecError(f"MODEL_CONFIG does not give a row layout: {e}")
     per_row = layout.table_len(max_len)
     pages = int(env.get("KV_PAGES") or 0) or slots * per_row
-    chunk = int(env.get("PREFILL_CHUNK_TOKENS") or "64")
-    if chunk <= 0:
+    # unset (or 0): the code chooses the width from the model that the
+    # same env describes; a stated width is the operator's and is held
+    # to the same checks as ever
+    chunk = int(env.get("PREFILL_CHUNK_TOKENS") or 0)
+    if chunk < 0:
         raise SpecError(
             f"PREFILL_CHUNK_TOKENS must be >= 1, got {chunk}"
         )
+    weights = None
+    if not chunk:
+        try:
+            weights = chunk_weights_from_env(env)
+            chunk = chosen_chunk_tokens(*weights, layout, max_len)
+        except (OSError, ValueError, KeyError, ZeroDivisionError) as e:
+            raise SpecError(
+                f"the env's model sizes give no prefill chunk width: {e!r}"
+            )
     if layout.window and (chunk % layout.chunk or chunk > layout.window):
         raise SpecError(
             f"PREFILL_CHUNK_TOKENS {chunk} must be a whole number of "
@@ -344,7 +488,7 @@ def paged_config_from_env(env) -> PagedServeConfig:
     return PagedServeConfig(
         page_tokens=page_tokens, pages=pages, chunk_tokens=chunk,
         max_len=max_len, slots=slots, prefix_cache=prefix,
-        layout=layout,
+        layout=layout, chunk_weights=weights,
     )
 
 

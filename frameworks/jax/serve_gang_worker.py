@@ -477,14 +477,17 @@ def main() -> int:
             on_idle=paged_idle_tick, idle_every_s=IDLE_TICK_S,
             stats_path=stats_path,
             log=lambda msg: print(msg, flush=True),
-            extra_stats={"http_port": bound_port},
+            extra_stats={
+                "http_port": bound_port,
+                "prefill_chunk_source": paged.chunk_source,
+            },
             annotate=jax.profiler.TraceAnnotation,
         )
         with open("ready", "w") as f:
             f.write("warm\n")
         shape = (
             f"{paged.pages}-page arena (pages of {paged.page_tokens}, "
-            f"{slots} rows, chunk {paged.chunk_tokens})"
+            f"{slots} rows, {paged.chunk_note})"
         )
         print(
             f"rank 0: serving sharded generate over a {shape} "

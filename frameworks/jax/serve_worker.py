@@ -463,7 +463,7 @@ def main() -> int:
     shape = (
         f"paged KV: {paged.pages} pages x {paged.page_tokens} "
         f"tokens, {slots} rows of {pool.pages_per_row} table "
-        f"entries, chunk {paged.chunk_tokens}, "
+        f"entries, {paged.chunk_note}, "
         f"prefix cache {'on' if paged.prefix_cache else 'off'}"
     )
     # which path a decode step's attention takes: the page walk that
@@ -499,7 +499,11 @@ def main() -> int:
             "moe_routing": config.moe_score,
             "conv_l_cache": config.conv_l_cache,
             "model_config": os.environ.get("MODEL_CONFIG", ""),
+            # what the chunk's width was chosen from, where the code
+            # chose it (serve/paging.py chosen_chunk_tokens)
+            **paged.chunk_stats,
         },
+        prefill_chunk_source=paged.chunk_source,
         warm_s=round(time.monotonic() - warm_t0, 2),
     )
     with open("ready", "w") as f:

@@ -207,6 +207,49 @@ def test_one_ahead_property_any_request_mix_matches_oracle():
     run()
 
 
+@pytest.mark.parametrize("env,chosen", [
+    # a dense model's 256; a mixture's 512; a short MAX_LEN's clamp
+    ({"MAX_LEN": "1024"}, 256),
+    ({"MAX_LEN": "1024", "N_EXPERTS": "8"}, 512),
+    ({"MAX_LEN": "96", "N_EXPERTS": "8"}, 96),
+])
+def test_the_chosen_chunk_width_serves_what_64_serves(env, chosen):
+    """The width the code chooses where the env states none
+    (serve/paging.py) changes how many calls a prompt takes, never a
+    token."""
+    from dcos_commons_tpu.serve.paging import paged_config_from_env
+
+    env = {**env, "KV_PAGE_TOKENS": "4", "SERVE_SLOTS": "3"}
+    rng = np.random.default_rng(36)
+    jobs = [
+        ([list(rng.integers(0, V, size=n))], 5, None)
+        for n in (3, 64, 65, 90, 17, 80)
+    ]
+    served, calls = {}, {}
+    for stated in ("64", ""):
+        paged = paged_config_from_env(
+            {**env, "PREFILL_CHUNK_TOKENS": stated}
+        )
+        half = OneAhead(ChainModel())
+        engine = _engine(
+            half, slots=paged.slots, pages=paged.pages,
+            max_len=paged.max_len, prompt_len=paged.max_len - 5,
+            chunk=paged.chunk_tokens,
+        )
+        try:
+            served[stated] = swarm(engine, jobs)
+            calls[stated] = settled_stats(engine)["loop"]["prefill_calls"]
+            width = engine.stats()["prefill_chunk_tokens"]
+        finally:
+            engine.stop()
+        assert width == (64 if stated else chosen)
+    assert served["64"] == served[""] == [
+        [chain_oracle(rows[0], n, eos)] for rows, n, eos in jobs
+    ]
+    # 64-wide: 1 + 1 + 2 + 2 + 1 + 2 chunks; chosen: one a prompt
+    assert (calls["64"], calls[""]) == (9, 6)
+
+
 def test_stop_leaves_no_step_behind():
     half = OneAhead(ChainModel(slots=1))
     engine = _engine(half, slots=1, pages=16, max_len=64, prompt_len=8)

@@ -142,8 +142,16 @@ def test_inference_pod_serves_generate(tmp_path):
             ) as resp:
                 return resp.read().decode()
 
-        loop = json.loads(get("/stats"))["loop"]
+        stats = json.loads(get("/stats"))
+        loop = stats["loop"]
         assert loop["requests_timed"] == 3 and loop["decode_calls"] >= 7
+        # the YAML states no chunk width: the code chose it from the
+        # model's sizes (a dense model's 256, held to MAX_LEN 48) and
+        # says so, with the two counts it chose from
+        assert stats["prefill_chunk_tokens"] == 48
+        assert stats["prefill_chunk_source"] == "model"
+        assert stats["model"]["chunk_read_weights"] \
+            == stats["model"]["chunk_token_weights"] > 0
         assert loop["phase_s"]["decode_call"] > 0
         assert "recorder off" in get("/trace")
         assert "recorder off" in get("/trace?fmt=chrome")

@@ -82,6 +82,12 @@ def test_paged_decode_attention_compiles_at_the_mixture_cells_sizes(one_chip):
     (256, 1536, 2048, 8, 64),    # lfm2-24b.chat: down
     (128, 4096, 14336, 3, 8),    # mixtral8x7b.chat: gate / up
     (128, 14336, 4096, 3, 8),    # mixtral8x7b.chat: down
+    # the same four at the chunk width the code chooses (512 tokens:
+    # serve/paging.py chosen_chunk_tokens), top-k assignments a token
+    (2048, 2048, 1536, 8, 64),
+    (2048, 1536, 2048, 8, 64),
+    (1024, 4096, 14336, 3, 8),
+    (1024, 14336, 4096, 3, 8),
 ])
 def test_grouped_matmul_compiles_at_the_cells_sizes(
     one_chip, rows, k, n, layers, experts
