@@ -688,6 +688,7 @@ def paged_prefill_chunk(
     start: jax.Array,
     true_len: jax.Array,
     slot: jax.Array = 0,
+    riders: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array], Optional[jax.Array]]:
     """One CHUNK of a prompt through the trunk into a paged arena.
 
@@ -714,14 +715,30 @@ def paged_prefill_chunk(
     prompt-wide dispatch that blocks the pool (head-of-line TTFT).
     A mixture computes for the true positions alone: the padding
     reaches no expert.
+
+    ``riders`` is a decode step over the whole pool, ``(token [S],
+    pos [S], tables [S, M])`` as ``paged_decode_step`` takes them, that
+    rides in this program (``chunk_carries_riders``): its rows go
+    through each layer's weights in one operand with the chunk's
+    positions, so the weights are read once for both, and attend and
+    write as the step alone would (their pages are other rows' than
+    the chunk's).  The result is then a 4-tuple, the riders' logits
+    ``[S, vocab]`` f32 last: what the chunk alone followed by
+    ``paged_decode_step`` gives.
     """
     b, c = tokens.shape
     if b != 1:
         raise ValueError(f"prefill chunks are per-request, got batch {b}")
+    if riders is not None and not chunk_carries_riders(config):
+        raise NotImplementedError(
+            f"no layer of attention {config.attention!r} runs a decode "
+            "step's rows beside a chunk's positions"
+        )
     if config.attention == "eva":
-        return _eva_prefill_chunk(
-            config, params, cache, tokens, table, start, true_len
-        ) + (None,)
+        out = _eva_prefill_chunk(
+            config, params, cache, tokens, table, start, true_len, riders
+        )
+        return out[:2] + (None,) + out[2:]
     h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     p_tok, lanes = cache["k"].shape[2], cache["k"].shape[-1]
     m = table.shape[0]
@@ -1041,8 +1058,19 @@ def _softmax_block(carry, qg, keys, values, mask, scale):
     return m_new, l, acc
 
 
-def _eva_prefill_chunk(config, params, cache, tokens, table, start, true_len):
-    """``paged_prefill_chunk`` for ``attention == "eva"``.
+def chunk_carries_riders(config: TransformerConfig) -> bool:
+    """Whether ``paged_prefill_chunk`` takes a decode step as
+    ``riders``: the family has a layer that runs a chunk's positions
+    and a step's rows as one operand.  EVA's has; a grouped-query
+    walk, a mixture's counts and conv state have none yet."""
+    return config.attention == "eva"
+
+
+def _eva_chunk_part(config, cache, table, start, true_len, c):
+    """A prefill chunk's part of an EVA layer: ``(positions [c],
+    attend)``, where ``attend(arena, layer, base, q, k_new, v_new)``
+    takes the chunk's roped projections (``[c, heads, hd]``), attends
+    and writes, and returns ``(attn [c, h, hd], arena)``.
 
     The row's table has two regions (serve/paging.py RowLayout): the
     first ``window_size / P`` entries are a RING of exact K/V pages
@@ -1066,7 +1094,6 @@ def _eva_prefill_chunk(config, params, cache, tokens, table, start, true_len):
     that are past.  A dense masked softmax over the whole table scores
     4,096 old entries a query where a chunk half way through an
     8,192-byte prompt can see 1,300."""
-    b, c = tokens.shape
     h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     win, chunk = config.window_size, config.chunk_size
     if c % chunk or c > win:
@@ -1081,7 +1108,6 @@ def _eva_prefill_chunk(config, params, cache, tokens, table, start, true_len):
     true_len = jnp.asarray(true_len, jnp.int32)
     offs = jnp.arange(c, dtype=jnp.int32)
     abs_pos = start + offs
-    positions = abs_pos[None, :]
     q_win = abs_pos // win                       # each query's window
     # exact K/V: into the ring (pad positions into the trash page)
     phys = jnp.where(offs < true_len, table[(abs_pos % win) // p_tok], 0)
@@ -1116,26 +1142,20 @@ def _eva_prefill_chunk(config, params, cache, tokens, table, start, true_len):
     old_sums = jnp.minimum(
         (start + c - 1) // win * per_win, start // chunk
     )                                            # the most any query sees
-    x = params["embed"][tokens].astype(config.dtype)
+    scale = hd ** -0.5
 
-    def layer_fn(carry, inputs):
-        x, arena = carry
-        layer, base = inputs
-        with jax.named_scope("attention"):
-            normed = _norm(config, x, layer["attn_norm"])
-            q, k_new, v_new = _project_kv(config, layer, normed, positions)
+    def attend(arena, layer, base, q, k_new, v_new):
         with jax.named_scope("eva_summarise"):
             k_sum_new, v_sum_new = _eva_summaries(
                 config, layer,
-                k_new[0].reshape(n_c, chunk, kv, hd),
-                v_new[0].reshape(n_c, chunk, kv, hd),
+                k_new.reshape(n_c, chunk, kv, hd),
+                v_new.reshape(n_c, chunk, kv, hd),
             )
         qg = q.reshape(1, c, kv, reps, hd)
-        scale = hd ** -0.5
         state = _softmax_start(1, c, kv, reps, hd)
         with jax.named_scope("eva_window_attention"):
             state = _softmax_block(
-                state, qg, k_new, v_new, mask_new, scale
+                state, qg, k_new[None], v_new[None], mask_new, scale
             )
         with jax.named_scope("eva_summary_attention"):
             state = _softmax_block(
@@ -1177,28 +1197,196 @@ def _eva_prefill_chunk(config, params, cache, tokens, table, start, true_len):
                 )
         with jax.named_scope("kv_write"):
             arena = {
-                "k": arena["k"].at[base + phys, slot_off].set(k_new[0])
+                "k": arena["k"].at[base + phys, slot_off].set(k_new)
                 .at[base + sum_phys, sum_off].set(k_sum_new),
-                "v": arena["v"].at[base + phys, slot_off].set(v_new[0])
+                "v": arena["v"].at[base + phys, slot_off].set(v_new)
                 .at[base + sum_phys, sum_off].set(v_sum_new),
             }
         with jax.named_scope("attention"):
             _m, norm, acc = state
             attn = (acc / norm[..., None]).astype(config.dtype)
-            x = x + attn.reshape(1, c, h * hd) @ dq(layer["wo"], x.dtype)
+        return attn[0], arena
+
+    return abs_pos, attend
+
+
+def _eva_step_part(config, cache, pos, tables):
+    """A decode step's part of an EVA layer, as ``_eva_chunk_part``
+    gives a chunk's: ``(positions [b], attend)``.  Each row writes its
+    new K/V into its ring, attends to its window's ring entries
+    ``<= pos`` and the summaries of the windows before, and, where
+    ``pos`` ends a chunk, pools that chunk's page into its summary
+    entry (any other row's pooled page goes to the trash page)."""
+    from dcos_commons_tpu.ops.eva_decode import (
+        eva_decode_attention,
+        live_pages,
+    )
+
+    b = pos.shape[0]
+    h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    win, chunk = config.window_size, config.chunk_size
+    p_tok = cache["k"].shape[2]
+    wp, sp = _eva_geometry(config, cache, tables.shape[1])
+    per_win, reps = win // chunk, h // kv
+    pos = jnp.asarray(pos, jnp.int32)
+    rows = jnp.arange(b)
+    ring_page = (pos % win) // p_tok
+    phys = tables[rows, ring_page]
+    slot_off = pos % p_tok
+    chunk_id = pos // chunk
+    sum_phys = jnp.where(
+        pos % chunk == chunk - 1,
+        tables[rows, wp + jnp.minimum(chunk_id // p_tok, sp - 1)], 0,
+    )
+    sum_off = chunk_id % p_tok
+    kernel = decode_attention_kernel(config, cache)
+    if kernel:
+        live, n_ring, n_live, n_win, n_sum = live_pages(
+            tables, pos, win, chunk, p_tok
+        )
+    else:
+        mask_win = (
+            lax.broadcasted_iota(jnp.int32, (1, 1, win), 2)
+            <= (pos % win)[:, None, None]
+        )                                        # [b, 1, win]
+        mask_sum = (
+            lax.broadcasted_iota(jnp.int32, (1, 1, sp * p_tok), 2)
+            < ((pos // win) * per_win)[:, None, None]
+        )                                        # [b, 1, sp * P]
+
+    def attend(arena, layer, base, q, k_new, v_new):
+        with jax.named_scope("kv_write"):
+            arena = {
+                "k": arena["k"].at[base + phys, slot_off].set(k_new),
+                "v": arena["v"].at[base + phys, slot_off].set(v_new),
+            }
+        if kernel:
+            with jax.named_scope("attention"):
+                attn = eva_decode_attention(
+                    q, arena["k"], arena["v"], base + live, n_ring,
+                    n_live, n_win, n_sum, scale=hd ** -0.5,
+                    interpret=kernel == "interpret",
+                )
+            # the page each row has just written into: its chunk
+            with jax.named_scope("paged_gather"):
+                k_page = arena["k"][base + phys]
+                v_page = arena["v"][base + phys]
+        else:
+            with jax.named_scope("paged_gather"):
+                pages = base + tables
+                k_all, v_all = arena["k"][pages], arena["v"][pages]
+                k_page = k_all[rows, ring_page]
+                v_page = v_all[rows, ring_page]
+            qg, scale = q.reshape(b, 1, kv, reps, hd), hd ** -0.5
+            with jax.named_scope("eva_window_attention"):
+                state = _softmax_block(
+                    _softmax_start(b, 1, kv, reps, hd), qg,
+                    k_all[:, :wp].reshape(b, win, kv, hd),
+                    v_all[:, :wp].reshape(b, win, kv, hd), mask_win, scale,
+                )
+            with jax.named_scope("eva_summary_attention"):
+                _m, norm, acc = _softmax_block(
+                    state, qg,
+                    k_all[:, wp:].reshape(b, sp * p_tok, kv, hd),
+                    v_all[:, wp:].reshape(b, sp * p_tok, kv, hd), mask_sum,
+                    scale,
+                )
+            attn = (acc / norm[..., None]).astype(config.dtype)
+        with jax.named_scope("eva_summarise"):
+            k_sum_new, v_sum_new = _eva_summaries(
+                config, layer, k_page, v_page
+            )
+        with jax.named_scope("kv_write"):
+            arena = {
+                "k": arena["k"].at[base + sum_phys, sum_off].set(k_sum_new),
+                "v": arena["v"].at[base + sum_phys, sum_off].set(v_sum_new),
+            }
+        return attn, arena
+
+    return pos, attend
+
+
+def _eva_trunk(config, params, cache, x, positions, parts):
+    """``x [B, S, d]`` at ``positions [B, S]`` through every EVA layer
+    over the arena: the norms, the q/k/v and output projections and
+    the FFN take ALL of ``x`` as one operand, so each weight is read
+    once; between the projections each of ``parts`` (``(rows,
+    attend)`` of ``_eva_chunk_part`` / ``_eva_step_part``, covering
+    ``x``'s ``B * S`` rows in order) attends and writes for its own
+    rows.  A chunk alone, a step alone, or a chunk with a step riding
+    behind it (whose rows' pages are other rows' than the chunk's).
+    Returns (x, the cache in its stored layout)."""
+    h, hd = config.n_heads, config.head_dim
+
+    def layer_fn(carry, inputs):
+        x, arena = carry
+        layer, base = inputs
+        with jax.named_scope("attention"):
+            normed = _norm(config, x, layer["attn_norm"])
+            q, k_new, v_new = (
+                a.reshape((-1,) + a.shape[2:])
+                for a in _project_kv(config, layer, normed, positions)
+            )
+        attn, lo = [], 0
+        for n, attend in parts:
+            rows = slice(lo, lo + n)
+            out, arena = attend(
+                arena, layer, base, q[rows], k_new[rows], v_new[rows]
+            )
+            attn.append(out.reshape(n, h * hd))
+            lo += n
+        with jax.named_scope("attention"):
+            attn = attn[0] if len(attn) == 1 else jnp.concatenate(attn)
+            x = x + attn.reshape(x.shape[:2] + (h * hd,)) @ dq(
+                layer["wo"], x.dtype
+            )
         x, _counts = _serve_ffn(config, layer, x)
         return (x, arena), None
 
     x, new_cache, _ = _scan_layers_over_arena(
         layer_fn, x, params["layers"], cache
     )
-    with jax.named_scope("logits"):
-        x = _norm(config, x, params["final_norm"])
-        x_last = lax.dynamic_index_in_dim(
-            x, true_len - 1, axis=1, keepdims=False
+    return x, new_cache
+
+
+def _eva_prefill_chunk(config, params, cache, tokens, table, start,
+                       true_len, riders=None):
+    """``paged_prefill_chunk`` for ``attention == "eva"``: the chunk's
+    ``c`` positions (``_eva_chunk_part``) and, behind them in the same
+    operand, the ``riders``' rows (``(token, pos, tables)`` of a decode
+    step, ``_eva_step_part``).  Returns (the chunk's logits, the
+    cache) and with riders their logits ``[slots, vocab]`` too."""
+    c = tokens.shape[1]
+    abs_pos, chunk_attend = _eva_chunk_part(
+        config, cache, table, start, true_len, c
+    )
+    x = params["embed"][tokens].astype(config.dtype)
+    positions, parts = abs_pos, [(c, chunk_attend)]
+    if riders is not None:
+        token, pos, tables = riders
+        pos, step_attend = _eva_step_part(config, cache, pos, tables)
+        x = jnp.concatenate(
+            [x, params["embed"][token][None].astype(config.dtype)], axis=1
         )
-        logits = _last_logits(config, params, x_last)
-    return logits, new_cache
+        positions = jnp.concatenate([abs_pos, pos])
+        parts.append((pos.shape[0], step_attend))
+    x, new_cache = _eva_trunk(
+        config, params, cache, x, positions[None], parts
+    )
+    with jax.named_scope("logits"):
+        # the rows anybody reads: the chunk's last real position, and
+        # every rider
+        rows = lax.dynamic_index_in_dim(
+            x, jnp.asarray(true_len, jnp.int32) - 1, axis=1, keepdims=False
+        )
+        if riders is not None:
+            rows = jnp.concatenate([rows, x[0, c:]])
+        logits = _last_logits(
+            config, params, _norm(config, rows, params["final_norm"])
+        )
+    if riders is None:
+        return logits, new_cache
+    return logits[:1], new_cache, logits[1:]
 
 
 def decode_attention_kernel(config: TransformerConfig, cache):
@@ -1230,109 +1418,12 @@ def decode_attention_kernel(config: TransformerConfig, cache):
 
 def _eva_decode_step(config, params, cache, token, pos, tables):
     """``paged_decode_step`` for ``attention == "eva"`` (the table's
-    two regions: ``_eva_prefill_chunk``).  Each row writes its new K/V
-    into its ring, attends to its window's ring entries ``<= pos`` and
-    the summaries of the windows before, and, where ``pos`` ends a
-    chunk, pools that chunk's page into its summary entry (any other
-    row's pooled page goes to the trash page)."""
-    from dcos_commons_tpu.ops.eva_decode import (
-        eva_decode_attention,
-        live_pages,
-    )
-
-    b = token.shape[0]
-    h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
-    win, chunk = config.window_size, config.chunk_size
-    p_tok = cache["k"].shape[2]
-    wp, sp = _eva_geometry(config, cache, tables.shape[1])
-    per_win, reps = win // chunk, h // kv
+    two regions: ``_eva_chunk_part``; what a row does a layer:
+    ``_eva_step_part``)."""
+    pos, attend = _eva_step_part(config, cache, pos, tables)
     x = params["embed"][token][:, None, :].astype(config.dtype)
-    pos = jnp.asarray(pos, jnp.int32)
-    positions = pos[:, None]
-    rows = jnp.arange(b)
-    ring_page = (pos % win) // p_tok
-    phys = tables[rows, ring_page]
-    slot_off = pos % p_tok
-    chunk_id = pos // chunk
-    sum_phys = jnp.where(
-        pos % chunk == chunk - 1,
-        tables[rows, wp + jnp.minimum(chunk_id // p_tok, sp - 1)], 0,
-    )
-    sum_off = chunk_id % p_tok
-    kernel = decode_attention_kernel(config, cache)
-    if kernel:
-        live, n_ring, n_live, n_win, n_sum = live_pages(
-            tables, pos, win, chunk, p_tok
-        )
-    else:
-        mask_win = (
-            lax.broadcasted_iota(jnp.int32, (1, 1, win), 2)
-            <= (pos % win)[:, None, None]
-        )                                        # [b, 1, win]
-        mask_sum = (
-            lax.broadcasted_iota(jnp.int32, (1, 1, sp * p_tok), 2)
-            < ((pos // win) * per_win)[:, None, None]
-        )                                        # [b, 1, sp * P]
-
-    def layer_fn(carry, inputs):
-        x, arena = carry
-        layer, base = inputs
-        with jax.named_scope("attention"):
-            normed = _norm(config, x, layer["attn_norm"])
-            q, k_new, v_new = _project_kv(config, layer, normed, positions)
-        with jax.named_scope("kv_write"):
-            arena = {
-                "k": arena["k"].at[base + phys, slot_off].set(k_new[:, 0]),
-                "v": arena["v"].at[base + phys, slot_off].set(v_new[:, 0]),
-            }
-        if kernel:
-            with jax.named_scope("attention"):
-                attn = eva_decode_attention(
-                    q[:, 0], arena["k"], arena["v"], base + live, n_ring,
-                    n_live, n_win, n_sum, scale=hd ** -0.5,
-                    interpret=kernel == "interpret",
-                )
-            # the page each row has just written into: its chunk
-            with jax.named_scope("paged_gather"):
-                k_page = arena["k"][base + phys]
-                v_page = arena["v"][base + phys]
-        else:
-            with jax.named_scope("paged_gather"):
-                pages = base + tables
-                k_all, v_all = arena["k"][pages], arena["v"][pages]
-                k_page = k_all[rows, ring_page]
-                v_page = v_all[rows, ring_page]
-            qg, scale = q.reshape(b, 1, kv, reps, hd), hd ** -0.5
-            with jax.named_scope("eva_window_attention"):
-                state = _softmax_block(
-                    _softmax_start(b, 1, kv, reps, hd), qg,
-                    k_all[:, :wp].reshape(b, win, kv, hd),
-                    v_all[:, :wp].reshape(b, win, kv, hd), mask_win, scale,
-                )
-            with jax.named_scope("eva_summary_attention"):
-                _m, norm, acc = _softmax_block(
-                    state, qg,
-                    k_all[:, wp:].reshape(b, sp * p_tok, kv, hd),
-                    v_all[:, wp:].reshape(b, sp * p_tok, kv, hd), mask_sum,
-                    scale,
-                )
-            attn = (acc / norm[..., None]).astype(config.dtype)
-        with jax.named_scope("attention"):
-            x = x + attn.reshape(b, 1, h * hd) @ dq(layer["wo"], x.dtype)
-        with jax.named_scope("eva_summarise"):
-            k_sum_new, v_sum_new = _eva_summaries(
-                config, layer, k_page, v_page
-            )
-        with jax.named_scope("kv_write"):
-            arena = {
-                "k": arena["k"].at[base + sum_phys, sum_off].set(k_sum_new),
-                "v": arena["v"].at[base + sum_phys, sum_off].set(v_sum_new),
-            }
-        x, _counts = _serve_ffn(config, layer, x)
-        return (x, arena), None
-
-    x, new_cache, _ = _scan_layers_over_arena(
-        layer_fn, x, params["layers"], cache
+    x, new_cache = _eva_trunk(
+        config, params, cache, x, pos[:, None], [(pos.shape[0], attend)]
     )
     with jax.named_scope("logits"):
         x = _norm(config, x, params["final_norm"])

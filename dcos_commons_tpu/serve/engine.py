@@ -251,6 +251,14 @@ class PagedEngine:
     outstanding; ``resolve_decode_fn()`` fetches the outstanding step
     without dispatching another.  Without it the two callables are called as
     above and a step is resolved when its call returns.
+
+    With ``chunk_riders`` beside it (``PagedPoolModel.chunk_riders``:
+    the device half's chunk program carries a decode step) a tick that
+    has a chunk hands its decode step to the tick's first chunk call,
+    ``prefill_chunk_fn(..., riders={tok, pos, temps, seeds, tables,
+    carry})``, which stands for the ``decode_fn`` call as well and
+    returns ``(first token or None, the PREVIOUS step's tokens)``: one
+    device program that tick, not two.
     """
 
     def __init__(
@@ -271,6 +279,7 @@ class PagedEngine:
         write_page: Optional[Callable] = None,
         handoff: Optional[Callable] = None,
         resolve_decode_fn: Optional[Callable] = None,
+        chunk_riders: bool = False,
         device_counters: Optional[Callable[[], dict]] = None,
         queue_timeout_s: float = 600.0,
         on_idle: Optional[Callable[[], None]] = None,
@@ -287,6 +296,10 @@ class PagedEngine:
         self._prefill_fn = prefill_chunk_fn
         self._decode_fn = decode_fn
         self._resolve_fn = resolve_decode_fn
+        # a step rides a chunk only as a step dispatched ahead
+        self._chunk_riders = bool(chunk_riders) and (
+            resolve_decode_fn is not None
+        )
         # cumulative sums the device half counted itself and fetched
         # with its steps' tokens (PagedPoolModel.loop_counters): they
         # join ``loop`` in ``stats()``
@@ -380,6 +393,8 @@ class PagedEngine:
         self._decode_ahead_calls = 0
         self._prefill_unfetched_calls = 0
         self._ahead_discarded_rows = 0
+        # chunk calls that carried a decode step with a live row in it
+        self._prefill_rider_calls = 0
         # what the decode calls themselves computed for: rows, and the
         # cache entries those rows read (the gauges `active_slots` /
         # `kv_live_tokens` are instants, and the second also holds rows
@@ -709,6 +724,7 @@ class PagedEngine:
         return {
             "decode_calls": self._decode_calls,
             "prefill_calls": self._prefill_calls,
+            "prefill_rider_calls": self._prefill_rider_calls,
             "decode_ahead_calls": self._decode_ahead_calls,
             "prefill_unfetched_calls": self._prefill_unfetched_calls,
             "ahead_discarded_rows": self._ahead_discarded_rows,
@@ -780,11 +796,16 @@ class PagedEngine:
             try:
                 self._tick_rows = 0
                 chunks_before = self._prefill_calls
+                riders_before = self._prefill_rider_calls
                 with self._tracer.span("engine.tick", track="loop") as tick:
                     self._work_tick()
                     tick.set_attr("rows", self._tick_rows)
                     tick.set_attr(
                         "chunks", self._prefill_calls - chunks_before
+                    )
+                    tick.set_attr(
+                        "riders",
+                        self._prefill_rider_calls - riders_before,
                     )
                 self._write_stats()
             except Exception as e:  # noqa: BLE001 — fail FAST, not silent
@@ -809,11 +830,12 @@ class PagedEngine:
     def _work_tick(self) -> None:
         """One scheduling round (loop thread, OUTSIDE the cv): page
         IO, one chunk for every prefilling row, then the next decode
-        step for every active row and the outstanding one's tokens."""
+        step for every active row and the outstanding one's tokens,
+        unless that step rode one of the chunks."""
         self._run_page_io()
-        self._prefill_tick()
+        stepped = self._prefill_tick()
         # loop thread is the only writer of both
-        if self._active or self._inflight:
+        if not stepped and (self._active or self._inflight):
             self._decode_tick()
 
     def _admit_locked(self) -> None:
@@ -953,6 +975,27 @@ class PagedEngine:
         self._decode_calls += 1
         self._inflight.append(dispatched)
         return tok, pos, tables, carry
+
+    def _rider_step(self) -> Optional[dict]:
+        """The tick's decode step for a chunk call to carry (loop
+        thread, outside the cv), as ``decode_fn``'s keywords; None
+        when no row decodes, or the outstanding step only has to be
+        resolved: the tick's ``_decode_tick`` does that."""
+        with self._phase("decode_prep"), self._cv:
+            if not (self._active or self._inflight):
+                return None
+            step = self._decode_prep_locked()
+            if step is None:
+                return None
+            active = self._active
+            if any(row is not None for row in self._inflight[-1]):
+                self._prefill_rider_calls += 1
+        self._tick_rows = active
+        tok, pos, tables, carry = step
+        return {
+            "tok": tok, "pos": pos, "temps": self._temps.copy(),
+            "seeds": self._seeds.copy(), "tables": tables, "carry": carry,
+        }
 
     def _decode_tick(self) -> None:
         """Dispatch the next step, then resolve the oldest outstanding
@@ -1214,9 +1257,16 @@ class PagedEngine:
         cheap chunk; serializing them across decode ticks would tax
         every short request one full decode per queue position).
         Per-tick prefill work stays bounded by the slot count: one
-        chunk-wide call a row."""
+        chunk-wide call a row.
+
+        Where the chunk program carries riders, the tick's decode step
+        rides the first chunk call (a row that finishes its prompt in
+        this tick was not in it: it joins the next tick's), and the
+        tokens that call resolves are applied as a decode call's are.
+        Returns whether the step went that way."""
         with self._cv:
             rows = list(self._prefilling)
+        stepped = False
         for row in rows:
             with self._cv:
                 if row.admission is None:
@@ -1245,6 +1295,11 @@ class PagedEngine:
                 ahead["final"] = start + clen >= plen
                 if not ahead["final"]:
                     self._prefill_unfetched_calls += 1
+            if self._chunk_riders and not stepped:
+                riders = self._rider_step()
+                if riders is not None:
+                    ahead["riders"] = riders
+                    stepped = True
             with self._phase("prefill_call"):
                 self._prefill_calls += 1
                 row.chunks += 1
@@ -1253,6 +1308,9 @@ class PagedEngine:
                     true_len=clen, temp=row.temp, seed=row.seed,
                     **ahead,
                 )
+            nxt = None
+            if "riders" in ahead:
+                first, nxt = first
             now = time.monotonic()
             handoff_row = None
             with self._cv:
@@ -1279,8 +1337,11 @@ class PagedEngine:
                     else:
                         self._prefilling.remove(row)
                         self._apply_admit_locked(row, int(first), now)
+            if nxt is not None:
+                self._apply_decode(nxt)
             if handoff_row is not None:
                 self._run_handoff(handoff_row)
+        return stepped
 
     def _run_handoff(self, row) -> None:
         """Hand a finished prefill to the decode pool (loop thread,

@@ -76,7 +76,14 @@ class PagedPoolModel:
     traced — a request resuming after a prefix-cache hit is the same
     program as one starting cold) and ONE decode program (per-row
     positions/temps/seeds/page tables traced) cover every request the
-    server ever admits.  The arena holds ``pages`` usable pages plus
+    server ever admits.  With ``riders`` (the caller's loop decodes,
+    and will hand a tick's decode step to the tick's chunk) and a
+    family whose chunk can carry one (models/decode.py
+    ``chunk_carries_riders``), the chunk program IS a chunk and a
+    decode step in one, ``chunk_riders`` says so, and a chunk with
+    nothing to carry takes idle riders: all-zero tables, which read
+    and write the trash page as the decode program's idle rows do.
+    Still two programs.  The arena holds ``pages`` usable pages plus
     the TRASH page (physical page 0): padding and inactive-row writes
     land there, so ``warm()`` — which runs both programs over
     all-zero tables — never dirties a real page.
@@ -99,12 +106,14 @@ class PagedPoolModel:
         cache_sharding: Optional[Any] = None,
         put: Optional[Callable] = None,
         constrain_out: Optional[Callable] = None,
+        riders: bool = False,
     ):
         import jax
         import jax.numpy as jnp
 
         from dcos_commons_tpu.models.decode import (
             arena_lanes,
+            chunk_carries_riders,
             init_paged_kv_cache,
             paged_decode_step,
             paged_prefill_chunk,
@@ -151,12 +160,42 @@ class PagedPoolModel:
         else:
             self.cache = jax.jit(init)()
 
-        def _prefill(params, cache, counted, tokens, table, start,
-                     true_len, temp, seed, slot):
-            logits, cache, counts = paged_prefill_chunk(
-                config, params, cache, tokens, table, start, true_len,
-                slot,
+        self.chunk_riders = bool(riders) and chunk_carries_riders(config)
+
+        # the mesh the arena is laid over is the ambient mesh while a
+        # decode step is traced, alone or as a chunk's riders: its
+        # attention kernel is chosen by it (models/decode.py
+        # decode_attention_kernel)
+        arena_mesh = (
+            functools.partial(
+                jax.sharding.use_abstract_mesh,
+                cache_sharding.mesh.abstract_mesh,
             )
+            if cache_sharding is not None else contextlib.nullcontext
+        )
+
+        def pick_rows(logits, temps, seeds, pos):
+            def pick_row(lg, temp, seed, p):
+                key = jax.random.fold_in(jax.random.key(seed), p)
+                return sample_token(lg, temp, key)
+
+            with jax.named_scope("sample"):
+                return jax.vmap(pick_row)(logits, temps, seeds, pos)
+
+        def _prefill(params, cache, counted, tokens, table, start,
+                     true_len, temp, seed, slot, *step):
+            # ``step``: what ``_decode`` takes after the cache, where
+            # the chunk carries riders
+            riders, mesh = None, contextlib.nullcontext
+            if step:
+                prev, carry, tok, pos, temps, seeds, tables = step
+                riders = (jnp.where(carry, prev, tok), pos, tables)
+                mesh = arena_mesh
+            with mesh():
+                logits, cache, counts, *rode = paged_prefill_chunk(
+                    config, params, cache, tokens, table, start, true_len,
+                    slot, riders,
+                )
             if counts is not None:
                 # this chunk's mixtures, and the chunk itself, on top
                 # of the chunks nobody has fetched yet
@@ -169,18 +208,12 @@ class PagedPoolModel:
                     jax.random.key(seed), start + true_len - 1
                 )
                 first = sample_token(logits[0], temp, key)
-            return (con(first), con(counted)), cache
-
-        # the mesh the arena is laid over is the ambient mesh while the
-        # decode step is traced: its attention kernel is chosen by it
-        # (models/decode.py decode_attention_kernel)
-        arena_mesh = (
-            functools.partial(
-                jax.sharding.use_abstract_mesh,
-                cache_sharding.mesh.abstract_mesh,
-            )
-            if cache_sharding is not None else contextlib.nullcontext
-        )
+            chunk = (con(first), con(counted))
+            if not step:
+                return chunk, cache
+            # the riders' tokens, as ``_decode`` hands a step's over
+            nxt = pick_rows(rode[0], temps, seeds, pos)
+            return (chunk, (con(nxt), con(jnp.zeros(2, jnp.int32)))), cache
 
         def _decode(params, cache, prev, carry, tok, pos, temps, seeds,
                     tables):
@@ -193,13 +226,7 @@ class PagedPoolModel:
                 )
             if counts is None:
                 counts = jnp.zeros(2, jnp.int32)
-
-            def pick_row(lg, temp, seed, p):
-                key = jax.random.fold_in(jax.random.key(seed), p)
-                return sample_token(lg, temp, key)
-
-            with jax.named_scope("sample"):
-                nxt = jax.vmap(pick_row)(logits, temps, seeds, pos)
+            nxt = pick_rows(logits, temps, seeds, pos)
             return (con(nxt), con(counts)), cache
 
         donate = {}
@@ -217,6 +244,13 @@ class PagedPoolModel:
             lambda: con(jnp.zeros(slots, jnp.int32))
         )()
         self._no_carry = np.zeros(slots, np.bool_)
+        # a decode step that computes for nobody, by ``decode``'s names
+        self._idle_step = dict(
+            tok=np.zeros(slots, np.int32), pos=np.zeros(slots, np.int32),
+            temps=np.zeros(slots, np.float32),
+            seeds=np.zeros(slots, np.int32),
+            tables=np.zeros((slots, self.pages_per_row), np.int32),
+        )
         # what the decode steps' mixtures counted on the device, summed
         # as each step's tokens are fetched (the same ``device_get``):
         # live (token, expert) assignments, and expert groups that held
@@ -235,8 +269,8 @@ class PagedPoolModel:
     def prefill_chunk(
         self, tokens: np.ndarray, slot: int, table: np.ndarray,
         start: int, true_len: int, temp: float, seed: int,
-        final: bool = True,
-    ) -> Optional[int]:
+        final: bool = True, riders: Optional[dict] = None,
+    ):
         """Run one [1, chunk_tokens] prompt chunk at virtual positions
         [start, start + true_len) through ``table``.  On a prompt's
         ``final`` chunk, returns the token sampled at its last real
@@ -244,27 +278,67 @@ class PagedPoolModel:
         (returns None): the host goes on while the device writes.
         ``slot`` is the engine's row id: where a pattern with conv
         layers keeps the row's state (models/decode.py); any other
-        model's math needs only the table."""
+        model's math needs only the table.
+
+        ``riders`` (only where ``chunk_riders``): a decode step by
+        ``decode``'s own names, ``tok pos temps seeds tables carry``,
+        that rides in the chunk's program.  The call is then that
+        ``decode(..., carry=)`` too: the step's tokens stay on the
+        device, and what comes back is ``(the chunk's token or None,
+        the PREVIOUS step's tokens)``."""
         with self._span("pool.prefill_chunk"):
-            (first, counted), self.cache = self._prefill_c(
+            if riders is not None and not self.chunk_riders:
+                raise ValueError(
+                    "this pool's chunk program carries no riders"
+                )
+            step, previous = (), None
+            if riders is not None:
+                previous = self._outstanding
+                step = self._step_args(previous, **riders)
+            elif self.chunk_riders:
+                step = self._step_args(
+                    None, carry=self._no_carry, **self._idle_step
+                )
+            out, self.cache = self._prefill_c(
                 self.params, self.cache, self._chunks_unfetched,
                 self._put(np.asarray(tokens, np.int32)),
                 self._put(np.asarray(table, np.int32)),
                 np.int32(start), np.int32(true_len),
                 np.float32(temp), np.int32(seed), np.int32(slot),
+                *step,
             )
+            (first, counted), rode = out if step else (out, None)
+            if riders is not None:
+                self._outstanding = rode
             if not final:
                 # nobody reads this chunk's sample, and its writes
                 # need no fence: whatever reads these pages later (a
                 # chunk or decode step sharing them, export_page) is
                 # a later program on the same in-order queue
                 self._chunks_unfetched = counted
-                return None
-            self._chunks_unfetched = self._no_chunks
-            with self._span("pool.prefill_chunk.fetch"):
-                first, counted = self._jax.device_get((first, counted))
-            self._moe_prefill_counts += np.asarray(counted, np.int64)
-            return int(first)
+                first = None
+            else:
+                self._chunks_unfetched = self._no_chunks
+                with self._span("pool.prefill_chunk.fetch"):
+                    first, counted = self._jax.device_get((first, counted))
+                self._moe_prefill_counts += np.asarray(counted, np.int64)
+                first = int(first)
+            if riders is None:
+                return first
+            return first, self._fetch(previous)
+
+    def _step_args(self, previous, carry, tok, pos, temps, seeds, tables):
+        """A decode step as either program takes it after the cache:
+        the outstanding step's tokens (a device value) first."""
+        return (
+            self._no_tokens if previous is None else previous[0],
+            self._put(np.asarray(carry, np.bool_)),
+            self._put(np.asarray(tok, np.int32)),
+            self._put(np.asarray(pos, np.int32)),
+            self._put(np.asarray(temps, np.float32)),
+            self._put(np.asarray(seeds, np.int32)),
+            self._put(np.asarray(tables, np.int32)),
+        )
 
     def decode(
         self, tok: np.ndarray, pos: np.ndarray,
@@ -298,15 +372,10 @@ class PagedPoolModel:
                     "resolve_decode() first"
                 )
             nxt, self.cache = self._decode_c(
-                self.params, self.cache,
-                self._no_tokens if previous is None else previous[0],
-                self._put(np.asarray(carry, np.bool_) if ahead
-                          else self._no_carry),
-                self._put(np.asarray(tok, np.int32)),
-                self._put(np.asarray(pos, np.int32)),
-                self._put(np.asarray(temps, np.float32)),
-                self._put(np.asarray(seeds, np.int32)),
-                self._put(np.asarray(tables, np.int32)),
+                self.params, self.cache, *self._step_args(
+                    previous, carry if ahead else self._no_carry,
+                    tok, pos, temps, seeds, tables,
+                ),
             )
             self._outstanding = nxt if ahead else None
             return self._fetch(previous if ahead else nxt)
@@ -393,29 +462,30 @@ class PagedPoolModel:
         every way the engine loop will call them, so that nothing is
         traced under traffic: a chunk unfetched and fetched, a decode
         step dispatched behind one still unread (the carried tokens
-        that step's own output, a device value) and the resolve.
+        that step's own output, a device value) and the resolve; where
+        the chunk carries riders, a step riding a chunk behind a
+        decode step and a decode step behind a rider chunk.
         ``ahead=False`` warms the synchronous calls alone: the gang
         driver's, whose ticks are resolved as they return.  All
         tables are zero, so every write lands in the trash page and
         every gather is masked — warmup leaves no residue a real
         request could attend to."""
-        for final in (False, True) if ahead else (True,):
-            self.prefill_chunk(
-                np.zeros((1, self.chunk_tokens), np.int32), slot=0,
-                table=np.zeros(self.pages_per_row, np.int32),
-                start=0, true_len=self.chunk_tokens, temp=0.0, seed=0,
-                final=final,
-            )
-        step = (
-            np.zeros(self.slots, np.int32),
-            np.zeros(self.slots, np.int32),
-            np.zeros(self.slots, np.float32),
-            np.zeros(self.slots, np.int32),
-            np.zeros((self.slots, self.pages_per_row), np.int32),
+        chunk = dict(
+            tokens=np.zeros((1, self.chunk_tokens), np.int32), slot=0,
+            table=np.zeros(self.pages_per_row, np.int32),
+            start=0, true_len=self.chunk_tokens, temp=0.0, seed=0,
         )
+        for final in (False, True) if ahead else (True,):
+            self.prefill_chunk(**chunk, final=final)
+        step = self._idle_step
         if not ahead:
-            self.decode(*step)
+            self.decode(**step)
             return
         for carry in (self._no_carry, ~self._no_carry):
-            self.decode(*step, carry=carry)
+            self.decode(**step, carry=carry)
+            if self.chunk_riders:
+                self.prefill_chunk(
+                    **chunk, final=bool(carry[0]),
+                    riders=dict(step, carry=~carry),
+                )
         self.resolve_decode()

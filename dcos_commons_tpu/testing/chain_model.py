@@ -118,13 +118,19 @@ class OneAhead:
         return first if final else None
 
     def decode(self, tok, pos, temps, seeds, tables, n_active, carry):
+        return self._dispatch(
+            "dispatch", tok, pos, temps, seeds, tables, n_active, carry
+        )
+
+    def _dispatch(self, kind, tok, pos, temps, seeds, tables, n_active,
+                  carry):
         carry = np.asarray(carry, bool)
         if carry.any():
             assert self._outstanding is not None, (
                 "a carried token with no step outstanding"
             )
             tok = np.where(carry, self._outstanding, tok)
-        self.log.append(("dispatch", self.steps))
+        self.log.append((kind, self.steps))
         previous = self._take()
         self._outstanding = np.asarray(
             self.model.decode(tok, pos, temps, seeds, tables, n_active)
@@ -141,6 +147,28 @@ class OneAhead:
             return np.zeros(0, np.int32)
         self.log.append(("resolve", self.steps - 1))
         return previous
+
+
+class ChunkRiders(OneAhead):
+    """``OneAhead`` whose chunk program carries a decode step
+    (``PagedPoolModel`` with ``chunk_riders``): ``prefill_chunk(...,
+    riders=)`` is the chunk and then that step, by ``decode``'s own
+    keywords, in ONE call, logged ("chunk", ...) then ("ride", step),
+    and returns (the chunk's token or None, the previous step's
+    tokens)."""
+
+    def engine_kwargs(self):
+        return {**super().engine_kwargs(), "chunk_riders": True}
+
+    def prefill_chunk(self, padded, slot, table, start, true_len,
+                      temp, seed, final=True, riders=None):
+        first = super().prefill_chunk(
+            padded, slot, table, start, true_len, temp, seed, final
+        )
+        if riders is None:
+            return first
+        live = int(np.asarray(riders["tables"]).any(axis=1).sum())
+        return first, self._dispatch("ride", n_active=live, **riders)
 
 
 def settled_stats(engine, timeout=10.0):

@@ -440,6 +440,9 @@ def main() -> int:
     pool = PagedPoolModel(
         config, params, slots, max_len, paged.page_tokens,
         paged.pages, paged.chunk_tokens, kv_dtype=kv_dtype,
+        # a pod that hands its prompts over decodes only when the
+        # hand-off fails: its chunks carry no decode step
+        riders=handoff is None,
     )
     engine = PagedEngine(
         pool.prefill_chunk, pool.decode, slots, max_len,
@@ -453,6 +456,9 @@ def main() -> int:
         # this device half carries a token on the device, so the
         # loop runs one call ahead of it
         resolve_decode_fn=pool.resolve_decode,
+        # and where its chunk program carries the decode step, a tick
+        # with a chunk is one device program
+        chunk_riders=pool.chunk_riders,
         device_counters=pool.loop_counters,
         log=lambda msg: print(msg, flush=True),
         extra_stats={"http_port": bound_port},

@@ -773,3 +773,183 @@ def test_live_pages_by_hand():
     assert ids[0, :1].tolist() == [100]
     assert ids[1, :4].tolist() == [100, 101, 108, 109]
     assert ids[2, :12].tolist() == list(range(100, 108)) + [108, 109, 110, 111]
+
+
+# -- a decode step riding a prefill chunk (ISSUE 40) --------------------
+
+# (the prefilling row's chunk width, where its next chunk starts, how
+# many of the chunk's positions are real)
+RIDER_CHUNKS = {
+    "inside_a_window": (8, 8, 8),
+    "short_last_chunk": (8, 16, 5),
+    "straddles_a_windows_end": (24, 24, 24),
+}
+
+
+def _pool_by_hand(config, params, chunk_tokens, start, path, monkeypatch):
+    """Four slots over one arena, by hand: slot 1 decodes at the last
+    position of a chunk that is also its window's last (its step pools
+    a summary and the next one begins a window), slot 2 in the middle
+    of its third window, slots 0 and 3 idle; a fifth row, outside the
+    decode set as the engine keeps it, has been prefilled up to
+    ``start``.  Returns (the shared cache, the prefilling row, the
+    decode step ``(token, pos, tables)``, the two programs)."""
+    import jax
+
+    from dcos_commons_tpu.models import decode
+
+    if path == "interpret":
+        monkeypatch.setattr(
+            decode, "decode_attention_kernel",
+            lambda config, cache: "interpret",
+        )
+    rows, cache = [], None
+    for i, plen in enumerate((31, 75, start)):
+        row = Row(config, params, n_pages=128, first_page=1 + 40 * i)
+        if cache is not None:
+            row.cache = cache
+        if plen:
+            row.prefill(_prompt(plen, 10 + i), chunk_tokens)
+        cache = row.cache
+        rows.append(row)
+    a, b, filling = rows
+    token = np.zeros(4, np.int32)
+    pos = np.zeros(4, np.int32)
+    tables = np.zeros((4, len(a.table)), np.int32)
+    for slot, row in ((1, a), (2, b)):
+        at = len(row.tokens)
+        row._ensure(at, at)
+        token[slot], pos[slot], tables[slot] = 7 + slot, at, row.table
+    chunk = jax.jit(
+        lambda c, t, tb, s, n, riders=None: decode.paged_prefill_chunk(
+            config, params, c, t, tb, s, n, 0, riders
+        )
+    )
+    step = jax.jit(
+        lambda c, t, p, tb: decode.paged_decode_step(
+            config, params, c, t, p, tb
+        )
+    )
+    return cache, filling, (token, pos, tables), chunk, step
+
+
+def _next_chunk(filling, chunk_tokens, start, true_len):
+    padded = np.zeros((1, chunk_tokens), np.int32)
+    padded[0, :true_len] = _prompt(true_len, 99)
+    filling._ensure(start, start + true_len - 1)
+    return padded, filling.table.copy(), start, true_len
+
+
+def _arenas_agree(got, want, but_page=None):
+    for name in want:
+        a, b = np.asarray(got[name]), np.asarray(want[name])
+        if but_page is not None:
+            a, b = np.delete(a, but_page, 1), np.delete(b, but_page, 1)
+        assert float(np.abs(a - b).max()) < TOLERANCE, name
+
+
+@pytest.mark.parametrize("path", ["gather", "interpret"])
+@pytest.mark.parametrize("case", sorted(RIDER_CHUNKS))
+def test_a_chunk_with_riders_is_the_chunk_then_the_step(
+        toy, case, path, monkeypatch):
+    """One program for both leaves the arena, the chunk's logits and
+    the riders' logits as the chunk's program followed by the decode
+    step's leaves them, on either attention path of the riders."""
+    config, params = toy
+    chunk_tokens, start, true_len = RIDER_CHUNKS[case]
+    cache, filling, riders, chunk, step = _pool_by_hand(
+        config, params, chunk_tokens, start, path, monkeypatch
+    )
+    args = _next_chunk(filling, chunk_tokens, start, true_len)
+    want_first, then, _ = chunk(cache, *args)
+    want_rows, want_cache, _ = step(then, *riders)
+    first, got_cache, counts, rows = chunk(cache, *args, riders=riders)
+    assert counts is None
+    assert first.shape == (1, 320) and rows.shape == (4, 320)
+    assert float(np.abs(first - want_first).max()) < TOLERANCE
+    assert float(np.abs(rows - want_rows).max()) < TOLERANCE
+    _arenas_agree(got_cache, want_cache)
+    # and it did something: both rows' pages, and the summary slot 1's
+    # step finishes, are not what they were
+    for name in ("k", "v"):
+        before, after = np.asarray(cache[name]), np.asarray(got_cache[name])
+        _token, pos, tables = riders
+        for slot in (1, 2):
+            ring = tables[slot][(pos[slot] % WINDOW) // CHUNK]
+            assert np.abs(after[:, ring] - before[:, ring]).max() > 0
+        summary = tables[1][LAYOUT.window_pages + (pos[1] // CHUNK) // CHUNK]
+        assert np.abs(after[:, summary] - before[:, summary]).max() > 0
+
+
+@pytest.mark.parametrize("path", ["gather", "interpret"])
+def test_idle_riders_change_nothing_but_the_trash_page(
+        toy, path, monkeypatch):
+    config, params = toy
+    cache, filling, riders, chunk, _step = _pool_by_hand(
+        config, params, 8, 8, path, monkeypatch
+    )
+    args = _next_chunk(filling, 8, 8, 8)
+    want_first, want_cache, _ = chunk(cache, *args)
+    idle = tuple(np.zeros_like(a) for a in riders)
+    first, got_cache, _, _rows = chunk(cache, *args, riders=idle)
+    assert float(np.abs(first - want_first).max()) < TOLERANCE
+    _arenas_agree(got_cache, want_cache, but_page=0)
+
+
+def test_a_family_without_the_mixed_layer_refuses_riders():
+    import jax.numpy as jnp
+
+    from dcos_commons_tpu.models import TransformerConfig, decode
+
+    config = TransformerConfig(
+        vocab=64, d_model=32, n_layers=1, n_heads=4, n_kv_heads=2,
+        d_ff=64, dtype=jnp.float32, remat=False,
+    )
+    assert not decode.chunk_carries_riders(config)
+    with pytest.raises(NotImplementedError, match="riders|beside a chunk"):
+        decode.paged_prefill_chunk(
+            config, {}, {}, jnp.zeros((1, 4), jnp.int32),
+            jnp.zeros(4, jnp.int32), 0, 4, 0,
+            (jnp.zeros(2, jnp.int32),) * 3,
+        )
+
+
+@pytest.mark.parametrize("riders", [False, True],
+                         ids=["plain-chunk", "rider-chunk"])
+def test_warm_traces_each_program_once(toy, riders):
+    """Every way the loop calls the two programs is warmed, riders or
+    not: serving after ``warm()`` compiles nothing."""
+    from dcos_commons_tpu.serve.pool import PagedPoolModel
+
+    config, params = toy
+    pool = PagedPoolModel(
+        config, params, 3, MAX_LEN, CHUNK, 40, 8, riders=riders
+    )
+    assert pool.chunk_riders is riders
+    pool.warm()
+    assert pool._prefill_c._cache_size() == 1
+    assert pool._decode_c._cache_size() == 1
+    engine = PagedEngine(
+        pool.prefill_chunk, pool.decode, 3, MAX_LEN, MAX_LEN - 48,
+        page_tokens=CHUNK, pages=40, chunk_tokens=8, layout=pool.layout,
+        queue_timeout_s=120, resolve_decode_fn=pool.resolve_decode,
+        chunk_riders=pool.chunk_riders,
+    )
+    # one call, so both rows are admitted in one tick: the short
+    # prompt decodes while the long one is still prefilling
+    prompts = [_prompt(6, 0), _prompt(50, 1)]
+    try:
+        outs = engine.submit(prompts, 12)
+        loop = settled_stats(engine)["loop"]
+    finally:
+        engine.stop()
+    assert [len(out) for out in outs] == [12, 12]
+    # the short prompt's one chunk had nobody to carry; each of the
+    # long prompt's seven carried the short row's next step
+    assert loop["prefill_calls"] == 8
+    assert loop["prefill_rider_calls"] == (7 if riders else 0)
+    assert pool._prefill_c._cache_size() == 1
+    assert pool._decode_c._cache_size() == 1
+    # the same tokens with and without riders: the reference's
+    for prompt, out in zip(prompts, outs):
+        _held_to_the_reference(params, prompt, out)

@@ -404,8 +404,12 @@ ONE_KIND = {
                     chunk_size=4, norm_unit_offset=True,
                     tie_embeddings=False, n_pred_heads=8,
                     rope_theta=100000.0, rms_norm_eps=1e-5),
-        tree="8cd45ac75946971e", prefill="4fa36e17449d50ba",
-        decode="b4d3618816a7cfdb",
+        # the two programs as ISSUE 40 left them: the same equations
+        # from ``_eva_chunk_part`` / ``_eva_step_part`` over one trunk
+        # (``_eva_trunk``), traced in another order, and the chunk
+        # norms the one row whose logits it returns
+        tree="8cd45ac75946971e", prefill="362c53e717b4c27c",
+        decode="38fb3c32658004c4",
     ),
 }
 
