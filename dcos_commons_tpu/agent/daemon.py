@@ -248,6 +248,7 @@ class AgentDaemon:
                 kill_grace_s=float(entry.get("kill_grace_s", 5.0)),
                 uris=entry.get("uris"),
                 rlimits=entry.get("rlimits"),
+                launch_env=entry.get("launch_env"),
             )
             launched.append(info.task_id)
         return launched

@@ -544,6 +544,7 @@ class LocalProcessAgent:
         kill_grace_s: float = 5.0,
         uris: Optional[List[dict]] = None,
         rlimits: Optional[List[dict]] = None,
+        launch_env: Optional[Dict[str, str]] = None,
     ) -> None:
         with self._lock:
             if info.task_id in self._tasks:
@@ -608,8 +609,12 @@ class LocalProcessAgent:
                     return
                 env = dict(os.environ)
                 env.update(info.env)
-                # secret env values ride the launch request only — merged
-                # here at exec time, never part of the persisted TaskInfo
+                # what belongs to THIS launch and not to the task's
+                # configuration (LAUNCH_TRACE: the launch span's ids
+                # and the hand-off's wall time) and secret env values
+                # ride the launch request only — merged here at exec
+                # time, never part of the persisted TaskInfo
+                env.update(launch_env or {})
                 env.update(secret_env or {})
                 env["SANDBOX"] = sandbox
                 try:

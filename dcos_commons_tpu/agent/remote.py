@@ -251,6 +251,7 @@ class RemoteFleet(Agent):
         kill_grace_s: float = 5.0,
         uris: Optional[List[dict]] = None,
         rlimits: Optional[List[dict]] = None,
+        launch_env: Optional[Dict[str, str]] = None,
     ) -> None:
         client = self._clients.get(info.agent_id)
         if client is None:
@@ -266,6 +267,7 @@ class RemoteFleet(Agent):
             "kill_grace_s": kill_grace_s,
             "uris": uris or [],
             "rlimits": rlimits or [],
+            "launch_env": launch_env or {},
         }
         try:
             client.launch([entry])

@@ -706,8 +706,10 @@ def stepcompare(
     and is not a property of the steady-state step.
     """
     from dcos_commons_tpu.metrics.registry import percentile
+    from dcos_commons_tpu.trace.steplog import step_records
 
-    records = list(records)[max(0, int(skip)):]
+    # steps only: a serve worker's start-up phases share the steplog
+    records = step_records(records)[max(0, int(skip)):]
     walls = sorted(
         float(r["wall_s"]) for r in records
         if isinstance(r.get("wall_s"), (int, float))

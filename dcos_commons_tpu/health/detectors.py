@@ -25,6 +25,8 @@ import time
 from statistics import median
 from typing import Dict, List, Optional
 
+from dcos_commons_tpu.trace.steplog import step_records
+
 # below this many hosts the fleet median IS (or is dragged by) the
 # outlier: scoring 1-2 hosts against themselves only yields noise
 MIN_FLEET_FOR_SCORING = 3
@@ -106,7 +108,9 @@ class StragglerDetector:
             ) else [records]
             owns = []
             for series in series_list:
-                for record in series[-self.window:]:
+                # steps only: a worker's start-up phases share the
+                # steplog, and a 12 s backend start is no slow step
+                for record in step_records(series)[-self.window:]:
                     own = self.own_time(record)
                     if own is not None:
                         owns.append(own)

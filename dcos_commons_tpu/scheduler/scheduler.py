@@ -46,6 +46,7 @@ from dcos_commons_tpu.state.state_store import (
     StateStore,
 )
 from dcos_commons_tpu.trace.recorder import TraceRecorder
+from dcos_commons_tpu.trace.startup import LAUNCH_TRACE_ENV, launch_context
 
 LOG = logging.getLogger(__name__)
 
@@ -744,7 +745,7 @@ class DefaultScheduler:
                 # never hears about the launch — a successor must
                 # re-issue it (the STAGING seed reconciles to LOST)
                 self._chaos_point("post-wal")
-                self._launch(result.task_infos, requirement)
+                self._launch(result.task_infos, requirement, launch_span)
             self.metrics.incr("operations.launch", len(result.task_infos))
         return len(candidates)
 
@@ -774,7 +775,7 @@ class DefaultScheduler:
                 continue  # previous launch already dead
             self.task_killer.kill(prev.task_id)
 
-    def _launch(self, task_infos, requirement) -> None:
+    def _launch(self, task_infos, requirement, launch_span=None) -> None:
         pod = requirement.pod
         for info in task_infos:
             task_spec = None
@@ -794,9 +795,19 @@ class DefaultScheduler:
                 files, secret_env = self._security_payload(
                     info, pod, task_spec
                 )
-                kwargs = {}
+                # the launch's trace context rides the request the way
+                # secret env does (merged into the environment at exec
+                # time, never part of the persisted TaskInfo, so no
+                # configuration differs and nothing relaunches for it):
+                # the worker stamps its start-up under this launch's
+                # trace id, from this hand-off on (trace/startup.py)
+                kwargs = {
+                    "launch_env": {
+                        LAUNCH_TRACE_ENV: launch_context(launch_span)
+                    }
+                }
                 if files or secret_env:
-                    kwargs = {"files": files, "secret_env": secret_env}
+                    kwargs.update(files=files, secret_env=secret_env)
                 if task_spec.uris:
                     # artifact entries ride the launch request; the
                     # agent fetches before the command runs (reference:

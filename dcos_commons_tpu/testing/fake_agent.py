@@ -45,7 +45,7 @@ class FakeAgent:
     def launch_one(self, info: TaskInfo, readiness=None, health=None,
                    templates=None, files=None, secret_env=None,
                    kill_grace_s: float = 5.0, uris=None,
-                   rlimits=None) -> None:
+                   rlimits=None, launch_env=None) -> None:
         with self._lock:
             if info.task_id in self._active:
                 return  # idempotent, like the real agent
@@ -62,6 +62,7 @@ class FakeAgent:
                 "templates": templates or [],
                 "files": files or [],
                 "secret_env": dict(secret_env or {}),
+                "launch_env": dict(launch_env or {}),
             }
 
     def kill(self, task_id: str, grace_period_s: float = 0.0) -> None:

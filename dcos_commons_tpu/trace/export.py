@@ -5,7 +5,9 @@ Chrome format (Perfetto/chrome://tracing loadable): one complete
 span's track (a pod instance like "trainer-2", "scheduler", "plan"),
 timestamps in wall microseconds.  Worker steplogs merge in as extra
 events on ``<task>/steps`` lanes, so a 4-host gang renders as four
-step rows whose horizontal offsets ARE the gang skew.
+step rows whose horizontal offsets ARE the gang skew; a worker's
+start-up phases (``startup.launch`` ... ``startup.ready``) render on
+``<task>/startup`` under the trace id of the launch that caused them.
 
 The text form is the ssh-and-curl view: one line per span, sorted by
 start, offsets relative to the first span.
@@ -20,6 +22,19 @@ from dcos_commons_tpu.trace.recorder import TraceRecorder
 from dcos_commons_tpu.trace.span import render_id
 
 Steplogs = Dict[str, List[dict]]
+
+
+def _steplog_row(record: dict):
+    """(wall start, seconds, lane, name) of one steplog record: a step
+    on ``steps``, a start-up phase (``phase`` in place of ``step``,
+    trace/startup.py) on ``startup`` under its own name.  A record
+    ends at its ``t`` and lasted ``wall_s``; the first phase crosses
+    two hosts' wall clocks, so a skewed one may read negative."""
+    wall_s = float(record.get("wall_s", 0.0) or 0.0)
+    end_wall = float(record.get("t", 0.0) or 0.0)
+    if "phase" in record:
+        return end_wall - wall_s, wall_s, "startup", str(record["phase"])
+    return end_wall - wall_s, wall_s, "steps", f"step {record.get('step', '?')}"
 
 
 def to_chrome(
@@ -48,14 +63,13 @@ def to_chrome(
         })
     for task_name, records in sorted((steplogs or {}).items()):
         for record in records:
-            wall_s = float(record.get("wall_s", 0.0) or 0.0)
-            end_wall = float(record.get("t", 0.0) or 0.0)
+            start_wall, wall_s, lane, name = _steplog_row(record)
             events.append({
-                "name": f"step {record.get('step', '?')}",
+                "name": name,
                 "ph": "X",
                 "pid": service,
-                "tid": f"{task_name}/steps",
-                "ts": int((end_wall - wall_s) * 1e6),
+                "tid": f"{task_name}/{lane}",
+                "ts": int(start_wall * 1e6),
                 "dur": max(1, int(wall_s * 1e6)),
                 "args": {
                     k: v for k, v in record.items() if k not in ("t",)
@@ -99,15 +113,19 @@ def to_text(
         ))
     for task_name, records in sorted((steplogs or {}).items()):
         for record in records:
-            wall_s = float(record.get("wall_s", 0.0) or 0.0)
-            end_wall = float(record.get("t", 0.0) or 0.0)
-            attrs = {k: v for k, v in record.items() if k not in ("t", "step")}
+            start_wall, wall_s, lane, name = _steplog_row(record)
+            attrs = {
+                k: v for k, v in record.items()
+                if k not in ("t", "step", "phase", "trace_id")
+            }
             rows.append((
-                end_wall - wall_s,
+                start_wall,
                 wall_s,
-                "steplog",
-                f"{task_name}/steps",
-                f"step {record.get('step', '?')}",
+                # a phase record carries its launch's trace id, as the
+                # exporters render it: the same tail as the launch span
+                str(record.get("trace_id") or "steplog")[-8:],
+                f"{task_name}/{lane}",
+                name,
                 attrs,
             ))
     for event in events or []:

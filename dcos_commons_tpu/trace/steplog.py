@@ -5,7 +5,8 @@ pjit step loop is invisible to it.  ``StepLog`` closes that gap from
 the task side: each training/serving step appends one JSON line
 (step index, wall seconds, tokens, seconds blocked waiting for the
 gang before the step's first collective) to ``steplog.jsonl`` in the
-task sandbox.  The agent's sandbox plumbing (``LocalProcessAgent.
+task sandbox, and the serve worker one line a START-UP PHASE
+(``phase`` in place of ``step``: trace/startup.py).  The agent's sandbox plumbing (``LocalProcessAgent.
 steplog_of``) surfaces the file and the scheduler's ``/v1/debug/trace``
 exporters merge it into the same timeline — per-host step lanes make
 gang skew directly visible (host 3's ``blocked_s`` IS the skew the
@@ -41,6 +42,18 @@ class StepLog:
     def record(self, step: int, **fields) -> None:
         entry = {"step": int(step), "t": time.time()}
         entry.update(fields)
+        self._append(entry)
+
+    def phase(self, phase: str, wall_s: float, **fields) -> None:
+        """A record of the worker's life outside its step loop (a
+        start-up phase, trace/startup.py): ``phase`` in place of
+        ``step``, so whatever reads STEPS (``step_records``) never
+        takes one for a slow step."""
+        entry = {"phase": str(phase), "t": time.time(), "wall_s": wall_s}
+        entry.update(fields)
+        self._append(entry)
+
+    def _append(self, entry: dict) -> None:
         try:
             if self._fh is None:
                 self._fh = open(self.path, "a", encoding="utf-8")
@@ -151,6 +164,14 @@ class InflightWindow:
         )
         self.drained += 1
         return step, result
+
+
+def step_records(records) -> List[dict]:
+    """The records of a steplog that are STEPS: what a straggler score
+    or a step-time comparison may read.  Phase records (a 12 s
+    ``startup.backend_up``) are the timeline's alone; told by their
+    ``phase`` key, since hand-made step records may name no ``step``."""
+    return [r for r in records if isinstance(r, dict) and "phase" not in r]
 
 
 def read_steplog(path: str) -> List[dict]:

@@ -48,6 +48,19 @@ tick — when SERVE_TRACE_CAPACITY > 0 (default 0: off).  POST /profile
 process into $SANDBOX/profile (the last capture only), with the
 engine's ``engine.*`` and the pool's ``pool.*`` host spans above the
 device's lines; 409 while another profiler session is open.
+
+The start-up's timeline: GET /stats ``startup`` (and the sandbox
+snapshot) holds this process's own account of launch -> ready, seven
+phases that touch (``launch imports backend_up weights build warm
+ready``, seconds and wall end stamps, their sum ``start_to_ready_s``),
+``warm`` by program (``_prefill`` / ``_decode`` / other: the host's
+``trace_s`` and ``lower_s``, the backend's ``compile_s``, of it
+``cache_read_s``) and what compiled after ``ready``
+(``compiles_after_ready``; an ``engine.compile`` span each in GET
+/trace).  Each phase is a record of the sandbox's steplog under the
+trace id the launch carried (``LAUNCH_TRACE``), so the scheduler's
+/v1/debug/trace shows it between ``launch:`` and
+``status:TASK_RUNNING`` (dcos_commons_tpu/trace/startup.py).
 """
 
 import json
@@ -81,6 +94,7 @@ from dcos_commons_tpu.serve.migration import (  # noqa: E402
     drain_sessions,
 )
 from dcos_commons_tpu.trace import (  # noqa: E402
+    StartupClock,
     TraceRecorder,
     chrome_json,
     to_text,
@@ -122,9 +136,14 @@ def main() -> int:
         restore_checkpoint,
     )
 
+    # the imports end here, and the first phase that is this code's
+    # begins; the listener goes on before the first trace
+    startup = StartupClock()
+    jax.monitoring.register_event_duration_secs_listener(startup.on_duration)
     # what this server runs on, before anything is built on it: a tpu:
     # pod that fell back to the CPU stops here instead of serving
     devices = claim_devices()
+    startup.mark("backend_up")
     print(f"devices: {json.dumps(devices)}", flush=True)
     enable_compilation_cache()
     config = config_from_env(
@@ -163,6 +182,9 @@ def main() -> int:
 
         params = jax.device_put(quantize_params_int8(params))
         print("weights quantized to int8 (per-channel)", flush=True)
+    # the parameters are on the device, whatever dispatched them last
+    jax.block_until_ready(params)
+    startup.mark("weights")
 
     # TWO compiles cover every request: the paged arena's
     # prefill-chunk + decode-step (page tables, start positions, true
@@ -464,8 +486,10 @@ def main() -> int:
         extra_stats={"http_port": bound_port},
         annotate=jax.profiler.TraceAnnotation, tracer=tracer,
     )
+    startup.mark("build")
     warm_t0 = time.monotonic()
-    pool.warm()
+    with startup.warm():
+        pool.warm()
     shape = (
         f"paged KV: {paged.pages} pages x {paged.page_tokens} "
         f"tokens, {slots} rows of {pool.pages_per_row} table "
@@ -511,9 +535,13 @@ def main() -> int:
         },
         prefill_chunk_source=paged.chunk_source,
         warm_s=round(time.monotonic() - warm_t0, 2),
+        # launch -> ready by phase, warm by program; the same dict all
+        # along, so its last phase and the after-ready counts show
+        startup=startup.stats,
     )
     with open("ready", "w") as f:
         f.write("warm\n")
+    startup.ready(tracer)
     print(
         f"warm: continuous batching ({shape}) "
         f"(prompts<={prompt_len}, <={new_tokens} new) on "
