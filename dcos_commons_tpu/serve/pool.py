@@ -2,7 +2,7 @@
 prefill-chunk / decode-step pair over a persistent page arena
 (models/decode.py).
 
-TWO compiles cover the server's whole life: ``prefill_chunk`` runs
+TWO programs cover the server's whole life: ``prefill_chunk`` runs
 one right-padded prompt chunk through a request's page table (traced
 start/true_len/temperature/seed — no recompile per request),
 ``decode`` advances EVERY row one step with per-row positions,
@@ -120,6 +120,8 @@ class PagedPoolModel:
             sample_token,
         )
         from dcos_commons_tpu.serve.paging import RowLayout
+        from dcos_commons_tpu.utils import compile_cache
+        from dcos_commons_tpu.utils.stored_program import StoredProgram
 
         self._jax = jax
         self._span = jax.profiler.TraceAnnotation
@@ -229,11 +231,26 @@ class PagedPoolModel:
             nxt = pick_rows(logits, temps, seeds, pos)
             return (con(nxt), con(counts)), cache
 
-        donate = {}
-        if jax.default_backend() != "cpu":
-            donate = {"donate_argnums": (1,)}
-        self._prefill_c = jax.jit(_prefill, **donate)
-        self._decode_c = jax.jit(_decode, **donate)
+        # each is called as the compiled executable itself, which a
+        # warm start loads from the store beside the compile cache
+        # (utils/stored_program.py).  A pool laid over a mesh compiles
+        # as ever and stores nothing: what ``put`` and
+        # ``constrain_out`` do is in no key, and no sharded executable
+        # has been shown to round-trip
+        on_mesh = any(
+            x is not None for x in (cache_sharding, put, constrain_out)
+        )
+        program = functools.partial(
+            StoredProgram,
+            donate_argnums=() if jax.default_backend() == "cpu" else (1,),
+            # what the two close over that no argument's shape shows
+            closed_over=dict(
+                config=config, kv_dtype=kv_dtype, riders=self.chunk_riders,
+            ),
+            directory=None if on_mesh else compile_cache.programs_dir(),
+        )
+        self._prefill_c = program(_prefill)
+        self._decode_c = program(_decode)
         self._jnp = jnp
         # the tokens of a step dispatched ahead that nobody has fetched
         # yet, as the device holds them: what a carried row reads.
@@ -458,9 +475,10 @@ class PagedPoolModel:
             )
 
     def warm(self, ahead: bool = True) -> None:
-        """Compile + execute both entry points before readiness, in
-        every way the engine loop will call them, so that nothing is
-        traced under traffic: a chunk unfetched and fetched, a decode
+        """Load or compile, and execute, both entry points before
+        readiness, in every way the engine loop will call them, so
+        that nothing is traced under traffic: a chunk unfetched and
+        fetched, a decode
         step dispatched behind one still unread (the carried tokens
         that step's own output, a device value) and the resolve; where
         the chunk carries riders, a step riding a chunk behind a

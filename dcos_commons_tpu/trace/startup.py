@@ -21,9 +21,13 @@ and most of a deploy's seconds.  Two halves meet here:
 
 Inside ``warm`` the clock listens to ``jax.monitoring``'s duration
 events and sums, by program, the host's tracing, its lowering and the
-backend compile (a cache read where it was one); after ``ready`` the
-same listener counts what still compiles.  It is never called in
-steady state: a cached executable raises no event.
+backend compile (a cache read where it was one), and the two events a
+stored program raises itself (utils/stored_program.py): the load of an
+executable the store held, and the write of one it did not.  A program
+that was loaded reads ``source`` ``"stored"`` and no tracing, lowering
+or compile at all; ``programs`` counts both kinds.  After ``ready`` the
+same listener counts what still compiles or loads.  It is never called
+in steady state: a program that is held raises no event.
 
 No jax import here: the worker registers ``on_duration`` itself.
 """
@@ -51,13 +55,25 @@ PHASES = (
 # the programs ``warm`` is split by; whatever else compiles inside it
 # (the pool's small constants) is ``other``
 WARM_PROGRAMS = ("_prefill", "_decode")
-WARM_KINDS = ("trace_s", "lower_s", "compile_s", "cache_read_s")
+WARM_KINDS = (
+    "trace_s", "lower_s", "compile_s", "cache_read_s", "load_s", "store_s",
+)
+# raised by a stored program (utils/stored_program.py), with
+# ``fun_name`` as JAX's own compile events carry it: an executable
+# deserialized from the store, and one serialized and written to it
+LOAD_EVENT = "/dcos_commons_tpu/stored_program/load_duration"
+STORE_EVENT = "/dcos_commons_tpu/stored_program/store_duration"
 _EVENT_KINDS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace_s",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
     "/jax/core/compile/backend_compile_duration": "compile_s",
     "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_s",
+    LOAD_EVENT: "load_s",
+    STORE_EVENT: "store_s",
 }
+# how a program came to be held: a compile (a cache read or not) wins
+# over a load, which was then of an entry that was discarded
+_SOURCES = {"compile_s": "compiled", "load_s": "stored"}
 # An event's start is its end (stamped here, when the listener is
 # called) less the duration JAX measured on its own clock: the two
 # ends of a nested event may each be off by the call between them.
@@ -130,8 +146,10 @@ def _program(fun_name: str) -> str:
     return name if name in WARM_PROGRAMS else "other"
 
 
-def warm_by_program(events: Iterable[_Event]) -> Dict[str, Dict[str, float]]:
-    """Sum ``warm``'s events by program and kind.  A trace inside a
+def warm_by_program(events: Iterable[_Event]) -> Dict[str, Dict[str, object]]:
+    """Sum ``warm``'s events by program and kind, and say each
+    program's ``source``: ``"compiled"``, ``"stored"`` (loaded, and
+    nothing compiled) or None (no event of either).  A trace inside a
     trace (every jitted function a program calls raises its own event
     while the program's is open) is the outer one's time already and
     is left out; a cache read counts where the compile it fired in
@@ -140,6 +158,7 @@ def warm_by_program(events: Iterable[_Event]) -> Dict[str, Dict[str, float]]:
         program: dict.fromkeys(WARM_KINDS, 0.0)
         for program in WARM_PROGRAMS + ("other",)
     }
+    seen = {(kind, program) for _start, _end, kind, program in events}
     spans = sorted(
         (e for e in events if e[2] != "cache_read_s"),
         key=lambda e: (e[0], -e[1]),
@@ -165,7 +184,13 @@ def warm_by_program(events: Iterable[_Event]) -> Dict[str, Dict[str, float]]:
         )
         out[holder]["cache_read_s"] += end - start
     return {
-        program: {kind: round(s, 6) for kind, s in kinds.items()}
+        program: dict(
+            {kind: round(s, 6) for kind, s in kinds.items()},
+            source=next(
+                (source for kind, source in _SOURCES.items()
+                 if (kind, program) in seen), None,
+            ),
+        )
         for program, kinds in out.items()
     }
 
@@ -212,6 +237,9 @@ class StartupClock:
             "phase_end": dict.fromkeys(PHASES),
             "start_to_ready_s": None,
             "warm": warm_by_program(()),
+            # the two programs (and any further set of argument types
+            # they met) by how they came to be held, to this moment
+            "programs": {"stored": 0, "compiled": 0},
             "compiles_after_ready": 0,
             "compile_after_ready_s_sum": 0.0,
         }
@@ -257,9 +285,10 @@ class StartupClock:
                 self.stats["warm"][program].update(kinds)
             totals = {
                 kind: round(sum(k[kind] for k in by_program.values()), 6)
-                for kind in ("trace_s", "lower_s", "compile_s")
+                # a cache read is inside the compile it was served in
+                for kind in WARM_KINDS if kind != "cache_read_s"
             }
-            self.mark("warm", **totals)
+            self.mark("warm", **totals, **self.stats["programs"])
 
     def ready(self, tracer=None) -> None:
         """The ``ready`` file is written: the last phase ends, the sum
@@ -277,20 +306,22 @@ class StartupClock:
     def on_duration(self, event: str, duration: float, fun_name: str = "",
                     **_kwargs) -> None:
         """``jax.monitoring``'s duration listener (JAX passes
-        ``fun_name`` with the three compile events; the cache's own
-        names no function and fires inside the backend compile it
-        belongs to)."""
+        ``fun_name`` with the three compile events, and a stored
+        program with its two; the cache's own names no function and
+        fires inside the backend compile it belongs to)."""
         kind = _EVENT_KINDS.get(event)
         if kind is None:
             return
         end = time.monotonic()
+        program = _program(fun_name)
         with self._lock:
+            source = _SOURCES.get(kind)
+            if source and program in WARM_PROGRAMS:
+                self.stats["programs"][source] += 1
             if self._events is not None:
-                self._events.append(
-                    (end - duration, end, kind, _program(fun_name))
-                )
+                self._events.append((end - duration, end, kind, program))
                 return
-            if not self._is_ready or kind != "compile_s":
+            if not self._is_ready or not source:
                 return
             self.stats["compiles_after_ready"] += 1
             self.stats["compile_after_ready_s_sum"] = round(

@@ -53,11 +53,15 @@ The start-up's timeline: GET /stats ``startup`` (and the sandbox
 snapshot) holds this process's own account of launch -> ready, seven
 phases that touch (``launch imports backend_up weights build warm
 ready``, seconds and wall end stamps, their sum ``start_to_ready_s``),
-``warm`` by program (``_prefill`` / ``_decode`` / other: the host's
-``trace_s`` and ``lower_s``, the backend's ``compile_s``, of it
-``cache_read_s``) and what compiled after ``ready``
-(``compiles_after_ready``; an ``engine.compile`` span each in GET
-/trace).  Each phase is a record of the sandbox's steplog under the
+``warm`` by program (``_prefill`` / ``_decode`` / other: ``source``,
+``"stored"`` where the program was loaded from the store beside the
+compile cache in ``load_s`` seconds, dcos_commons_tpu/utils/
+stored_program.py, else ``"compiled"``: the host's ``trace_s`` and
+``lower_s``, the backend's ``compile_s``, of it ``cache_read_s``, and
+``store_s``, the entry's write), ``programs`` (the two programs counted
+by ``stored`` / ``compiled``) and what compiled or loaded after
+``ready`` (``compiles_after_ready``; an ``engine.compile`` span each in
+GET /trace).  Each phase is a record of the sandbox's steplog under the
 trace id the launch carried (``LAUNCH_TRACE``), so the scheduler's
 /v1/debug/trace shows it between ``launch:`` and
 ``status:TASK_RUNNING`` (dcos_commons_tpu/trace/startup.py).

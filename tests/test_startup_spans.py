@@ -48,6 +48,7 @@ from dcos_commons_tpu.trace import (
 from dcos_commons_tpu.trace import startup as startup_module
 from dcos_commons_tpu.trace.span import render_id
 from dcos_commons_tpu.trace.startup import PHASES, warm_by_program
+from dcos_commons_tpu.utils.compile_cache import CACHE_ENV
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -74,10 +75,13 @@ CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 def sums(by_program):
-    return {
-        program: {k: v for k, v in kinds.items() if v}
-        for program, kinds in by_program.items() if any(kinds.values())
-    }
+    """The seconds that are not zero, without each program's source."""
+    out = {}
+    for program, kinds in by_program.items():
+        seconds = {k: v for k, v in kinds.items() if v and k != "source"}
+        if seconds:
+            out[program] = seconds
+    return out
 
 
 @pytest.mark.parametrize("events,expected", [
@@ -104,8 +108,14 @@ def sums(by_program):
     ([(0.0, 0.1, "trace_s", "other"), (0.1, 0.2, "lower_s", "other"),
       (0.2, 0.4, "compile_s", "other"), (1.0, 1.1, "trace_s", "other")],
      {"other": {"trace_s": 0.2, "lower_s": 0.1, "compile_s": 0.2}}),
+    # a stored program's own two events: a load, and the write that
+    # follows a compile
+    ([(0.0, 0.25, "load_s", "_prefill"), (1.0, 1.5, "compile_s", "_decode"),
+      (1.5, 1.75, "store_s", "_decode")],
+     {"_prefill": {"load_s": 0.25},
+      "_decode": {"compile_s": 0.5, "store_s": 0.25}}),
 ], ids=["nothing", "three-parts", "nested-traces", "twice", "cache-read-held",
-        "cache-read-unheld", "other"])
+        "cache-read-unheld", "other", "stored-program"])
 def test_warm_by_program(events, expected):
     got = sums(warm_by_program(events))
     assert set(got) == set(expected)
@@ -113,12 +123,27 @@ def test_warm_by_program(events, expected):
         assert got[program] == pytest.approx(kinds)
 
 
+@pytest.mark.parametrize("kinds,source", [
+    ((), None), (("trace_s", "lower_s"), None), (("load_s",), "stored"),
+    (("trace_s", "lower_s", "compile_s", "store_s"), "compiled"),
+    # an entry that loaded and was discarded: what is held was compiled
+    (("load_s", "compile_s"), "compiled"),
+])
+def test_a_programs_source_is_how_it_came_to_be_held(kinds, source):
+    events = [(float(i), i + 0.5, kind, "_decode")
+              for i, kind in enumerate(kinds)]
+    by_program = warm_by_program(events)
+    assert by_program["_decode"]["source"] == source
+    assert by_program["_prefill"]["source"] is None
+
+
 def test_warm_by_program_always_names_both_programs_and_every_kind():
     empty = warm_by_program(())
     assert set(empty) == {"_prefill", "_decode", "other"}
     for kinds in empty.values():
         assert kinds == {"trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
-                         "cache_read_s": 0.0}
+                         "cache_read_s": 0.0, "load_s": 0.0, "store_s": 0.0,
+                         "source": None}
 
 
 @pytest.mark.parametrize("fun_name,program", [
@@ -285,6 +310,8 @@ def test_only_a_compile_after_ready_counts_and_is_a_span(tmp_path):
     clock.on_duration(COMPILE, 0.5, fun_name="jit(_prefill)")
     assert clock.stats["compiles_after_ready"] == 2
     assert clock.stats["compile_after_ready_s_sum"] == pytest.approx(1.25)
+    # the two programs by how they came to be held, `init` is neither
+    assert clock.stats["programs"] == {"stored": 0, "compiled": 3}
     # the warm-up's sums are closed
     assert clock.stats["warm"]["_decode"]["compile_s"] == pytest.approx(0.25)
     spans = [s for s in tracer.snapshot() if s.name == "engine.compile"]
@@ -620,6 +647,11 @@ def workers(tmp_path_factory):
     """One worker launched by the scheduler (port 23310..) and one by
     hand without a launch context, started together."""
     root = tmp_path_factory.mktemp("startup")
+    # compile caches (and program stores) of these workers' own: what
+    # an earlier run of the tests left in the checkout's would be
+    # LOADED, and a start that loads traces nothing
+    patch = pytest.MonkeyPatch()
+    patch.setenv(CACHE_ENV, str(root / "cache"))
     entry_dir = root / "entry"
     entry_dir.mkdir()
     (entry_dir / "serve_worker.py").write_text(ENTRY.format(repo=REPO))
@@ -645,13 +677,17 @@ def workers(tmp_path_factory):
     try:
         scheduler.run_cycle()
         launched = scheduler.state_store.fetch_task("server-0-api")
-        bare_env = dict(launched.env, PORT_HTTP="0")
-        bare = TaskInfo(
-            name="bare-0-api", task_id="bare-0-api__1", agent_id="h0",
-            command=launched.command, env=bare_env,
-        )
-        # a hand-built launch: no context rides it
-        bare_agent.launch([bare])
+        def launch_bare(name, cache):
+            # a hand-built launch: no context rides it
+            bare_agent.launch([TaskInfo(
+                name=name, task_id=name + "__1", agent_id="h0",
+                command=launched.command,
+                env=dict(launched.env, PORT_HTTP="0",
+                         **{CACHE_ENV: str(root / cache)}),
+            )])
+            return str(root / "bare" / name)
+
+        launch_bare("bare-0-api", "bare-cache")
         deadline = time.monotonic() + 240
         bare_ready = str(root / "bare" / "bare-0-api" / "ready")
         while time.monotonic() < deadline:
@@ -674,10 +710,12 @@ def workers(tmp_path_factory):
             "bare_url": f"http://127.0.0.1:{bare_port}",
             "sandbox": str(root / "sbx" / "server-0-api"),
             "bare_sandbox": str(root / "bare" / "bare-0-api"),
+            "launch_bare": launch_bare,
         }
     finally:
         agent.shutdown()
         bare_agent.shutdown()
+        patch.undo()
 
 
 def test_a_deployed_worker_has_every_phase_and_they_touch(workers):
@@ -705,6 +743,10 @@ def test_warm_by_program_names_both_programs(workers):
         kinds = startup["warm"][program]
         assert kinds["trace_s"] > 0 and kinds["lower_s"] > 0, program
         assert kinds["compile_s"] > 0, program
+        # a cache of its own, found empty: compiled, and stored
+        assert kinds["source"] == "compiled" and kinds["store_s"] > 0
+        assert kinds["load_s"] == 0
+    assert startup["programs"] == {"stored": 0, "compiled": 2}
     total = sum(
         kinds[k] for kinds in startup["warm"].values()
         for k in ("trace_s", "lower_s", "compile_s")
@@ -830,3 +872,36 @@ def test_a_worker_without_a_launch_context_starts_and_reads_null(workers):
     assert [r["phase"] for r in records] == [
         "startup." + p for p in PHASES[1:]
     ]
+
+
+def test_the_next_worker_of_that_cache_loads_both_programs(workers):
+    """A relaunch, a rolling update, a scale-out replica: the store
+    beside the first worker's compile cache holds both programs, and a
+    worker started on it traces, lowers and compiles neither."""
+    sandbox = workers["launch_bare"]("bare-1-api", "cache")
+    wait_for(os.path.join(sandbox, "ready"), timeout_s=240)
+    with open(os.path.join(sandbox, "servestats.json")) as f:
+        url = f"http://127.0.0.1:{json.load(f)['http_port']}"
+    startup = json.loads(get(url + "/stats"))["startup"]
+    assert startup["programs"] == {"stored": 2, "compiled": 0}
+    for program in ("_prefill", "_decode"):
+        kinds = startup["warm"][program]
+        assert kinds["source"] == "stored" and kinds["load_s"] > 0
+        assert kinds["trace_s"] == kinds["lower_s"] == 0
+        assert kinds["compile_s"] == kinds["store_s"] == 0
+    # and serves what the worker that compiled them serves
+    said = []
+    for worker in (workers["url"], url):
+        request = urllib.request.Request(
+            worker + "/generate",
+            data=json.dumps(
+                {"tokens": [[5, 6, 7, 8]], "max_new_tokens": 6}
+            ).encode(),
+            headers={"Content-Type": "application/json"}, method="POST",
+        )
+        with urllib.request.urlopen(request, timeout=60) as response:
+            said.append(json.loads(response.read())["tokens"])
+    assert said[0] == said[1] and len(said[0][0]) == 6
+    after = json.loads(get(url + "/stats"))["startup"]
+    assert after["compiles_after_ready"] == 0
+    assert after["programs"] == {"stored": 2, "compiled": 0}
