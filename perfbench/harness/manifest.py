@@ -45,14 +45,24 @@
                           context_tokens)`` -> ``{"bytes", "flops",
                           ...}``, what the call needs of the chip
 
-``<bench>`` is the first of ``paths``.  A later PR adds a cell, a mix,
-a metric or a family by adding files and entries; nothing here names
-one.  The one key every configuration's file keeps, whatever its
-family, is ``vocab_size``: the rows of the vocabulary held here
-(traffic draws its ids from it, answers are held to it).  All else in
-the file is read by its family's four files alone.  They are loaded in
-the harness's own process too, which never touches JAX: import it inside
-the functions, as the harness's files do.
+``<bench>`` is the first of ``paths``.  A later PR adds a
+configuration, a cell, a traffic mix, a family or a per-layer metric as
+NEW files and as entries APPENDED to their lists in ``BENCHMARK.json``;
+nothing here names one.  It appends its new cell's name to the
+``workloads`` of every metric that the cell reports, and edits nothing
+else that is there.  An entry put into the middle of a list reads to
+the driver as an edit of the entry whose place it takes, and the PR is
+refused.  The tests hold the lists open at their ends
+(``tests/bench/toyroot.py appended``: every entry test runs again on a
+root with a fourth configuration, cell and metric appended), so find an
+entry by its name, never by its place or a list's length.
+
+The one key every configuration's file keeps, whatever its family, is
+``vocab_size``: the rows of the vocabulary held here (traffic draws its
+ids from it, answers are held to it).  All else in the file is read by
+its family's four files alone.  They are loaded in the harness's own
+process too, which never touches JAX: import it inside the functions,
+as the harness's files do.
 """
 
 from __future__ import annotations

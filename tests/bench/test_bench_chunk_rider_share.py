@@ -1,7 +1,7 @@
 """The reader of the loop's chunk-rider counter (``/stats`` ->
-``loop.prefill_rider_calls``, ISSUE 40), on hand-made runs.  The
-metric has no entry in ``BENCHMARK.json`` yet (``PERF.md`` 7 ac), so
-the reader is loaded by its file's name, as an entry's would be."""
+``loop.prefill_rider_calls``, ISSUE 40), on hand-made runs, and its
+entry in ``BENCHMARK.json`` (ISSUE 43): ``evabyte.docqa`` alone among
+the cells of today, since the mixtures' chunks carry no rider."""
 
 import os
 import sys
@@ -12,7 +12,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)
 )))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import toyroot  # noqa: E402
+from toyroot import bench_roots  # noqa: E402,F401
 from perfbench.harness.manifest import Manifest  # noqa: E402
 
 NAME = "engine_chunk_rider_share.chat"
@@ -87,3 +90,19 @@ def test_the_engines_own_counters_feed_the_reader(read):
         engine.stop()
     assert before["prefill_rider_calls"] == 0
     assert read(run_of([before, after])) == pytest.approx(3 / 4)
+
+
+@pytest.mark.parametrize("where", toyroot.ROOTS)
+def test_the_entry(bench_roots, where):
+    bench = Manifest(bench_roots[where])
+    metric = dict(toyroot.named(bench.data["per_layer"], NAME))
+    assert set(metric.pop("workloads")) & set(toyroot.CELLS) == {
+        "evabyte.docqa"}
+    assert metric == {
+        "name": NAME, "unit": "share", "better": "higher",
+        "source": "program_counter", "layer": "engine host loop",
+        "moves": "norm_lat_p50_s",
+    }
+    assert NAME in {
+        m["name"] for m in bench.metrics("per_layer", "evabyte.docqa")
+    }

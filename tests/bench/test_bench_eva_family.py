@@ -18,6 +18,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import toyroot  # noqa: E402
+from toyroot import bench_roots  # noqa: E402,F401
 from toyroot import family  # noqa: E402
 
 from perfbench.harness import check  # noqa: E402
@@ -174,7 +176,8 @@ def test_program_env_refuses_what_the_program_cannot_build(eva):
         eva.program_env(dict(TOY, window_size=24), CONFIG)
 
 
-def test_evabyte_keeps_its_published_widths(published):
+@pytest.mark.parametrize("where", toyroot.ROOTS)
+def test_evabyte_keeps_its_published_widths(published, bench_roots, where):
     """By its own names: every width, all heads, the whole vocabulary;
     depth alone is cut."""
     want = {
@@ -194,8 +197,8 @@ def test_evabyte_keeps_its_published_widths(published):
         published["assumed"]
     )
     assert "four pipeline stages of 8" in published["deployment"]
-    entry = next(c for c in Manifest(REPO).data["configs"]
-                 if c["name"] == "evabyte-6.5b")
+    entry = toyroot.named(
+        Manifest(bench_roots[where]).data["configs"], "evabyte-6.5b")
     assert entry["reduced"] == ["num_hidden_layers"]
     assert entry["source"] == (
         "https://huggingface.co/EvaByte/EvaByte/blob/main/config.json"
@@ -306,8 +309,9 @@ def test_the_two_new_readers_on_a_recorded_sample():
     assert share(_run([])) is None and rollovers(_run([])) is None
 
 
-def test_the_cell_and_its_entries():
-    bench = Manifest(REPO)
+@pytest.mark.parametrize("where", toyroot.ROOTS)
+def test_the_cell_and_its_entries(bench_roots, where):
+    bench = Manifest(bench_roots[where])
     cell = bench.cell("evabyte.docqa")
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "evabyte-6.5b", "docqa-steady", 1

@@ -18,6 +18,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 import toyroot  # noqa: E402
+from toyroot import bench_roots  # noqa: E402,F401
 from perfbench.harness import check  # noqa: E402
 from perfbench.harness.manifest import Manifest  # noqa: E402
 
@@ -183,7 +184,8 @@ def test_program_env_refuses_what_the_program_cannot_build(lfm2, toy):
         lfm2.program_env(dict(toy, conv_bias=True), CONFIG)
 
 
-def test_lfm2_keeps_its_published_widths(published):
+@pytest.mark.parametrize("where", toyroot.ROOTS)
+def test_lfm2_keeps_its_published_widths(published, bench_roots, where):
     """Every width under its published key; what was cut is depth."""
     assert published["hidden_size"] == 2048
     assert (published["num_attention_heads"],
@@ -206,8 +208,9 @@ def test_lfm2_keeps_its_published_widths(published):
     assert sorted(published["reduced"]) == [
         "layer_types", "num_dense_layers", "num_hidden_layers",
     ]
-    entry = Manifest(REPO).data["configs"][-1]
-    assert entry["name"] == "lfm2-24b-a2b"
+    entry = toyroot.named(
+        Manifest(bench_roots[where]).data["configs"], "lfm2-24b-a2b")
+    assert entry["file"] == os.path.relpath(CONFIG, REPO)
     assert sorted(entry["reduced"]) == sorted(published["reduced"])
     assert entry["source"] == published["source"]
 
@@ -413,13 +416,22 @@ def test_the_two_new_readers_on_a_recorded_sample(published):
     assert share(dict(run, final_stats={"model": outside})) is None
 
 
-def test_the_cell_and_its_entries():
-    bench = Manifest(REPO)
+OWN_METRICS = {
+    "moe_experts_touched_per_layer.chat", "moe_grouped_matmul_roofline.chat",
+}
+
+
+@pytest.mark.parametrize("where", toyroot.ROOTS)
+def test_the_cell_and_its_entries(bench_roots, where):
+    """Every entry is found by its NAME: a later PR appends a
+    configuration, a cell and metrics of its own (and its cell's name
+    to the ``workloads`` of the metrics it reports), and nothing here
+    may depend on what stands last or on how many there are."""
+    bench = Manifest(bench_roots[where])
     cell = bench.cell("lfm2-24b.chat")
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "lfm2-24b-a2b", "chat-steady", 1,
     )
-    assert bench.data["workloads"][-1] is cell
     params = bench.cell_params("lfm2-24b.chat")
     # ISSUE 33's fallback, 0.6 of the swept knee; the file says why
     assert params["rate_rps"] == pytest.approx(0.6 * params["knee_rps"])
@@ -429,27 +441,35 @@ def test_the_cell_and_its_entries():
         "steady_max_gap", "steady_mean_gap", "steady_mismatch_share",
         "steady_wide_gap_share",
     }
-    names = {m["name"] for m in bench.metrics("per_layer", "lfm2-24b.chat")}
-    mixtral = {
-        m["name"] for m in bench.metrics("per_layer", "mixtral8x7b.chat")
-    }
-    # all that Mixtral's cell reports but one (ISSUE 34 appended the
-    # cell to engine_decode_ahead_share.chat): decode_step_roofline.chat
-    # sets a gauge's rows beside the traced steps, and here the bytes
-    # of a step follow its rows (families/lfm2_moe/needs.py)
-    assert len(mixtral) == 19
+
+    def moving_latency(name):
+        return {
+            m["name"] for m in bench.metrics("per_layer", name)
+            if m["moves"] == "norm_lat_p50_s"
+        }
+
+    names, mixtral = (
+        moving_latency("lfm2-24b.chat"), moving_latency("mixtral8x7b.chat")
+    )
+    # of the metrics that move the latency, all that Mixtral's cell
+    # reports but one (ISSUE 34 appended the cell to
+    # engine_decode_ahead_share.chat): decode_step_roofline.chat sets a
+    # gauge's rows beside the traced steps, and here the bytes of a
+    # step follow its rows (families/lfm2_moe/needs.py); plus its own two
     assert mixtral - names == {"decode_step_roofline.chat"}
-    assert names - mixtral == {
-        "moe_experts_touched_per_layer.chat",
-        "moe_grouped_matmul_roofline.chat",
-    }
-    for metric in bench.data["per_layer"][-2:]:
-        assert metric["workloads"] == ["lfm2-24b.chat"]
+    assert names - mixtral == OWN_METRICS
+    # the two are this cell's alone among the cells of today: a later
+    # cell of the family may join the list, no accepted cell may
+    for name in OWN_METRICS:
+        metric = toyroot.named(bench.data["per_layer"], name)
+        assert set(metric["workloads"]) & set(toyroot.CELLS) == {
+            "lfm2-24b.chat"}
         assert metric["moves"] == "norm_lat_p50_s"
     assert [m["name"] for m in bench.metrics("end_to_end", "lfm2-24b.chat")] \
         == ["norm_lat_p50_s", "setup_s"]
-    # the accepted cells report what they did, and nothing of this one's
-    assert not {
-        "moe_experts_touched_per_layer.chat",
-        "moe_grouped_matmul_roofline.chat",
-    } & mixtral
+    # what moves the set-up is reported here as in Mixtral's cell
+    assert {
+        m["name"] for m in bench.metrics("per_layer", "lfm2-24b.chat")
+    } - names == {
+        m["name"] for m in bench.metrics("per_layer", "mixtral8x7b.chat")
+    } - mixtral

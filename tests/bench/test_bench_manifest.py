@@ -3,7 +3,9 @@ name in it against the file the harness finds by that name.  The entry
 tests hold for a configuration of ANY family: they run over the real
 file's entries and over those that ``toyroot.py`` appends, whose
 configuration is of a second family and shares no size's name with the
-first but ``vocab_size``.  What only one configuration promises (its
+first but ``vocab_size``, and a second time over the root in which a
+fourth configuration, cell and per-layer metric are appended
+(``toyroot.appended``), as a `model_config` PR appends them.  What only one configuration promises (its
 published widths) is a test keyed to that configuration's name; a later
 PR brings such a test in a file of its own."""
 
@@ -21,6 +23,7 @@ sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import toyroot  # noqa: E402
+from toyroot import bench_roots  # noqa: E402,F401
 from perfbench.harness.manifest import Manifest  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -35,11 +38,14 @@ def load():
 
 
 @pytest.fixture(scope="module")
-def roots(tmp_path_factory):
-    """Where each manifest's files lie: the repo, and the throw-away
-    root with the toy family, configuration, mix, cell and metrics."""
-    return {"repo": REPO,
-            "toy": toyroot.build(str(tmp_path_factory.mktemp("entries")))}
+def roots(tmp_path_factory, bench_roots):
+    """Where each manifest's files lie: the repo, the throw-away root
+    with the toy family, configuration, mix, cell and metrics, and the
+    one with a fourth of each kind appended."""
+    return dict(
+        bench_roots,
+        toy=toyroot.build(str(tmp_path_factory.mktemp("entries"))),
+    )
 
 
 def manifest_of(root):
@@ -63,13 +69,18 @@ def test_top_level_keys_and_sizes():
 
 
 def entries(kind):
-    """(which root, entry): every entry of the real file, and every
-    entry that the toy root adds to it."""
+    """(which root, entry): every entry of the real file, every entry
+    that the toy root adds to it, and every entry of the root with a
+    fourth of each kind appended (the real ones again, as they stand
+    there, and the three copies)."""
     real = load()[kind]
     names = {e["name"] for e in real}
     added = [e for e in toyroot.manifest()[kind] if e["name"] not in names]
     return [pytest.param("repo", e, id=e["name"]) for e in real] + [
         pytest.param("toy", e, id="toyroot-" + e["name"]) for e in added
+    ] + [
+        pytest.param("appended", e, id="appended-" + e["name"])
+        for e in toyroot.appended()[kind]
     ]
 
 

@@ -1,13 +1,14 @@
 """The eight readers of the worker's own start-up account (``/stats``
 -> ``startup``, ISSUE 41), on a recorded ``final_stats``, and one
 rehearsal run of the harness on the CPU in which the real scheduler,
-agent and worker produce what they read.  The eight have no entry in
-``BENCHMARK.json`` yet (``PERF.md`` 7 ah: an entry at the end of
-``per_layer`` fails ``test_bench_lfm2_family.py``, one in the middle
-reads as a change to what was there), so the readers are loaded by
-their files' names, as an entry's would be, and the entries a
-``benchmark`` PR is to append are held here, ``ENTRIES``, and entered
-in the throw-away root of the rehearsal."""
+agent and worker produce what they read, a first start (which compiles)
+and a second on the same compile cache (which loads the pool's two
+stored programs).  The eight are entered in ``BENCHMARK.json`` for
+every cell (ISSUE 43; ``ENTRIES`` is what was appended), so the toy
+root's cell reports them as every cell does.  The rehearsal keeps a
+compile cache of its own under the test's temporary directory: what
+the checkout's ``.jax_cache/programs/`` holds from an earlier run must
+not decide whether the first start compiles."""
 
 import json
 import os
@@ -23,6 +24,7 @@ sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import toyroot  # noqa: E402
+from toyroot import bench_roots  # noqa: E402,F401
 
 from perfbench.harness.manifest import Manifest  # noqa: E402
 
@@ -133,68 +135,73 @@ def entry(name, cells):
     }
 
 
-# what a `benchmark` PR is to append to `per_layer`, `evabyte.docqa`
-# first (the cell ISSUE 41 names)
-ENTRIES = [entry(name, ["evabyte.docqa"]) for name in METRICS]
+# what ISSUE 43 appended to `per_layer`, for every cell of its day
+ENTRIES = [entry(name, toyroot.CELLS) for name in METRICS]
 
 
 @pytest.mark.parametrize("metric", ENTRIES, ids=list(METRICS))
-def test_the_entry_to_append_fits_the_manifest(bench, metric):
-    """As `test_bench_manifest.py` holds an entry that is there: a
-    layer the manifest names, an end-to-end metric that the entry's
-    cells report, a reader's file under the entry's name."""
-    data = bench.data
-    assert metric["layer"] in {m["layer"] for m in data["per_layer"]}
-    moved = {m["name"]: m for m in data["end_to_end"]}[metric["moves"]]
-    cells = [w["name"] for w in data["workloads"]]
-    assert set(metric["workloads"]) <= set(moved.get("workloads", cells))
+def test_the_entry_is_the_one_that_was_to_be_appended(bench, metric):
+    """Found by its name; a later cell may have appended itself to its
+    ``workloads``.  A reader's file stands under the entry's name."""
+    entered = dict(toyroot.named(bench.data["per_layer"], metric["name"]))
+    assert set(entered.pop("workloads")) >= set(metric["workloads"])
+    assert entered == {k: v for k, v in metric.items() if k != "workloads"}
     assert os.path.isfile(os.path.join(
         REPO, "perfbench", "layer_metrics", metric["name"] + ".py"
     ))
 
 
-def test_none_of_the_eight_is_entered_yet(bench):
-    """The `benchmark` PR that appends ``ENTRIES`` turns this one and
-    the next around."""
-    entered = {m["name"] for m in bench.data["per_layer"]}
-    assert not set(METRICS) & entered
+@pytest.mark.parametrize("where", toyroot.ROOTS)
+def test_all_eight_are_entered(bench_roots, where):
+    entered = {m["name"] for m in Manifest(bench_roots[where]).data["per_layer"]}
+    assert set(METRICS) <= entered
 
 
-@pytest.mark.parametrize(
-    "cell", ["evabyte.docqa", "mixtral8x7b.chat", "lfm2-24b.chat"])
-def test_no_cell_reports_them_yet(bench, cell):
+@pytest.mark.parametrize("cell", toyroot.CELLS)
+def test_every_cell_reports_them(bench, cell):
     names = {m["name"] for m in bench.metrics("per_layer", cell)}
-    assert not set(METRICS) & names
+    assert set(METRICS) <= names
 
 
 # -- through the harness, on the CPU -----------------------------------
 
 
-@pytest.fixture(scope="module")
-def rehearsal(tmp_path_factory):
-    """One traced rehearsal of the toy cell with the eight entered for
-    it in the throw-away root's manifest, its record kept."""
-    root = toyroot.build(str(tmp_path_factory.mktemp("startup_bench")))
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    manifest["per_layer"] += [entry(name, ["toy.open"]) for name in METRICS]
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(manifest, f)
-    keep = str(tmp_path_factory.mktemp("startup_kept"))
-    env = dict(os.environ, BENCH_RUN="3")
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+def start_toy_cell(root, cache, keep, seed):
+    """One traced rehearsal of the toy cell on the compile cache
+    ``cache``: its result line and the record it kept."""
+    env = dict(os.environ, BENCH_RUN="3", JAX_COMPILATION_CACHE_DIR=cache)
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "perfbench", "run.py"),
-         "--workload", "toy.open", "--seed", str(2**31 + 41), "--seconds",
+         "--workload", "toy.open", "--seed", str(seed), "--seconds",
          "3", "--trace", "1", "--root", root, "--rehearse-cpu",
          "--keep", keep],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
         timeout=600,
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
-    with open(os.path.join(keep, f"toy.open.{2**31 + 41}.run.json")) as f:
+    with open(os.path.join(keep, f"toy.open.{seed}.run.json")) as f:
         kept = json.load(f)
     return json.loads(proc.stdout.splitlines()[-1]), kept
+
+
+@pytest.fixture(scope="module")
+def starts(tmp_path_factory):
+    """Two starts of the toy cell, whose entry in the throw-away root
+    lists the eight as every cell's does, on ONE compile cache that was
+    empty before the first: (result line, kept record) of each."""
+    root = toyroot.build(str(tmp_path_factory.mktemp("startup_bench")))
+    cache = str(tmp_path_factory.mktemp("startup_cache"))
+    keep = str(tmp_path_factory.mktemp("startup_kept"))
+    return [
+        start_toy_cell(root, cache, keep, seed)
+        for seed in (2**31 + 41, 2**31 + 42)
+    ]
+
+
+@pytest.fixture(scope="module")
+def rehearsal(starts):
+    """The first start: nothing stored, both programs compile."""
+    return starts[0]
 
 
 def test_a_traced_run_prints_all_eight(rehearsal):
@@ -228,3 +235,25 @@ def test_the_account_a_run_keeps_is_whole_and_close_to_the_outside_one(
     # the warm-up's two parts against the worker's own `warm_s`
     parts = value["worker_warm_trace_lower_s"] + value["worker_warm_compile_s"]
     assert 0 < parts <= value["worker_warm_s"] + 0.01
+
+
+def test_a_second_start_on_the_same_cache_loads_both_programs(starts):
+    """Since PR 42 a warm start loads the pool's two programs from
+    ``<compile cache>/programs/``: the two metrics of the host's
+    tracing, lowering and compiling read 0 and the warm-up is what is
+    left (the loads and the programs' first runs)."""
+    (_first, first_kept), (result, kept) = starts
+    assert {
+        program["source"]
+        for name, program in first_kept["final_stats"]["startup"]["warm"].items()
+        if name != "other"
+    } == {"compiled"}
+    warm = kept["final_stats"]["startup"]["warm"]
+    assert {warm[name]["source"] for name in ("_prefill", "_decode")} == {
+        "stored"}
+    assert kept["final_stats"]["startup"]["programs"] == {
+        "stored": 2, "compiled": 0}
+    value = {k: v["value"] for k, v in result["metrics"].items()}
+    assert value["worker_warm_trace_lower_s"] == 0
+    assert 0 < value["worker_warm_s"]
+    assert result["correct"] is True

@@ -4,12 +4,22 @@ files), a toy configuration of that family, a toy mix, a cell of the
 two, two more per-layer metrics with readers of their own and one
 (``decode_tick_ms.toy``) that an existing reader serves under a new
 suffix, all ADDED as new files and new entries.  No file that is there
-is edited, which is what a later PR is held to."""
+is edited, which is what a later PR is held to.
+
+``build_appended`` makes the plainer root that guards the lists
+themselves: the real manifest with ONE more configuration, cell and
+per-layer metric at the end of each list (``appended``), copies of the
+last of each under new names.  The entry tests of every file here run
+against it too (``ROOTS``), so a test that finds an entry by its place,
+counts a list or holds a ``workloads`` list to today's cells fails
+here before it stops a later PR."""
 
 import json
 import os
 import shutil
 import sys
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)
@@ -117,7 +127,7 @@ TOY_OPEN = {
                       "min": 4, "max": 72},
     "output_tokens": {"kind": "lognormal", "median": 8, "sigma": 0.5,
                       "min": 2, "max": 16},
-    "pairing_seed": 7, "ramp_s": 1, "tail_s": 5, "drain_limit_s": 60,
+    "pairing_seed": 7, "order_seed": 11, "ramp_s": 1, "tail_s": 5, "drain_limit_s": 60,
     "request_timeout_s": 120, "clients": 16, "trace_after_s": 0.5,
     "trace_s": 1, "check_draw": 2, "check_tokens": 4,
     "sizing_env": TOY_SIZING,
@@ -144,13 +154,18 @@ def read(run):
 '''
 
 
-def build(root: str) -> str:
-    """Make the throw-away root under ``root``; returns it."""
+def copy_bench(root: str) -> str:
+    """The real files under ``perfbench/``, copied into ``root``."""
     shutil.copytree(
         os.path.join(REPO, "perfbench"), os.path.join(root, "perfbench"),
         ignore=shutil.ignore_patterns("__pycache__"),
     )
-    bench = os.path.join(root, "perfbench")
+    return os.path.join(root, "perfbench")
+
+
+def build(root: str) -> str:
+    """Make the throw-away root under ``root``; returns it."""
+    bench = copy_bench(root)
 
     def put(relative, payload):
         path = os.path.join(bench, relative)
@@ -203,3 +218,89 @@ def manifest() -> dict:
          "moves": "setup_s", "workloads": ["toy.open"]},
     ]
     return manifest
+
+
+# which root an entry test reads: the repo's own manifest, and the one
+# with a fourth of each kind appended
+ROOTS = ["repo", "appended"]
+AGAIN = "-again"
+# the cells of today (PR 43), for a test of which of THEM an entry lists:
+# a later cell may append itself to any ``workloads``
+CELLS = ["mixtral8x7b.chat", "evabyte.docqa", "lfm2-24b.chat"]
+
+
+def named(entries: list, name: str) -> dict:
+    """The entry of a manifest's list by its name, never by its place."""
+    return next(e for e in entries if e["name"] == name)
+
+
+def again(name: str) -> str:
+    """``lfm2-24b.chat`` -> ``lfm2-24b-again.chat``: the suffix (what a
+    quantity is split by, the mix's half of a cell's name) stays."""
+    stem, dot, suffix = name.partition(".")
+    return stem + AGAIN + dot + suffix
+
+
+def appended(manifest: dict = None) -> dict:
+    """``manifest`` (the real ``BENCHMARK.json``) as a `model_config`
+    PR leaves it: copies of the last configuration, the last cell and
+    the last per-layer metric under new names at the END of their
+    lists, and the new cell's name appended to every ``workloads`` list
+    that holds the cell it copies.  Nothing that was there moves."""
+    if manifest is None:
+        with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+            manifest = json.load(f)
+    config, cell, metric = (
+        dict(manifest[kind][-1])
+        for kind in ("configs", "workloads", "per_layer")
+    )
+    copied = cell["name"]
+    config["name"] = again(config["name"])
+    config["file"] = "{}{}.json".format(config["file"][:-len(".json")], AGAIN)
+    cell["name"], cell["config"] = again(copied), config["name"]
+    # the copy of the last metric lists what the last lists, once that
+    # has the new cell
+    for listed in manifest["end_to_end"] + manifest["per_layer"]:
+        if copied in listed.get("workloads", []):
+            listed["workloads"].append(cell["name"])
+    metric["name"] = again(metric["name"])
+    metric["workloads"] = list(manifest["per_layer"][-1]["workloads"])
+    manifest["configs"].append(config)
+    manifest["workloads"].append(cell)
+    manifest["per_layer"].append(metric)
+    return manifest
+
+
+def build_appended(root: str) -> str:
+    """The throw-away root of ``appended()``: the real files, and the
+    three copies' files beside them."""
+    copy_bench(root)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        real = json.load(f)
+    manifest = appended()
+    for old, new in (
+        (real["configs"][-1]["file"], manifest["configs"][-1]["file"]),
+        ("perfbench/cells/" + real["workloads"][-1]["name"] + ".json",
+         "perfbench/cells/" + manifest["workloads"][-1]["name"] + ".json"),
+        ("perfbench/layer_metrics/" + real["per_layer"][-1]["name"] + ".py",
+         "perfbench/layer_metrics/" + manifest["per_layer"][-1]["name"]
+         + ".py"),
+    ):
+        assert not os.path.exists(os.path.join(root, new)), new
+        shutil.copy(os.path.join(root, old), os.path.join(root, new))
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(manifest, f)
+    return root
+
+
+@pytest.fixture(scope="module")
+def bench_roots(tmp_path_factory):
+    """Where an entry test reads ``BENCHMARK.json``, by the names of
+    ``ROOTS``: the repo, and the throw-away root in which a fourth
+    configuration, cell and per-layer metric are appended.  A test file
+    imports it by name (a ``conftest.py`` here would hide ``tests/``'s
+    own from the tests that import that one as a module)."""
+    return {
+        "repo": REPO,
+        "appended": build_appended(str(tmp_path_factory.mktemp("appended"))),
+    }
