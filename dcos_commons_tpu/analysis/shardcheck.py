@@ -439,6 +439,9 @@ def _serve_leaves(env, mesh_total_tp: int) -> Tuple[Any, List[AbstractLeaf]]:
         paged.page_tokens, env.get("KV_DTYPE", "native"), slots,
         # as the chip holds it: entries in whole 128-lane rows
         whole_lanes(config.head_dim),
+        # the window layers' arena, every slot's ring and a trash page
+        # (0 where the pattern has no such layer)
+        paged.window_arena_pages,
     ))
     # cache dims (layers, pages, tokens, kv_heads, head_dim): heads
     # ride tp like the attention weights when divisible (the gang
@@ -450,8 +453,10 @@ def _serve_leaves(env, mesh_total_tp: int) -> Tuple[Any, List[AbstractLeaf]]:
     )
     kv_spec = {
         # what a row keeps outside its pages (layers, slots, taps - 1,
-        # d_model) is one chip's: the gang refuses such a pattern
+        # d_model) is one chip's, as the window layers' rings are: the
+        # gang refuses such a pattern
         name: ((), (), (), ()) if name == "conv_state"
+        else ((), (), (), (), ()) if name.endswith("_window")
         else ((), (), (), ("tp",) if kv_sharded else (), ())
         for name in cache_shapes
     }

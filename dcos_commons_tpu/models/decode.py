@@ -22,6 +22,7 @@ drop-free server by construction.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -31,6 +32,7 @@ from jax import lax
 from dcos_commons_tpu.models.quantize import dequantize_weight as dq
 from dcos_commons_tpu.models.transformer import (
     TransformerConfig,
+    _mlp,
     _mlp_block,
     _norm,
     _rope,
@@ -88,6 +90,7 @@ def arena_lanes(config: TransformerConfig) -> int:
 def init_paged_kv_cache(
     config: TransformerConfig, n_pages: int, page_tokens: int,
     kv_dtype: str = "native", slots: int = 0, lanes: int = 0,
+    window_pages: int = 0,
 ) -> Dict[str, jax.Array]:
     """The paged arena: K/V stored as fixed-size pages instead of
     per-request rows.  Shape [n_attention_layers, n_pages, page_tokens,
@@ -115,11 +118,26 @@ def init_paged_kv_cache(
     (layers, pages, page_tokens, kv_heads, ``lanes``) — kv heads stay
     dim 3, exactly where the gang lays the tp axis; ``lanes`` is
     ``head_dim`` unless the caller says more (``arena_lanes``: the
-    pool does on a TPU)."""
+    pool does on a TPU).
+
+    Where the pattern has WINDOW layers ("sliding") they own an arena
+    of their own, ``k_window`` / ``v_window [n_sliding_layers,
+    window_pages, ...]``: every slot's ring and a trash page
+    (serve/paging.py RowLayout, PagedServeConfig.window_arena_pages),
+    the first entries of a row's table; ``k`` / ``v`` are then the
+    FULL layers' alone, ``n_pages`` of history each."""
     shape = (
         config.n_layers_of("attention"), n_pages, page_tokens,
         config.n_kv_heads, lanes or config.head_dim,
     )
+    n_sliding = config.n_layers_of("sliding")
+    if n_sliding and (kv_dtype == "int8" or window_pages < 2):
+        raise ValueError(
+            "window attention layers keep a ring a slot in an arena of "
+            f"their own, in the serving dtype: got KV_DTYPE {kv_dtype!r}, "
+            f"{window_pages} window pages (no int8 ring is built: a "
+            "ring's scales would need the same second arena)"
+        )
     if kv_dtype == "int8":
         scale_shape = shape[:-1] + (1,)
         cache = {
@@ -133,6 +151,10 @@ def init_paged_kv_cache(
             "k": jnp.zeros(shape, config.dtype),
             "v": jnp.zeros(shape, config.dtype),
         }
+    if n_sliding:
+        ring = (n_sliding, window_pages) + shape[2:]
+        cache["k_window"] = jnp.zeros(ring, config.dtype)
+        cache["v_window"] = jnp.zeros(ring, config.dtype)
     if config.n_layers_of("conv"):
         if slots < 1:
             raise ValueError(
@@ -180,8 +202,10 @@ def _quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return q, scale
 
 
-def _project_kv(config, layer, normed, positions):
-    """normed [b, s, d] -> roped q, k, v in [b, s, heads, hd].
+def _project_kv(config, layer, normed, positions, rope=True):
+    """normed [b, s, d] -> roped q, k, v in [b, s, heads, hd]
+    (``rope`` False: a layer with no position encoding rotates
+    nothing).
 
     Weights may be weight-only int8 (models/quantize.py); the dequant
     fuses into each projection matmul."""
@@ -195,9 +219,36 @@ def _project_kv(config, layer, normed, positions):
         # cache holds the normed, rotated keys
         q = rms_norm(q, layer["q_norm"], eps=config.rms_norm_eps)
         k = rms_norm(k, layer["k_norm"], eps=config.rms_norm_eps)
-    q = _rope(q, positions, config.rope_theta)
-    k = _rope(k, positions, config.rope_theta)
+    if rope:
+        q = _rope(q, positions, config.rope_theta)
+        k = _rope(k, positions, config.rope_theta)
     return q, k, v
+
+
+def _embed(config: TransformerConfig, params: Params, tokens: jax.Array):
+    """The residual stream's start: the tokens' rows of the embedding,
+    times sqrt(d_model) where the configuration scales them."""
+    x = params["embed"][tokens].astype(config.dtype)
+    if config.embed_scale:
+        x = x * jnp.asarray(config.d_model ** 0.5, config.dtype)
+    return x
+
+
+def _attention_residual(config: TransformerConfig, layer, x, normed, attn):
+    """``x`` plus the attention block's output: ``attn [.., h * hd]``
+    (gated by ``sigmoid(normed Wg)`` where the configuration has an
+    output gate) through ``wo`` (and the block's second norm, where
+    norms stand on both sides)."""
+    if config.attention_gate:
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(
+                (normed @ dq(layer["wg"], x.dtype)).astype(jnp.float32)
+            )
+            attn = (attn.astype(jnp.float32) * gate).astype(x.dtype)
+    out = attn @ dq(layer["wo"], x.dtype)
+    if config.sandwich_norm:
+        out = _norm(config, out, layer["attn_post_norm"])
+    return x + out
 
 
 def prefill(
@@ -440,6 +491,10 @@ def _serve_ffn(config: TransformerConfig, layer, x: jax.Array, live=None,
     leaves are a stack of one."""
     if "router" not in layer:
         with jax.named_scope("mlp"):
+            if config.sandwich_norm:
+                return x + _norm(
+                    config, _mlp(config, layer, x), layer["mlp_post_norm"]
+                ), None
             return _mlp_block(config, layer, x), None
     from dcos_commons_tpu.models.moe import moe_serve_ffn
 
@@ -454,7 +509,10 @@ def _serve_ffn(config: TransformerConfig, layer, x: jax.Array, live=None,
         moe_config_of(config), layer, experts, index,
         normed.reshape(b * s, d), live,
     )
-    return x + y.reshape(b, s, d), counts
+    y = y.reshape(b, s, d)
+    if config.sandwich_norm:
+        y = _norm(config, y, layer["mlp_post_norm"])
+    return x + y, counts
 
 
 def _scan_layers_over_arena(layer_fn, x, layers, cache):
@@ -510,11 +568,19 @@ def layer_plan(kinds) -> Tuple[int, int, int, int]:
     return best[1]
 
 
-def _walk_pattern(config, params, cache, x, attend, convolve, live):
+# the window layers' arena, beside the full layers' ``k`` / ``v``
+_WINDOW_ARENA = ("k_window", "v_window")
+
+
+def _walk_pattern(config, params, cache, x, attend, convolve, live,
+                  attend_window=None):
     """Run ``x`` through a MIXED layer pattern (``layer_plan``) over
     the cache: ``attend(x, arena, layer, base) -> (x, arena)`` is the
     attention operator over the merged arena (``base = a * n_pages``
     for the ``a``-th ATTENTION layer: only those own pages),
+    ``attend_window`` the same of a "sliding" layer over the window
+    layers' merged arena (under the names ``k`` / ``v``; the ``a``-th
+    such layer's first page its ``base``),
     ``convolve(x, conv_state, layer, c) -> (x, conv_state)`` the conv
     operator of the ``c``-th conv layer.  Arena and conv state are the
     scan's carry, never scanned arrays (``_scan_layers_over_arena``
@@ -524,6 +590,9 @@ def _walk_pattern(config, params, cache, x, attend, convolve, live):
     kinds = config.layer_kinds
     lead, period, reps, _tail = layer_plan(kinds)
     n_pages = cache["k"].shape[1]
+    n_window_pages = (
+        cache["k_window"].shape[1] if "k_window" in cache else 0
+    )
     state = {
         name: arr if name == "conv_state"
         else arr.reshape((-1,) + arr.shape[2:])
@@ -557,9 +626,18 @@ def _walk_pattern(config, params, cache, x, attend, convolve, live):
         op_i = index[l][0] + trip * stride[op]
         ffn_i = index[l][1] + trip * stride[ffn]
         if op == "attention":
-            arena = {k: v for k, v in state.items() if k != "conv_state"}
+            arena = {
+                k: v for k, v in state.items()
+                if k != "conv_state" and k not in _WINDOW_ARENA
+            }
             x, arena = attend(x, arena, at(stacks[op], op_i), op_i * n_pages)
             state = dict(state, **arena)
+        elif op == "sliding":
+            arena = {"k": state["k_window"], "v": state["v_window"]}
+            x, arena = attend_window(
+                x, arena, at(stacks[op], op_i), op_i * n_window_pages
+            )
+            state = dict(state, k_window=arena["k"], v_window=arena["v"])
         else:
             x, conv = convolve(
                 x, state["conv_state"], at(stacks[op], op_i), op_i
@@ -679,6 +757,82 @@ def _kv_entries(
     return {"k": kq, "v": vq, "k_scale": ks_new, "v_scale": vs_new}
 
 
+# the most positions of history a prefill chunk scores at once; a
+# longer history is attended in blocks of this many (tests shrink it)
+CHUNK_ATTENTION_BLOCK = 2048
+
+
+def _ring_pages(config: TransformerConfig, ring_pages: int) -> int:
+    """``ring_pages`` as a serving program was handed it, held to the
+    pattern: window layers need their ring, no other model has one."""
+    if bool(ring_pages) != bool(config.n_layers_of("sliding")):
+        raise ValueError(
+            f"{config.n_layers_of('sliding')} window attention layers and "
+            f"a ring of {ring_pages} pages a row: a table's first entries "
+            "are the window layers' ring where the pattern has such "
+            "layers, and only there (serve/paging.py RowLayout)"
+        )
+    return int(ring_pages)
+
+
+def _ring_positions(index, last_page, ring: int, page_tokens: int):
+    """The position each ring entry ``index`` (its place among the
+    ring's ``ring * page_tokens`` entries) holds once the row has
+    written as far as virtual page ``last_page``: a ring page holds the
+    newest virtual page that maps onto it (before 0: nothing yet).
+    Entries of ``last_page`` past the row's end still hold the page a
+    whole ring before: the caller masks by the row's length."""
+    page = last_page - (last_page - index // page_tokens) % ring
+    return page * page_tokens + index % page_tokens
+
+
+def _attention_block_pages(pages: int, page_tokens: int,
+                           most: int = 0) -> int:
+    """Pages a block of a region of ``pages`` pages: the most whole
+    pages within ``most`` positions (``CHUNK_ATTENTION_BLOCK`` where 0)
+    that divide the region, so every block is whole."""
+    block = max(1, min(pages, (most or CHUNK_ATTENTION_BLOCK) // page_tokens))
+    while pages % block:
+        block -= 1
+    return block
+
+
+def _attend_blocks(q, arena, pages, block, n_blocks, key_pos, q_pos, end,
+                   window, hd):
+    """A chunk's queries ``q [1, c, h, hd]`` (in the serving dtype,
+    unscaled) against the entries of ``pages [n]`` (arena rows), read
+    ``block`` pages at a time under ONE online softmax
+    (``_softmax_block``); only the first ``n_blocks`` blocks (traced)
+    are gathered at all.  ``key_pos(index [L]) -> [L]`` says which
+    position each entry of the region holds; the query at ``q_pos[i]``
+    sees the keys at positions ``j`` with ``q_pos[i] - window < j <=
+    q_pos[i]`` and ``0 <= j < end``.  Returns ``[1, c, kv, reps, hd]``
+    float32."""
+    _b, c, h, _hd = q.shape
+    p_tok, kv = arena["k"].shape[1:3]
+    reps = h // kv
+    qg = q.reshape(1, c, kv, reps, hd)
+    n = block * p_tok
+
+    def one(i, state):
+        with jax.named_scope("paged_gather"):
+            ids = lax.dynamic_slice_in_dim(pages, i * block, block)
+            keys = arena["k"][ids].reshape(1, n, kv, -1)[..., :hd]
+            values = arena["v"][ids].reshape(1, n, kv, -1)[..., :hd]
+        held = key_pos(i * n + jnp.arange(n, dtype=jnp.int32))
+        mask = (
+            (held[None, :] <= q_pos[:, None])
+            & (held[None, :] > q_pos[:, None] - window)
+            & (held >= 0)[None, :] & (held < end)[None, :]
+        )[None]
+        return _softmax_block(state, qg, keys, values, mask, hd ** -0.5)
+
+    _m, norm, acc = lax.fori_loop(
+        0, n_blocks, one, _softmax_start(1, c, kv, reps, hd)
+    )
+    return acc / norm[..., None]
+
+
 def paged_prefill_chunk(
     config: TransformerConfig,
     params: Params,
@@ -689,6 +843,7 @@ def paged_prefill_chunk(
     true_len: jax.Array,
     slot: jax.Array = 0,
     riders: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
+    ring_pages: int = 0,
 ) -> Tuple[jax.Array, Dict[str, jax.Array], Optional[jax.Array]]:
     """One CHUNK of a prompt through the trunk into a paged arena.
 
@@ -725,6 +880,19 @@ def paged_prefill_chunk(
     the chunk's).  The result is then a 4-tuple, the riders' logits
     ``[S, vocab]`` f32 last: what the chunk alone followed by
     ``paged_decode_step`` gives.
+
+    ``ring_pages`` (static; serve/paging.py RowLayout): where the
+    pattern has window layers, the first ``ring_pages`` entries of
+    ``table`` are the row's ring in the window layers' arena and the
+    rest the full layers' history.  Each kind writes the chunk's K/V
+    into its own cache and attends through it: a window layer over its
+    ring alone (keys are stored rotated, so the ring's entries need no
+    order: what an entry holds follows from its place and the row's
+    length), a full layer over the history.  Where the history is
+    longer than ``CHUNK_ATTENTION_BLOCK`` positions it is attended a
+    block of pages at a time under one running softmax, as far as the
+    row reaches and no further: a score tensor of ``[chunk, MAX_LEN]``
+    is never built.
     """
     b, c = tokens.shape
     if b != 1:
@@ -741,7 +909,10 @@ def paged_prefill_chunk(
         return out[:2] + (None,) + out[2:]
     h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     p_tok, lanes = cache["k"].shape[2], cache["k"].shape[-1]
-    m = table.shape[0]
+    ring = _ring_pages(config, ring_pages)
+    # the full layers' entries
+    history = table[ring:] if ring else table
+    m = history.shape[0]
     length = m * p_tok
     quantized = "k_scale" in cache
     reps = h // kv
@@ -754,34 +925,54 @@ def paged_prefill_chunk(
     live = offs < true_len
     # pad positions (>= true_len) scatter into the trash page: their
     # K/V must never land in a real page a later chunk would attend to
-    phys = jnp.where(live, table[vpage], 0)
+    phys = jnp.where(live, history[vpage], 0)
     slot_off = abs_pos % p_tok
-    # causal across the whole virtual sequence: key position <= query
-    # position — covers prior chunks, cached prefix pages, and the
-    # in-chunk prefix in one mask; unallocated pages sit past every
-    # valid query and mask out
-    valid = (
-        lax.broadcasted_iota(jnp.int32, (c, length), 1)
-        <= abs_pos[:, None]
-    )                                            # [c, L]
-    x = params["embed"][tokens].astype(config.dtype)
+    # a long history goes a block at a time (an int8 arena's scales
+    # have no blocked form: it keeps the one softmax)
+    block = _attention_block_pages(m, p_tok)
+    blocked = block < m and not quantized
+    if not blocked:
+        # causal across the whole virtual sequence: key position <=
+        # query position — covers prior chunks, cached prefix pages, and
+        # the in-chunk prefix in one mask; unallocated pages sit past
+        # every valid query and mask out
+        valid = (
+            lax.broadcasted_iota(jnp.int32, (c, length), 1)
+            <= abs_pos[:, None]
+        )                                        # [c, L]
+    x = _embed(config, params, tokens)
+    end = start + true_len                       # positions written so far
 
     def attend(x, arena, layer, base):
         """The attention operator; ``base``: the layer's first page."""
         with jax.named_scope("attention"):
             normed = _norm(config, x, layer["attn_norm"])
-            q, k_new, v_new = _project_kv(config, layer, normed, positions)
+            q, k_new, v_new = _project_kv(
+                config, layer, normed, positions,
+                rope=not config.nope_full_attention,
+            )
         with jax.named_scope("kv_write"):
             new = _kv_entries(k_new[0], v_new[0], quantized, lanes)
             arena = {
                 name: arr.at[base + phys, slot_off].set(new[name])
                 for name, arr in arena.items()
             }
+        if blocked:
+            with jax.named_scope("attention_full"):
+                attn = _attend_blocks(
+                    q, arena, base + history, block,
+                    -(-end // (block * p_tok)),
+                    lambda index: index, abs_pos, end, length, hd,
+                ).astype(config.dtype)
+                x = _attention_residual(
+                    config, layer, x, normed, attn.reshape(1, c, h * hd)
+                )
+            return x, arena
         # gather the request's whole virtual sequence through the
         # table (scatter-then-gather: in-chunk keys ride the same
         # path as prior pages — one attention covers both)
         with jax.named_scope("paged_gather"):
-            pages = base + table
+            pages = base + history
             k_all = arena["k"][pages].reshape(1, length, kv, lanes)[..., :hd]
             v_all = arena["v"][pages].reshape(1, length, kv, lanes)[..., :hd]
             if quantized:
@@ -809,8 +1000,50 @@ def paged_prefill_chunk(
             attn = jnp.einsum(
                 "bqkrl,blkd->bqkrd", probs, v_all.astype(jnp.float32)
             ).astype(config.dtype)
-            x = x + attn.reshape(1, c, h * hd) @ dq(layer["wo"], x.dtype)
+            x = _attention_residual(
+                config, layer, x, normed, attn.reshape(1, c, h * hd)
+            )
         return x, arena
+
+    attend_window = None
+    if ring:
+        window = config.sliding_window
+        ring_table = table[:ring]
+        ring_phys = jnp.where(live, ring_table[(abs_pos // p_tok) % ring], 0)
+        ring_block = _attention_block_pages(ring, p_tok, c)
+        # what the ring holds once this chunk's true positions are written
+        ring_pos = functools.partial(
+            _ring_positions, last_page=(end - 1) // p_tok, ring=ring,
+            page_tokens=p_tok,
+        )
+
+        def attend_window(x, arena, layer, base):
+            """A window layer: RoPE, the ring, the last ``window``
+            positions; ``base``: the layer's first ring page."""
+            with jax.named_scope("attention_window"):
+                normed = _norm(config, x, layer["attn_norm"])
+                q, k_new, v_new = _project_kv(
+                    config, layer, normed, positions
+                )
+            with jax.named_scope("kv_write"):
+                new = _kv_entries(k_new[0], v_new[0], False, lanes)
+                arena = {
+                    name: arr.at[base + ring_phys, slot_off].set(new[name])
+                    for name, arr in arena.items()
+                }
+            with jax.named_scope("attention_window"):
+                # a ring that has not wrapped holds its first entries
+                attn = _attend_blocks(
+                    q, arena, base + ring_table, ring_block,
+                    jnp.minimum(
+                        -(-end // (ring_block * p_tok)), ring // ring_block
+                    ),
+                    ring_pos, abs_pos, end, window, hd,
+                ).astype(config.dtype)
+                x = _attention_residual(
+                    config, layer, x, normed, attn.reshape(1, c, h * hd)
+                )
+            return x, arena
 
     if config.one_kind:
         n_pages = cache["k"].shape[1]
@@ -832,7 +1065,7 @@ def paged_prefill_chunk(
         x, new_cache, counts = _walk_pattern(
             config, params, cache, x, attend, _conv_chunk_operator(
                 config, start, true_len, jnp.asarray(slot, jnp.int32)
-            ), live,
+            ), live, attend_window,
         )
     with jax.named_scope("logits"):
         x = _norm(config, x, params["final_norm"])
@@ -850,6 +1083,7 @@ def paged_decode_step(
     token: jax.Array,
     pos: jax.Array,
     tables: jax.Array,
+    ring_pages: int = 0,
 ) -> Tuple[jax.Array, Dict[str, jax.Array], Optional[jax.Array]]:
     """One autoregressive step over the whole pool, KV indirected
     through per-row page tables: ``token [S]`` at per-row positions
@@ -868,24 +1102,37 @@ def paged_decode_step(
     ``counts`` is int32 ``[2]``: the live (token, expert) assignments
     the step's mixtures routed and the expert groups that held at
     least one, summed over the expert layers; None where the model
-    routes nothing."""
+    routes nothing.
+
+    ``ring_pages`` as ``paged_prefill_chunk`` takes it: a window layer
+    writes the row's new K/V into its ring and reads the last
+    ``sliding_window`` positions from it (the page walk of
+    ops/paged_decode.py over the ring's pages in virtual order, with a
+    lower bound on the entries that count), a full layer the whole
+    history behind the ring's entries."""
     if config.attention == "eva":
         return _eva_decode_step(
             config, params, cache, token, pos, tables
         ) + (None,)
-    from dcos_commons_tpu.ops.paged_decode import paged_decode_attention
+    from dcos_commons_tpu.ops.paged_decode import (
+        paged_decode_attention,
+        window_decode_attention,
+    )
 
     b = token.shape[0]
     h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
     p_tok, lanes = cache["k"].shape[2], cache["k"].shape[-1]
-    m = tables.shape[1]
+    ring = _ring_pages(config, ring_pages)
+    # the full layers' entries
+    history = tables[:, ring:] if ring else tables
+    m = history.shape[1]
     length = m * p_tok
-    x = params["embed"][token][:, None, :].astype(config.dtype)
+    x = _embed(config, params, token)[:, None, :]
     pos = jnp.asarray(pos, jnp.int32)
     positions = pos[:, None]
     rows = jnp.arange(b)
     vpage = jnp.minimum(pos // p_tok, m - 1)
-    phys = tables[rows, vpage]                   # [b]
+    phys = history[rows, vpage]                  # [b]
     slot_off = pos % p_tok
     # a live row holds at least its first page
     live = tables[:, 0] > 0
@@ -902,7 +1149,10 @@ def paged_decode_step(
         """The attention operator; ``base``: the layer's first page."""
         with jax.named_scope("attention"):
             normed = _norm(config, x, layer["attn_norm"])
-            q, k_new, v_new = _project_kv(config, layer, normed, positions)
+            q, k_new, v_new = _project_kv(
+                config, layer, normed, positions,
+                rope=not config.nope_full_attention,
+            )
         with jax.named_scope("kv_write"):
             new = _kv_entries(k_new[:, 0], v_new[:, 0], quantized, lanes)
             arena = {
@@ -912,15 +1162,15 @@ def paged_decode_step(
         if kernel:
             # the row's new K/V is read back through the page it was
             # just written into
-            with jax.named_scope("attention"):
+            with jax.named_scope("attention_full" if ring else "attention"):
                 attn = paged_decode_attention(
                     jnp.pad(q[:, 0], ((0, 0), (0, 0), (0, lanes - hd))),
-                    arena["k"], arena["v"], base + tables, pos,
+                    arena["k"], arena["v"], base + history, pos,
                     scale=hd ** -0.5, interpret=kernel == "interpret",
                 )[..., :hd]
         else:
             with jax.named_scope("paged_gather"):
-                pages = base + tables
+                pages = base + history
                 k_all = arena["k"][pages].reshape(
                     b, length, kv, lanes
                 )[..., :hd]
@@ -949,8 +1199,70 @@ def paged_decode_step(
                     "bkrl,blkd->bkrd", probs, v_all.astype(jnp.float32)
                 ).astype(config.dtype)
         with jax.named_scope("attention"):
-            x = x + attn.reshape(b, 1, h * hd) @ dq(layer["wo"], x.dtype)
+            x = _attention_residual(
+                config, layer, x, normed, attn.reshape(b, 1, h * hd)
+            )
         return x, arena
+
+    attend_window = None
+    if ring:
+        window = config.sliding_window
+        ring_tables = tables[:, :ring]
+        ring_phys = ring_tables[rows, (pos // p_tok) % ring]
+        if not kernel:
+            # the position each ring entry of each row holds once the
+            # row's new K/V is written (``paged_prefill_chunk``)
+            held = _ring_positions(
+                jnp.arange(ring * p_tok, dtype=jnp.int32)[None, :],
+                (pos // p_tok)[:, None], ring, p_tok,
+            )
+            seen = (
+                (held <= pos[:, None]) & (held > pos[:, None] - window)
+                & (held >= 0)
+            )[:, None, :]                        # [b, 1, ring * P]
+
+        def attend_window(x, arena, layer, base):
+            """A window layer: RoPE, the ring, the last ``window``
+            positions; ``base``: the layer's first ring page."""
+            with jax.named_scope("attention_window"):
+                normed = _norm(config, x, layer["attn_norm"])
+                q, k_new, v_new = _project_kv(
+                    config, layer, normed, positions
+                )
+            with jax.named_scope("kv_write"):
+                new = _kv_entries(k_new[:, 0], v_new[:, 0], False, lanes)
+                arena = {
+                    name: arr.at[base + ring_phys, slot_off].set(new[name])
+                    for name, arr in arena.items()
+                }
+            with jax.named_scope("attention_window"):
+                if kernel:
+                    attn = window_decode_attention(
+                        jnp.pad(q[:, 0], ((0, 0), (0, 0), (0, lanes - hd))),
+                        arena["k"], arena["v"], base + ring_tables, pos,
+                        window=window, scale=hd ** -0.5,
+                        interpret=kernel == "interpret",
+                    )[..., :hd]
+                else:
+                    with jax.named_scope("paged_gather"):
+                        pages = base + ring_tables
+                        n = ring * p_tok
+                        keys = arena["k"][pages].reshape(
+                            b, n, kv, lanes
+                        )[..., :hd]
+                        values = arena["v"][pages].reshape(
+                            b, n, kv, lanes
+                        )[..., :hd]
+                    _m, norm, acc = _softmax_block(
+                        _softmax_start(b, 1, kv, reps, hd),
+                        q.reshape(b, 1, kv, reps, hd), keys, values, seen,
+                        hd ** -0.5,
+                    )
+                    attn = (acc / norm[..., None]).astype(config.dtype)
+                x = _attention_residual(
+                    config, layer, x, normed, attn.reshape(b, 1, h * hd)
+                )
+            return x, arena
 
     if config.one_kind:
         n_pages = cache["k"].shape[1]
@@ -971,7 +1283,7 @@ def paged_decode_step(
     else:
         x, new_cache, counts = _walk_pattern(
             config, params, cache, x, attend,
-            _conv_step_operator(config, live), live,
+            _conv_step_operator(config, live), live, attend_window,
         )
     with jax.named_scope("logits"):
         x = _norm(config, x, params["final_norm"])

@@ -61,6 +61,14 @@ class MoEConfig:
     expert_bias: bool = False
     norm_topk: bool = True
     scaling: float = 1.0
+    # what the renormalisation adds to the chosen scores' sum; under 0:
+    # the published forms (1e-6 on a sigmoid's, a softmax's held over
+    # 1e-9)
+    norm_eps: float = -1.0
+    # shared experts: SwiGLUs of width ``d_ff`` (leaves ``shared_gate``
+    # / ``shared_up`` / ``shared_down``) that EVERY token goes through,
+    # added to the routed sum with weight 1, outside the routing
+    n_shared: int = 0
 
     def __post_init__(self) -> None:
         if self.score not in ("softmax", "sigmoid"):
@@ -96,6 +104,12 @@ def init_moe_params(config: MoEConfig, key: jax.Array) -> MoEParams:
         params["expert_bias"] = jax.random.normal(
             jax.random.fold_in(key, 4), (e,), jnp.float32
         ) * 0.01
+    if config.n_shared:
+        fs = f * config.n_shared
+        shared = jax.random.split(jax.random.fold_in(key, 5), 3)
+        params["shared_gate"] = normal(shared[0], (d, fs), d ** -0.5)
+        params["shared_up"] = normal(shared[1], (d, fs), d ** -0.5)
+        params["shared_down"] = normal(shared[2], (fs, d), fs ** -0.5)
     return params
 
 
@@ -120,10 +134,13 @@ def route(
         total = gate_vals.sum(-1, keepdims=True)
         # the published forms: a sigmoid's sum gets 1e-6 added, a
         # softmax's is held over 1e-9
-        gate_vals = gate_vals / (
-            jnp.maximum(total, 1e-9) if config.score == "softmax"
-            else total + 1e-6
-        )
+        if config.norm_eps >= 0:
+            gate_vals = gate_vals / (total + config.norm_eps)
+        else:
+            gate_vals = gate_vals / (
+                jnp.maximum(total, 1e-9) if config.score == "softmax"
+                else total + 1e-6
+            )
     if config.scaling != 1.0:
         gate_vals = gate_vals * config.scaling
     return gate_vals, expert_idx, scores
@@ -363,8 +380,12 @@ def moe_serve_ffn(
     row's behind every group; the three grouped products run over the
     sorted rows; the results go back to their tokens by the inverse
     permutation (a gather, no scatter), are weighted in float32 and
-    summed.  Returns (``y [t, d]``, int32 ``[2]``: the live
-    assignments and the expert groups that hold at least one)."""
+    summed.  A shared expert (``routing`` then holds its three
+    leaves) is a dense SwiGLU over every row beside them, under the
+    scope ``shared_expert``: its 3 x d x d_ff weights are read once by
+    a plain product, and the sort, the groups and the counts stay the
+    ROUTED experts' alone.  Returns (``y [t, d]``, int32 ``[2]``: the
+    live assignments and the expert groups that hold at least one)."""
     from dcos_commons_tpu.ops.grouped_matmul import grouped_matmul
 
     t, d = x.shape
@@ -411,6 +432,15 @@ def moe_serve_ffn(
             out[back].reshape(t, k, d).astype(jnp.float32)
             * weight[:, :, None], axis=1,
         )
+    if config.n_shared:
+        with jax.named_scope("shared_expert"):
+            h = x.astype(dt)
+            hidden = jax.nn.silu(h @ dq(routing["shared_gate"], dt)) * (
+                h @ dq(routing["shared_up"], dt)
+            )
+            y = y + (hidden @ dq(routing["shared_down"], dt)).astype(
+                jnp.float32
+            )
     return y.astype(x.dtype), counts
 
 
@@ -427,7 +457,7 @@ def expert_shard_spec():
 
 
 def moe_sharding_rules(prefix: str = "", stacked: bool = False,
-                       expert_bias: bool = False):
+                       expert_bias: bool = False, shared: bool = False):
     """Param path -> PartitionSpec for the jit/GSPMD path: experts over
     ``ep``, then the scaling-book fsdp/tp split within each expert.
 
@@ -450,4 +480,8 @@ def moe_sharding_rules(prefix: str = "", stacked: bool = False,
     }
     if expert_bias:
         rules[f"{prefix}expert_bias"] = P(*lead, None)
+    if shared:
+        rules[f"{prefix}shared_gate"] = P(*lead, "fsdp", "tp")
+        rules[f"{prefix}shared_up"] = P(*lead, "fsdp", "tp")
+        rules[f"{prefix}shared_down"] = P(*lead, "tp", "fsdp")
     return rules

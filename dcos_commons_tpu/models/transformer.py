@@ -88,13 +88,36 @@ class TransformerConfig:
             )
         if self.attention == "eva" and self.n_experts > 0:
             raise ValueError("eva attention serves a dense FFN only")
-        unknown = set(self.layer_types) - {"attention", "conv"}
+        if self.attention == "eva" and (
+            self.attention_gate or self.sandwich_norm or self.embed_scale
+        ):
+            raise ValueError(
+                "eva attention has no output gate, second norms or "
+                "embedding scale: the grouped-query walk builds those"
+            )
+        unknown = set(self.layer_types) - {"attention", "conv", "sliding"}
         if unknown or (
             self.layer_types and len(self.layer_types) != self.n_layers
         ):
             raise ValueError(
                 f"layer_types names {self.n_layers} operators, each "
-                f"'attention' or 'conv', got {self.layer_types!r}"
+                f"'attention', 'sliding' or 'conv', got {self.layer_types!r}"
+            )
+        if "sliding" in self.layer_types and (
+            self.attention != "gqa" or self.sliding_window < 1
+            or "attention" not in self.layer_types
+            or "conv" in self.layer_types
+        ):
+            raise ValueError(
+                "sliding (window) attention layers go with at least one "
+                "full grouped-query attention layer, a sliding_window >= 1 "
+                f"and no conv layer, got attention {self.attention!r}, "
+                f"sliding_window {self.sliding_window}, {self.layer_types!r}"
+            )
+        if self.n_shared_experts not in (0, 1):
+            raise ValueError(
+                f"n_shared_experts {self.n_shared_experts}: one shared "
+                "expert of the routed experts' width is built, no more"
             )
         if "conv" in self.layer_types and (
             self.attention != "gqa" or self.conv_l_cache < 2
@@ -179,17 +202,52 @@ class TransformerConfig:
     moe_expert_bias: bool = False
     moe_norm_topk: bool = True
     moe_scaling: float = 1.0
+    moe_norm_eps: float = -1.0
+    n_shared_experts: int = 0
     # queries and keys RMS-normed a head, over head_dim, before RoPE
     qk_norm: bool = False
+    # a head's width where the file states one (0: d_model / n_heads);
+    # the query and output projections are then n_heads * d_head wide,
+    # whatever d_model is
+    d_head: int = 0
+    # "sliding" layers of ``layer_types`` attend to the last
+    # ``sliding_window`` positions alone (query i sees keys j with
+    # i - sliding_window < j <= i) and keep no more of a row than a
+    # ring of them (serve/paging.py RowLayout); "attention" layers the
+    # whole history.  ``nope_full_attention``: the full layers of such
+    # a pattern rotate nothing (no position encoding), the sliding ones
+    # keep RoPE
+    sliding_window: int = 0
+    nope_full_attention: bool = False
+    # the attention output times sigmoid(x Wg), a head and a lane at a
+    # time, before the output projection (leaf ``wg``)
+    attention_gate: bool = False
+    # a norm after each block as well as before it: x += norm_post(
+    # block(norm_pre(x))) (leaves ``attn_post_norm``, ``mlp_post_norm``)
+    sandwich_norm: bool = False
+    # the embedding's rows times sqrt(d_model)
+    embed_scale: bool = False
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def serving_only(self) -> str:
+        """What of this configuration the TRAINING forward does not
+        build ("" where it builds all of it)."""
+        found = [
+            name for name in (
+                "qk_norm", "attention_gate", "sandwich_norm", "embed_scale",
+                "nope_full_attention", "n_shared_experts",
+            ) if getattr(self, name)
+        ]
+        return ", ".join(found)
 
     @property
     def layer_kinds(self) -> tuple:
-        """(operator, ffn) of every layer: "attention" | "conv" and
-        "dense" | "moe"."""
+        """(operator, ffn) of every layer: "attention" | "sliding" |
+        "conv" and "dense" | "moe"."""
         operators = self.layer_types or ("attention",) * self.n_layers
         return tuple(
             (op, "moe" if self.n_experts > 0 and i >= self.n_dense_layers
@@ -230,9 +288,26 @@ _FILE_KEYS = {
     "norm_topk_prob": "moe_norm_topk", "use_expert_bias": "moe_expert_bias",
     "routed_scaling_factor": "moe_scaling",
     "router_activation": "moe_score", "qk_norm": "qk_norm",
+    "head_dim": "d_head", "sliding_window": "sliding_window",
+    "num_shared_experts": "n_shared_experts", "score_func": "moe_score",
+    "route_norm": "moe_norm_topk", "route_scale": "moe_scaling",
+    "route_norm_eps": "moe_norm_eps", "mup_enabled": "embed_scale",
+    # what a published config.json has no key for and a file states
+    # under the program's own names (its ``assumed`` says from where)
+    "attention_gate": "attention_gate", "sandwich_norm": "sandwich_norm",
+    "nope_on_full_attention": "nope_full_attention",
 }
 # a published ``layer_types`` entry -> the operator it names
-_OPERATORS = {"full_attention": "attention", "conv": "conv"}
+_OPERATORS = {
+    "full_attention": "attention", "sliding_attention": "sliding",
+    "conv": "conv",
+}
+# published keys that ask for what the program does not build, each
+# with the most it may say
+_UNBUILT = {
+    "n_group": 1, "topk_group": 1, "num_expert_groups": 1,
+    "num_limited_groups": 1, "num_shared_experts": 1,
+}
 
 
 def config_fields_from_file(path: str) -> Dict[str, Any]:
@@ -241,8 +316,11 @@ def config_fields_from_file(path: str) -> Dict[str, Any]:
     ``config.json`` (``_FILE_KEYS``; ``attention_class`` names the
     attention, ``layer_types`` each layer's operator,
     ``rope_parameters.rope_theta`` is read where it is nested).  A key this table lacks is not the program's to
-    interpret and is passed over; a ``head_dim`` or an attention class
-    the program cannot build is an error, not a silent other model."""
+    interpret and is passed over; a ``head_dim`` is taken as stated
+    (the heads need not fill ``hidden_size``); what the program cannot
+    build (an attention class, a ``rope_scaling``, a routing limited
+    to groups of experts, a second shared expert) is an error by its
+    name, not a silent other model."""
     import json
 
     with open(path, "r", encoding="utf-8") as f:
@@ -253,6 +331,18 @@ def config_fields_from_file(path: str) -> Dict[str, Any]:
         )
         for key, field in _FILE_KEYS.items() if data.get(key) is not None
     }
+    for key, most in _UNBUILT.items():
+        if data.get(key) is not None and int(data[key]) > most:
+            raise ValueError(
+                f"{path}: {key} {data[key]} is not built (at most {most}): "
+                "every expert stands in one group and one shared expert "
+                "of the routed width beside them"
+            )
+    if data.get("rope_scaling") is not None:
+        raise ValueError(
+            f"{path}: rope_scaling {data['rope_scaling']!r} is not built; "
+            "the program rotates by rope_theta alone"
+        )
     rope = data.get("rope_parameters") or {}
     if rope.get("rope_theta") is not None:
         if rope.get("rope_type", "default") != "default":
@@ -276,14 +366,9 @@ def config_fields_from_file(path: str) -> Dict[str, Any]:
     if fields["attention"] != "eva":
         fields.pop("window_size", None)
         fields.pop("chunk_size", None)
-    head_dim = data.get("head_dim")
-    if head_dim is not None and "d_model" in fields and "n_heads" in fields \
-            and head_dim * fields["n_heads"] != fields["d_model"]:
-        raise ValueError(
-            f"{path}: head_dim {head_dim} x {fields['n_heads']} heads is "
-            f"not hidden_size {fields['d_model']}; the program derives "
-            "head_dim as hidden_size / heads"
-        )
+    if "sliding" not in fields.get("layer_types", ()):
+        # a window that no layer of the pattern keeps
+        fields.pop("sliding_window", None)
     return fields
 
 
@@ -332,6 +417,7 @@ def moe_config_of(config: TransformerConfig):
         capacity_factor=config.moe_capacity_factor, dtype=config.dtype,
         score=config.moe_score, expert_bias=config.moe_expert_bias,
         norm_topk=config.moe_norm_topk, scaling=config.moe_scaling,
+        norm_eps=config.moe_norm_eps, n_shared=config.n_shared_experts,
     )
 
 
@@ -360,7 +446,7 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
     # a unit-offset norm stores its weight's distance from one
     norm_init = jnp.zeros if config.norm_unit_offset else jnp.ones
 
-    def attention_stack(n):
+    def attention_stack(n, keys=keys):
         stack = {
             "attn_norm": norm_init((n, d), dt),
             "wq": normal(keys[1], (n, d, h * hd), d ** -0.5),
@@ -371,6 +457,12 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
         if config.qk_norm:
             stack["q_norm"] = jnp.ones((n, hd), dt)
             stack["k_norm"] = jnp.ones((n, hd), dt)
+        if config.attention_gate:
+            stack["wg"] = normal(
+                jax.random.fold_in(keys[1], 1), (n, d, h * hd), d ** -0.5
+            )
+        if config.sandwich_norm:
+            stack["attn_post_norm"] = norm_init((n, d), dt)
         if config.attention == "eva":
             # the two learned vectors a head of the chunk summaries
             # (models/decode.py ``_eva_summaries``)
@@ -395,12 +487,19 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
             "conv_out": normal(conv_keys[2], (n, d, d), d ** -0.5),
         }
 
+    def post_norm(n):
+        return (
+            {"mlp_post_norm": norm_init((n, d), dt)}
+            if config.sandwich_norm else {}
+        )
+
     def dense_stack(n):
         return {
             "mlp_norm": norm_init((n, d), dt),
             "w_gate": normal(keys[5], (n, d, f), d ** -0.5),
             "w_up": normal(keys[6], (n, d, f), d ** -0.5),
             "w_down": normal(keys[7], (n, f, d), f ** -0.5),
+            **post_norm(n),
         }
 
     def moe_stack(n):
@@ -411,10 +510,16 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
         moe_config = moe_config_of(config)
         return {"mlp_norm": norm_init((n, d), dt), **jax.vmap(
             lambda k: init_moe_params(moe_config, k)
-        )(jax.random.split(keys[5], n))}
+        )(jax.random.split(keys[5], n)), **post_norm(n)}
 
     builders = {
         "attention": attention_stack, "conv": conv_stack,
+        # the same leaves as a full layer's, drawn from keys of its own
+        "sliding": functools.partial(
+            attention_stack, keys=jax.random.split(
+                jax.random.fold_in(key, 11), 8
+            ),
+        ),
         "dense": dense_stack, "moe": moe_stack,
     }
     stacks = {
@@ -423,7 +528,7 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
     }
     if config.one_kind:
         layers = {}
-        for part in ("attention", "conv", "moe", "dense"):
+        for part in ("attention", "sliding", "conv", "moe", "dense"):
             layers.update(stacks.get(part, {}))
     else:
         layers = stacks
@@ -470,6 +575,8 @@ def sharding_rules(config: TransformerConfig) -> Dict[str, P]:
     if config.qk_norm:
         parts["attention"]["q_norm"] = P(None, None)
         parts["attention"]["k_norm"] = P(None, None)
+    if config.attention_gate:
+        parts["attention"]["wg"] = P(None, "fsdp", "tp")
     if config.attention == "eva":
         parts["attention"]["eva_phi"] = P(None, "tp", None)
         parts["attention"]["eva_mu"] = P(None, "tp", None)
@@ -479,8 +586,15 @@ def sharding_rules(config: TransformerConfig) -> Dict[str, P]:
         from dcos_commons_tpu.models.moe import moe_sharding_rules
 
         parts["moe"] = {"mlp_norm": P(None, None), **moe_sharding_rules(
-            stacked=True, expert_bias=config.moe_expert_bias
+            stacked=True, expert_bias=config.moe_expert_bias,
+            shared=config.n_shared_experts > 0,
         )}
+    if config.sandwich_norm:
+        parts["attention"]["attn_post_norm"] = P(None, None)
+        for ffn in ("dense", "moe"):
+            if ffn in parts:
+                parts[ffn]["mlp_post_norm"] = P(None, None)
+    parts["sliding"] = parts["attention"]
     rules = {"embed": P("tp", "fsdp"), "final_norm": P(None)}
     for part, leaves in parts.items():
         if not config.n_layers_of(part):
@@ -592,11 +706,16 @@ def _attention_block(config: TransformerConfig, layer, x, positions):
     return x + attn @ dq(layer["wo"], x.dtype)
 
 
-def _mlp_block(config: TransformerConfig, layer, x):
+def _mlp(config: TransformerConfig, layer, x):
+    """The dense SwiGLU of ``x``'s norm, before the residual."""
     normed = _norm(config, x, layer["mlp_norm"])
     gate = jax.nn.silu(normed @ dq(layer["w_gate"], x.dtype))
     up = normed @ dq(layer["w_up"], x.dtype)
-    return x + (gate * up) @ dq(layer["w_down"], x.dtype)
+    return (gate * up) @ dq(layer["w_down"], x.dtype)
+
+
+def _mlp_block(config: TransformerConfig, layer, x):
+    return x + _mlp(config, layer, x)
 
 
 def _ffn_block(config: TransformerConfig, layer, x):
@@ -649,13 +768,14 @@ def _layer_scan(config: TransformerConfig, layers, x, positions):
     that fraction of the recompute back.  The non-remat span is the
     tail because those activations die first in backward."""
 
-    if not config.one_kind or config.qk_norm:
+    if not config.one_kind or config.serving_only:
         raise NotImplementedError(
-            "the training forward scans ONE kind of layer, attention "
-            "without a query/key norm; this layer pattern "
-            f"({sorted(set(config.layer_kinds))}, qk_norm "
-            f"{config.qk_norm}) has a serving path only (models/decode.py "
-            "paged_prefill_chunk / paged_decode_step)"
+            "the training forward scans ONE kind of layer, plain "
+            "attention and a routed mixture alone; this layer pattern "
+            f"({sorted(set(config.layer_kinds))}, with "
+            f"{config.serving_only or 'nothing else'}) has a serving path "
+            "only (models/decode.py paged_prefill_chunk / "
+            "paged_decode_step)"
         )
 
     def layer_fn(x, layer):

@@ -16,9 +16,13 @@ query a head is no work for the MXU); the softmax state lives with KV
 heads on sublanes (``[kv, 1]``, ``[kv, hd]``), once for each of the
 ``reps`` query heads that share a KV head, so that no step transposes.
 
-Two callers, one ``tpu_custom_call`` name each: ``paged_decode_attention``
-below (full history, heads grouped over ``n_kv_heads``) and
-``eva_decode_attention`` (ops/eva_decode.py: two regions, no grouping).
+Three callers, one ``tpu_custom_call`` name each:
+``paged_decode_attention`` below (full history, heads grouped over
+``n_kv_heads``), ``window_decode_attention`` below it (the last
+``window`` positions out of a row's ring: the one kernel with a LOWER
+bound on the entries that count, ``paged_decode_attention_window`` in a
+trace) and ``eva_decode_attention`` (ops/eva_decode.py: two regions, no
+grouping).
 """
 
 from __future__ import annotations
@@ -33,11 +37,14 @@ _NEG = -1e30
 
 
 def _kernel(ids_ref, n_first_ref, n_pages_ref, bound_first_ref,
-            bound_rest_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, *,
-            scale: float):
+            bound_rest_ref, *refs, scale: float, lower: bool = False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    # with ``lower``, one more prefetched scalar a row: the first
+    # region's entries count from that index on
+    lower_ref = refs[0] if lower else None
+    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sem = refs[int(lower):]
     row = pl.program_id(0)
     n_pages, n_first = n_pages_ref[row], n_first_ref[row]
     bound_first, bound_rest = bound_first_ref[row], bound_rest_ref[row]
@@ -80,6 +87,8 @@ def _kernel(ids_ref, n_first_ref, n_pages_ref, bound_first_ref,
             jnp.int32, (page, kv_heads, 1), 0
         )
         counts = index < bound
+        if lower:
+            counts &= index >= jnp.where(in_first, lower_ref[row], 0)
         state = []
         for q_r, (m, l, acc) in zip(q, carry):
             s = jnp.sum(k * q_r[None], axis=-1, keepdims=True)  # [P, kv, 1]
@@ -107,7 +116,7 @@ def _kernel(ids_ref, n_first_ref, n_pages_ref, bound_first_ref,
 
 def page_walk_attention(q, arena_k, arena_v, page_ids, n_first, n_pages,
                         bound_first, bound_rest, *, scale: float, name: str,
-                        interpret: bool = False):
+                        interpret: bool = False, lower_first=None):
     """``q [S, reps * kv, hd]`` against ``arena_k``/``arena_v
     [N, P, kv, hd]``: query head ``r * kv + g`` reads KV head ``g``.
 
@@ -117,7 +126,9 @@ def page_walk_attention(q, arena_k, arena_v, page_ids, n_first, n_pages,
     ``bound_first[s]``, then the rest, whose entries count while their
     index is under ``bound_rest[s]``.  Every row has at least its first
     page with one entry that counts (an idle row's is the trash page).
-    Returns ``[S, reps * kv, hd]`` in ``q``'s dtype from the
+    With ``lower_first [S]`` an entry of the first region counts only
+    from that index on (a kernel of its own: the others carry no such
+    operand).  Returns ``[S, reps * kv, hd]`` in ``q``'s dtype from the
     ``tpu_custom_call`` called ``name``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -126,7 +137,7 @@ def page_walk_attention(q, arena_k, arena_v, page_ids, n_first, n_pages,
     page, kv_heads = arena_k.shape[1:3]
     block = (1, heads, head_dim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=5 + (lower_first is not None),
         grid=(rows,),
         in_specs=[
             pl.BlockSpec(block, lambda s, *_: (s, 0, 0)),
@@ -140,17 +151,18 @@ def page_walk_attention(q, arena_k, arena_v, page_ids, n_first, n_pages,
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
+    scalars = [page_ids, n_first, n_pages, bound_first, bound_rest]
+    if lower_first is not None:
+        scalars.append(lower_first)
     return pl.pallas_call(
-        functools.partial(_kernel, scale=scale),
+        functools.partial(
+            _kernel, scale=scale, lower=lower_first is not None
+        ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
         name=name,
-    )(
-        page_ids.astype(jnp.int32), n_first.astype(jnp.int32),
-        n_pages.astype(jnp.int32), bound_first.astype(jnp.int32),
-        bound_rest.astype(jnp.int32), q, arena_k, arena_v,
-    )
+    )(*(a.astype(jnp.int32) for a in scalars), q, arena_k, arena_v)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -172,6 +184,44 @@ def paged_decode_attention(q, arena_k, arena_v, page_ids, pos, *,
         arena_k, arena_v, page_ids, n_pages, n_pages, pos + 1,
         jnp.zeros_like(pos), scale=scale, name="paged_decode_attention",
         interpret=interpret,
+    )
+    return out.reshape(rows, reps, kv_heads, head_dim).swapaxes(1, 2).reshape(
+        q.shape
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("window", "scale", "interpret"))
+def window_decode_attention(q, arena_k, arena_v, ring_ids, pos, *,
+                            window: int, scale: float,
+                            interpret: bool = False):
+    """Window attention of rows at ``pos [S]`` out of their RINGS:
+    ``q [S, H, hd]`` against the positions ``j`` with ``pos[s] - window
+    < j <= pos[s]`` of row ``s``, where position ``p`` lies in page
+    ``ring_ids[s, (p // P) % R]`` of ``arena_k``/``arena_v [N, P, kv,
+    hd]`` at entry ``p % P`` (serve/paging.py RowLayout).  The kernel
+    is handed the ring's pages in virtual order, from the page of the
+    first position seen to the page of ``pos``, and the index of the
+    first entry that counts among them; it reads those pages and no
+    other.  Head ``h`` reads KV head ``h // (H // kv)``.  Returns
+    ``[S, H, hd]`` in ``q``'s dtype."""
+    rows, heads, head_dim = q.shape
+    page, kv_heads = arena_k.shape[1:3]
+    reps = heads // kv_heads
+    ring = ring_ids.shape[1]
+    first_seen = jnp.maximum(pos - window + 1, 0)
+    first_page = first_seen // page
+    n_pages = pos // page - first_page + 1
+    # a window's positions lie in at most this many pages
+    most = min(ring, -(-window // page) + 1)
+    order = (first_page[:, None] + jnp.arange(most, dtype=jnp.int32)) % ring
+    out = page_walk_attention(
+        q.reshape(rows, kv_heads, reps, head_dim).swapaxes(1, 2).reshape(
+            q.shape
+        ),
+        arena_k, arena_v, jnp.take_along_axis(ring_ids, order, axis=1),
+        n_pages, n_pages, pos + 1 - first_page * page, jnp.zeros_like(pos),
+        scale=scale, name="paged_decode_attention_window",
+        interpret=interpret, lower_first=first_seen - first_page * page,
     )
     return out.reshape(rows, reps, kv_heads, head_dim).swapaxes(1, 2).reshape(
         q.shape

@@ -401,6 +401,9 @@ class PagedEngine:
         # still prefilling, which no decode call reads)
         self._decode_rows_sum = 0
         self._decode_entries_sum = 0
+        # and the ring entries they read in a window layer (0 where
+        # the model has none)
+        self._decode_window_entries_sum = 0
         # a windowed row layout's events (serve/paging.py RowLayout);
         # both stay 0 where every token of a row is kept
         self._window_rollovers = 0
@@ -645,6 +648,19 @@ class PagedEngine:
                 # they stand for; equal where every token is kept
                 "kv_live_tokens": live_tokens,
                 "context_live_tokens": sum(positions),
+                # what the window layers' rings hold of them that still
+                # counts (0 where no layer keeps a window), and the
+                # pages in use by pool: the rings' (a live row's whole
+                # ring is its slot's) and the history's
+                "kv_window_live_tokens": sum(
+                    self._layout.window_entries(n) for n in positions
+                ),
+                "kv_window_pages_in_use": (
+                    len(positions) * self._layout.ring_pages
+                ),
+                "kv_history_pages_in_use": (
+                    alloc.pages_total - alloc.free_pages
+                ),
                 # PHYSICAL occupancy: shared prefix pages count
                 # once, not once per pinning row — under heavy
                 # sharing the virtual sum can exceed the arena and
@@ -730,6 +746,7 @@ class PagedEngine:
             "ahead_discarded_rows": self._ahead_discarded_rows,
             "decode_rows_sum": self._decode_rows_sum,
             "decode_entries_sum": self._decode_entries_sum,
+            "decode_window_entries_sum": self._decode_window_entries_sum,
             "window_rollovers": self._window_rollovers,
             "summary_entries_written": self._summary_entries,
             "phase_s": {
@@ -856,6 +873,10 @@ class PagedEngine:
             row.slot = self._free.pop()
             row.admission = admission
             row.table = np.zeros(self._pages_per_row, np.int32)
+            # window layers' ring: the slot's own pages, never drawn
+            row.table[:self._layout.ring_pages] = (
+                self._layout.ring_entries(row.slot)
+            )
             for i, entry in enumerate(admission.matched):
                 row.table[self._layout.share_slot(i)] = entry.page
             # prefill resumes past the cache-served pages
@@ -966,6 +987,9 @@ class PagedEngine:
             dispatched[slot] = row
             self._decode_rows_sum += 1
             self._decode_entries_sum += self._layout.entries(at)
+            self._decode_window_entries_sum += (
+                self._layout.window_entries(at + 1)
+            )
         if riding is not None and not any(
             row is not None for row in dispatched
         ):
@@ -1674,6 +1698,10 @@ class PagedEngine:
             row.slot = self._free.pop()
             row.admission = admission
             row.table = np.zeros(self._pages_per_row, np.int32)
+            # window layers' ring: the slot's own pages, never drawn
+            row.table[:self._layout.ring_pages] = (
+                self._layout.ring_entries(row.slot)
+            )
             for i, entry in enumerate(admission.matched):
                 row.table[layout.share_slot(i)] = entry.page
             row.registered_to = m

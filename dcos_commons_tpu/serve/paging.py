@@ -47,7 +47,13 @@ thread; the device half (arena tensors, gather-attention) lives in
   summaries, ``P`` chunks a page, which are written once and are the
   only pages such a row can share.  A 32,768-position row then needs
   256 pages where full attention needs 2,048, and the admission rule
-  reserves by that.
+  reserves by that.  Where WINDOW layers stand among full ones
+  (``sliding_window``), a row keeps one cache a KIND of layer: entries
+  ``[0, ring_pages)`` are a ring of the window layers' last
+  ``sliding_window`` + one prefill chunk of positions, in an arena of
+  their own whose pages belong to the row's SLOT, and the entries
+  behind them the whole history of the full layers, drawn from the
+  page budget as ever.
 
 ``paged_config_from_env`` is the ONE env -> paged-geometry contract,
 shared by both serve workers, shardcheck's ``_serve_leaves`` footprint
@@ -58,6 +64,7 @@ a deploy-time SpecError, not a permanent runtime 503.
 
 from __future__ import annotations
 
+import dataclasses
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -102,14 +109,42 @@ class RowLayout:
     needs of it; but a row's pages alone then no longer say what the
     row has seen: such a layout shares no prefix page, and a session
     of it cannot be exported, spliced or handed off
-    (``carries_state``)."""
+    (``carries_state``).
+
+    ``sliding_window > 0``: the model has window attention layers
+    among full ones, and a row one cache a KIND.  Entries ``[0,
+    ring_pages)`` are the window layers' RING: position ``p`` lives in
+    ring page ``(p // P) % ring_pages``, and ``ring_pages`` covers the
+    window and one prefill chunk (``with_chunk`` sets it: a layout is
+    whole once it knows the chunk), so a chunk's first
+    query still finds its ``sliding_window - 1`` predecessors once the
+    chunk's last keys are written.  A ring's pages are the row's
+    SLOT's, in the window layers' own arena (``ring_entries``: resident
+    for every slot as conv state is, so they are never allocated, and
+    a free slot is all that admission needs of them).  Entry
+    ``ring_pages + v`` holds positions ``[v*P, (v+1)*P)`` of the full
+    layers' history, for ever, from the page budget.  A ring holds
+    what the prefix before it left, so such a row's pages do not
+    travel either (``carries_state``)."""
 
     page_tokens: int
     window: int = 0
     chunk: int = 0
     state_bytes_per_row: int = 0
+    sliding_window: int = 0
+    ring_pages: int = 0
 
     def __post_init__(self) -> None:
+        if self.sliding_window and self.window:
+            raise ValueError(
+                "a row has window layers among full ones or EVA's window "
+                "and summaries, not both"
+            )
+        if self.ring_pages and not self.sliding_window:
+            raise ValueError(
+                f"a ring of {self.ring_pages} pages and no window layer "
+                "to keep it (sliding_window 0)"
+            )
         if not self.window:
             return
         if self.chunk != self.page_tokens:
@@ -123,15 +158,49 @@ class RowLayout:
                 f"pages ({self.chunk * self.page_tokens} positions each)"
             )
 
+    def with_chunk(self, chunk_tokens: int) -> "RowLayout":
+        """This layout for prefill chunks of ``chunk_tokens``: window
+        layers' rings then hold the window and one chunk, in whole
+        pages (one more where the window ends inside a page)."""
+        if not self.sliding_window:
+            return self
+        p = self.page_tokens
+        if chunk_tokens % p:
+            raise ValueError(
+                f"a ring is written a prefill chunk at a time, in whole "
+                f"pages: chunk {chunk_tokens} of {p}-token pages"
+            )
+        return dataclasses.replace(self, ring_pages=pages_for(
+            self.sliding_window + chunk_tokens, p
+        ) + bool(self.sliding_window % p))
+
     @property
     def window_pages(self) -> int:
         return self.window // self.page_tokens if self.window else 0
+
+    def ring_entries(self, slot: int) -> List[int]:
+        """The ring of the row in ``slot``: its pages of the window
+        layers' arena (page 0 there is that arena's trash page)."""
+        first = 1 + slot * self.ring_pages
+        return list(range(first, first + self.ring_pages))
+
+    def window_entries(self, positions: int) -> int:
+        """Ring entries that still count for a row with ``positions``
+        behind it: what its next step reads in a window layer."""
+        return min(positions, self.sliding_window)
 
     @property
     def carries_state(self) -> str:
         """Why this layout's pages may not travel or be shared without
         the row they belong to ("" where they may): the reason a
         client is given."""
+        if self.ring_pages:
+            return (
+                f"a row of this model keeps the last {self.sliding_window} "
+                "positions of its window attention layers in a ring of its "
+                "slot, which no history page carries: its pages are not "
+                "shared, exported, spliced or handed off"
+            )
         if not self.state_bytes_per_row:
             return ""
         return (
@@ -161,7 +230,7 @@ class RowLayout:
     def table_len(self, max_len: int) -> int:
         """Page-table length of a row of up to ``max_len`` positions."""
         if not self.window:
-            return pages_for(max_len, self.page_tokens)
+            return self.ring_pages + pages_for(max_len, self.page_tokens)
         return self.window_pages + pages_for(
             max_len // self.chunk, self.page_tokens
         )
@@ -174,7 +243,8 @@ class RowLayout:
             return []
         pages = range(first_pos // p, last_pos // p + 1)
         if not self.window:
-            return list(pages)
+            # the history's; a ring's pages are resident
+            return [self.ring_pages + v for v in pages]
         # consecutive pages of positions are consecutive ring entries
         ring = [
             v % self.window_pages
@@ -194,7 +264,14 @@ class RowLayout:
         carry.  A past window's ring pages are dead."""
         p = self.page_tokens
         if not self.window:
-            return list(range(pages_for(kv_end, p)))
+            # of a ring, the pages of the positions the next query sees
+            seen = range(
+                max(0, kv_end - self.sliding_window + 1) // p,
+                pages_for(kv_end, p),
+            ) if self.ring_pages else ()
+            return sorted({v % self.ring_pages for v in seen}) + [
+                self.ring_pages + v for v in range(pages_for(kv_end, p))
+            ]
         ring = pages_for(kv_end % self.window, p)
         sums = pages_for(kv_end // self.chunk, p)
         return list(range(ring)) + [
@@ -211,8 +288,9 @@ class RowLayout:
 
     def entries(self, positions: int) -> int:
         """Cache ENTRIES the next step of a row reads when ``positions``
-        are behind it: every one, or the current window's and one a
-        chunk of the windows before."""
+        are behind it: every one (in a full layer; what a window layer
+        reads beside them is ``window_entries``), or the current
+        window's and one a chunk of the windows before."""
         if not self.window:
             return positions
         return positions % self.window + (
@@ -254,9 +332,15 @@ def layout_from_env(env, page_tokens: int) -> RowLayout:
     if not data:
         return RowLayout(page_tokens)
     if data.get("attention_class") != "eva":
+        types = data.get("layer_types") or ()
+        if "sliding_attention" in types:
+            # the ring's size follows the prefill chunk (``with_chunk``)
+            return RowLayout(
+                page_tokens, sliding_window=int(data["sliding_window"])
+            )
         # a conv layer's state, at the 2 bytes an element the chip
         # serves in (the pool states what its arena really holds)
-        n_conv = sum(t == "conv" for t in data.get("layer_types") or ())
+        n_conv = sum(t == "conv" for t in types)
         return RowLayout(page_tokens, state_bytes_per_row=(
             n_conv * (int(data.get("conv_L_cache", 3)) - 1)
             * int(data["hidden_size"]) * 2 if n_conv else 0
@@ -316,11 +400,15 @@ def chunk_weights_from_env(env) -> Tuple[int, int]:
         data.get("layer_types")
         or ("full_attention",) * sizes["num_hidden_layers"]
     )
-    head_dim = d // heads
+    head_dim = int(data.get("head_dim") or d // heads)
+    # wq and wo (and the output gate's wg), wk and wv
+    attention = (
+        (3 if data.get("attention_gate") else 2) * d * heads * head_dim
+        + 2 * d * kv_heads * head_dim
+    )
+    shared = int(data.get("num_shared_experts") or 0)
     mixer = {
-        # wq and wo, wk and wv
-        "full_attention": 2 * d * heads * head_dim
-        + 2 * d * kv_heads * head_dim,
+        "full_attention": attention, "sliding_attention": attention,
         # the three gates' in-projection, the taps, the out-projection
         "conv": 3 * d * d + d * sizes["conv_L_cache"] + d * d,
     }
@@ -332,7 +420,7 @@ def chunk_weights_from_env(env) -> Tuple[int, int]:
     read = (
         sum(mixer[operator] for operator in operators)
         + (len(operators) - moe_layers) * 3 * d * d_ff
-        + moe_layers * (d * experts + experts * expert)
+        + moe_layers * (d * experts + (experts + shared) * expert)
     )
     idle = max(0, experts - sizes["num_experts_per_tok"])
     per_token = read - moe_layers * idle * expert
@@ -394,6 +482,13 @@ class PagedServeConfig:
         return self.pages + 1
 
     @property
+    def window_arena_pages(self) -> int:
+        """Pages of the window layers' arena: every slot's ring and
+        the trash page; 0 where the model has no window layer."""
+        ring = self.layout.ring_pages
+        return self.slots * ring + 1 if ring else 0
+
+    @property
     def chunk_source(self) -> str:
         """Who fixed ``chunk_tokens``: "env" or "model"."""
         return "env" if self.chunk_weights is None else "model"
@@ -449,7 +544,8 @@ def paged_config_from_env(env) -> PagedServeConfig:
         layout = layout_from_env(env, page_tokens)
     except (OSError, ValueError, KeyError) as e:
         raise SpecError(f"MODEL_CONFIG does not give a row layout: {e}")
-    per_row = layout.table_len(max_len)
+    # a window layer's ring is its slot's and outside the page budget
+    per_row = layout.table_len(max_len) - layout.ring_pages
     pages = int(env.get("KV_PAGES") or 0) or slots * per_row
     # unset (or 0): the code chooses the width from the model that the
     # same env describes; a stated width is the operator's and is held
@@ -474,6 +570,10 @@ def paged_config_from_env(env) -> PagedServeConfig:
             f"{layout.chunk}-position chunks and at most one window "
             f"({layout.window})"
         )
+    try:
+        layout = layout.with_chunk(chunk)
+    except ValueError as e:
+        raise SpecError(f"PREFILL_CHUNK_TOKENS {chunk}: {e}")
     need_one = layout.worst_case_pages(max_len, 0)
     if pages < need_one:
         raise SpecError(

@@ -137,13 +137,18 @@ class PagedPoolModel:
         # layout, and the two programs below read it off ``config``
         eva = config.attention == "eva"
         n_conv = config.n_layers_of("conv")
+        sliding = config.n_layers_of("sliding") > 0
         self.layout = RowLayout(
             page_tokens, config.window_size if eva else 0,
             config.chunk_size if eva else 0,
             # a row's share of ``cache["conv_state"]``
             state_bytes_per_row=n_conv * (config.conv_l_cache - 1)
             * config.d_model * jnp.dtype(config.dtype).itemsize,
-        )
+            # window layers among full ones: a ring a slot, sized by
+            # the window and this pool's chunk
+            sliding_window=config.sliding_window if sliding else 0,
+        ).with_chunk(chunk_tokens)
+        ring_pages = self.layout.ring_pages
         self.pages_per_row = self.layout.table_len(max_len)
         self._put = put if put is not None else (lambda x: x)
         con = constrain_out if constrain_out is not None else (lambda x: x)
@@ -151,12 +156,15 @@ class PagedPoolModel:
         init = functools.partial(
             init_paged_kv_cache, config, pages + 1, page_tokens,
             kv_dtype, slots, arena_lanes(config),
+            slots * ring_pages + 1 if ring_pages else 0,
         )
         if cache_sharding is not None:
-            if n_conv:
+            if n_conv or ring_pages:
                 raise ValueError(
-                    "a sharded arena has no layout for conv state: a "
-                    "pattern with conv layers is served on one device"
+                    "a sharded arena has no layout for conv state or for "
+                    "the window layers' rings: a pattern with conv or "
+                    "window attention layers is served on one device "
+                    "(the serving gang lays ONE arena over its tp mesh)"
                 )
             self.cache = jax.jit(init, out_shardings=cache_sharding)()
         else:
@@ -196,7 +204,7 @@ class PagedPoolModel:
             with mesh():
                 logits, cache, counts, *rode = paged_prefill_chunk(
                     config, params, cache, tokens, table, start, true_len,
-                    slot, riders,
+                    slot, riders, ring_pages,
                 )
             if counts is not None:
                 # this chunk's mixtures, and the chunk itself, on top
@@ -224,7 +232,7 @@ class PagedPoolModel:
             tok = jnp.where(carry, prev, tok)
             with arena_mesh():
                 logits, cache, counts = paged_decode_step(
-                    config, params, cache, tok, pos, tables,
+                    config, params, cache, tok, pos, tables, ring_pages,
                 )
             if counts is None:
                 counts = jnp.zeros(2, jnp.int32)

@@ -517,6 +517,7 @@ def main() -> int:
             "vocab": config.vocab, "d_model": config.d_model,
             "n_layers": config.n_layers, "n_heads": config.n_heads,
             "n_kv_heads": config.n_kv_heads, "d_ff": config.d_ff,
+            "head_dim": config.head_dim,
             "dtype": jnp.dtype(config.dtype).name,
             "attention": config.attention,
             "decode_attention": decode_attention,
@@ -532,6 +533,14 @@ def main() -> int:
             "moe_d_ff": config.moe_d_ff or config.d_ff,
             "moe_routing": config.moe_score,
             "conv_l_cache": config.conv_l_cache,
+            "n_shared_experts": config.n_shared_experts,
+            # window layers among full ones: the window, a row's ring
+            # in their arena, and the history pool's pages as asked
+            "sliding_window": config.sliding_window,
+            "kv_ring_pages": pool.layout.ring_pages,
+            "kv_pages": paged.pages,
+            "attention_gate": config.attention_gate,
+            "sandwich_norm": config.sandwich_norm,
             "model_config": os.environ.get("MODEL_CONFIG", ""),
             # what the chunk's width was chosen from, where the code
             # chose it (serve/paging.py chosen_chunk_tokens)

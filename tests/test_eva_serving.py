@@ -355,9 +355,11 @@ def test_config_from_a_file_wins_over_the_size_names(tmp_path):
     assert (plain.vocab, plain.attention, plain.tie_embeddings) == (
         64, "gqa", True
     )
+    # a head_dim that is not hidden_size / heads is taken as stated
+    # (PR 44: the heads need not fill the hidden size)
     path.write_text(json.dumps(dict(MODEL, head_dim=32)))
-    with pytest.raises(ValueError):
-        config_from_env({"MODEL_CONFIG": str(path)})
+    stated = config_from_env({"MODEL_CONFIG": str(path)})
+    assert stated.head_dim == 32 != stated.d_model // stated.n_heads
     path.write_text(json.dumps(dict(MODEL, attention_class="latent")))
     with pytest.raises(ValueError):
         config_from_env({"MODEL_CONFIG": str(path)})

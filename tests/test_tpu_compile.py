@@ -114,3 +114,47 @@ def test_grouped_matmul_compiles_at_the_cells_sizes(
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "gmm" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 21
+
+
+@pytest.mark.parametrize("kind", ["window", "full"])
+def test_decode_attention_compiles_at_the_window_and_full_cells_sizes(
+    one_chip, kind
+):
+    """``trinity-mini.longdoc``: 24 rows, pages of ``bf16[16, 4, 128]``
+    (4 KV heads), 32 query heads of 128.  A window layer reads a ring of
+    160 pages a row out of the 4 x 3841 of its arena, through the one
+    kernel with a lower bound; the full layer 2,048 table entries over
+    49,153 pages."""
+    import jax
+    import jax.numpy as jnp
+
+    from dcos_commons_tpu.ops.paged_decode import (
+        paged_decode_attention,
+        window_decode_attention,
+    )
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = 24
+    if kind == "window":
+        table, pages, name = 160, 4 * 3841, "paged_decode_attention_window"
+        fn = lambda *a: window_decode_attention(  # noqa: E731
+            *a, window=2048, scale=128 ** -0.5
+        )
+    else:
+        table, pages, name = 2048, 49153, "paged_decode_attention"
+        fn = lambda *a: paged_decode_attention(  # noqa: E731
+            *a, scale=128 ** -0.5
+        )
+    arena = shaped((pages, 16, 4, 128), jnp.bfloat16)
+    compiled = jax.jit(fn).lower(
+        shaped((rows, 32, 128), jnp.bfloat16), arena, arena,
+        shaped((rows, table), jnp.int32), shaped((rows,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and name in text
+    if kind == "full":
+        assert "paged_decode_attention_window" not in text
+    # the arena is read in place: no copy of it, no gathered buffer
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
