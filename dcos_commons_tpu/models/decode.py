@@ -1165,7 +1165,7 @@ def paged_decode_step(
             with jax.named_scope("attention_full" if ring else "attention"):
                 attn = paged_decode_attention(
                     jnp.pad(q[:, 0], ((0, 0), (0, 0), (0, lanes - hd))),
-                    arena["k"], arena["v"], base + history, pos,
+                    arena["k"], arena["v"], base + history, pos, live,
                     scale=hd ** -0.5, interpret=kernel == "interpret",
                 )[..., :hd]
         else:
@@ -1240,7 +1240,7 @@ def paged_decode_step(
                     attn = window_decode_attention(
                         jnp.pad(q[:, 0], ((0, 0), (0, 0), (0, lanes - hd))),
                         arena["k"], arena["v"], base + ring_tables, pos,
-                        window=window, scale=hd ** -0.5,
+                        live, window=window, scale=hd ** -0.5,
                         interpret=kernel == "interpret",
                     )[..., :hd]
                 else:
@@ -1556,6 +1556,8 @@ def _eva_step_part(config, cache, pos, tables):
         live, n_ring, n_live, n_win, n_sum = live_pages(
             tables, pos, win, chunk, p_tok
         )
+        # a slot that decodes holds at least its first ring page
+        decodes = tables[:, 0] > 0
     else:
         mask_win = (
             lax.broadcasted_iota(jnp.int32, (1, 1, win), 2)
@@ -1576,7 +1578,7 @@ def _eva_step_part(config, cache, pos, tables):
             with jax.named_scope("attention"):
                 attn = eva_decode_attention(
                     q, arena["k"], arena["v"], base + live, n_ring,
-                    n_live, n_win, n_sum, scale=hd ** -0.5,
+                    n_live, n_win, n_sum, decodes, scale=hd ** -0.5,
                     interpret=kernel == "interpret",
                 )
             # the page each row has just written into: its chunk
@@ -1726,6 +1728,25 @@ def decode_attention_kernel(config: TransformerConfig, cache):
     if jax.sharding.get_abstract_mesh().size > 1:
         return None
     return "compiled" if jax.default_backend() == "tpu" else None
+
+
+def decode_attention_step(config: TransformerConfig, cache) -> dict:
+    """What one step of the page walk does under each kernel name the
+    decode program holds (``ops/paged_decode.py walk_step``: the pages
+    a step takes and the form of its products, both chosen from the
+    arena's shapes); empty where the step takes the gather path."""
+    from dcos_commons_tpu.ops.paged_decode import walk_step
+
+    if not decode_attention_kernel(config, cache):
+        return {}
+    if config.attention == "eva":
+        return {"eva_decode_attention": walk_step(cache["k"])}
+    steps = {}
+    if config.n_layers_of("attention"):
+        steps["paged_decode_attention"] = walk_step(cache["k"])
+    if config.n_layers_of("sliding"):
+        steps["paged_decode_attention_window"] = walk_step(cache["k_window"])
+    return steps
 
 
 def _eva_decode_step(config, params, cache, token, pos, tables):

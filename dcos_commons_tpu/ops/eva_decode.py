@@ -7,9 +7,14 @@ windows before it (summary pages), one softmax over both
 whole table into a dense buffer first, live or not, and read that
 again: at 24 rows of 256 pages that is 4.8 GB a layer for 0.4 GB of
 live entries.  ``live_pages`` lists each row's live pages and the page
-walk of ops/paged_decode.py copies every one of them once; the cost
-follows what the rows hold.  No head is grouped here (``reps`` 1), and
-the walk's two regions are the ring and the summaries.
+walk of ops/paged_decode.py copies every one of them once, a block of
+pages a step (8 at this model's 32 heads of 128), and visits no slot
+that decodes nothing; the cost follows what the rows hold.  No head is
+grouped here (``reps`` 1): the walk's one form, every head's query
+against every (entry, head) row of a block in one MXU product and a
+mask that keeps each head its own, replaces the lane sum a head an
+entry.  The walk's two regions are the ring and the summaries, and a
+block may hold the end of one and the start of the other.
 The ``tpu_custom_call`` is named ``eva_decode_attention``.
 """
 
@@ -25,7 +30,7 @@ from dcos_commons_tpu.ops.paged_decode import page_walk_attention
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def eva_decode_attention(q, arena_k, arena_v, page_ids, n_ring, n_pages,
-                         n_window, n_summary, *, scale: float,
+                         n_window, n_summary, live=None, *, scale: float,
                          interpret: bool = False):
     """``q [S, H, hd]`` against ``arena_k``/``arena_v [N, P, H, hd]``.
 
@@ -33,13 +38,14 @@ def eva_decode_attention(q, arena_k, arena_v, page_ids, n_ring, n_pages,
     arena's leading axis): first its ``n_ring[s]`` ring pages, whose
     entries count while their index in the ring is under
     ``n_window[s]``, then its summary pages, whose entries count while
-    their index is under ``n_summary[s]``.  Every row has at least its
-    first ring page with one entry that counts (an idle row's is the
-    trash page).  Returns ``[S, H, hd]`` in ``q``'s dtype."""
+    their index is under ``n_summary[s]``.  Rows that ``live [S]`` says
+    are idle are not read and give zeros; every other row has at least
+    its first ring page with one entry that counts.  Returns ``[S, H,
+    hd]`` in ``q``'s dtype."""
     return page_walk_attention(
         q, arena_k, arena_v, page_ids, n_ring, n_pages, n_window,
         n_summary, scale=scale, name="eva_decode_attention",
-        interpret=interpret,
+        interpret=interpret, live=live,
     )
 
 

@@ -131,6 +131,7 @@ def main() -> int:
     from dcos_commons_tpu.models import config_from_env, init_params
     from dcos_commons_tpu.models.decode import (
         decode_attention_kernel,
+        decode_attention_step,
         layer_plan,
     )
     from dcos_commons_tpu.serve.pool import PagedPoolModel
@@ -521,6 +522,11 @@ def main() -> int:
             "dtype": jnp.dtype(config.dtype).name,
             "attention": config.attention,
             "decode_attention": decode_attention,
+            # what a step of the kernel's page walk takes, by the
+            # kernel's name in a trace ({} on the gather path)
+            "decode_attention_step": decode_attention_step(
+                config, pool.cache
+            ),
             "window_size": config.window_size,
             "chunk_size": config.chunk_size,
             # the layer pattern, and how a mixture's tokens choose

@@ -266,11 +266,33 @@ def test_the_reference_can_tell_a_window_that_is_off_by_one(params, off):
     )
 
 
-def test_the_window_kernel_reads_the_last_window_out_of_a_ring():
+def test_both_kernels_steps_are_stated(config, monkeypatch):
+    """``/stats`` ``model.decode_attention_step`` names the full
+    layers' kernel and the window layers', each with the step its own
+    arena's shapes give."""
+    import jax
+
+    from dcos_commons_tpu.models import decode
+    from dcos_commons_tpu.ops.paged_decode import walk_step
+
+    cache = decode.init_paged_kv_cache(
+        config, 64, PAGE, slots=SLOTS, window_pages=SLOTS * RING + 1
+    )
+    assert decode.decode_attention_step(config, cache) == {}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert decode.decode_attention_step(config, cache) == {
+        "paged_decode_attention": walk_step(cache["k"]),
+        "paged_decode_attention_window": walk_step(cache["k_window"]),
+    }
+
+
+@pytest.mark.parametrize("idle", [(), (0, 3, 6)], ids=["all_live", "idle"])
+def test_the_window_kernel_reads_the_last_window_out_of_a_ring(idle):
     """ops/paged_decode.py ``window_decode_attention`` (interpreted)
     against a softmax over the positions ``(p - W, p]`` laid out in
     position order, at positions before, at and after the window's
-    edge and after several wraps."""
+    edge and after several wraps; slots it is told are idle, before,
+    between and behind the live rows, are not read and give zeros."""
     import jax
     import jax.numpy as jnp
 
@@ -290,12 +312,17 @@ def test_the_window_kernel_reads_the_last_window_out_of_a_ring():
         for p in range(pos[s] + 1):          # later positions write over
             arena_k[ids[s, (p // PAGE) % ring], p % PAGE] = k_seq[s, p]
             arena_v[ids[s, (p // PAGE) % ring], p % PAGE] = v_seq[s, p]
+    live = np.array([s not in idle for s in range(rows)])
     got = window_decode_attention(
-        q, jnp.asarray(arena_k), jnp.asarray(arena_v), jnp.asarray(ids),
-        jnp.asarray(pos, jnp.int32), window=WINDOW, scale=hd ** -0.5,
-        interpret=True,
+        q, jnp.asarray(arena_k), jnp.asarray(arena_v),
+        jnp.asarray(ids * live[:, None]), jnp.asarray(pos, jnp.int32),
+        jnp.asarray(live) if idle else None, window=WINDOW,
+        scale=hd ** -0.5, interpret=True,
     )
     for s in range(rows):
+        if s in idle:
+            assert not np.asarray(got[s]).any()
+            continue
         lo = max(0, pos[s] - WINDOW + 1)
         for head in range(heads):
             g = head // (heads // kv)

@@ -761,6 +761,23 @@ def test_the_decode_kernel_reads_what_the_gather_path_reads(toy, monkeypatch):
     assert float(np.abs(kernel - want).max()) < TOLERANCE
 
 
+def test_the_kernels_step_is_stated(toy, monkeypatch):
+    """``/stats`` ``model.decode_attention_step`` under the one name an
+    EVA program holds the kernel by."""
+    import jax
+
+    from dcos_commons_tpu.models import decode
+    from dcos_commons_tpu.ops.paged_decode import walk_step
+
+    config, _params = toy
+    cache = decode.init_paged_kv_cache(config, 8, CHUNK)
+    assert decode.decode_attention_step(config, cache) == {}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert decode.decode_attention_step(config, cache) == {
+        "eva_decode_attention": walk_step(cache["k"]),
+    }
+
+
 def test_live_pages_by_hand():
     import jax.numpy as jnp
 

@@ -24,6 +24,55 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (a jit's, a kernel's, a loop's body)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for held in param if isinstance(param, (list, tuple)) else [param]:
+                held = getattr(held, "jaxpr", held)
+                if hasattr(held, "eqns"):
+                    yield from _eqns(held)
+
+
+def _page_walk(fn, *shapes):
+    """(products, VMEM scratch bytes) of the one page-walk kernel that
+    ``fn`` over ``shapes`` traces to: the ``dot_general`` equations its
+    body holds and what its scratch buffers take of the fast memory."""
+    import math
+
+    import jax
+
+    calls = [
+        eqn for eqn in _eqns(jax.make_jaxpr(fn)(*shapes).jaxpr)
+        if eqn.primitive.name == "pallas_call"
+    ]
+    assert len(calls) == 1
+    kernel = calls[0].params["jaxpr"]
+    n_scratch = calls[0].params["grid_mapping"].num_scratch_operands
+    scratch = kernel.invars[len(kernel.invars) - n_scratch:]
+    held = sum(
+        math.prod(ref.aval.shape) * ref.aval.dtype.itemsize
+        for ref in scratch if str(ref.aval.memory_space) == "vmem"
+    )
+    products = sum(
+        eqn.primitive.name == "dot_general" for eqn in _eqns(kernel)
+    )
+    return products, held
+
+
+def _assert_a_step_is_two_products_inside_the_budget(fn, *shapes):
+    """Scores and weighted sums are products on the MXU (one each a
+    block: no lane sum is left), and the step's two blocks each of K
+    and V stay under the budget ``page_walk_attention`` reckons."""
+    from dcos_commons_tpu.ops import paged_decode
+
+    products, held = _page_walk(fn, *shapes)
+    assert products == 2
+    assert 0 < held <= paged_decode.STEP_VMEM_BYTES
+
+
 def test_eva_decode_attention_compiles_at_the_published_widths(one_chip):
     """24 rows of 256 table entries over an arena of 8 x 4097 pages of
     16 entries, 32 heads of 128, bfloat16: the cell's sizes."""
@@ -38,14 +87,18 @@ def test_eva_decode_attention_compiles_at_the_published_widths(one_chip):
     rows, table, pages = 24, 256, 8 * 4097
     arena = shaped((pages, 16, 32, 128), jnp.bfloat16)
     per_row = shaped((rows,), jnp.int32)
-    compiled = jax.jit(
-        lambda *a: eva_decode_attention(*a, scale=128 ** -0.5)
-    ).lower(
+    fn = lambda *a: eva_decode_attention(  # noqa: E731
+        *a, scale=128 ** -0.5
+    )
+    shapes = (
         shaped((rows, 32, 128), jnp.bfloat16), arena, arena,
         shaped((rows, table), jnp.int32), per_row, per_row, per_row, per_row,
-    ).compile()
+        shaped((rows,), jnp.bool_),
+    )
+    compiled = jax.jit(fn).lower(*shapes).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "eva_decode_attention" in text
+    _assert_a_step_is_two_products_inside_the_budget(fn, *shapes)
     # the arena is read in place: no copy of it, no gathered buffer
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
@@ -53,8 +106,9 @@ def test_eva_decode_attention_compiles_at_the_published_widths(one_chip):
 def test_paged_decode_attention_compiles_at_the_mixture_cells_sizes(one_chip):
     """64 rows of 128 table entries over an arena of 3 x 4097 pages of
     ``bf16[16, 8, 128]`` (8 KV heads), 32 query heads of 128: the sizes
-    of ``mixtral8x7b.chat``.  Mosaic takes a page of 8 bfloat16
-    sublanes as it is."""
+    of ``mixtral8x7b.chat`` (and, in its arena rows of whole lanes, of
+    ``lfm2-24b.chat``).  The walk sees a page as ``bf16[128, 128]``,
+    and XLA hands the arena over as it lies: no copy."""
     import jax
     import jax.numpy as jnp
 
@@ -65,14 +119,18 @@ def test_paged_decode_attention_compiles_at_the_mixture_cells_sizes(one_chip):
 
     rows, table, pages = 64, 128, 3 * 4097
     arena = shaped((pages, 16, 8, 128), jnp.bfloat16)
-    compiled = jax.jit(
-        lambda *a: paged_decode_attention(*a, scale=128 ** -0.5)
-    ).lower(
+    fn = lambda *a: paged_decode_attention(  # noqa: E731
+        *a, scale=128 ** -0.5
+    )
+    shapes = (
         shaped((rows, 32, 128), jnp.bfloat16), arena, arena,
         shaped((rows, table), jnp.int32), shaped((rows,), jnp.int32),
-    ).compile()
+        shaped((rows,), jnp.bool_),
+    )
+    compiled = jax.jit(fn).lower(*shapes).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "paged_decode_attention" in text
+    _assert_a_step_is_two_products_inside_the_budget(fn, *shapes)
     # the arena is read in place: no copy of it, no gathered buffer
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
@@ -148,12 +206,15 @@ def test_decode_attention_compiles_at_the_window_and_full_cells_sizes(
             *a, scale=128 ** -0.5
         )
     arena = shaped((pages, 16, 4, 128), jnp.bfloat16)
-    compiled = jax.jit(fn).lower(
+    shapes = (
         shaped((rows, 32, 128), jnp.bfloat16), arena, arena,
         shaped((rows, table), jnp.int32), shaped((rows,), jnp.int32),
-    ).compile()
+        shaped((rows,), jnp.bool_),
+    )
+    compiled = jax.jit(fn).lower(*shapes).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and name in text
+    _assert_a_step_is_two_products_inside_the_budget(fn, *shapes)
     if kind == "full":
         assert "paged_decode_attention_window" not in text
     # the arena is read in place: no copy of it, no gathered buffer
