@@ -21,12 +21,19 @@ router's logits, an ``expert_bias`` that moves the selection and not
 the weights, the renormalisation over the chosen, a scaling factor.
 
 Serving has ONE dispatch, ``moe_serve_ffn``, and it drops nothing:
-the step's assignments are sorted by expert, each expert's rows form a
-group, and a grouped matmul (ops/grouped_matmul.py) runs the groups
-that hold rows.  Rows that stand for nothing (slots with no live
-request, a chunk's padding) are given to no group, so the experts read
-follow the live rows.  The capacity-bound dispatches above are the
-training forward's.
+ONE dispatch plan an expert layer (ops/grouped_matmul.py
+``dispatch_plan``) says where each of the step's assignments stands
+among the rows sorted by expert, each expert's rows form a group, and a
+grouped matmul (the repo's own forward ``gmm`` kernel there, handed the
+plan's tile metadata; ``lax.ragged_dot`` off a TPU and under a mesh)
+runs the groups that hold rows, three times from the one plan.  The
+plan is counted, not sorted: a handful of fused compare-and-sums where
+two argsorts, a bincount and a kernel wrapper's own metadata in every
+product stood (138 operations a layer beside the kernels at
+``lfm2-24b.chat``'s decode shape, now 47: PERF.md section 6, PR 49).
+Rows that stand for nothing (slots with no live request, a chunk's
+padding) are given to no group, so the experts read follow the live
+rows.  The capacity-bound dispatches above are the training forward's.
 """
 
 from __future__ import annotations
@@ -376,34 +383,33 @@ def moe_serve_ffn(
     ``experts`` holds EVERY expert layer's ``w_gate`` / ``w_up`` /
     ``w_down`` stacked ``[n, E, ...]`` and ``layer`` says which of the
     ``n`` this is (ops/grouped_matmul.py reads the layer's experts in
-    place).  The ``t * k`` assignments are sorted by expert, a dead
-    row's behind every group; the three grouped products run over the
-    sorted rows; the results go back to their tokens by the inverse
-    permutation (a gather, no scatter), are weighted in float32 and
-    summed.  A shared expert (``routing`` then holds its three
-    leaves) is a dense SwiGLU over every row beside them, under the
-    scope ``shared_expert``: its 3 x d x d_ff weights are read once by
-    a plain product, and the sort, the groups and the counts stay the
-    ROUTED experts' alone.  Returns (``y [t, d]``, int32 ``[2]``: the
-    live assignments and the expert groups that hold at least one)."""
-    from dcos_commons_tpu.ops.grouped_matmul import grouped_matmul
+    place).  ONE dispatch plan a layer (ops/grouped_matmul.py
+    ``dispatch_plan``) says where each of the ``t * k`` assignments
+    stands among the rows sorted by expert, a dead row's behind every
+    group, and holds the kernel's tile metadata; the three grouped
+    products run over the sorted rows from that one plan; the results
+    go back to their tokens by the plan's ``back`` (a gather, no
+    scatter), are weighted in float32 and summed.  A dead row's
+    assignments are owned by no group, so no product writes their rows:
+    they are left out here, once, with the row's weights.  A shared
+    expert (``routing`` then holds its three leaves) is a dense SwiGLU
+    over every row beside them, under the scope ``shared_expert``: its
+    3 x d x d_ff weights are read once by a plain product, and the
+    plan, the groups and the counts stay the ROUTED experts' alone.
+    Returns (``y [t, d]``, int32 ``[2]``: the live assignments and the
+    expert groups that hold at least one)."""
+    from dcos_commons_tpu.ops.grouped_matmul import (
+        dispatch_plan,
+        grouped_matmul,
+        take_rows,
+    )
 
     t, d = x.shape
     e, k = config.n_experts, config.top_k
     dt = config.dtype
     with jax.named_scope("moe_router"):
         gate_vals, expert_idx, _scores = route(config, routing, x)
-        flat_expert = expert_idx.reshape(-1)                   # [t * k]
-        if live is not None:
-            flat_expert = jnp.where(jnp.repeat(live, k), flat_expert, e)
-        order = jnp.argsort(flat_expert, stable=True)
-        back = jnp.argsort(order)
-        group_sizes = jnp.bincount(flat_expert, length=e + 1)[:e].astype(
-            jnp.int32
-        )
-        counts = jnp.stack(
-            [group_sizes.sum(), (group_sizes > 0).sum()]
-        ).astype(jnp.int32)
+        plan = dispatch_plan(expert_idx, live, e)
     with jax.named_scope("moe_experts"):
         layer = jnp.asarray(layer, jnp.int32)
 
@@ -415,23 +421,22 @@ def moe_serve_ffn(
                 w = dq(jax.tree.map(lambda a: lax.dynamic_index_in_dim(
                     a, layer, axis=0, keepdims=False
                 ), w), dt)
-                return grouped_matmul(rows, w, group_sizes, 0)
+                return grouped_matmul(rows, w, plan, 0)
             return grouped_matmul(
-                rows, w.reshape((-1,) + w.shape[-2:]), group_sizes,
-                layer * e,
+                rows, w.reshape((-1,) + w.shape[-2:]), plan, layer * e
             )
 
-        rows = x.astype(dt)[order // k]                        # [t * k, d]
+        rows = take_rows(x.astype(dt), plan.src)               # [m, d]
         gate = jax.nn.silu(product(rows, "w_gate"))
         out = product(gate * product(rows, "w_up"), "w_down")
-        # a dead row's result is zeros and its weight is left out too
-        weight = gate_vals if live is None else jnp.where(
-            live[:, None], gate_vals, 0.0
+        weighted = (
+            take_rows(out, plan.back).reshape(t, k, d).astype(jnp.float32)
+            * gate_vals[:, :, None]
         )
-        y = jnp.sum(
-            out[back].reshape(t, k, d).astype(jnp.float32)
-            * weight[:, :, None], axis=1,
-        )
+        if live is not None:
+            # what a dead row's assignments point at was never written
+            weighted = jnp.where(live[:, None, None], weighted, 0.0)
+        y = jnp.sum(weighted, axis=1)
     if config.n_shared:
         with jax.named_scope("shared_expert"):
             h = x.astype(dt)
@@ -441,7 +446,7 @@ def moe_serve_ffn(
             y = y + (hidden @ dq(routing["shared_down"], dt)).astype(
                 jnp.float32
             )
-    return y.astype(x.dtype), counts
+    return y.astype(x.dtype), plan.counts
 
 
 def expert_shard_spec():

@@ -319,8 +319,11 @@ def test_rows_that_stand_for_nothing_reach_no_expert_group(
 
 
 def test_the_grouped_kernel_computes_what_ragged_dot_does():
-    """The Pallas kernel of a TPU, interpreted here: groups at their
-    place among all the stack's, rows past the last group zeros."""
+    """The Pallas kernel of a TPU, interpreted here, from the dispatch
+    plan of sixteen assignments already in order: groups at their place
+    among all the stack's; the six rows past the last group belong to
+    nobody (``ragged_dot`` gives zeros there, the kernel writes
+    nothing)."""
     from unittest import mock
 
     import jax
@@ -330,12 +333,24 @@ def test_the_grouped_kernel_computes_what_ragged_dot_does():
 
     rows = jax.random.normal(jax.random.key(0), (16, 32))
     stack = jax.random.normal(jax.random.key(1), (3 * 4, 32, 24))
-    sizes = jnp.asarray([3, 0, 5, 2], jnp.int32)
-    want = gm.grouped_matmul(rows, stack, sizes, 4)
+    chosen = jnp.asarray([0] * 3 + [2] * 5 + [3] * 2 + [1] * 6)[:, None]
+    live = jnp.arange(16) < 10
+    plan = gm.dispatch_plan(chosen, live, 4)
+    assert plan.tiles is None
+    assert plan.group_sizes.tolist() == [3, 0, 5, 2]
+    assert plan.src.tolist() == plan.back.tolist() == list(range(16))
+    want = gm.grouped_matmul(rows, stack, plan, 4)
+    assert not np.asarray(want)[10:].any()
     with mock.patch.object(gm, "grouped_matmul_kernel", lambda: "interpret"):
-        got = gm.grouped_matmul(rows, stack, sizes, jnp.int32(4))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
-    assert not np.asarray(got)[10:].any()
+        tiled = gm.dispatch_plan(chosen, live, 4)
+        got = gm.grouped_matmul(rows, stack, tiled, jnp.int32(4))
+    # three groups hold rows, all in the one row tile
+    assert int(tiled.tiles.num_tiles) == 3
+    assert tiled.tiles.group_ids[:3].tolist() == [0, 2, 3]
+    assert tiled.tiles.group_offsets.tolist() == [0, 3, 3, 8, 10]
+    np.testing.assert_allclose(
+        np.asarray(got)[:10], np.asarray(want)[:10], atol=1e-4
+    )
     by_hand = np.asarray(rows[3:8] @ stack[4 + 2])
     np.testing.assert_allclose(np.asarray(got)[3:8], by_hand, atol=1e-4)
 

@@ -135,24 +135,25 @@ def test_paged_decode_attention_compiles_at_the_mixture_cells_sizes(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
-@pytest.mark.parametrize("rows,k,n,layers,experts", [
-    (256, 2048, 1536, 8, 64),    # lfm2-24b.chat: gate / up
-    (256, 1536, 2048, 8, 64),    # lfm2-24b.chat: down
-    (128, 4096, 14336, 3, 8),    # mixtral8x7b.chat: gate / up
-    (128, 14336, 4096, 3, 8),    # mixtral8x7b.chat: down
+@pytest.mark.parametrize("rows,k,n,layers,experts,top_k", [
+    (256, 2048, 1536, 8, 64, 4),    # lfm2-24b.chat: gate / up
+    (256, 1536, 2048, 8, 64, 4),    # lfm2-24b.chat: down
+    (128, 4096, 14336, 3, 8, 2),    # mixtral8x7b.chat: gate / up
+    (128, 14336, 4096, 3, 8, 2),    # mixtral8x7b.chat: down
     # the same four at the chunk width the code chooses (512 tokens:
     # serve/paging.py chosen_chunk_tokens), top-k assignments a token
-    (2048, 2048, 1536, 8, 64),
-    (2048, 1536, 2048, 8, 64),
-    (1024, 4096, 14336, 3, 8),
-    (1024, 14336, 4096, 3, 8),
+    (2048, 2048, 1536, 8, 64, 4),
+    (2048, 1536, 2048, 8, 64, 4),
+    (1024, 4096, 14336, 3, 8, 2),
+    (1024, 14336, 4096, 3, 8, 2),
 ])
 def test_grouped_matmul_compiles_at_the_cells_sizes(
-    one_chip, rows, k, n, layers, experts
+    one_chip, rows, k, n, layers, experts, top_k
 ):
     """A step's sorted assignments against EVERY expert layer's experts
-    on one axis: the kernel is in, its tiles fit the chip's fast memory,
-    and no copy of a layer's experts stands in front of it."""
+    on one axis, from the step's dispatch plan: the kernel is in, its
+    tiles fit the chip's fast memory, and no copy of a layer's experts
+    stands in front of it."""
     from unittest import mock
 
     import jax
@@ -163,15 +164,165 @@ def test_grouped_matmul_compiles_at_the_cells_sizes(
     def shaped(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    def product(lhs, stack, chosen, live, first):
+        plan = gm.dispatch_plan(chosen, live, experts)
+        return gm.grouped_matmul(lhs, stack, plan, first)
+
     with mock.patch.object(gm, "grouped_matmul_kernel", lambda: "compiled"):
-        compiled = jax.jit(gm.grouped_matmul).lower(
+        compiled = jax.jit(product).lower(
             shaped((rows, k), jnp.bfloat16),
             shaped((layers * experts, k, n), jnp.bfloat16),
-            shaped((experts,), jnp.int32), shaped((), jnp.int32),
+            shaped((rows // top_k, top_k), jnp.int32),
+            shaped((rows // top_k,), jnp.bool_), shaped((), jnp.int32),
         ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "gmm" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 21
+
+
+# what the entry computation of a compiled program holds beside work
+_NO_OPERATION = {
+    "parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+}
+
+
+def _executed(text):
+    """The operations a compiled program executes, by opcode, from its
+    optimized HLO: the entry computation's own (a fusion is one, a
+    kernel is one ``tpu_custom_call``), a loop's body times its trips
+    (read off the one bound its condition compares with), a called
+    computation's."""
+    import collections
+    import re
+
+    bodies, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY\s+)?(%?[\w.\-]+)\s*(\([^{]*)?\{\s*$", line)
+        if head and not line.startswith(" "):
+            name = head.group(2).lstrip("%")
+            bodies[name] = []
+            if head.group(1):
+                bodies["ENTRY"] = bodies[name]
+        elif line.startswith("}"):
+            name = None
+        elif name is not None and "=" in line:
+            bodies[name].append(line)
+
+    found = collections.Counter()
+
+    def walk(body, times):
+        for line in bodies[body]:
+            # a long tuple's type numbers its elements in comments
+            line = re.sub(r"/\*.*?\*/", "", line)
+            op = re.search(r"=\s+[^=]*?\s([a-z][\w\-]*)\(", line)
+            if not op or op.group(1) in _NO_OPERATION:
+                continue
+            op = op.group(1)
+            if op == "custom-call" and "tpu_custom_call" in line:
+                op = "tpu_custom_call"
+            found[op] += times
+            if op == "while":
+                condition = re.search(
+                    r"condition=%?([\w.\-]+)", line
+                ).group(1)
+                # the loop's bound: the one integer its condition holds
+                bounds = re.findall(
+                    r"s32\[\]\S* constant\((\d+)\)",
+                    "\n".join(bodies[condition]),
+                )
+                trips = int(bounds[0]) if len(bounds) == 1 else 1
+                walk(re.search(r"body=%?([\w.\-]+)", line).group(1),
+                     times * trips)
+                walk(condition, times * (trips + 1))
+            elif op == "call":
+                walk(re.search(r"to_apply=%?([\w.\-]+)", line).group(1),
+                     times)
+
+    walk("ENTRY", 1)
+    return found
+
+
+@pytest.mark.parametrize(
+    "tokens,experts,top_k,d,f,layers,shared,score,budget,sorts,temp", [
+        # the three mixture cells' decode steps (SERVE_SLOTS rows), one
+        # block of assignments each, and their 512-token chunks.
+        # ``budget``: the operations beside the three kernels; before the
+        # plan (two argsorts, a bincount, megablox's own metadata over
+        # the stack's groups in every product) this count read 138, 163
+        # and 92 a decode layer and 161, 216 and 145 a chunk's (a loop's
+        # body counted once a trip), now 47, 70, 46 and 55, 101, 57
+        (64, 64, 4, 2048, 1536, 8, 0, "sigmoid", 54, 1, 2 ** 21),    # lfm2-24b
+        (24, 128, 8, 2048, 1024, 4, 1, "sigmoid", 78, 1, 2 ** 21),   # trinity
+        # its two [128, 14336] intermediates are 7 MB: no expert's copy
+        (64, 8, 2, 4096, 14336, 3, 0, "softmax", 53, 1, 2 ** 23),    # mixtral
+        (512, 64, 4, 2048, 1536, 8, 0, "sigmoid", 62, 2, 2 ** 21),
+        (512, 128, 8, 2048, 1024, 4, 1, "sigmoid", 110, 2, 2 ** 21),
+        # [1024, 14336] twice, 29 MB; an expert's copy would be 117 MB
+        (512, 8, 2, 4096, 14336, 3, 0, "softmax", 64, 2, 2 ** 25),
+    ]
+)
+def test_one_expert_layer_is_a_plan_and_three_kernels(
+    one_chip, tokens, experts, top_k, d, f, layers, shared, score, budget,
+    sorts, temp,
+):
+    """One expert layer of a serving program (models/moe.py
+    ``moe_serve_ffn``): three ``gmm`` kernels under that name (the
+    benchmark's reader finds them by it), the dispatch around them a
+    handful of fused operations and not a program of its own: the
+    router's top-k is the one sort of a decode step (a chunk inverts its
+    plan by one more), nothing loops, no cumulative sum stands as an
+    operator of its own, and nothing is as large as a copy of a layer's
+    experts."""
+    import re
+    from unittest import mock
+
+    import jax
+    import jax.numpy as jnp
+
+    from dcos_commons_tpu.models.moe import MoEConfig, moe_serve_ffn
+    from dcos_commons_tpu.ops import grouped_matmul as gm
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    config = MoEConfig(
+        d_model=d, d_ff=f, n_experts=experts, top_k=top_k,
+        dtype=jnp.bfloat16, score=score, expert_bias=score == "sigmoid",
+        n_shared=shared,
+    )
+    routing = {"router": shaped((d, experts), jnp.float32)}
+    if config.expert_bias:
+        routing["expert_bias"] = shaped((experts,), jnp.float32)
+    if shared:
+        routing.update(
+            shared_gate=shaped((d, f)), shared_up=shaped((d, f)),
+            shared_down=shaped((f, d)),
+        )
+    held = {
+        "w_gate": shaped((layers, experts, d, f)),
+        "w_up": shaped((layers, experts, d, f)),
+        "w_down": shaped((layers, experts, f, d)),
+    }
+    with mock.patch.object(gm, "grouped_matmul_kernel", lambda: "compiled"):
+        compiled = jax.jit(
+            lambda routing, held, layer, x, live: moe_serve_ffn(
+                config, routing, held, layer, x, live
+            )
+        ).lower(
+            routing, held, shaped((), jnp.int32), shaped((tokens, d)),
+            shaped((tokens,), jnp.bool_),
+        ).compile()
+    text = compiled.as_text()
+    kernels = re.findall(
+        r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text
+    )
+    assert len(kernels) == 3 and all(k.startswith("gmm") for k in kernels)
+    found = _executed(text)
+    assert found["tpu_custom_call"] == 3
+    assert sum(found.values()) - 3 <= budget, dict(found)
+    assert found["sort"] <= sorts
+    assert not found["while"] and not found["reduce-window"]
+    assert compiled.memory_analysis().temp_size_in_bytes < temp
 
 
 @pytest.mark.parametrize("kind", ["window", "full"])
