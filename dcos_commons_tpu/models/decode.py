@@ -23,7 +23,7 @@ drop-free server by construction.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,21 +49,22 @@ def init_kv_cache(
     config: TransformerConfig, batch: int, max_len: int,
     kv_dtype: str = "native",
 ) -> Dict[str, jax.Array]:
-    shape = (
+    return _kv_leaves(config, kv_dtype, (
         config.n_layers, batch, max_len, config.n_kv_heads,
         config.head_dim,
-    )
-    if kv_dtype == "int8":
-        scale_shape = shape[:-1] + (1,)
-        return {
-            "k": jnp.zeros(shape, jnp.int8),
-            "v": jnp.zeros(shape, jnp.int8),
-            "k_scale": jnp.zeros(scale_shape, jnp.float32),
-            "v_scale": jnp.zeros(scale_shape, jnp.float32),
-        }
+    ))
+
+
+def _kv_leaves(config: TransformerConfig, kv_dtype: str, shape):
+    """Zeroed keys and values of ``shape`` in the serving dtype, or
+    int8 with a float32 scale a vector."""
+    if kv_dtype != "int8":
+        return {name: jnp.zeros(shape, config.dtype) for name in ("k", "v")}
+    scales = shape[:-1] + (1,)
     return {
-        "k": jnp.zeros(shape, config.dtype),
-        "v": jnp.zeros(shape, config.dtype),
+        "k": jnp.zeros(shape, jnp.int8), "v": jnp.zeros(shape, jnp.int8),
+        "k_scale": jnp.zeros(scales, jnp.float32),
+        "v_scale": jnp.zeros(scales, jnp.float32),
     }
 
 
@@ -138,19 +139,7 @@ def init_paged_kv_cache(
             f"{window_pages} window pages (no int8 ring is built: a "
             "ring's scales would need the same second arena)"
         )
-    if kv_dtype == "int8":
-        scale_shape = shape[:-1] + (1,)
-        cache = {
-            "k": jnp.zeros(shape, jnp.int8),
-            "v": jnp.zeros(shape, jnp.int8),
-            "k_scale": jnp.zeros(scale_shape, jnp.float32),
-            "v_scale": jnp.zeros(scale_shape, jnp.float32),
-        }
-    else:
-        cache = {
-            "k": jnp.zeros(shape, config.dtype),
-            "v": jnp.zeros(shape, config.dtype),
-        }
+    cache = _kv_leaves(config, kv_dtype, shape)
     if n_sliding:
         ring = (n_sliding, window_pages) + shape[2:]
         cache["k_window"] = jnp.zeros(ring, config.dtype)
@@ -200,6 +189,39 @@ def _quantize_kv(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
         jnp.round(x.astype(jnp.float32) / scale), -127, 127
     ).astype(jnp.int8)
     return q, scale
+
+
+def _kv_entries(k_new, v_new, quantized: bool):
+    """New keys and values keyed like the cache that takes them: int8
+    with their per-vector scales where it is quantized."""
+    if not quantized:
+        return {"k": k_new, "v": v_new}
+    kq, ks_new = _quantize_kv(k_new)
+    vq, vs_new = _quantize_kv(v_new)
+    return {"k": kq, "v": vq, "k_scale": ks_new, "v_scale": vs_new}
+
+
+def _masked_attention(config, q, keys, values, valid, scales=()):
+    """A step's queries ``q [b, 1, h, hd]`` against each row's ``keys``
+    and ``values [b, L, kv, hd]`` where ``valid [b | 1, 1, L]``: one
+    masked softmax in float32, a grouped contraction against the
+    UNEXPANDED heads (a jnp.repeat to full heads would multiply the
+    bytes streamed a step by h/kv in an HBM-bound loop).  ``scales``:
+    an int8 cache's per-vector ``[b, L, kv]``, K's folded into the
+    scores and V's into the probabilities: the dequantize costs one
+    multiply, never a second pass over the cache bytes."""
+    b, _length, kv, hd = keys.shape
+    qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(b, kv, -1, hd)
+    scores = jnp.einsum("bkrd,blkd->bkrl", qg, keys.astype(jnp.float32))
+    if scales:
+        scores = scores * scales[0].transpose(0, 2, 1)[:, :, None, :]
+    scores = jnp.where(valid[:, :, None, :], scores, _NEG)
+    probs = jax.nn.softmax(scores, axis=-1)
+    if scales:
+        probs = probs * scales[1].transpose(0, 2, 1)[:, :, None, :]
+    return jnp.einsum(
+        "bkrl,blkd->bkrd", probs, values.astype(jnp.float32)
+    ).astype(config.dtype)
 
 
 def _project_kv(config, layer, normed, positions, rope=True):
@@ -301,24 +323,15 @@ def prefill(
         # (capacity pressure is a training behavior), and the decode
         # steps that continue this cache are drop-free too
         x, _counts = _serve_ffn(config, layer, x)
-        # pad the captured K/V out to the static cache length
+        # pad the captured K/V out to the static cache length (scales
+        # share the pad spec: same axes, trailing dim 1)
         pad = [(0, 0), (0, max_len - s), (0, 0), (0, 0)]
-        if kv_dtype == "int8":
-            kq, ks = _quantize_kv(k)
-            vq, vs = _quantize_kv(v)
-            # scales share the pad spec: same axes, trailing dim 1
-            return x, (
-                jnp.pad(kq, pad), jnp.pad(vq, pad),
-                jnp.pad(ks, pad), jnp.pad(vs, pad),
-            )
-        return x, (jnp.pad(k, pad), jnp.pad(v, pad))
+        return x, {
+            name: jnp.pad(new, pad)
+            for name, new in _kv_entries(k, v, kv_dtype == "int8").items()
+        }
 
-    if kv_dtype == "int8":
-        x, (ck, cv, cks, cvs) = lax.scan(layer_fn, x, params["layers"])
-        cache = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
-    else:
-        x, (ck, cv) = lax.scan(layer_fn, x, params["layers"])
-        cache = {"k": ck, "v": cv}
+    x, cache = lax.scan(layer_fn, x, params["layers"])
     x = _norm(config, x, params["final_norm"])
     last = (
         jnp.asarray(true_len, jnp.int32) - 1 if true_len is not None
@@ -391,70 +404,25 @@ def decode_step(
             return buf.at[rows, pos].set(new[:, 0])
         return lax.dynamic_update_slice(buf, new, (0, pos, 0, 0))
 
-    quantized = "k_scale" in cache
-    reps = h // kv
-
-    def _attend(q, ck, cv, ks=None, vs=None):
-        # grouped GQA contraction against the UNEXPANDED cache: a
-        # jnp.repeat to full heads would multiply the cache bytes
-        # streamed per step by h/kv in an HBM-bound loop.
-        # q [b, 1, kv, reps, hd] x K [b, L, kv, hd] -> [b, kv, reps, L]
-        qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(
-            b, kv, reps, hd
-        )
-        scores = jnp.einsum("bkrd,blkd->bkrl", qg, ck.astype(jnp.float32))
-        if ks is not None:
-            # int8 cache: fold the per-vector K scale into the scores
-            # ([b, L, kv, 1] -> [b, kv, 1, L]) and the V scale into
-            # the probabilities — the dequantize costs one multiply,
-            # never a second pass over the cache bytes
-            scores = scores * ks[..., 0].transpose(0, 2, 1)[:, :, None, :]
-        scores = jnp.where(valid[:, :, None, :], scores, _NEG)
-        probs = jax.nn.softmax(scores, axis=-1)
-        if vs is not None:
-            probs = probs * vs[..., 0].transpose(0, 2, 1)[:, :, None, :]
-        return jnp.einsum(
-            "bkrl,blkd->bkrd", probs, cv.astype(jnp.float32)
-        ).astype(config.dtype)
-
     def layer_fn(x, inputs):
-        if quantized:
-            layer, ck, cv, cks, cvs = inputs
-        else:
-            layer, ck, cv = inputs
-            cks = cvs = None
+        layer, entry = inputs                    # the layer's cache
         normed = _norm(config, x, layer["attn_norm"])
         q, k_new, v_new = _project_kv(config, layer, normed, positions)
-        if quantized:
-            kq, ks_new = _quantize_kv(k_new)
-            vq, vs_new = _quantize_kv(v_new)
-            ck = _cache_write(ck, kq)
-            cv = _cache_write(cv, vq)
-            cks = _cache_write(cks, ks_new)
-            cvs = _cache_write(cvs, vs_new)
-        else:
-            ck = _cache_write(ck, k_new)
-            cv = _cache_write(cv, v_new)
-        attn = _attend(q, ck, cv, cks, cvs)
+        new = _kv_entries(k_new, v_new, "k_scale" in entry)
+        entry = {
+            name: _cache_write(buf, new[name]) for name, buf in entry.items()
+        }
+        attn = _masked_attention(
+            config, q, entry["k"], entry["v"], valid, [
+                entry[name][..., 0] for name in ("k_scale", "v_scale")
+                if name in entry
+            ],
+        )
         x = x + attn.reshape(b, 1, h * hd) @ dq(layer["wo"], x.dtype)
         x, _counts = _serve_ffn(config, layer, x)
-        if quantized:
-            return x, (ck, cv, cks, cvs)
-        return x, (ck, cv)
+        return x, entry
 
-    if quantized:
-        x, (ck, cv, cks, cvs) = lax.scan(
-            layer_fn,
-            x,
-            (params["layers"], cache["k"], cache["v"],
-             cache["k_scale"], cache["v_scale"]),
-        )
-        new_cache = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
-    else:
-        x, (ck, cv) = lax.scan(
-            layer_fn, x, (params["layers"], cache["k"], cache["v"])
-        )
-        new_cache = {"k": ck, "v": cv}
+    x, new_cache = lax.scan(layer_fn, x, (params["layers"], cache))
     x = _norm(config, x, params["final_norm"])
     return _last_logits(config, params, x[:, 0]), new_cache
 
@@ -568,31 +536,102 @@ def layer_plan(kinds) -> Tuple[int, int, int, int]:
     return best[1]
 
 
-# the window layers' arena, beside the full layers' ``k`` / ``v``
-_WINDOW_ARENA = ("k_window", "v_window")
+class Part(NamedTuple):
+    """An attention class's share of ONE layer of a serving program:
+    ``attend(arena, layer, base, q, k_new, v_new) -> (attn, arena)``
+    writes what ``rows`` rows of the layer's operand keep of their new
+    keys and values and attends for them.  ``arena``: the layer kind's
+    merged arena (``_scan_layers_over_arena``), ``base`` the layer's
+    first page in it; ``q``, ``k_new``, ``v_new``: the rows'
+    projections ``[rows, heads, hd]``, or, where the operand is the
+    part's alone, as they stand (``[1, rows, ..]`` of a chunk,
+    ``[rows, 1, ..]`` of a step: ``_rows``), so nothing is traced to
+    lay them out twice; ``attn``: ``[rows, h, hd]`` in whatever shape.
+
+    The part owns the class's cache: its region of the row's table,
+    write indexes, masks, the entries' format, the rule that chooses a
+    kernel.  Norm, projection and residual are ``_attention_layer``'s,
+    so a new class (a latent cache, a selector's keys) is a cache spec
+    and two parts, a chunk's and a step's (docs/developer-guide.md).
+    ``scope``: the profile scope of the layer's residual where it is
+    not the kind's own."""
+
+    rows: int
+    attend: Callable
+    scope: str = ""
 
 
-def _walk_pattern(config, params, cache, x, attend, convolve, live,
-                  attend_window=None):
+# an attention layer by its kind: the profile scope of its norm,
+# projections and residual, and what the cache adds to the names its
+# parts know their arena's leaves by (``k``, ``v``, an int8 arena's
+# scales)
+_KINDS = {
+    "attention": dict(scope="attention", suffix=""),
+    "sliding": dict(scope="attention_window", suffix="_window"),
+}
+
+
+def _rows(a: jax.Array, unit_axis: Optional[int] = None) -> jax.Array:
+    """A part's rows ``[n, heads, hd]`` of a projection that reached it
+    flat or as it stood (``Part``): the entry of its ``unit_axis`` or,
+    given none, one reshape (each program keeps the form it had)."""
+    if a.ndim == 3:
+        return a
+    if unit_axis is None:
+        return a.reshape((-1,) + a.shape[2:])
+    return a[0] if unit_axis == 0 else a[:, 0]
+
+
+def _attention_layer(config, kind, parts, x, positions, arena, layer, base):
+    """One attention layer of a serving program, its only spelling:
+    norm -> q/k/v (``_project_kv``; a full layer of a pattern without
+    position encoding rotates nothing) -> each of ``parts`` on its rows
+    of ``x [B, S, d]``, in order -> gate / ``wo`` / second norm ->
+    residual (``_attention_residual``).  Norms and projections take ALL
+    of ``x`` as one operand, so each weight is read once however many
+    parts share the layer: a chunk alone, a step alone, or a chunk
+    with a step riding behind it (whose rows' pages are other rows'
+    than the chunk's).  Returns (x, arena)."""
+    width = config.n_heads * config.head_dim
+    scope, alone = _KINDS[kind]["scope"], len(parts) == 1
+    with jax.named_scope(scope):
+        normed = _norm(config, x, layer["attn_norm"])
+        qkv = _project_kv(
+            config, layer, normed, positions,
+            rope=kind != "attention" or not config.nope_full_attention,
+        )
+        if not alone:
+            qkv = [_rows(a) for a in qkv]
+    outs, lo = [], 0
+    for part in parts:
+        out, arena = part.attend(arena, layer, base, *(
+            qkv if alone else [a[lo:lo + part.rows] for a in qkv]
+        ))
+        outs.append(out if alone else out.reshape(part.rows, width))
+        lo += part.rows
+    with jax.named_scope(parts[0].scope or scope):
+        attn = outs[0] if alone else jnp.concatenate(outs)
+        x = _attention_residual(
+            config, layer, x, normed, attn.reshape(x.shape[:2] + (width,))
+        )
+    return x, arena
+
+
+def _walk_pattern(config, params, cache, x, positions, parts, convolve,
+                  live):
     """Run ``x`` through a MIXED layer pattern (``layer_plan``) over
-    the cache: ``attend(x, arena, layer, base) -> (x, arena)`` is the
-    attention operator over the merged arena (``base = a * n_pages``
-    for the ``a``-th ATTENTION layer: only those own pages),
-    ``attend_window`` the same of a "sliding" layer over the window
-    layers' merged arena (under the names ``k`` / ``v``; the ``a``-th
-    such layer's first page its ``base``),
+    the cache: an attention layer of either kind is
+    ``_attention_layer`` with ``parts[kind]`` over the kind's merged
+    arena (``_KINDS``; ``base = a * n_pages`` for the ``a``-th layer of
+    the kind: only those own its pages),
     ``convolve(x, conv_state, layer, c) -> (x, conv_state)`` the conv
-    operator of the ``c``-th conv layer.  Arena and conv state are the
+    operator of the ``c``-th conv layer.  Arenas and conv state are the
     scan's carry, never scanned arrays (``_scan_layers_over_arena``
     says why); a layer's weights are read at its index of its part's
     stack (``init_params``), the experts in place.  Returns (x, the
     cache in its stored layout, the mixtures' counts summed)."""
     kinds = config.layer_kinds
     lead, period, reps, _tail = layer_plan(kinds)
-    n_pages = cache["k"].shape[1]
-    n_window_pages = (
-        cache["k_window"].shape[1] if "k_window" in cache else 0
-    )
     state = {
         name: arr if name == "conv_state"
         else arr.reshape((-1,) + arr.shape[2:])
@@ -625,24 +664,23 @@ def _walk_pattern(config, params, cache, x, attend, convolve, live,
         op, ffn = kinds[l]
         op_i = index[l][0] + trip * stride[op]
         ffn_i = index[l][1] + trip * stride[ffn]
-        if op == "attention":
-            arena = {
-                k: v for k, v in state.items()
-                if k != "conv_state" and k not in _WINDOW_ARENA
-            }
-            x, arena = attend(x, arena, at(stacks[op], op_i), op_i * n_pages)
-            state = dict(state, **arena)
-        elif op == "sliding":
-            arena = {"k": state["k_window"], "v": state["v_window"]}
-            x, arena = attend_window(
-                x, arena, at(stacks[op], op_i), op_i * n_window_pages
-            )
-            state = dict(state, k_window=arena["k"], v_window=arena["v"])
-        else:
+        if op == "conv":
             x, conv = convolve(
                 x, state["conv_state"], at(stacks[op], op_i), op_i
             )
             state = dict(state, conv_state=conv)
+        else:
+            suffix = _KINDS[op]["suffix"]
+            x, arena = _attention_layer(
+                config, op, parts[op], x, positions, {
+                    name: state[name + suffix]
+                    for name in ("k", "v", "k_scale", "v_scale")
+                    if name + suffix in state
+                }, at(stacks[op], op_i), op_i * cache["k" + suffix].shape[1],
+            )
+            state = dict(
+                state, **{name + suffix: arr for name, arr in arena.items()}
+            )
         x, c = _serve_ffn(
             config, at(stacks[ffn], ffn_i), x, live, experts, ffn_i
         )
@@ -665,6 +703,42 @@ def _walk_pattern(config, params, cache, x, attend, convolve, live,
     return x, {
         name: arr.reshape(cache[name].shape) for name, arr in state.items()
     }, counts
+
+
+def _serving_trunk(config, params, cache, x, positions, parts, convolve,
+                   live):
+    """``x [B, S, d]`` at ``positions [B, S]`` through every layer over
+    the cache, for both serving programs of every family: ``parts`` by
+    layer kind (``{"attention": [..], "sliding": [..]}``, each list
+    covering ``x``'s ``B * S`` rows in order: ``Part``), ``convolve``
+    the conv operator, ``live [B * S]`` the rows that stand for
+    something.  The ONE place that chooses how the layers are driven: a
+    scan over the one stack where every layer is of one kind,
+    ``_walk_pattern`` over a pattern.  Returns (x, the cache in its
+    stored layout, the mixtures' counts summed or None)."""
+    if not config.one_kind:
+        return _walk_pattern(
+            config, params, cache, x, positions, parts, convolve, live
+        )
+    n_pages = cache["k"].shape[1]
+    experts, scanned = _held_experts(params["layers"])
+
+    def layer_fn(carry, inputs):
+        layer, base = inputs
+        x, arena = _attention_layer(
+            config, "attention", parts["attention"], carry[0], positions,
+            carry[1], layer, base,
+        )
+        # a dense block asks for no place among held experts
+        x, counts = _serve_ffn(
+            config, layer, x, live, experts, base // n_pages if experts else 0
+        )
+        return (x, arena), counts
+
+    x, new_cache, counts = _scan_layers_over_arena(
+        layer_fn, x, scanned, cache
+    )
+    return x, new_cache, None if counts is None else counts.sum(0)
 
 
 def _conv_gates(config: TransformerConfig, layer, x):
@@ -739,22 +813,41 @@ def _conv_step_operator(config: TransformerConfig, live):
     return convolve
 
 
-def _kv_entries(
-    k_new: jax.Array, v_new: jax.Array, quantized: bool, lanes: int
-) -> Dict[str, jax.Array]:
-    """What one layer writes into the arena, keyed like the cache:
-    the new K/V rows, widened with zeros to the arena's ``lanes``
-    (``arena_lanes``), int8 with their per-vector scales when the
-    arena is quantized."""
-    short = lanes - k_new.shape[-1]
-    if short:
-        pad = [(0, 0)] * (k_new.ndim - 1) + [(0, short)]
-        k_new, v_new = jnp.pad(k_new, pad), jnp.pad(v_new, pad)
-    if not quantized:
-        return {"k": k_new, "v": v_new}
-    kq, ks_new = _quantize_kv(k_new)
-    vq, vs_new = _quantize_kv(v_new)
-    return {"k": kq, "v": vq, "k_scale": ks_new, "v_scale": vs_new}
+def _write_rows(arena, base, pages, offset, k_new, v_new):
+    """``arena`` with the rows' new K/V ``[n, kv, hd]`` at entry
+    ``offset [n]`` of the layer's ``pages [n]`` (``base`` its first):
+    widened with zeros to the arena's lanes (``arena_lanes``), int8
+    with their per-vector scales where the arena is quantized."""
+    with jax.named_scope("kv_write"):
+        short = arena["k"].shape[-1] - k_new.shape[-1]
+        if short:
+            pad = [(0, 0)] * (k_new.ndim - 1) + [(0, short)]
+            k_new, v_new = jnp.pad(k_new, pad), jnp.pad(v_new, pad)
+        new = _kv_entries(k_new, v_new, "k_scale" in arena)
+        # one sum a leaf: the programs' equations are held to what they
+        # were (tests/test_serving_programs.py)
+        return {
+            name: arr.at[base + pages, offset].set(new[name])
+            for name, arr in arena.items()
+        }
+
+
+def _gather_pages(arena, base, pages, b: int, hd: int):
+    """The entries of the layer's ``pages`` (``base`` its first; ``b``
+    rows' tables or one) in their order: keys and values ``[b, L, kv,
+    hd]`` and an int8 arena's two scales ``[b, L, kv]``."""
+    kv, lanes = arena["k"].shape[-2:]
+    with jax.named_scope("paged_gather"):
+        pages = base + pages
+        keys, values = (
+            arena[name][pages].reshape(b, -1, kv, lanes)[..., :hd]
+            for name in ("k", "v")
+        )
+        scales = [
+            arena[name][pages].reshape(b, -1, kv)
+            for name in ("k_scale", "v_scale") if name in arena
+        ]
+    return keys, values, scales
 
 
 # the most positions of history a prefill chunk scores at once; a
@@ -799,7 +892,7 @@ def _attention_block_pages(pages: int, page_tokens: int,
 
 def _attend_blocks(q, arena, pages, block, n_blocks, key_pos, q_pos, end,
                    window, hd):
-    """A chunk's queries ``q [1, c, h, hd]`` (in the serving dtype,
+    """A chunk's queries ``q [.., c, h, hd]`` (in the serving dtype,
     unscaled) against the entries of ``pages [n]`` (arena rows), read
     ``block`` pages at a time under ONE online softmax
     (``_softmax_block``); only the first ``n_blocks`` blocks (traced)
@@ -808,7 +901,7 @@ def _attend_blocks(q, arena, pages, block, n_blocks, key_pos, q_pos, end,
     sees the keys at positions ``j`` with ``q_pos[i] - window < j <=
     q_pos[i]`` and ``0 <= j < end``.  Returns ``[1, c, kv, reps, hd]``
     float32."""
-    _b, c, h, _hd = q.shape
+    c, h = q.shape[-3:-1]
     p_tok, kv = arena["k"].shape[1:3]
     reps = h // kv
     qg = q.reshape(1, c, kv, reps, hd)
@@ -833,130 +926,41 @@ def _attend_blocks(q, arena, pages, block, n_blocks, key_pos, q_pos, end,
     return acc / norm[..., None]
 
 
-def paged_prefill_chunk(
-    config: TransformerConfig,
-    params: Params,
-    cache: Dict[str, jax.Array],
-    tokens: jax.Array,
-    table: jax.Array,
-    start: jax.Array,
-    true_len: jax.Array,
-    slot: jax.Array = 0,
-    riders: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
-    ring_pages: int = 0,
-) -> Tuple[jax.Array, Dict[str, jax.Array], Optional[jax.Array]]:
-    """One CHUNK of a prompt through the trunk into a paged arena.
-
-    ``tokens [1, C]`` carries up to C prompt tokens at virtual
-    positions ``[start, start + true_len)`` of one request whose page
-    table is ``table [M]`` (physical page per virtual page; 0 =
-    unallocated).  K/V for the chunk is scattered through the table
-    (pad positions land in the trash page), then each chunk query
-    attends to EVERY earlier virtual position — prior chunks' pages,
-    prefix-cache pages, and the in-chunk causal prefix — gathered
-    through the same table.  Returns (logits at the chunk's last real
-    position [1, vocab] f32, updated cache, the mixtures' counts as
-    ``paged_decode_step`` returns them).
-
-    ``start``, ``true_len`` and ``slot`` are TRACED: one compile covers
-    every chunk of every prompt — a request resuming at position k*P
-    after a prefix-cache hit runs the same program as one starting at
-    0.  ``slot`` is the row's place among the pool's rows: what a
-    pattern with conv layers keeps of a row outside its pages lives
-    there (``init_paged_kv_cache``), and a chunk with ``start == 0``
-    begins it from zeros; no other model reads it.
-    This is the chunked-prefill entry: a long prompt costs several
-    SMALL dispatches interleaved with decode ticks instead of one
-    prompt-wide dispatch that blocks the pool (head-of-line TTFT).
-    A mixture computes for the true positions alone: the padding
-    reaches no expert.
-
-    ``riders`` is a decode step over the whole pool, ``(token [S],
-    pos [S], tables [S, M])`` as ``paged_decode_step`` takes them, that
-    rides in this program (``chunk_carries_riders``): its rows go
-    through each layer's weights in one operand with the chunk's
-    positions, so the weights are read once for both, and attend and
-    write as the step alone would (their pages are other rows' than
-    the chunk's).  The result is then a 4-tuple, the riders' logits
-    ``[S, vocab]`` f32 last: what the chunk alone followed by
-    ``paged_decode_step`` gives.
-
-    ``ring_pages`` (static; serve/paging.py RowLayout): where the
-    pattern has window layers, the first ``ring_pages`` entries of
-    ``table`` are the row's ring in the window layers' arena and the
-    rest the full layers' history.  Each kind writes the chunk's K/V
-    into its own cache and attends through it: a window layer over its
-    ring alone (keys are stored rotated, so the ring's entries need no
-    order: what an entry holds follows from its place and the row's
-    length), a full layer over the history.  Where the history is
-    longer than ``CHUNK_ATTENTION_BLOCK`` positions it is attended a
-    block of pages at a time under one running softmax, as far as the
-    row reaches and no further: a score tensor of ``[chunk, MAX_LEN]``
-    is never built.
-    """
-    b, c = tokens.shape
-    if b != 1:
-        raise ValueError(f"prefill chunks are per-request, got batch {b}")
-    if riders is not None and not chunk_carries_riders(config):
-        raise NotImplementedError(
-            f"no layer of attention {config.attention!r} runs a decode "
-            "step's rows beside a chunk's positions"
-        )
-    if config.attention == "eva":
-        out = _eva_prefill_chunk(
-            config, params, cache, tokens, table, start, true_len, riders
-        )
-        return out[:2] + (None,) + out[2:]
+def _full_chunk_part(config, cache, history, abs_pos, offset, live, end):
+    """A prefill chunk's part of a full-attention layer (``Part``): the
+    chunk's K/V is scattered through ``history [m]``, the full layers'
+    entries of the row's table (pad positions, not ``live``, into the
+    trash page: never into a page a later chunk attends to), then each
+    query at ``abs_pos`` attends to EVERY earlier position through the
+    same entries: prior chunks' pages, prefix-cache pages and the
+    in-chunk causal prefix ride one path.  ``offset``: each position's
+    place in its page; ``end``: the positions written once this chunk
+    is.  A history longer than ``CHUNK_ATTENTION_BLOCK`` positions is
+    attended a block of pages at a time under one running softmax, as
+    far as the row reaches: no ``[chunk, MAX_LEN]`` scores are built
+    (an int8 arena's scales have no blocked form: one softmax)."""
     h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
-    p_tok, lanes = cache["k"].shape[2], cache["k"].shape[-1]
-    ring = _ring_pages(config, ring_pages)
-    # the full layers' entries
-    history = table[ring:] if ring else table
-    m = history.shape[0]
+    p_tok, reps = cache["k"].shape[2], h // kv
+    c, m = abs_pos.shape[0], history.shape[0]
     length = m * p_tok
-    quantized = "k_scale" in cache
-    reps = h // kv
-    start = jnp.asarray(start, jnp.int32)
-    true_len = jnp.asarray(true_len, jnp.int32)
-    offs = jnp.arange(c, dtype=jnp.int32)
-    abs_pos = start + offs                       # [c] virtual positions
-    positions = abs_pos[None, :]                 # [1, c]
-    vpage = jnp.minimum(abs_pos // p_tok, m - 1)
-    live = offs < true_len
-    # pad positions (>= true_len) scatter into the trash page: their
-    # K/V must never land in a real page a later chunk would attend to
-    phys = jnp.where(live, history[vpage], 0)
-    slot_off = abs_pos % p_tok
-    # a long history goes a block at a time (an int8 arena's scales
-    # have no blocked form: it keeps the one softmax)
+    phys = jnp.where(
+        live, history[jnp.minimum(abs_pos // p_tok, m - 1)], 0
+    )
     block = _attention_block_pages(m, p_tok)
-    blocked = block < m and not quantized
+    blocked = block < m and "k_scale" not in cache
     if not blocked:
         # causal across the whole virtual sequence: key position <=
-        # query position — covers prior chunks, cached prefix pages, and
-        # the in-chunk prefix in one mask; unallocated pages sit past
-        # every valid query and mask out
+        # query position; unallocated pages sit past every valid query
+        # and mask out
         valid = (
             lax.broadcasted_iota(jnp.int32, (c, length), 1)
             <= abs_pos[:, None]
         )                                        # [c, L]
-    x = _embed(config, params, tokens)
-    end = start + true_len                       # positions written so far
 
-    def attend(x, arena, layer, base):
-        """The attention operator; ``base``: the layer's first page."""
-        with jax.named_scope("attention"):
-            normed = _norm(config, x, layer["attn_norm"])
-            q, k_new, v_new = _project_kv(
-                config, layer, normed, positions,
-                rope=not config.nope_full_attention,
-            )
-        with jax.named_scope("kv_write"):
-            new = _kv_entries(k_new[0], v_new[0], quantized, lanes)
-            arena = {
-                name: arr.at[base + phys, slot_off].set(new[name])
-                for name, arr in arena.items()
-            }
+    def attend(arena, layer, base, q, k_new, v_new):
+        arena = _write_rows(
+            arena, base, phys, offset, _rows(k_new, 0), _rows(v_new, 0)
+        )
         if blocked:
             with jax.named_scope("attention_full"):
                 attn = _attend_blocks(
@@ -964,20 +968,8 @@ def paged_prefill_chunk(
                     -(-end // (block * p_tok)),
                     lambda index: index, abs_pos, end, length, hd,
                 ).astype(config.dtype)
-                x = _attention_residual(
-                    config, layer, x, normed, attn.reshape(1, c, h * hd)
-                )
-            return x, arena
-        # gather the request's whole virtual sequence through the
-        # table (scatter-then-gather: in-chunk keys ride the same
-        # path as prior pages — one attention covers both)
-        with jax.named_scope("paged_gather"):
-            pages = base + history
-            k_all = arena["k"][pages].reshape(1, length, kv, lanes)[..., :hd]
-            v_all = arena["v"][pages].reshape(1, length, kv, lanes)[..., :hd]
-            if quantized:
-                ks_all = arena["k_scale"][pages].reshape(1, length, kv)
-                vs_all = arena["v_scale"][pages].reshape(1, length, kv)
+            return attn, arena
+        k_all, v_all, scales = _gather_pages(arena, base, history, 1, hd)
         with jax.named_scope("attention"):
             qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(
                 1, c, kv, reps, hd
@@ -985,310 +977,150 @@ def paged_prefill_chunk(
             scores = jnp.einsum(
                 "bqkrd,blkd->bqkrl", qg, k_all.astype(jnp.float32)
             )
-            if quantized:
-                scores = (
-                    scores * ks_all.transpose(0, 2, 1)[:, None, :, None, :]
-                )
-            scores = jnp.where(
-                valid[None, :, None, None, :], scores, _NEG
-            )
+            if scales:
+                scores *= scales[0].transpose(0, 2, 1)[:, None, :, None, :]
+            scores = jnp.where(valid[None, :, None, None, :], scores, _NEG)
             probs = jax.nn.softmax(scores, axis=-1)
-            if quantized:
-                probs = (
-                    probs * vs_all.transpose(0, 2, 1)[:, None, :, None, :]
-                )
+            if scales:
+                probs *= scales[1].transpose(0, 2, 1)[:, None, :, None, :]
             attn = jnp.einsum(
                 "bqkrl,blkd->bqkrd", probs, v_all.astype(jnp.float32)
             ).astype(config.dtype)
-            x = _attention_residual(
-                config, layer, x, normed, attn.reshape(1, c, h * hd)
-            )
-        return x, arena
+        return attn, arena
 
-    attend_window = None
-    if ring:
-        window = config.sliding_window
-        ring_table = table[:ring]
-        ring_phys = jnp.where(live, ring_table[(abs_pos // p_tok) % ring], 0)
-        ring_block = _attention_block_pages(ring, p_tok, c)
-        # what the ring holds once this chunk's true positions are written
-        ring_pos = functools.partial(
-            _ring_positions, last_page=(end - 1) // p_tok, ring=ring,
-            page_tokens=p_tok,
-        )
-
-        def attend_window(x, arena, layer, base):
-            """A window layer: RoPE, the ring, the last ``window``
-            positions; ``base``: the layer's first ring page."""
-            with jax.named_scope("attention_window"):
-                normed = _norm(config, x, layer["attn_norm"])
-                q, k_new, v_new = _project_kv(
-                    config, layer, normed, positions
-                )
-            with jax.named_scope("kv_write"):
-                new = _kv_entries(k_new[0], v_new[0], False, lanes)
-                arena = {
-                    name: arr.at[base + ring_phys, slot_off].set(new[name])
-                    for name, arr in arena.items()
-                }
-            with jax.named_scope("attention_window"):
-                # a ring that has not wrapped holds its first entries
-                attn = _attend_blocks(
-                    q, arena, base + ring_table, ring_block,
-                    jnp.minimum(
-                        -(-end // (ring_block * p_tok)), ring // ring_block
-                    ),
-                    ring_pos, abs_pos, end, window, hd,
-                ).astype(config.dtype)
-                x = _attention_residual(
-                    config, layer, x, normed, attn.reshape(1, c, h * hd)
-                )
-            return x, arena
-
-    if config.one_kind:
-        n_pages = cache["k"].shape[1]
-        experts, scanned = _held_experts(params["layers"])
-
-        def layer_fn(carry, inputs):
-            layer, base = inputs
-            x, arena = attend(*carry, layer, base)
-            x, counts = _serve_ffn(
-                config, layer, x, live, experts, base // n_pages
-            )
-            return (x, arena), counts
-
-        x, new_cache, counts = _scan_layers_over_arena(
-            layer_fn, x, scanned, cache
-        )
-        counts = None if counts is None else counts.sum(0)
-    else:
-        x, new_cache, counts = _walk_pattern(
-            config, params, cache, x, attend, _conv_chunk_operator(
-                config, start, true_len, jnp.asarray(slot, jnp.int32)
-            ), live, attend_window,
-        )
-    with jax.named_scope("logits"):
-        x = _norm(config, x, params["final_norm"])
-        x_last = lax.dynamic_index_in_dim(
-            x, true_len - 1, axis=1, keepdims=False
-        )
-        logits = _last_logits(config, params, x_last)
-    return logits, new_cache, counts
+    return Part(c, attend, "attention_full" if blocked else "")
 
 
-def paged_decode_step(
-    config: TransformerConfig,
-    params: Params,
-    cache: Dict[str, jax.Array],
-    token: jax.Array,
-    pos: jax.Array,
-    tables: jax.Array,
-    ring_pages: int = 0,
-) -> Tuple[jax.Array, Dict[str, jax.Array], Optional[jax.Array]]:
-    """One autoregressive step over the whole pool, KV indirected
-    through per-row page tables: ``token [S]`` at per-row positions
-    ``pos [S]``, ``tables [S, M]`` mapping each row's virtual pages to
-    arena pages -> (logits [S, vocab] f32, updated cache, counts).
-
-    The row's new K/V is scattered to ``(tables[s, pos // P],
-    pos % P)`` — inactive rows (all-zero tables) write identical
-    values into the trash page — and attention gathers each row's
-    pages back into virtual order, so the masked-softmax math is
-    element-for-element ``decode_step``'s with ``max_len = M * P``.
-
-    A row whose table is all zeros holds no live request: it reaches no
-    expert of a mixture and leaves its slot's conv state as it was
-    (the slot may belong to a row that is still prefilling).
-    ``counts`` is int32 ``[2]``: the live (token, expert) assignments
-    the step's mixtures routed and the expert groups that held at
-    least one, summed over the expert layers; None where the model
-    routes nothing.
-
-    ``ring_pages`` as ``paged_prefill_chunk`` takes it: a window layer
-    writes the row's new K/V into its ring and reads the last
-    ``sliding_window`` positions from it (the page walk of
-    ops/paged_decode.py over the ring's pages in virtual order, with a
-    lower bound on the entries that count), a full layer the whole
-    history behind the ring's entries."""
-    if config.attention == "eva":
-        return _eva_decode_step(
-            config, params, cache, token, pos, tables
-        ) + (None,)
-    from dcos_commons_tpu.ops.paged_decode import (
-        paged_decode_attention,
-        window_decode_attention,
+def _window_chunk_part(config, cache, ring_table, abs_pos, offset, live,
+                       end):
+    """A prefill chunk's part of a window layer, as ``_full_chunk_part``
+    a full layer's: K/V into the row's ring ``ring_table [ring]`` in
+    the window layers' arena, attention over the ring alone, the last
+    ``sliding_window`` positions.  Keys are stored rotated, so the
+    ring's entries need no order: what an entry holds follows from its
+    place and the row's length (``_ring_positions``)."""
+    hd, p_tok = config.head_dim, cache["k_window"].shape[2]
+    c, ring = abs_pos.shape[0], ring_table.shape[0]
+    ring_phys = jnp.where(live, ring_table[(abs_pos // p_tok) % ring], 0)
+    ring_block = _attention_block_pages(ring, p_tok, c)
+    # what the ring holds once this chunk's true positions are written
+    ring_pos = functools.partial(
+        _ring_positions, last_page=(end - 1) // p_tok, ring=ring,
+        page_tokens=p_tok,
     )
 
-    b = token.shape[0]
-    h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    def attend(arena, layer, base, q, k_new, v_new):
+        arena = _write_rows(
+            arena, base, ring_phys, offset, _rows(k_new, 0), _rows(v_new, 0)
+        )
+        with jax.named_scope("attention_window"):
+            # a ring that has not wrapped holds its first entries
+            attn = _attend_blocks(
+                q, arena, base + ring_table, ring_block,
+                jnp.minimum(
+                    -(-end // (ring_block * p_tok)), ring // ring_block
+                ),
+                ring_pos, abs_pos, end, config.sliding_window, hd,
+            ).astype(config.dtype)
+        return attn, arena
+
+    return Part(c, attend)
+
+
+def _full_step_part(config, cache, history, pos, rows, offset, live):
+    """A decode step's part of a full-attention layer (``Part``): row
+    ``s`` (``rows`` counts them) writes its new K/V at ``(history[s,
+    pos // P], offset)`` (a row that is not ``live``, its table all
+    zeros, into the trash page) and attends to its whole history: the
+    page walk of ops/paged_decode.py over the row's live pages in
+    place, or (``decode_attention_kernel``) every row's pages gathered
+    into virtual order and one masked softmax, element for element
+    ``decode_step``'s with ``max_len = M * P``."""
+    from dcos_commons_tpu.ops.paged_decode import paged_decode_attention
+
+    hd, (b, m) = config.head_dim, history.shape
     p_tok, lanes = cache["k"].shape[2], cache["k"].shape[-1]
-    ring = _ring_pages(config, ring_pages)
-    # the full layers' entries
-    history = tables[:, ring:] if ring else tables
-    m = history.shape[1]
-    length = m * p_tok
-    x = _embed(config, params, token)[:, None, :]
-    pos = jnp.asarray(pos, jnp.int32)
-    positions = pos[:, None]
-    rows = jnp.arange(b)
-    vpage = jnp.minimum(pos // p_tok, m - 1)
-    phys = history[rows, vpage]                  # [b]
-    slot_off = pos % p_tok
-    # a live row holds at least its first page
-    live = tables[:, 0] > 0
-    quantized = "k_scale" in cache
-    reps = h // kv
+    phys = history[rows, jnp.minimum(pos // p_tok, m - 1)]      # [b]
     kernel = decode_attention_kernel(config, cache)
     if not kernel:
         valid = (
-            lax.broadcasted_iota(jnp.int32, (1, 1, length), 2)
+            lax.broadcasted_iota(jnp.int32, (1, 1, m * p_tok), 2)
             <= pos[:, None, None]
         )                                        # [b, 1, L]
 
-    def attend(x, arena, layer, base):
-        """The attention operator; ``base``: the layer's first page."""
-        with jax.named_scope("attention"):
-            normed = _norm(config, x, layer["attn_norm"])
-            q, k_new, v_new = _project_kv(
-                config, layer, normed, positions,
-                rope=not config.nope_full_attention,
-            )
-        with jax.named_scope("kv_write"):
-            new = _kv_entries(k_new[:, 0], v_new[:, 0], quantized, lanes)
-            arena = {
-                name: arr.at[base + phys, slot_off].set(new[name])
-                for name, arr in arena.items()
-            }
+    def attend(arena, layer, base, q, k_new, v_new):
+        arena = _write_rows(
+            arena, base, phys, offset, _rows(k_new, 1), _rows(v_new, 1)
+        )
         if kernel:
             # the row's new K/V is read back through the page it was
             # just written into
-            with jax.named_scope("attention_full" if ring else "attention"):
-                attn = paged_decode_attention(
-                    jnp.pad(q[:, 0], ((0, 0), (0, 0), (0, lanes - hd))),
+            with jax.named_scope(
+                "attention_full" if config.n_layers_of("sliding")
+                else "attention"
+            ):
+                return paged_decode_attention(
+                    jnp.pad(_rows(q, 1), ((0, 0), (0, 0), (0, lanes - hd))),
                     arena["k"], arena["v"], base + history, pos, live,
                     scale=hd ** -0.5, interpret=kernel == "interpret",
-                )[..., :hd]
-        else:
-            with jax.named_scope("paged_gather"):
-                pages = base + history
-                k_all = arena["k"][pages].reshape(
-                    b, length, kv, lanes
-                )[..., :hd]
-                v_all = arena["v"][pages].reshape(
-                    b, length, kv, lanes
-                )[..., :hd]
-                if quantized:
-                    ks_all = arena["k_scale"][pages].reshape(b, length, kv)
-                    vs_all = arena["v_scale"][pages].reshape(b, length, kv)
-            with jax.named_scope("attention"):
-                qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(
-                    b, kv, reps, hd
-                )
-                scores = jnp.einsum(
-                    "bkrd,blkd->bkrl", qg, k_all.astype(jnp.float32)
-                )
-                if quantized:
-                    scores = (
-                        scores * ks_all.transpose(0, 2, 1)[:, :, None, :]
-                    )
-                scores = jnp.where(valid[:, :, None, :], scores, _NEG)
-                probs = jax.nn.softmax(scores, axis=-1)
-                if quantized:
-                    probs = probs * vs_all.transpose(0, 2, 1)[:, :, None, :]
-                attn = jnp.einsum(
-                    "bkrl,blkd->bkrd", probs, v_all.astype(jnp.float32)
-                ).astype(config.dtype)
+                )[..., :hd], arena
+        k_all, v_all, scales = _gather_pages(arena, base, history, b, hd)
         with jax.named_scope("attention"):
-            x = _attention_residual(
-                config, layer, x, normed, attn.reshape(b, 1, h * hd)
-            )
-        return x, arena
+            return _masked_attention(
+                config, q, k_all, v_all, valid, scales
+            ), arena
 
-    attend_window = None
-    if ring:
-        window = config.sliding_window
-        ring_tables = tables[:, :ring]
-        ring_phys = ring_tables[rows, (pos // p_tok) % ring]
-        if not kernel:
-            # the position each ring entry of each row holds once the
-            # row's new K/V is written (``paged_prefill_chunk``)
-            held = _ring_positions(
-                jnp.arange(ring * p_tok, dtype=jnp.int32)[None, :],
-                (pos // p_tok)[:, None], ring, p_tok,
-            )
-            seen = (
-                (held <= pos[:, None]) & (held > pos[:, None] - window)
-                & (held >= 0)
-            )[:, None, :]                        # [b, 1, ring * P]
+    return Part(b, attend)
 
-        def attend_window(x, arena, layer, base):
-            """A window layer: RoPE, the ring, the last ``window``
-            positions; ``base``: the layer's first ring page."""
-            with jax.named_scope("attention_window"):
-                normed = _norm(config, x, layer["attn_norm"])
-                q, k_new, v_new = _project_kv(
-                    config, layer, normed, positions
-                )
-            with jax.named_scope("kv_write"):
-                new = _kv_entries(k_new[:, 0], v_new[:, 0], False, lanes)
-                arena = {
-                    name: arr.at[base + ring_phys, slot_off].set(new[name])
-                    for name, arr in arena.items()
-                }
-            with jax.named_scope("attention_window"):
-                if kernel:
-                    attn = window_decode_attention(
-                        jnp.pad(q[:, 0], ((0, 0), (0, 0), (0, lanes - hd))),
-                        arena["k"], arena["v"], base + ring_tables, pos,
-                        live, window=window, scale=hd ** -0.5,
-                        interpret=kernel == "interpret",
-                    )[..., :hd]
-                else:
-                    with jax.named_scope("paged_gather"):
-                        pages = base + ring_tables
-                        n = ring * p_tok
-                        keys = arena["k"][pages].reshape(
-                            b, n, kv, lanes
-                        )[..., :hd]
-                        values = arena["v"][pages].reshape(
-                            b, n, kv, lanes
-                        )[..., :hd]
-                    _m, norm, acc = _softmax_block(
-                        _softmax_start(b, 1, kv, reps, hd),
-                        q.reshape(b, 1, kv, reps, hd), keys, values, seen,
-                        hd ** -0.5,
-                    )
-                    attn = (acc / norm[..., None]).astype(config.dtype)
-                x = _attention_residual(
-                    config, layer, x, normed, attn.reshape(b, 1, h * hd)
-                )
-            return x, arena
 
-    if config.one_kind:
-        n_pages = cache["k"].shape[1]
-        experts, scanned = _held_experts(params["layers"])
+def _window_step_part(config, cache, ring_tables, pos, rows, offset, live):
+    """A decode step's part of a window layer, as ``_full_step_part`` a
+    full layer's: the row's new K/V into its ring ``ring_tables[s]``,
+    the last ``sliding_window`` positions read from it (the page walk
+    over the ring's pages in virtual order with a lower bound on the
+    entries that count, or the ring gathered and masked by what each
+    entry holds: ``_ring_positions``)."""
+    from dcos_commons_tpu.ops.paged_decode import window_decode_attention
 
-        def layer_fn(carry, inputs):
-            layer, base = inputs
-            x, arena = attend(*carry, layer, base)
-            x, counts = _serve_ffn(
-                config, layer, x, live, experts, base // n_pages
-            )
-            return (x, arena), counts
-
-        x, new_cache, counts = _scan_layers_over_arena(
-            layer_fn, x, scanned, cache
+    h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    p_tok, lanes = cache["k_window"].shape[2], cache["k_window"].shape[-1]
+    b, ring = ring_tables.shape
+    window, reps = config.sliding_window, h // kv
+    ring_phys = ring_tables[rows, (pos // p_tok) % ring]
+    kernel = decode_attention_kernel(config, cache)
+    if not kernel:
+        # the position each ring entry of each row holds once the
+        # row's new K/V is written
+        held = _ring_positions(
+            jnp.arange(ring * p_tok, dtype=jnp.int32)[None, :],
+            (pos // p_tok)[:, None], ring, p_tok,
         )
-        counts = None if counts is None else counts.sum(0)
-    else:
-        x, new_cache, counts = _walk_pattern(
-            config, params, cache, x, attend,
-            _conv_step_operator(config, live), live, attend_window,
+        seen = (
+            (held <= pos[:, None]) & (held > pos[:, None] - window)
+            & (held >= 0)
+        )[:, None, :]                            # [b, 1, ring * P]
+
+    def attend(arena, layer, base, q, k_new, v_new):
+        arena = _write_rows(
+            arena, base, ring_phys, offset, _rows(k_new, 1), _rows(v_new, 1)
         )
-    with jax.named_scope("logits"):
-        x = _norm(config, x, params["final_norm"])
-        logits = _last_logits(config, params, x[:, 0])
-    return logits, new_cache, counts
+        with jax.named_scope("attention_window"):
+            if kernel:
+                return window_decode_attention(
+                    jnp.pad(_rows(q, 1), ((0, 0), (0, 0), (0, lanes - hd))),
+                    arena["k"], arena["v"], base + ring_tables, pos,
+                    live, window=window, scale=hd ** -0.5,
+                    interpret=kernel == "interpret",
+                )[..., :hd], arena
+            keys, values, _ = _gather_pages(arena, base, ring_tables, b, hd)
+            _m, norm, acc = _softmax_block(
+                _softmax_start(b, 1, kv, reps, hd),
+                q.reshape(b, 1, kv, reps, hd), keys, values, seen,
+                hd ** -0.5,
+            )
+            return (acc / norm[..., None]).astype(config.dtype), arena
+
+    return Part(b, attend)
 
 
 def _eva_geometry(config: TransformerConfig, cache, table_len: int):
@@ -1378,11 +1210,11 @@ def chunk_carries_riders(config: TransformerConfig) -> bool:
     return config.attention == "eva"
 
 
-def _eva_chunk_part(config, cache, table, start, true_len, c):
-    """A prefill chunk's part of an EVA layer: ``(positions [c],
-    attend)``, where ``attend(arena, layer, base, q, k_new, v_new)``
-    takes the chunk's roped projections (``[c, heads, hd]``), attends
-    and writes, and returns ``(attn [c, h, hd], arena)``.
+def _eva_chunk_part(config, cache, table, start, true_len, offs, abs_pos,
+                    live):
+    """A prefill chunk's part of an EVA layer (``Part``), for the
+    positions ``abs_pos = start + offs`` of which the first
+    ``true_len`` are ``live``.
 
     The row's table has two regions (serve/paging.py RowLayout): the
     first ``window_size / P`` entries are a RING of exact K/V pages
@@ -1407,7 +1239,7 @@ def _eva_chunk_part(config, cache, table, start, true_len, c):
     4,096 old entries a query where a chunk half way through an
     8,192-byte prompt can see 1,300."""
     h, kv, hd = config.n_heads, config.n_kv_heads, config.head_dim
-    win, chunk = config.window_size, config.chunk_size
+    win, chunk, c = config.window_size, config.chunk_size, offs.shape[0]
     if c % chunk or c > win:
         raise ValueError(
             f"an eva prefill chunk is a whole number of {chunk}-position "
@@ -1416,13 +1248,9 @@ def _eva_chunk_part(config, cache, table, start, true_len, c):
     p_tok = cache["k"].shape[2]
     wp, sp = _eva_geometry(config, cache, table.shape[0])
     per_win, n_c, reps = win // chunk, c // chunk, h // kv
-    start = jnp.asarray(start, jnp.int32)
-    true_len = jnp.asarray(true_len, jnp.int32)
-    offs = jnp.arange(c, dtype=jnp.int32)
-    abs_pos = start + offs
     q_win = abs_pos // win                       # each query's window
     # exact K/V: into the ring (pad positions into the trash page)
-    phys = jnp.where(offs < true_len, table[(abs_pos % win) // p_tok], 0)
+    phys = jnp.where(live, table[(abs_pos % win) // p_tok], 0)
     slot_off = abs_pos % p_tok
     # the summaries this chunk finishes: into the summary region
     chunk_id = start // chunk + jnp.arange(n_c, dtype=jnp.int32)
@@ -1457,6 +1285,7 @@ def _eva_chunk_part(config, cache, table, start, true_len, c):
     scale = hd ** -0.5
 
     def attend(arena, layer, base, q, k_new, v_new):
+        q, k_new, v_new = (_rows(a) for a in (q, k_new, v_new))
         with jax.named_scope("eva_summarise"):
             k_sum_new, v_sum_new = _eva_summaries(
                 config, layer,
@@ -1517,14 +1346,14 @@ def _eva_chunk_part(config, cache, table, start, true_len, c):
         with jax.named_scope("attention"):
             _m, norm, acc = state
             attn = (acc / norm[..., None]).astype(config.dtype)
-        return attn[0], arena
+        return attn[0].reshape(c, h * hd), arena
 
-    return abs_pos, attend
+    return Part(c, attend)
 
 
 def _eva_step_part(config, cache, pos, tables):
     """A decode step's part of an EVA layer, as ``_eva_chunk_part``
-    gives a chunk's: ``(positions [b], attend)``.  Each row writes its
+    gives a chunk's.  Each row writes its
     new K/V into its ring, attends to its window's ring entries
     ``<= pos`` and the summaries of the windows before, and, where
     ``pos`` ends a chunk, pools that chunk's page into its summary
@@ -1540,7 +1369,6 @@ def _eva_step_part(config, cache, pos, tables):
     p_tok = cache["k"].shape[2]
     wp, sp = _eva_geometry(config, cache, tables.shape[1])
     per_win, reps = win // chunk, h // kv
-    pos = jnp.asarray(pos, jnp.int32)
     rows = jnp.arange(b)
     ring_page = (pos % win) // p_tok
     phys = tables[rows, ring_page]
@@ -1569,6 +1397,7 @@ def _eva_step_part(config, cache, pos, tables):
         )                                        # [b, 1, sp * P]
 
     def attend(arena, layer, base, q, k_new, v_new):
+        q, k_new, v_new = (_rows(a) for a in (q, k_new, v_new))
         with jax.named_scope("kv_write"):
             arena = {
                 "k": arena["k"].at[base + phys, slot_off].set(k_new),
@@ -1615,92 +1444,179 @@ def _eva_step_part(config, cache, pos, tables):
                 "k": arena["k"].at[base + sum_phys, sum_off].set(k_sum_new),
                 "v": arena["v"].at[base + sum_phys, sum_off].set(v_sum_new),
             }
-        return attn, arena
+        return attn.reshape(b, h * hd), arena
 
-    return pos, attend
-
-
-def _eva_trunk(config, params, cache, x, positions, parts):
-    """``x [B, S, d]`` at ``positions [B, S]`` through every EVA layer
-    over the arena: the norms, the q/k/v and output projections and
-    the FFN take ALL of ``x`` as one operand, so each weight is read
-    once; between the projections each of ``parts`` (``(rows,
-    attend)`` of ``_eva_chunk_part`` / ``_eva_step_part``, covering
-    ``x``'s ``B * S`` rows in order) attends and writes for its own
-    rows.  A chunk alone, a step alone, or a chunk with a step riding
-    behind it (whose rows' pages are other rows' than the chunk's).
-    Returns (x, the cache in its stored layout)."""
-    h, hd = config.n_heads, config.head_dim
-
-    def layer_fn(carry, inputs):
-        x, arena = carry
-        layer, base = inputs
-        with jax.named_scope("attention"):
-            normed = _norm(config, x, layer["attn_norm"])
-            q, k_new, v_new = (
-                a.reshape((-1,) + a.shape[2:])
-                for a in _project_kv(config, layer, normed, positions)
-            )
-        attn, lo = [], 0
-        for n, attend in parts:
-            rows = slice(lo, lo + n)
-            out, arena = attend(
-                arena, layer, base, q[rows], k_new[rows], v_new[rows]
-            )
-            attn.append(out.reshape(n, h * hd))
-            lo += n
-        with jax.named_scope("attention"):
-            attn = attn[0] if len(attn) == 1 else jnp.concatenate(attn)
-            x = x + attn.reshape(x.shape[:2] + (h * hd,)) @ dq(
-                layer["wo"], x.dtype
-            )
-        x, _counts = _serve_ffn(config, layer, x)
-        return (x, arena), None
-
-    x, new_cache, _ = _scan_layers_over_arena(
-        layer_fn, x, params["layers"], cache
-    )
-    return x, new_cache
+    return Part(b, attend)
 
 
-def _eva_prefill_chunk(config, params, cache, tokens, table, start,
-                       true_len, riders=None):
-    """``paged_prefill_chunk`` for ``attention == "eva"``: the chunk's
-    ``c`` positions (``_eva_chunk_part``) and, behind them in the same
-    operand, the ``riders``' rows (``(token, pos, tables)`` of a decode
-    step, ``_eva_step_part``).  Returns (the chunk's logits, the
-    cache) and with riders their logits ``[slots, vocab]`` too."""
-    c = tokens.shape[1]
-    abs_pos, chunk_attend = _eva_chunk_part(
-        config, cache, table, start, true_len, c
-    )
-    x = params["embed"][tokens].astype(config.dtype)
-    positions, parts = abs_pos, [(c, chunk_attend)]
+def _step_parts(config, cache, pos, tables, ring_pages):
+    """A decode step's attention parts by layer kind, and the rows
+    that are live (None where no layer asks: EVA's kernel finds them)."""
+    if config.attention == "eva":
+        part = _eva_step_part(config, cache, pos, tables)
+        return {"attention": [part]}, None
+    ring = _ring_pages(config, ring_pages)
+    # a live row holds at least its first page
+    live = tables[:, 0] > 0
+    rows = (pos, jnp.arange(pos.shape[0]), pos % cache["k"].shape[2], live)
+    # the ring's entries come first in a row's table, then the history
+    parts = {"attention": [_full_step_part(
+        config, cache, tables[:, ring:] if ring else tables, *rows
+    )]}
+    if ring:
+        parts["sliding"] = [
+            _window_step_part(config, cache, tables[:, :ring], *rows)
+        ]
+    return parts, live
+
+
+def paged_prefill_chunk(
+    config: TransformerConfig,
+    params: Params,
+    cache: Dict[str, jax.Array],
+    tokens: jax.Array,
+    table: jax.Array,
+    start: jax.Array,
+    true_len: jax.Array,
+    slot: jax.Array = 0,
+    riders: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
+    ring_pages: int = 0,
+) -> Tuple[jax.Array, Dict[str, jax.Array], Optional[jax.Array]]:
+    """One CHUNK of a prompt through the trunk into a paged arena.
+
+    ``tokens [1, C]`` carries up to C prompt tokens at virtual
+    positions ``[start, start + true_len)`` of one request whose page
+    table is ``table [M]`` (physical page per virtual page; 0 =
+    unallocated).  Each attention layer writes what the chunk keeps
+    through the table and attends to every earlier position through it
+    (what an entry is and who sees it is the attention class's:
+    ``_full_chunk_part``, ``_window_chunk_part``, ``_eva_chunk_part``).
+    Returns (logits at the chunk's last real position [1, vocab] f32,
+    updated cache, the mixtures' counts as ``paged_decode_step``
+    returns them).
+
+    ``start``, ``true_len`` and ``slot`` are TRACED: one compile covers
+    every chunk of every prompt — a request resuming at position k*P
+    after a prefix-cache hit runs the same program as one starting at
+    0.  ``slot`` is the row's place among the pool's rows: what a
+    pattern with conv layers keeps of a row outside its pages lives
+    there (``init_paged_kv_cache``), and a chunk with ``start == 0``
+    begins it from zeros; no other model reads it.  A long prompt costs
+    several SMALL dispatches between decode ticks, not one that blocks
+    the pool (head-of-line TTFT).  A mixture computes for the true
+    positions alone: the padding reaches no expert.
+
+    ``riders`` is a decode step over the whole pool, ``(token [S],
+    pos [S], tables [S, M])`` as ``paged_decode_step`` takes them, that
+    rides in this program (``chunk_carries_riders``): its rows are a
+    second part of every layer (``_attention_layer``), so the weights
+    are read once for both.  The result is then a 4-tuple, the riders'
+    logits ``[S, vocab]`` f32 last: what the chunk alone followed by
+    ``paged_decode_step`` gives.
+
+    ``ring_pages`` (static; serve/paging.py RowLayout): where the
+    pattern has window layers, the first ``ring_pages`` entries of
+    ``table`` are the row's ring in the window layers' arena and the
+    rest the full layers' history.
+    """
+    b, c = tokens.shape
+    if b != 1:
+        raise ValueError(f"prefill chunks are per-request, got batch {b}")
+    if riders is not None and not chunk_carries_riders(config):
+        raise NotImplementedError(
+            f"no layer of attention {config.attention!r} runs a decode "
+            "step's rows beside a chunk's positions"
+        )
+    start = jnp.asarray(start, jnp.int32)
+    true_len = jnp.asarray(true_len, jnp.int32)
+    offs = jnp.arange(c, dtype=jnp.int32)
+    positions = start + offs                     # [c] virtual positions
+    live = offs < true_len
+    if config.attention == "eva":
+        parts = {"attention": [_eva_chunk_part(
+            config, cache, table, start, true_len, offs, positions, live
+        )]}
+    else:
+        ring = _ring_pages(config, ring_pages)
+        rows = (
+            positions, positions % cache["k"].shape[2], live,
+            start + true_len,
+        )
+        parts = {"attention": [_full_chunk_part(
+            config, cache, table[ring:] if ring else table, *rows
+        )]}
+        if ring:
+            parts["sliding"] = [
+                _window_chunk_part(config, cache, table[:ring], *rows)
+            ]
+    x = _embed(config, params, tokens)
     if riders is not None:
         token, pos, tables = riders
-        pos, step_attend = _eva_step_part(config, cache, pos, tables)
-        x = jnp.concatenate(
-            [x, params["embed"][token][None].astype(config.dtype)], axis=1
-        )
-        positions = jnp.concatenate([abs_pos, pos])
-        parts.append((pos.shape[0], step_attend))
-    x, new_cache = _eva_trunk(
-        config, params, cache, x, positions[None], parts
+        pos = jnp.asarray(pos, jnp.int32)
+        step, _live = _step_parts(config, cache, pos, tables, ring_pages)
+        parts = {kind: parts[kind] + step[kind] for kind in parts}
+        x = jnp.concatenate([x, _embed(config, params, token)[None]], axis=1)
+        positions = jnp.concatenate([positions, pos])
+    x, new_cache, counts = _serving_trunk(
+        config, params, cache, x, positions[None], parts,
+        _conv_chunk_operator(
+            config, start, true_len, jnp.asarray(slot, jnp.int32)
+        ), live,
     )
     with jax.named_scope("logits"):
-        # the rows anybody reads: the chunk's last real position, and
-        # every rider
-        rows = lax.dynamic_index_in_dim(
-            x, jnp.asarray(true_len, jnp.int32) - 1, axis=1, keepdims=False
+        last = functools.partial(
+            lax.dynamic_index_in_dim, index=true_len - 1, axis=1,
+            keepdims=False,
         )
-        if riders is not None:
-            rows = jnp.concatenate([rows, x[0, c:]])
-        logits = _last_logits(
-            config, params, _norm(config, rows, params["final_norm"])
-        )
+        if chunk_carries_riders(config):
+            # where riders may ride, the rows anybody reads are picked
+            # before the norm: the chunk's last real position, the riders
+            rows = last(x)
+            if riders is not None:
+                rows = jnp.concatenate([rows, x[0, c:]])
+            rows = _norm(config, rows, params["final_norm"])
+        else:
+            rows = last(_norm(config, x, params["final_norm"]))
+        logits = _last_logits(config, params, rows)
     if riders is None:
-        return logits, new_cache
-    return logits[:1], new_cache, logits[1:]
+        return logits, new_cache, counts
+    return logits[:1], new_cache, counts, logits[1:]
+
+
+def paged_decode_step(
+    config: TransformerConfig,
+    params: Params,
+    cache: Dict[str, jax.Array],
+    token: jax.Array,
+    pos: jax.Array,
+    tables: jax.Array,
+    ring_pages: int = 0,
+) -> Tuple[jax.Array, Dict[str, jax.Array], Optional[jax.Array]]:
+    """One autoregressive step over the whole pool, KV indirected
+    through per-row page tables: ``token [S]`` at per-row positions
+    ``pos [S]``, ``tables [S, M]`` mapping each row's virtual pages to
+    arena pages -> (logits [S, vocab] f32, updated cache, counts).
+
+    Each attention layer writes the row's new entry through its table
+    and attends through it (``_full_step_part``, ``_window_step_part``,
+    ``_eva_step_part``).  A row whose table is all zeros holds no live
+    request: it writes into the trash page, reaches no expert of a
+    mixture and leaves its slot's conv state as it was (the slot may
+    belong to a row that is still prefilling).  ``counts`` is int32
+    ``[2]``: the live (token, expert) assignments the step's mixtures
+    routed and the expert groups that held at least one, summed over
+    the expert layers; None where the model routes nothing.
+    ``ring_pages`` as ``paged_prefill_chunk`` takes it."""
+    pos = jnp.asarray(pos, jnp.int32)
+    parts, live = _step_parts(config, cache, pos, tables, ring_pages)
+    x, new_cache, counts = _serving_trunk(
+        config, params, cache, _embed(config, params, token)[:, None, :],
+        pos[:, None], parts, _conv_step_operator(config, live), live,
+    )
+    with jax.named_scope("logits"):
+        x = _norm(config, x, params["final_norm"])
+        logits = _last_logits(config, params, x[:, 0])
+    return logits, new_cache, counts
 
 
 def decode_attention_kernel(config: TransformerConfig, cache):
@@ -1747,21 +1663,6 @@ def decode_attention_step(config: TransformerConfig, cache) -> dict:
     if config.n_layers_of("sliding"):
         steps["paged_decode_attention_window"] = walk_step(cache["k_window"])
     return steps
-
-
-def _eva_decode_step(config, params, cache, token, pos, tables):
-    """``paged_decode_step`` for ``attention == "eva"`` (the table's
-    two regions: ``_eva_chunk_part``; what a row does a layer:
-    ``_eva_step_part``)."""
-    pos, attend = _eva_step_part(config, cache, pos, tables)
-    x = params["embed"][token][:, None, :].astype(config.dtype)
-    x, new_cache = _eva_trunk(
-        config, params, cache, x, pos[:, None], [(pos.shape[0], attend)]
-    )
-    with jax.named_scope("logits"):
-        x = _norm(config, x, params["final_norm"])
-        logits = _last_logits(config, params, x[:, 0])
-    return logits, new_cache
 
 
 def generate(
@@ -1812,21 +1713,11 @@ def generate(
     )
     key = key if key is not None else jax.random.key(0)
     temp = jnp.asarray(temperature, jnp.float32)
-
-    def pick(logits, key):
-        # both branches are a few FLOPs on [b, vocab]; selecting
-        # beats a cond because temperature stays a traced operand
-        sampled = jax.random.categorical(
-            key, logits / jnp.maximum(temp, 1e-6), axis=-1
-        )
-        greedy = jnp.argmax(logits, axis=-1)
-        return jnp.where(temp > 0.0, sampled, greedy).astype(jnp.int32)
-
     # split once up front: the prefill pick and the scan step keys must
     # be derived from DISTINCT keys, or the first sampled token's
     # randomness correlates with the step keys (PRNG key reuse)
     first_key, rest_key = jax.random.split(key)
-    first = pick(logits, first_key)
+    first = sample_token(logits, temp, first_key)
     start = (
         jnp.asarray(true_len, jnp.int32) if true_len is not None
         else jnp.int32(s)
@@ -1835,7 +1726,7 @@ def generate(
     def step(carry, step_key):
         token, pos, cache = carry
         logits, cache = decode_step(config, params, cache, token, pos)
-        nxt = pick(logits, step_key)
+        nxt = sample_token(logits, temp, step_key)
         return (nxt, pos + 1, cache), token
 
     keys = jax.random.split(rest_key, max_new_tokens)

@@ -3,7 +3,7 @@
 One query a row a head against the row's LIVE entries: the exact keys
 of its current window (ring pages) and the chunk summaries of the
 windows before it (summary pages), one softmax over both
-(models/decode.py ``_eva_decode_step``).  XLA can only gather a row's
+(models/decode.py ``_eva_step_part``).  XLA can only gather a row's
 whole table into a dense buffer first, live or not, and read that
 again: at 24 rows of 256 pages that is 4.8 GB a layer for 0.4 GB of
 live entries.  ``live_pages`` lists each row's live pages and the page

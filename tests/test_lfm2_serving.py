@@ -419,11 +419,15 @@ ONE_KIND = {
                     chunk_size=4, norm_unit_offset=True,
                     tie_embeddings=False, n_pred_heads=8,
                     rope_theta=100000.0, rms_norm_eps=1e-5),
-        # the two programs as ISSUE 40 left them: the same equations
-        # from ``_eva_chunk_part`` / ``_eva_step_part`` over one trunk
-        # (``_eva_trunk``), traced in another order, and the chunk
-        # norms the one row whose logits it returns
-        tree="8cd45ac75946971e", prefill="362c53e717b4c27c",
+        # the two programs as ISSUE 40 left them (the chunk norms the
+        # one row whose logits it returns); ``prefill`` re-taken at
+        # ISSUE 52, whose one trunk for every family
+        # (``_serving_trunk``) traces the chunk's positions before the
+        # EVA part asks for them: the same equations in another order,
+        # which tests/test_serving_programs.py holds by multiset
+        # against the commit before (was 362c53e717b4c27c); ``decode``
+        # kept its order
+        tree="8cd45ac75946971e", prefill="170c5dd5aaf2f172",
         decode="38fb3c32658004c4",
     ),
 }
